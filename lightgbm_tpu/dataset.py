@@ -506,7 +506,7 @@ class Dataset:
         try:
             tables = ING.build_ingest_tables(self)
         except ING.IngestUnsupported as e:
-            ING.demote(str(e), warn=False)
+            ING.demote(str(e), elected_by="unsupported", warn=False)
             self._ingest = {}
             return None
         plan = plan_ingest(
@@ -533,8 +533,9 @@ class Dataset:
         """Bin ``raw`` into ``out`` on device when the election says
         so.  True only when every byte was committed device-side and
         the salted parity probe passed first (byte-identical to
-        ``_bin_block`` by contract); any failure re-zeroes ``out`` and
-        returns False so the host oracle runs."""
+        ``_bin_block`` by contract); a probe mismatch returns False so
+        the host oracle runs.  A kernel error is not a verdict: it
+        propagates."""
         from .ops import ingest as ING
         if sp is not None:
             return False
@@ -552,44 +553,38 @@ class Dataset:
         import time as _time
 
         from .obs.trace import span as _span
-        try:
-            if not st["probed"]:
-                with _span("ingest.parity_probe"):
-                    if not ING.parity_probe(binner, self, raw):
-                        ING.demote(
-                            "parity probe: device bytes diverge from "
-                            "host value_to_bin")
-                        self._ingest = {}
-                        return False
-                st["probed"] = True
-            import jax
+        if not st["probed"]:
+            with _span("ingest.parity_probe"):
+                if not ING.parity_probe(binner, self, raw):
+                    ING.demote(
+                        "parity probe: device bytes diverge from "
+                        "host value_to_bin", elected_by="parity_probe")
+                    self._ingest = {}
+                    return False
+            st["probed"] = True
+        import jax
 
-            from .data.stream import IngestPump
-            local = jax.local_devices()
-            devices = local if len(local) > 1 else None
-            t0 = _time.perf_counter()
-            with _span("ingest.device_bin", rows=n,
-                       chunk_rows=plan.chunk_rows,
-                       tile_rows=plan.tile_rows):
-                for _i, start, rows, chunk in IngestPump(
-                        raw, plan.chunk_rows, devices=devices):
-                    out[start:start + rows] = np.asarray(binner(chunk))
-            dt = _time.perf_counter() - t0
-            rps = round(n / max(dt, 1e-9), 1)
-            ING.record_ingest_story(
-                path="kernel", elected_by=plan.elected_by, rows=n,
-                chunk_rows=plan.chunk_rows, tile_rows=plan.tile_rows,
-                bin_seconds=round(dt, 4), bin_rows_per_sec=rps,
-                parity_probe=True)
-            from .obs.metrics import global_registry
-            global_registry.counter("ingest_rows_total").inc(n)
-            global_registry.gauge("bin_rows_per_sec").set(rps)
-            return True
-        except Exception as e:    # lowering/OOM/backend loss — any of it
-            out[:] = 0            # the host fold assumes zero-init
-            ING.demote(f"{type(e).__name__}: {str(e)[:200]}")
-            self._ingest = {}
-            return False
+        from .data.stream import IngestPump
+        local = jax.local_devices()
+        devices = local if len(local) > 1 else None
+        t0 = _time.perf_counter()
+        with _span("ingest.device_bin", rows=n,
+                   chunk_rows=plan.chunk_rows,
+                   tile_rows=plan.tile_rows):
+            for _i, start, rows, chunk in IngestPump(
+                    raw, plan.chunk_rows, devices=devices):
+                out[start:start + rows] = np.asarray(binner(chunk))
+        dt = _time.perf_counter() - t0
+        rps = round(n / max(dt, 1e-9), 1)
+        ING.record_ingest_story(
+            path="kernel", elected_by=plan.elected_by, rows=n,
+            chunk_rows=plan.chunk_rows, tile_rows=plan.tile_rows,
+            bin_seconds=round(dt, 4), bin_rows_per_sec=rps,
+            parity_probe=True)
+        from .obs.metrics import global_registry
+        global_registry.counter("ingest_rows_total").inc(n)
+        global_registry.gauge("bin_rows_per_sec").set(rps)
+        return True
 
     # -- streaming construction (reference: LGBM_DatasetCreateFromSampledColumn
     #    + LGBM_DatasetPushRows / PushRowsByCSR, c_api.h:98-144) -------------

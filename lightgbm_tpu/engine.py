@@ -64,9 +64,10 @@ def train(params: dict, train_set: Dataset, num_boost_round: int = 100,
     BIT-IDENTICAL to the uninterrupted one; corrupt newest bundles are
     skipped in favor of the previous verified one (docs/RESILIENCE.md).
 
-    ``LGBM_TPU_COMPILE_CACHE=<dir>`` enables the persistent XLA
-    compilation cache at engine init (docs/PERF.md): repeated trainings
-    of same-shaped programs skip XLA entirely on the warm path.
+    The persistent XLA compilation cache is on from engine init
+    (``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``
+    — utils/platform.py): repeated trainings of same-shaped programs
+    skip XLA entirely on the warm path.
 
     ``pause_control`` is the co-resident brownout seam
     (coresident/control.py, duck-typed): consulted at every chunk

@@ -28,7 +28,7 @@ classes, residency replans), ``topology`` (multi-device placement
 planner: replicate hot models, partition the cold tail), ``router``
 (PodFleet: health-scored routing, hedged retries, brownout degradation,
 device-loss failover), ``aot`` (jax.export serialize/restore of
-bucket programs under LGBM_TPU_COMPILE_CACHE/serving), ``lowprec``
+bucket programs under the compile-cache dir's serving/), ``lowprec``
 (bf16/int8 forest quantization + the accuracy-budget measurement).
 The single-model building blocks stay in ``lightgbm_tpu.serving``.
 """

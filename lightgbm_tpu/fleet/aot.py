@@ -1,8 +1,8 @@
 """AOT-serialized serving programs: compile-free cold start.
 
-PR 5 gave training a persistent XLA compile cache behind
-``LGBM_TPU_COMPILE_CACHE``; this module extends the same cache directory
-to SERVING buckets.  ``AOTStore.export_device_forest`` serializes each
+Training persists its compiled programs in the compile-cache directory
+(``utils.platform.compile_cache_dir``); this module extends the same
+directory to SERVING buckets.  ``AOTStore.export_device_forest`` serializes each
 (model digest, bucket) routing program with ``jax.export`` — the traced,
 lowered StableHLO with the forest arrays baked in as constants — into
 ``<cache>/serving/``; a fresh replica then builds its bucket programs by
@@ -36,14 +36,12 @@ AOT_VERSION = 1
 _SUBDIR = "serving"
 
 
-def aot_dir_from_env() -> Optional[str]:
-    """``LGBM_TPU_COMPILE_CACHE=<dir>`` -> ``<dir>/serving``, or None
-    when the persistent cache is disabled (same off-switch spellings as
-    ``utils.platform.enable_compile_cache``)."""
-    d = os.environ.get("LGBM_TPU_COMPILE_CACHE", "").strip()
-    if not d or d.lower() in ("0", "off", "none"):
-        return None
-    return os.path.join(d, _SUBDIR)
+def aot_dir_from_env() -> str:
+    """The default AOT store: ``serving/`` inside the compile-cache
+    directory (``JAX_COMPILATION_CACHE_DIR`` when set, else
+    ``<checkout>/.jax_cache`` — ``utils.platform.compile_cache_dir``)."""
+    from ..utils.platform import compile_cache_dir
+    return os.path.join(compile_cache_dir(), _SUBDIR)
 
 
 class AOTStore:

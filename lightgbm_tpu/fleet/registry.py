@@ -66,7 +66,7 @@ class FleetConfig:
 
     max_queue_rows: int = 1 << 16       # fleet-wide admission budget
     hbm_budget_bytes: Optional[int] = None   # None = planner-measured limit
-    aot_dir: Optional[str] = None       # None = LGBM_TPU_COMPILE_CACHE/serving
+    aot_dir: Optional[str] = None       # None = <compile cache>/serving
     backend: str = "device"             # default per-model backend
     min_bucket_rows: int = 8            # default per-model ladder
     max_batch_rows: int = 1024

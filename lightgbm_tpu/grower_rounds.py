@@ -419,7 +419,7 @@ def _grow_tree_rounds_traced(
         # The right-child leaf of the rank-r candidate is num_leaves + r,
         # so the update is pure arithmetic on the per-row candidate rank —
         # no [n]-sized gather from a leaf table (measured ~130 ms per
-        # gathered pass at 11M rows on v5e, tpu_probe_r5.json).
+        # gathered pass at 11M rows on v5e in a builder's r5 probe).
         new_leaf_id = jnp.where((crank < k) & ~gl,
                                 c.tree.num_leaves + crank, c.leaf_id)
 

@@ -11,7 +11,7 @@ function from a flat signal dict to an ordered list of verdicts, so the
 same rules serve the ``obs_doctor`` CLI, the journaled bench stage, and
 the tests that inject each bottleneck.
 
-Verdict taxonomy (docs/OBSERVABILITY.md):
+Verdict catalogue (docs/OBSERVABILITY.md):
 
 - ``dcn-bound``        — the slow-tier wire time is a material fraction
                          of the iteration under the planner's link model;
@@ -231,7 +231,7 @@ def diagnose(signals: dict) -> List[Verdict]:
                 f"XLA compilation is {frac:.0%} of wall-clock "
                 f"({comp:.1f}s compile vs {train:.1f}s train); compile "
                 f"cache {'WARM — shapes are churning' if warm else 'COLD'}"
-                " — set LGBM_TPU_COMPILE_CACHE / stop varying shapes",
+                " — keep the compile cache dir across runs / stop varying shapes",
                 {"compile_seconds": comp, "train_seconds": train,
                  "fraction": round(frac, 4),
                  "compile_cache_warm": warm}))

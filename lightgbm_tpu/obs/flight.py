@@ -207,23 +207,13 @@ class FlightRecorder:
                     if k.startswith(_ENV_PREFIXES)},
             "context": dict(self._context),
         }
+        # jax.devices() INITIALIZES the default backend when none exists
+        # — multi-second TPU init from a crash path — so the device facts
+        # ride in ``context`` instead: training records them once its
+        # backend is live (GBDT._build_jit_fns)
         jax = sys.modules.get("jax")
-        if jax is not None:       # never initializes a backend here
-            try:
-                fp["jax_version"] = getattr(jax, "__version__", "")
-                # jax.devices() INITIALIZES the default backend when none
-                # exists — multi-second TPU init from a crash path; only
-                # report device facts a live backend already knows
-                from jax._src import xla_bridge
-                if getattr(xla_bridge, "_backends", None):
-                    devs = jax.devices()
-                    fp["backend"] = devs[0].platform
-                    fp["device_kind"] = getattr(devs[0], "device_kind", "")
-                    fp["n_devices"] = len(devs)
-                    fp["process_index"] = jax.process_index()
-                    fp["process_count"] = jax.process_count()
-            except Exception:  # noqa: BLE001 — uninitialized backend
-                pass
+        if jax is not None:
+            fp["jax_version"] = getattr(jax, "__version__", "")
         try:
             from .metrics import global_registry
             g = global_registry.to_dict().get("gauges", {})

@@ -69,11 +69,27 @@ grower runs (explicit ``hist_method=fused`` still honors a forced
 
 Off-accelerator the whole family runs under
 ``pl.pallas_call(..., interpret=True)`` so tier-1's ``JAX_PLATFORMS=cpu``
-pytest run executes the kernels instead of skipping them.
+pytest run executes the kernels instead of skipping them — and tier-1
+also COMPILES them for the chip (tests/test_chip_compile.py: a described
+v5e, no chip attached), because interpret mode cannot see what the
+chip's compiler refuses.
+
+**What runs on the chip.**  The accumulate half compiles and is what an
+accelerator runs.  The scan epilogue does not — the Pallas TPU lowering
+has no ``cumsum``, and Mosaic has no layout for the combined kernel's
+2-D→4-D arena view — so there the in-VMEM scan described above is not
+elected: ``interpret=None`` on an accelerator means accumulate kernel,
+then ``_derive_and_scan`` as plain XLA over the arena it emits (the same
+body, hence the same tuples; the ``[K, ch, F, B]`` arena makes one HBM
+round trip the single-kernel form would save).  The combined kernel and
+the standalone scan kernel stay for interpret mode and for the rewrite
+that can claim that saving; forcing them with ``interpret=False`` raises
+the compiler's error (docs/PERF.md "What compiles on the chip").
 """
 
 from __future__ import annotations
 
+import math
 import os
 from typing import Optional
 
@@ -95,6 +111,18 @@ _DEF_FEAT_TILE = 8
 
 def _interp(interpret: Optional[bool]) -> bool:
     return (not on_accelerator()) if interpret is None else bool(interpret)
+
+
+def _scan_in_xla(interpret: Optional[bool]) -> bool:
+    """Whether the gain scan runs as plain XLA over the kernel-built
+    arena instead of inside a Pallas kernel: what the election
+    (``interpret=None``) does on an accelerator, whose compiler has no
+    lowering for the scan epilogue (module docstring, "What runs on the
+    chip": ``Unimplemented primitive in Pallas TPU lowering ... cumsum``,
+    ``infer-vector-layout: unsupported shape cast``).  An explicit
+    ``interpret=False`` still asks for the in-kernel form and raises that
+    error; it is never demoted."""
+    return interpret is None and on_accelerator()
 
 
 def hist_scan_traffic_bytes(num_candidates: int, num_features: int,
@@ -148,6 +176,39 @@ def _derive_and_scan(small, sums_k, meta_rows, hp,
         monotone_constraints=mono, leaf_output_bounds=bounds)
 
 
+# dtypes of the six per-feature-best tuple planes the scan emits, in
+# NumericFeatureBest order (gain, threshold, default_left, left sums)
+_TUPLE_DTYPES = (jnp.float32, jnp.int32, jnp.int32,
+                 jnp.float32, jnp.float32, jnp.float32)
+
+
+def _feature_blocked(a: jax.Array, Ft: int) -> jax.Array:
+    """[R, F_pad] -> [F_pad // Ft, R, Ft]: one feature block per leading
+    index, so a kernel's per-block window ``(None, R, Ft)`` spans the
+    whole of the two minor dims (the TPU lowering takes no (R, Ft) window
+    of an [R, F_pad] array unless it is a whole (8, 128) tile)."""
+    R, F_pad = a.shape
+    return a.reshape(R, F_pad // Ft, Ft).transpose(1, 0, 2)
+
+
+def _feature_unblocked(a: jax.Array) -> jax.Array:
+    """Inverse of ``_feature_blocked``: [nf, R, Ft] -> [R, nf * Ft]."""
+    nf, R, Ft = a.shape
+    return a.transpose(1, 0, 2).reshape(R, nf * Ft)
+
+
+def _arena_dims(K: int, B: int, Ft: int, quant: bool):
+    """(K_pad, B_pad): the slot and bin axes padded so the VMEM arena
+    ``[ch*K_pad, Ft*B_pad]`` and both one-hot operands sit on whole
+    (sublane, lane) tiles — slots to the sublane count (16 keeps the
+    int8 lhs ``[2*K_pad, C]`` on its 32-row tile), bins so one feature
+    block spans whole 128-lane groups.  Padded bins match no row and
+    padded slots only the dropped rows (slot == K); both are sliced off
+    outside the kernel."""
+    lane_groups = 128 // math.gcd(Ft, 128)
+    return _pad_rows(K, 16 if quant else 8), _pad_rows(B, max(lane_groups, 8))
+
+
 def _fused_call(
     binned_t: jax.Array,          # [F, n] uint8/uint16 feature-major
     vals_t: jax.Array,            # f32 [3, n] (g,h,1)*w  |  int8 [2, n]
@@ -176,6 +237,17 @@ def _fused_call(
     and returns only the histogram."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    if with_scan and _scan_in_xla(interpret):
+        hist = _fused_call(
+            binned_t, vals_t, slot, num_slots, num_bins, None, None, None,
+            feat_tile=feat_tile, block_rows=block_rows,
+            tile_rows=tile_rows, with_scan=False)
+        return hist, fused_sibling_scan(
+            hist, child_sums, *meta_vecs, hp, small_left=small_left,
+            parent_hist=parent_hist, quant_scales=quant_scales,
+            monotone_constraints=monotone_constraints,
+            child_bounds=child_bounds)
 
     quant = vals_t.dtype == jnp.int8
     ch = int(vals_t.shape[0])
@@ -208,6 +280,7 @@ def _fused_call(
 
     n_pad = _pad_rows(n, C)
     F_pad = _pad_rows(F, Ft)
+    Kp, Bp = _arena_dims(K, B, Ft, quant)
     bt = binned_t
     if n_pad != n or F_pad != F:
         bt = jnp.pad(bt, ((0, F_pad - F), (0, n_pad - n)))
@@ -217,9 +290,13 @@ def _fused_call(
     nf_blocks = F_pad // Ft
     nt = n_pad // C
 
-    in_arrays = [bt, vt, st]
+    # the binned matrix rides feature-BLOCKED [nf, Ft, n] (a free
+    # leading-dim split): the per-step window then spans the whole Ft
+    # axis, which the TPU lowering takes at any feature tile — a bare
+    # (Ft, C) window of [F, n] would need Ft % 8 == 0
+    in_arrays = [bt.reshape(nf_blocks, Ft, n_pad), vt, st]
     in_specs = [
-        pl.BlockSpec((Ft, C), lambda j, i: (j, i)),
+        pl.BlockSpec((None, Ft, C), lambda j, i: (j, 0, i)),
         pl.BlockSpec((ch, C), lambda j, i: (0, i)),
         pl.BlockSpec((1, C), lambda j, i: (0, i)),
     ]
@@ -244,8 +321,8 @@ def _fused_call(
         sums = jnp.asarray(child_sums, jnp.float32)        # [3, NC]
         in_arrays.append(sums)
         in_specs.append(pl.BlockSpec((3, NC), lambda j, i: (0, 0)))
-        in_arrays.append(meta)
-        in_specs.append(pl.BlockSpec((R, Ft), lambda j, i: (0, j)))
+        in_arrays.append(_feature_blocked(meta, Ft))
+        in_specs.append(pl.BlockSpec((None, R, Ft), lambda j, i: (j, 0, 0)))
         if quant:
             in_arrays.append(
                 jnp.stack([jnp.asarray(quant_scales[0], jnp.float32),
@@ -285,14 +362,18 @@ def _fused_call(
         def _init():
             acc[...] = jnp.zeros_like(acc)
 
-        _accumulate_tile(acc, b_ref, v_ref, s_ref, K, Ft, B, ch, quant)
+        _accumulate_tile(acc, b_ref, v_ref, s_ref, Kp, Ft, Bp, ch, quant)
 
-        # ---- epilogue after the last tile: derive + scan in VMEM ----
+        # ---- epilogue after the last tile: emit the 2-D arena as it
+        # sits in VMEM (the [K, ch, F, B] view is an XLA reshape outside
+        # the kernel — Mosaic has no such shape cast), then derive +
+        # scan in VMEM when the combined form is asked for
         @pl.when(i == nt - 1)
         def _epilogue():
-            small = acc[...].reshape(ch, K, Ft, B).transpose(1, 0, 2, 3)
-            hist_ref[...] = small
+            hist_ref[...] = acc[...]
             if with_scan:
+                small = acc[...].reshape(ch, Kp, Ft, Bp)[
+                    :, :K, :, :B].transpose(1, 0, 2, 3)
                 res = _derive_and_scan(
                     small, sum_ref[...],
                     (m_ref[0, :], m_ref[1, :], m_ref[2, :]), hp,
@@ -309,20 +390,14 @@ def _fused_call(
                 lh_ref[...] = res.left_sum_hess
                 lc_ref[...] = res.left_count
 
-    hist_spec = pl.BlockSpec((K, ch, Ft, B), lambda j, i: (0, 0, j, 0))
-    hist_shape = jax.ShapeDtypeStruct((K, ch, F_pad, B), acc_dtype)
-    tuple_spec = pl.BlockSpec((NC, Ft), lambda j, i: (0, j))
+    hist_spec = pl.BlockSpec((ch * Kp, Ft * Bp), lambda j, i: (0, j))
+    hist_shape = jax.ShapeDtypeStruct((ch * Kp, F_pad * Bp), acc_dtype)
+    tuple_spec = pl.BlockSpec((None, NC, Ft), lambda j, i: (j, 0, 0))
     if with_scan:
         out_specs = [hist_spec] + [tuple_spec] * 6
-        out_shape = [
-            hist_shape,
-            jax.ShapeDtypeStruct((NC, F_pad), jnp.float32),   # gain
-            jax.ShapeDtypeStruct((NC, F_pad), jnp.int32),     # threshold
-            jax.ShapeDtypeStruct((NC, F_pad), jnp.int32),     # default_left
-            jax.ShapeDtypeStruct((NC, F_pad), jnp.float32),   # left_sum_grad
-            jax.ShapeDtypeStruct((NC, F_pad), jnp.float32),   # left_sum_hess
-            jax.ShapeDtypeStruct((NC, F_pad), jnp.float32),   # left_count
-        ]
+        out_shape = [hist_shape] + [
+            jax.ShapeDtypeStruct((nf_blocks, NC, Ft), dt)
+            for dt in _TUPLE_DTYPES]
     else:
         out_specs = [hist_spec]
         out_shape = [hist_shape]
@@ -332,43 +407,59 @@ def _fused_call(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((ch * K, Ft * B), acc_dtype)],
+        scratch_shapes=[pltpu.VMEM((ch * Kp, Ft * Bp), acc_dtype)],
         interpret=_interp(interpret),
     )(*in_arrays)
+    hist = out[0].reshape(ch, Kp, F_pad, Bp)[
+        :, :K, :F, :B].transpose(1, 0, 2, 3)               # [K, ch, F, B]
     if not with_scan:
-        return out[0][:, :, :F, :]
-    hist, gain, thr, dl, lgs, lhs_, lcs = out
+        return hist
+    gain, thr, dl, lgs, lhs_, lcs = (_feature_unblocked(o) for o in out[1:])
     best = NumericFeatureBest(
         gain=gain[:, :F], threshold=thr[:, :F],
         default_left=dl[:, :F].astype(bool),
         left_sum_grad=lgs[:, :F], left_sum_hess=lhs_[:, :F],
         left_count=lcs[:, :F])
-    return hist[:, :, :F, :], best
+    return hist, best
 
 
 def _accumulate_tile(acc, b_ref, v_ref, s_ref, K, Ft, B, ch, quant):
     """One row tile of the slot-expanded one-hot matmul, accumulated
     into the VMEM arena — the accumulate half of the megakernel, shared
-    verbatim by the combined kernel and ``fused_frontier_accumulate``."""
+    verbatim by the combined kernel and ``fused_frontier_accumulate``.
+
+    Written in the forms the TPU compiler lowers: both one-hot operands
+    are built as 2-D sublane concatenations (no 3-D compare, no
+    minor-dim-merging reshape) and the bin one-hot stays TRANSPOSED
+    ``[Ft*B, C]`` — the contraction runs over the lane axis of both
+    operands (the q·kᵀ matmul form), so no in-kernel transpose is
+    needed.  ``K`` and ``B`` arrive padded to the sublane/lane tiling
+    (``_arena_dims``)."""
     blk = b_ref[...].astype(jnp.int32)                 # [Ft, C]
     C = blk.shape[1]
-    sl = s_ref[0, :]                                   # [C]
-    iota_s = lax.broadcasted_iota(jnp.int32, (K, C), 0)
-    oh_s = sl[None, :] == iota_s                       # [K, C]
+    oh_s = s_ref[...] == lax.broadcasted_iota(jnp.int32, (K, C), 0)
     v = v_ref[...]                                     # [ch, C]
-    iota_b = lax.broadcasted_iota(jnp.int32, (C, Ft, B), 2)
-    ohb = blk.T[:, :, None] == iota_b                  # [C, Ft, B]
+    iota_b = lax.broadcasted_iota(jnp.int32, (B, C), 0)
+    nt = (((1,), (1,)), ((), ()))                      # lhs · rhsᵀ
     if quant:
-        lhs = (v[:, None, :] * oh_s[None].astype(jnp.int8)
-               ).reshape(ch * K, C)
-        part = lax.dot(lhs, ohb.astype(jnp.int8).reshape(C, Ft * B),
-                       preferred_element_type=jnp.int32)
+        lhs = jnp.concatenate(
+            [jnp.where(oh_s, v[c:c + 1, :].astype(jnp.int32), 0)
+             for c in range(ch)], axis=0).astype(jnp.int8)   # [ch*K, C]
+        oh_bt = jnp.concatenate(
+            [(blk[f:f + 1, :] == iota_b).astype(jnp.int32)
+             for f in range(Ft)], axis=0).astype(jnp.int8)   # [Ft*B, C]
+        part = lax.dot_general(lhs, oh_bt, nt,
+                               preferred_element_type=jnp.int32)
     else:
-        lhs = (v[:, None, :] * oh_s[None].astype(jnp.float32)
-               ).reshape(ch * K, C)
-        part = lax.dot(lhs, ohb.astype(jnp.float32).reshape(C, Ft * B),
-                       precision=lax.Precision.HIGHEST,
-                       preferred_element_type=jnp.float32)
+        oh_sf = oh_s.astype(jnp.float32)
+        lhs = jnp.concatenate(
+            [v[c:c + 1, :] * oh_sf for c in range(ch)], axis=0)
+        oh_bt = jnp.concatenate(
+            [(blk[f:f + 1, :] == iota_b).astype(jnp.float32)
+             for f in range(Ft)], axis=0)
+        part = lax.dot_general(lhs, oh_bt, nt,
+                               precision=lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
     acc[...] += part
 
 
@@ -430,22 +521,34 @@ def fused_sibling_scan(
     NC = 2 * K if with_parent else K
     has_mono = monotone_constraints is not None
     has_bounds = child_bounds is not None
+    acc_dtype = jnp.int32 if quant else jnp.float32
+    meta_rows = [jnp.asarray(num_bin, jnp.int32),
+                 jnp.asarray(missing_type, jnp.int32),
+                 jnp.asarray(default_bin, jnp.int32)]
+    if has_mono:
+        meta_rows.append(jnp.asarray(monotone_constraints, jnp.int32))
+    if _scan_in_xla(interpret):
+        def f32(pair):
+            return tuple(jnp.asarray(x, jnp.float32) for x in pair)
+        return _derive_and_scan(
+            small_hist.astype(acc_dtype),
+            jnp.asarray(child_sums, jnp.float32), meta_rows[:3], hp,
+            parent=parent_hist.astype(acc_dtype) if with_parent else None,
+            s_is_left_vec=(small_left.astype(jnp.int32) if with_parent
+                           else None),
+            scales=f32(quant_scales) if quant else None,
+            mono=meta_rows[3] if has_mono else None,
+            bounds=f32(child_bounds) if has_bounds else None)
     if feat_tile is None:
         from .planner import plan_fused
         fp = plan_fused(K, B, bool(quant), with_parent=with_parent)
         feat_tile = fp["feat_tile"] if fp else 1
     Ft = max(1, min(int(feat_tile), F))
     F_pad = _pad_rows(F, Ft)
-    acc_dtype = jnp.int32 if quant else jnp.float32
 
     small = small_hist.astype(acc_dtype)
     if F_pad != F:
         small = jnp.pad(small, ((0, 0), (0, 0), (0, F_pad - F), (0, 0)))
-    meta_rows = [jnp.asarray(num_bin, jnp.int32),
-                 jnp.asarray(missing_type, jnp.int32),
-                 jnp.asarray(default_bin, jnp.int32)]
-    if has_mono:
-        meta_rows.append(jnp.asarray(monotone_constraints, jnp.int32))
     meta = jnp.stack(meta_rows)
     if F_pad != F:
         meta = jnp.pad(meta, ((0, 0), (0, F_pad - F)))
@@ -464,8 +567,8 @@ def fused_sibling_scan(
         in_specs.append(pl.BlockSpec((1, K), lambda j: (0, 0)))
     in_arrays.append(jnp.asarray(child_sums, jnp.float32))
     in_specs.append(pl.BlockSpec((3, NC), lambda j: (0, 0)))
-    in_arrays.append(meta)
-    in_specs.append(pl.BlockSpec((R, Ft), lambda j: (0, j)))
+    in_arrays.append(_feature_blocked(meta, Ft))
+    in_specs.append(pl.BlockSpec((None, R, Ft), lambda j: (j, 0, 0)))
     if quant:
         in_arrays.append(
             jnp.stack([jnp.asarray(quant_scales[0], jnp.float32),
@@ -508,23 +611,18 @@ def fused_sibling_scan(
         lh_ref[...] = res.left_sum_hess
         lc_ref[...] = res.left_count
 
-    tuple_spec = pl.BlockSpec((NC, Ft), lambda j: (0, j))
+    nf_blocks = F_pad // Ft
+    tuple_spec = pl.BlockSpec((None, NC, Ft), lambda j: (j, 0, 0))
     out = pl.pallas_call(
         kernel,
-        grid=(F_pad // Ft,),
+        grid=(nf_blocks,),
         in_specs=in_specs,
         out_specs=[tuple_spec] * 6,
-        out_shape=[
-            jax.ShapeDtypeStruct((NC, F_pad), jnp.float32),
-            jax.ShapeDtypeStruct((NC, F_pad), jnp.int32),
-            jax.ShapeDtypeStruct((NC, F_pad), jnp.int32),
-            jax.ShapeDtypeStruct((NC, F_pad), jnp.float32),
-            jax.ShapeDtypeStruct((NC, F_pad), jnp.float32),
-            jax.ShapeDtypeStruct((NC, F_pad), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((nf_blocks, NC, Ft), dt)
+                   for dt in _TUPLE_DTYPES],
         interpret=_interp(interpret),
     )(*in_arrays)
-    gain, thr, dl, lgs, lhs_, lcs = out
+    gain, thr, dl, lgs, lhs_, lcs = (_feature_unblocked(o) for o in out)
     return NumericFeatureBest(
         gain=gain[:, :F], threshold=thr[:, :F],
         default_left=dl[:, :F].astype(bool),
@@ -667,22 +765,22 @@ def pick_fused_best(best: NumericFeatureBest, sum_grad, sum_hess, num_data,
         jnp.zeros(f.shape + (MAX_CAT_WORDS,), jnp.uint32))
 
 
-# one-time per-backend verdict: does the fused megakernel COMPILE AND
-# AGREE with the staged pipeline on this backend?  {backend_name: bool}
+# one-time per-backend verdict: does the fused arm AGREE with the staged
+# pipeline on this backend?  {backend_name: bool}
 _FUSED_PROBE: dict = {}
 
 
 def fused_kernel_verified() -> bool:
-    """Compile + run the fused kernel at a tiny shape on the live backend
-    and check its tuples against the staged scan.
+    """Run the fused arm at a tiny shape on the live accelerator and
+    check its numbers against the staged pipeline.
 
-    The scan epilogue leans on ops (cumsum, argmax, take_along_axis)
-    whose Pallas/Mosaic lowering varies by backend and jax version; a
-    backend where any of them fails must NOT be elected by
-    ``hist_method=auto`` — it falls back to the staged family instead of
-    crashing the trace (same pattern as histogram.py's
-    ``_table_matmul_verified``).  Off-accelerator (interpret mode) the
-    kernel is plain jax — verified trivially."""
+    A NUMERIC probe only.  Whether the kernels compile is settled
+    before a chip is touched — tests/test_chip_compile.py compiles them
+    for a described v5e — so a compile or lowering error here is a
+    defect and propagates; nothing is caught.  A numeric mismatch
+    demotes ``hist_method=auto`` to the staged family, at warning
+    level.  Off-accelerator (interpret mode) the kernels are plain jax
+    — verified trivially."""
     backend = jax.default_backend()
     ok = _FUSED_PROBE.get(backend)
     if ok is not None:
@@ -690,67 +788,60 @@ def fused_kernel_verified() -> bool:
     if not on_accelerator():
         _FUSED_PROBE[backend] = True
         return True
-    try:
-        rng = np.random.RandomState(0)
-        F, n, B, K = 4, 256, 8, 2
-        binned = jnp.asarray(rng.randint(0, B - 1, (F, n)), jnp.uint8)
-        g = jnp.asarray(rng.randn(n), jnp.float32)
-        h = jnp.abs(g) + 0.1
-        vals = jnp.stack([g, h, jnp.ones_like(g)])
-        slot = jnp.asarray(rng.randint(0, K + 1, n), jnp.int32)
-        sums = []
-        for k in range(K):
-            m = np.asarray(slot) == k
-            sums.append([float(np.asarray(g)[m].sum()),
-                         float(np.asarray(h)[m].sum()), float(m.sum())])
-        sums = jnp.asarray(np.asarray(sums).T, jnp.float32)
-        nb = jnp.full((F,), B, jnp.int32)
-        zero = jnp.zeros((F,), jnp.int32)
-        hp = SplitHyperparams(min_data_in_leaf=1)
-        hist, best = jax.jit(
-            lambda b, v, s, su: fused_segment_splits(
-                b, v, s, K, B, su, nb, zero, zero, hp,
-                feat_tile=2, block_rows=128))(binned, vals, slot, sums)
-        # BOTH halves of the kernel are checked: the accumulated
-        # histograms against the staged scatter segment pass (a Mosaic
-        # mis-lowering of the slot-expanded dot would be internally
-        # consistent with the in-kernel scan, so scan parity alone
-        # cannot catch it), and the scan against the shared body
-        from .histogram import segment_histogram
-        ref_hist = segment_histogram(binned, g, h, jnp.ones_like(g),
-                                     slot, K, B)
-        ok = bool(np.allclose(np.asarray(hist), np.asarray(ref_hist),
-                              rtol=1e-4, atol=1e-3))
-        ref = numeric_feature_scan(hist.astype(jnp.float32), sums[0],
-                                   sums[1], sums[2], nb, zero, zero, hp)
-        ok = ok and bool(np.allclose(np.asarray(best.gain),
-                                     np.asarray(ref.gain), equal_nan=True))
-        # the seam halves ride the same backend verdict: accumulate-only
-        # must reproduce the combined kernel's arena, and the standalone
-        # scan the combined kernel's tuples
-        acc_only = jax.jit(
-            lambda b, v, s: fused_frontier_accumulate(
-                b, v, s, K, B, feat_tile=2, block_rows=128))(
-                    binned, vals, slot)
-        ok = ok and bool(np.allclose(np.asarray(acc_only),
-                                     np.asarray(hist), rtol=1e-4,
-                                     atol=1e-3))
-        scan_only = jax.jit(
-            lambda hh, su: fused_sibling_scan(
-                hh, su, nb, zero, zero, hp, feat_tile=2))(hist, sums)
-        ok = ok and bool(np.allclose(np.asarray(scan_only.gain),
-                                     np.asarray(best.gain),
-                                     equal_nan=True))
-    except Exception:
-        ok = False
+    rng = np.random.RandomState(0)
+    F, n, B, K = 4, 256, 8, 2
+    binned_np = rng.randint(0, B - 1, (F, n))
+    slot_np = rng.randint(0, K + 1, n)
+    binned = jnp.asarray(binned_np, jnp.uint8)
+    g = jnp.asarray(rng.randn(n), jnp.float32)
+    h = jnp.abs(g) + 0.1
+    vals = jnp.stack([g, h, jnp.ones_like(g)])
+    slot = jnp.asarray(slot_np, jnp.int32)
+    sums = []
+    for k in range(K):
+        m = slot_np == k
+        sums.append([float(np.asarray(g)[m].sum()),
+                     float(np.asarray(h)[m].sum()), float(m.sum())])
+    sums = jnp.asarray(np.asarray(sums).T, jnp.float32)
+    nb = jnp.full((F,), B, jnp.int32)
+    zero = jnp.zeros((F,), jnp.int32)
+    hp = SplitHyperparams(min_data_in_leaf=1)
+    hist, best = jax.jit(
+        lambda b, v, s, su: fused_segment_splits(
+            b, v, s, K, B, su, nb, zero, zero, hp,
+            feat_tile=2, block_rows=128))(binned, vals, slot, sums)
+    # the accumulated histograms against the staged scatter segment
+    # pass (a mis-lowered slot-expanded dot would be internally
+    # consistent with the scan that follows it, so scan parity alone
+    # cannot catch it), then the scan against the shared body
+    from .histogram import segment_histogram
+    ref_hist = segment_histogram(binned, g, h, jnp.ones_like(g),
+                                 slot, K, B)
+    ok = bool(np.allclose(np.asarray(hist), np.asarray(ref_hist),
+                          rtol=1e-4, atol=1e-3))
+    ref = numeric_feature_scan(hist.astype(jnp.float32), sums[0],
+                               sums[1], sums[2], nb, zero, zero, hp)
+    ok = ok and bool(np.allclose(np.asarray(best.gain),
+                                 np.asarray(ref.gain), equal_nan=True))
+    # the integer family: int8 operands, int32 accumulation — exact
+    vq_np = rng.randint(-8, 8, (2, n)).astype(np.int8)
+    got_q = np.asarray(jax.jit(
+        lambda b, v, s: fused_frontier_accumulate(
+            b, v, s, K, B, feat_tile=2, block_rows=128))(
+                binned, jnp.asarray(vq_np), slot))
+    ref_q = np.zeros((K + 1, 2, F, B), np.int32)
+    for f in range(F):
+        for c in range(2):
+            np.add.at(ref_q[:, c, f, :], (slot_np, binned_np[f]),
+                      vq_np[c].astype(np.int32))
+    ok = ok and bool(np.array_equal(got_q, ref_q[:K]))
     _FUSED_PROBE[backend] = ok
     if not ok:
-        import warnings
-        warnings.warn(
-            f"fused histogram→split megakernel is unavailable on backend "
-            f"{jax.default_backend()!r}; hist_method=auto falls back to "
-            "the staged kernel family (set tpu_hist_method explicitly to "
-            "override)")
+        from ..utils.log import log_warning
+        log_warning(
+            f"fused histogram kernel disagrees with the staged pipeline "
+            f"on backend {backend!r}; hist_method=auto falls back to the "
+            "staged kernel family")
     return ok
 
 

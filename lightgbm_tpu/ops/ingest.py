@@ -39,19 +39,20 @@ rather than approximate:
 The host NumPy path is the never-deleted fallback AND the parity
 oracle: before the first committed device block of a dataset, a salted
 probe (first rows + zeros / NaN / sign extremes / non-category codes)
-is binned both ways and compared byte-for-byte; any mismatch — or any
-kernel exception — demotes that dataset to the host path with a
-warning (``fused_predict_verified`` precedent: never wrong bytes).
-``LGBM_TPU_INGEST_KERNEL`` pins the arm for bisection; off accelerators
-the kernel interprets as the same jnp math, so CPU parity tests are
-meaningful.
+is binned both ways and compared byte-for-byte; a mismatch demotes that
+dataset to the host path at warning level (``fused_predict_verified``
+precedent: never wrong bytes).  The probe is numeric only — a compile,
+lowering or runtime error of the kernel propagates, it never elects the
+host.  ``LGBM_TPU_INGEST_KERNEL`` pins the arm for bisection; off
+accelerators the kernel interprets as the same jnp math, so CPU parity
+tests are meaningful, and tier-1 also compiles it for the chip at the
+tile the planner elects (tests/test_chip_compile.py).
 """
 
 from __future__ import annotations
 
 import threading
 import time
-import warnings
 from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -309,6 +310,11 @@ def record_ingest_story(**kw) -> None:
     with _INGEST_LAST_LOCK:
         _INGEST_LAST.clear()
         _INGEST_LAST.update(kw, ts=time.time())
+    # which arm binned the last dataset, and who chose it (the ingest
+    # twin of train_hist_method / train_hist_elected_by)
+    from ..obs.metrics import global_registry
+    global_registry.gauge("ingest_variant").set(kw.get("path", ""))
+    global_registry.gauge("ingest_elected_by").set(kw.get("elected_by", ""))
 
 
 def ingest_last() -> dict:
@@ -316,8 +322,9 @@ def ingest_last() -> dict:
         return dict(_INGEST_LAST)
 
 
-def demote(reason: str, warn: bool = True, **kw) -> None:
+def demote(reason: str, elected_by: str, warn: bool = True) -> None:
     """Record a host fallback and say why (the bisect gate's evidence)."""
-    record_ingest_story(path="host", reason=reason, **kw)
+    record_ingest_story(path="host", reason=reason, elected_by=elected_by)
     if warn:
-        warnings.warn(f"device ingest demoted to host binning: {reason}")
+        from ..utils.log import log_warning
+        log_warning(f"device ingest demoted to host binning: {reason}")

@@ -47,8 +47,8 @@ import threading
 from contextlib import contextmanager
 from typing import NamedTuple, Optional
 
-# default assumed HBM when the backend reports nothing (one v5e-class
-# chip; r5 measured 17.2 GB reported — stay conservative)
+# the limit CPU planning runs against (tests; one v5e-class chip).  An
+# accelerator's limit is read from the device, never assumed
 DEFAULT_HBM_BYTES = 16 * (1 << 30)
 # fraction of the limit a plan may claim: XLA needs slack for fusion
 # temps, the program image, and collectives' staging buffers
@@ -150,9 +150,11 @@ def hbm_limit_bytes() -> tuple:
     """(limit_bytes, source) for the active device.
 
     Priority: ``LGBM_TPU_HBM_BYTES`` env (tests / fake memory models) >
-    the device allocator's reported ``bytes_limit`` > the conservative
-    default.  Never raises — planning must work before/without a
-    backend.
+    the device allocator's reported ``bytes_limit``.  On an accelerator
+    a device that reports none is an error — a plan against a guessed
+    limit is how a shape OOMs.  Off-accelerator (CPU planning in tests;
+    the CPU allocator reports no limit) the conservative default
+    stands, and ``source`` says so.
     """
     env = os.environ.get("LGBM_TPU_HBM_BYTES", "").strip()
     if env:
@@ -160,15 +162,17 @@ def hbm_limit_bytes() -> tuple:
             return max(int(float(env)), 1), "env"
         except ValueError:
             pass
-    try:
-        import jax
-        stats = jax.local_devices()[0].memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            return limit, "memory_stats"
-    except Exception:
-        pass
-    return DEFAULT_HBM_BYTES, "default"
+    from .histogram import on_accelerator
+    if not on_accelerator():
+        return DEFAULT_HBM_BYTES, "default"
+    import jax
+    dev = jax.local_devices()[0]
+    limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
+    if limit <= 0:
+        raise RuntimeError(
+            f"{dev} reports no memory_stats()['bytes_limit']; set "
+            "LGBM_TPU_HBM_BYTES to plan against a stated limit")
+    return limit, "memory_stats"
 
 
 def vmem_limit_bytes() -> int:
@@ -446,7 +450,8 @@ def autotune_enabled() -> bool:
 
 
 def autotune_dir():
-    """Directory of the measured-timings store, or None (analytic-only).
+    """Directory of the measured-timings store, or None (analytic-only:
+    ``LGBM_TPU_AUTOTUNE_DIR=off``).
 
     ``LGBM_TPU_AUTOTUNE_DIR`` wins; otherwise an ``autotune/`` sibling
     inside the persistent compile-cache dir — the measurements describe
@@ -456,11 +461,8 @@ def autotune_dir():
     d = os.environ.get("LGBM_TPU_AUTOTUNE_DIR", "").strip()
     if d:
         return None if d.lower() in ("0", "off", "none") else d
-    cc = os.environ.get("LGBM_TPU_COMPILE_CACHE", "").strip() \
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
-    if cc and cc.lower() not in ("0", "off", "none"):
-        return os.path.join(cc, "autotune")
-    return None
+    from ..utils.platform import compile_cache_dir
+    return os.path.join(compile_cache_dir(), "autotune")
 
 
 def shape_bucket_key(rows: int, features: int, num_bins: int,
@@ -1881,17 +1883,18 @@ def record_predict_timing(rows, features, num_trees, num_class, precision,
 
 
 def measured_predict_election(rows, features, num_trees, num_class,
-                              precision, path=None):
+                              precision, path=None, skip=()):
     """Fastest measured traversal variant for this predict bucket, or
     None (cold).  Unknown variant names (a store written by a future
-    version) are skipped, not adopted."""
+    version) and the names in ``skip`` (variants this platform cannot
+    elect) are passed over, not adopted."""
     key = predict_bucket_key(rows, features, num_trees, num_class, precision)
     slot = _load_autotune_store(path).get(key)
     if not isinstance(slot, dict):
         return None
     best_v, best = None, None
     for v, rec in slot.items():
-        if str(v) not in PREDICT_VARIANTS:
+        if str(v) not in PREDICT_VARIANTS or str(v) in skip:
             continue
         try:
             s = float(rec["seconds"])
@@ -2038,8 +2041,14 @@ def plan_predict(num_trees: int, nodes_dim: int, leaves_dim: int,
     Budget: the ledger's remaining bytes when one is leased against
     (serving co-residency, PR 17), else HEADROOM x the device limit.
     Variant: ``LGBM_TPU_PREDICT_KERNEL`` > the measured predict family
-    > analytic (fused on accelerators when its VMEM tile fits, fori
-    everywhere else — the while arm is never elected, only pinned).
+    > analytic (``fori`` — the while arm is never elected, only pinned).
+    The fused Pallas traversal is NOT in the accelerator election: the
+    chip's compiler refuses its in-kernel table gathers (an
+    ``AssertionError`` in Mosaic's gather lowering rule — docs/PERF.md
+    "what compiles on the chip"), so there neither the analytic verdict
+    nor a measured entry may pick it; only the env pin can, and it then
+    raises the compiler's error.  Where Pallas interprets it stays
+    electable through the measured store.
     """
     if accel is None:
         from .histogram import on_accelerator
@@ -2064,20 +2073,20 @@ def plan_predict(num_trees: int, nodes_dim: int, leaves_dim: int,
                                  leaves_dim, num_class,
                                  emit_scores=not routing_only,
                                  vmem_bytes=vmem_bytes)
-    analytic = "fused" if (accel and ft is not None) else "fori"
-    variant, elected_by = analytic, "analytic"
+    variant, elected_by = "fori", "analytic"
     measured_variant, autotune_key = "", ""
     if autotune_enabled():
         autotune_key = predict_bucket_key(rows or chunk, features,
                                           num_trees, num_class, precision)
-        m = measured_predict_election(rows or chunk, features, num_trees,
-                                      num_class, precision)
+        m = measured_predict_election(
+            rows or chunk, features, num_trees, num_class, precision,
+            skip=("fused",) if accel else ())
         with _AUTOTUNE_LOCK:
             if m is not None:
                 measured_variant = m["variant"]
                 variant, elected_by = measured_variant, "measured"
                 _AUTOTUNE_STATS["hits"] += 1
-                if variant != analytic:
+                if variant != "fori":       # the analytic verdict
                     _AUTOTUNE_STATS["flips"] += 1
             else:
                 _AUTOTUNE_STATS["misses"] += 1
@@ -2110,7 +2119,7 @@ INGEST_VARIANTS = ("kernel", "host")
 # largest device ingest chunk the election reaches for (a ladder rung)
 MAX_INGEST_CHUNK_ROWS = 1 << 21
 # bucketize+pack row-tile ladder (widest VMEM-resident tile first)
-INGEST_TILES = (2048, 1024, 512, 256)
+INGEST_TILES = (2048, 1024, 512, 256, 128)
 # past this width the unrolled per-feature kernel stops being the
 # analytic default (compile time grows with the feature loop); the env
 # pin and the measured store can still elect it
@@ -2193,20 +2202,27 @@ def measured_ingest_election(rows, features, num_groups, item_bytes,
 
 def ingest_vmem_bytes(features: int, tile_rows: int, bounds_width: int,
                       cats_width: int, num_groups: int) -> int:
-    """Predicted VMEM bytes of one bucketize+pack grid step
-    (ops/ingest.py): the double-buffered [tile, F] f32 input window,
-    the resident boundary + category tables, the [tile, G] i32 output
-    block, and the broadcast compare plane (two transient copies).
-    Deliberately simple — the right ORDER for fits/doesn't."""
+    """Predicted scoped-VMEM bytes of one bucketize+pack grid step
+    (ops/ingest.py), in the chip's layout: every [tile, x] f32/i32
+    plane pads its minor dim to 128 lanes, so the unit is one padded
+    row of 512 bytes.  Per row: the double-buffered input and output
+    windows (4 units), the per-feature column extractions and the
+    per-group fold columns the unrolled feature loop keeps live
+    (0.8 units each), and the broadcast compare plane with its i32
+    cast (2 units per 128 table columns); the resident tables ride on
+    top.  The per-column factor is a fit to what the TPU compiler
+    itself reports as the kernel's scoped allocation (25.74 MB at tile
+    1024, F = G = 28; 94 units/row at F = G = 56 — docs/PERF.md "what
+    compiles on the chip"); the earlier dense-bytes model was 4x short
+    and elected a tile the compiler refused."""
     F = max(int(features), 1)
     C = max(int(tile_rows), 8)
     G = max(int(num_groups), 1)
     W = max(int(bounds_width), int(cats_width), 1)
-    x = 2 * C * F * 4
-    tables = F * (max(int(bounds_width), 1) + max(int(cats_width), 1)) * 4
-    out = C * G * 4
-    transients = 2 * C * W * 4
-    return x + tables + out + transients
+    row_units = 5 + 0.8 * (F + G) + 2 * (_pad(W, 128) // 128)
+    tables = _pad(F, 8) * (_pad(max(int(bounds_width), 1), 128)
+                           + _pad(max(int(cats_width), 1), 128)) * 4
+    return int(C * row_units * 512) + tables
 
 
 def plan_ingest_tile(features, bounds_width, cats_width, num_groups,

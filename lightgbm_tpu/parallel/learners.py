@@ -51,27 +51,6 @@ FEATURE_AXIS = "feature"
 DataAxis = Union[str, Tuple[str, ...]]
 
 
-def shard_map_compat(f=None, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` with old-jax fallback.
-
-    The repo targets the stable ``jax.shard_map`` API (``check_vma``);
-    jax <= 0.4.x only ships ``jax.experimental.shard_map.shard_map``
-    (``check_rep``).  Every shard_map call site routes through here so
-    the distributed paths work on both.  Usable directly or as a
-    decorator factory (``f=None``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-    if f is None:
-        return functools.partial(sm, **kw)
-    return sm(f, **kw)
-
-
 def pad_rows_to(n: int, devices: int) -> int:
     return (n + devices - 1) // devices * devices
 
@@ -138,7 +117,7 @@ def make_sharded_grower(
                    else P(None, data_axis))
 
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(binned_spec, row_spec, row_spec, row_spec),
         out_specs=(P(), row_spec),
         check_vma=False,

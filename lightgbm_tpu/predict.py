@@ -365,6 +365,7 @@ class DeviceForest:
         # the bucket ladder.
         from .ops import planner as _planner
         from .ops import predict_kernels as _pk
+        elected_by = "caller"
         if chunk_rows is None or variant is None or tile_rows is None:
             plan = _planner.plan_predict(
                 num_trees=f.num_trees,
@@ -375,15 +376,22 @@ class DeviceForest:
                 cat_words=int(f.cat_words.size),
                 ledger=_planner.active_ledger())
             chunk_rows = plan.chunk_rows if chunk_rows is None else chunk_rows
-            variant = plan.variant if variant is None else variant
+            if variant is None:
+                variant, elected_by = plan.variant, plan.elected_by
             tile_rows = plan.tile_rows if tile_rows is None else tile_rows
         self.chunk_rows = int(chunk_rows)
         self.tile_rows = int(tile_rows) or 512
         if variant not in _pk.PREDICT_VARIANTS:
             raise ValueError(f"unknown predict kernel variant {variant!r}")
         if variant == "fused" and not _pk.fused_predict_verified(self):
-            variant = "fori"               # probe demotion, warned there
+            # probe demotion, warned there
+            variant, elected_by = "fori", "parity_probe"
         self.variant = variant
+        # which traversal the last-built forest runs, and who chose it
+        # (the predict twin of train_hist_method / train_hist_elected_by)
+        from .obs.metrics import global_registry
+        global_registry.gauge("predict_variant").set(variant)
+        global_registry.gauge("predict_elected_by").set(elected_by)
         if variant == "while":
             leaves_fn = self._leaves
         elif variant == "fori":

@@ -63,8 +63,8 @@ class ServingConfig:
     precision: str = "f32"
     accuracy_budget: Optional[float] = None
     probe_X: Optional[object] = None
-    # AOT serving-program cache directory (fleet/aot.py); None = look at
-    # LGBM_TPU_COMPILE_CACHE/serving, "" / "off" = disabled
+    # AOT serving-program cache directory (fleet/aot.py); None = the
+    # serving/ subtree of the compile-cache dir, "" / "off" = disabled
     aot_dir: Optional[str] = None
     # liveness-beat name of this server's batcher thread (watchdog.py);
     # a pod fleet names each replica's beat so per-replica health
@@ -195,9 +195,8 @@ class Server:
     @staticmethod
     def _resolve_aot(aot_dir):
         """AOT serving-program store (fleet/aot.py): an explicit dir wins;
-        ``None`` follows LGBM_TPU_COMPILE_CACHE/serving (the PR 5
-        persistent cache, extended to serving buckets); "" / "off"
-        disables."""
+        ``None`` follows the compile-cache dir's ``serving/`` subtree
+        (utils/platform.compile_cache_dir); "" / "off" disables."""
         from ..fleet.aot import AOTStore, aot_dir_from_env
         if aot_dir is None:
             aot_dir = aot_dir_from_env()
@@ -225,8 +224,8 @@ class Server:
         store = AOTStore(path) if path is not None else self.aot
         if store is None:
             raise ServingError(
-                "no AOT store configured: pass path=, set aot_dir, or "
-                "set LGBM_TPU_COMPILE_CACHE")
+                "the AOT store is disabled (aot_dir=off): pass path= or "
+                "set aot_dir")
         model = self.models.active
         rows = self._ladder_rows(buckets)
         return model.export_aot(store, rows)

@@ -120,8 +120,6 @@ FLAGS: Dict[str, EnvFlag] = {f.name: f for f in [
     _f("LGBM_TPU_MODEL_BATCH", "", "ops/planner.py",
        "cap the batched model-axis lane chunk ('0'/'off' forces "
        "sequential training)", _PERF),
-    _f("LGBM_TPU_COMPILE_CACHE", "", "utils/platform.py, fleet/aot.py",
-       "persistent XLA compile-cache + AOT-export directory", _PERF),
     _f("LGBT_DEFER_HOST_TREES", "", "boosting/gbdt.py",
        "'1' defers host tree fetch to training end (legacy prefix)", _PERF),
     # ------------------------------------------------------ model lifecycle
@@ -196,10 +194,6 @@ FLAGS: Dict[str, EnvFlag] = {f.name: f for f in [
     _f("BENCH_LEAVES", "255", "bench.py", "num_leaves for bench stages",
        _PERF),
     _f("BENCH_BIN", "63", "bench.py", "max_bin for bench stages", _PERF),
-    _f("BENCH_CPU_ROWS", "200000", "bench.py",
-       "CPU-fallback stage rows", _PERF),
-    _f("BENCH_CPU_TREES", "50", "bench.py",
-       "CPU-fallback stage tree count", _PERF),
     _f("BENCH_SMOKE_ROWS", "500000", "bench.py", "smoke-stage rows", _PERF),
     _f("BENCH_SMOKE_TREES", "3", "bench.py",
        "smoke-stage tree count", _PERF),
@@ -217,23 +211,15 @@ FLAGS: Dict[str, EnvFlag] = {f.name: f for f in [
        "bulk offline-scoring stage rows", _PERF),
     _f("BENCH_TOTAL_BUDGET", "6600", "bench.py",
        "wall-clock budget (seconds) the stage gates spend against", _PERF),
-    _f("BENCH_STALL_TIMEOUT", "2400", "bench.py",
-       "driver-side worker stall kill timer (seconds)", _PERF),
     _f("BENCH_EXTRA_PARAMS", "", "bench.py",
        "JSON dict merged into every bench stage's train params", _PERF),
     # ------------------------------------------------------ bench plumbing
-    _f("BENCH_STAGE", "", "bench.py",
-       "internal: which worker the re-exec'd child runs", _PERF),
     _f("BENCH_JOURNAL", "", "bench.py",
        "journal path ('0' disables; default ./bench_journal.json)", _PERF),
     _f("BENCH_ONLY", "", "bench.py",
        "comma list of worker stages to run exclusively", _PERF),
-    _f("BENCH_WORKER_ROWS", "", "bench.py",
-       "internal: row count handed to the TPU worker's full stage", _PERF),
     _f("BENCH_WORKER_ALLOW_CPU", "", "bench.py",
-       "'1' lets the TPU worker run on a CPU backend", _PERF),
-    _f("BENCH_FORCE_CPU", "", "bench.py",
-       "'1' runs only the CPU-fallback stage", _PERF),
+       "'1' lets bench.py walk its stages on a CPU backend (CI)", _PERF),
     _f("BENCH_PROFILE", "", "bench.py",
        "'1' captures a jax.profiler trace around the train loop", _OBS),
     # ------------------------------------------------------ bench skips

@@ -1,43 +1,19 @@
-"""The driver contract: bench.py must ALWAYS leave a parseable JSON result
-line as its last stdout line (round 4 failed with parsed=null after a
-budget-exhausted TPU wedge — the fix is staged emission + a concurrent
-CPU fallback whose result is banked the moment it exists)."""
+"""The bench contract: ``python bench.py`` is ONE process.  It refuses to
+measure without a TPU (non-zero exit, no result under a device metric's
+name); with ``BENCH_WORKER_ALLOW_CPU=1`` CI walks the same stage pipeline
+on the CPU, and every stage line says which device it ran on."""
 
 import json
 import os
 import subprocess
 import sys
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-def test_bench_cpu_pipeline_emits_parseable_result():
+
+def _run_bench(env_extra, timeout=240):
     env = dict(os.environ)
     env.update({
-        "BENCH_FORCE_CPU": "1",
-        "BENCH_CPU_ROWS": "20000",
-        "BENCH_CPU_TREES": "5",
-        "BENCH_BUDGET": "300",
-        "JAX_PLATFORMS": "cpu",
-    })
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py")],
-        capture_output=True, text=True, timeout=280, env=env, cwd=repo)
-    lines = [ln for ln in proc.stdout.strip().splitlines()
-             if ln.startswith("{")]
-    assert lines, proc.stdout[-2000:] + proc.stderr[-2000:]
-    last = json.loads(lines[-1])
-    for key in ("metric", "value", "unit", "vs_baseline"):
-        assert key in last, last
-    assert last.get("sec_per_tree", 0) > 0, last
-    assert "cpu" in last["metric"].lower(), last["metric"]
-
-
-def _run_worker(env_extra, timeout=240):
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env.update({
-        "BENCH_STAGE": "tpu-worker",
-        "BENCH_WORKER_ALLOW_CPU": "1",
         "BENCH_ROWS": "5000",
         "BENCH_TREES": "3",
         "BENCH_LEAVES": "15",
@@ -46,8 +22,8 @@ def _run_worker(env_extra, timeout=240):
     })
     env.update(env_extra)
     proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py")],
-        capture_output=True, text=True, timeout=timeout, env=env, cwd=repo)
+        [sys.executable, os.path.join(REPO, "bench.py")],
+        capture_output=True, text=True, timeout=timeout, env=env, cwd=REPO)
     stages = []
     for ln in proc.stdout.strip().splitlines():
         try:
@@ -56,7 +32,45 @@ def _run_worker(env_extra, timeout=240):
             continue
         if isinstance(obj, dict) and obj.get("stage"):
             stages.append(obj)
-    return stages
+    return proc, stages
+
+
+def _run_worker(env_extra, timeout=240):
+    return _run_bench(dict(env_extra, BENCH_WORKER_ALLOW_CPU="1"),
+                      timeout)[1]
+
+
+def test_bench_without_tpu_refuses_to_measure():
+    """No TPU and no BENCH_WORKER_ALLOW_CPU: exit non-zero after the init
+    line, which names the platform it found and says ok=false — there is
+    no CPU continuation, and nothing is printed under a metric's name."""
+    proc, stages = _run_bench({"BENCH_JOURNAL": "0"})
+    assert proc.returncode == 3, proc.stdout[-2000:] + proc.stderr[-2000:]
+    assert [s["stage"] for s in stages] == ["init"]
+    assert stages[0]["ok"] is False and stages[0]["platform"] == "cpu"
+    assert "sec_per_tree" not in proc.stdout
+    assert "metric" not in proc.stdout
+    assert "refusing to measure" in proc.stderr
+
+
+def test_bench_cpu_pipeline_stamps_every_stage_with_its_device(tmp_path):
+    """The CI walk (ALLOW_CPU) runs the smoke stage end to end in the one
+    process, and every stage line carries platform / device_kind /
+    n_devices — a CPU number can never pass for a device's."""
+    proc, stages = _run_bench({
+        "BENCH_WORKER_ALLOW_CPU": "1",
+        "BENCH_JOURNAL": str(tmp_path / "journal.json"),
+        "BENCH_ONLY": "smoke", "BENCH_SMOKE_ROWS": "5000",
+        "BENCH_SKIP_OBS": "1"})
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    assert [s["stage"] for s in stages] == ["init", "smoke"]
+    for s in stages:
+        assert s["platform"] == "cpu" and s["n_devices"] >= 1, s
+        assert s["device_kind"], s
+    smoke = stages[1]
+    assert "error" not in smoke and smoke["sec_per_tree"] > 0, smoke
+    # a CPU run has no device utilization to report
+    assert "mfu_histogram_lower_bound" not in smoke
 
 
 def test_bench_journal_resume_after_crash(tmp_path):
@@ -110,7 +124,6 @@ def test_bench_diff_gate(tmp_path):
     """tools/bench_diff.py is the perf gate: an unchanged journal passes
     (exit 0), a synthetic 2x sec_per_tree regression is flagged by name
     with a nonzero exit, and the last stdout line is one JSON verdict."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     base = {"fingerprint": "fp", "stages": {
         "full@200000": {"sec_per_tree": 0.5, "value": 25.0,
                         "holdout_auc": 0.965, "iters_per_sec": 2.0,
@@ -124,7 +137,7 @@ def test_bench_diff_gate(tmp_path):
 
     def run(old, new, *extra):
         return subprocess.run(
-            [sys.executable, os.path.join(repo, "tools", "bench_diff.py"),
+            [sys.executable, os.path.join(REPO, "tools", "bench_diff.py"),
              str(old), str(new), *extra],
             capture_output=True, text=True, timeout=60)
 
@@ -161,8 +174,9 @@ def test_bench_diff_gate(tmp_path):
     verdict = json.loads(proc.stdout.strip().splitlines()[-1])
     assert {r["metric"] for r in verdict["regressions"]} == {"qps"}
 
-    # a BENCH_r*.json driver file compares as stage "full" — but only
-    # against a side that HAS a full stage; here: driver file vs itself
+    # a driver-format file ({"n", "rc", "parsed"}) compares as stage
+    # "full" — but only against a side that HAS a full stage; here: the
+    # driver file vs itself
     d = tmp_path / "driver.json"
     d.write_text(json.dumps(
         {"n": 1, "rc": 0, "parsed": {"sec_per_tree": 0.7, "value": 35.0}}))
@@ -200,8 +214,7 @@ def test_bench_journal_fingerprint_invalidation(tmp_path, monkeypatch):
     """A journal written under a different workload shape must not be
     replayed (stale telemetry masquerading as current is worse than a
     rerun)."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, repo)
+    sys.path.insert(0, REPO)
     journal = str(tmp_path / "j.json")
     monkeypatch.setenv("BENCH_JOURNAL", journal)
     monkeypatch.setenv("BENCH_ROWS", "1000")

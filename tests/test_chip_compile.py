@@ -1,0 +1,271 @@
+"""The chip's compiler, held in tier-1: every Pallas kernel the main path
+can elect on a TPU is compiled here for a DESCRIBED v5e chip (the TPU
+compiler ships with jaxlib; no chip is attached), at HIGGS width —
+28 features, max_bin 63, 255 leaves, 1M and 10.5M-bucket rows — and
+every variant taken out of the on-chip election is shown to stay out
+and to raise the compiler's own error when forced.
+
+Interpret mode (what the rest of tier-1 runs the kernels in) cannot see
+what is refused here: a shape cast Mosaic has no layout for, a block the
+TPU lowering does not tile, more scoped VMEM than a kernel may take, a
+primitive without a lowering rule.  A compile that passes is not a chip
+run — ``chip_smoke.py`` is.
+
+All cases live in this one file, and the topology is described inside a
+module-scoped fixture: only one process at a time may load the TPU's
+library, so it must happen in the one xdist worker that runs this file,
+after a test of it has started — never at import.
+"""
+
+import numpy as np
+import pytest
+
+F, B, LEAVES = 28, 63, 255          # HIGGS width, max_bin=63, 255 leaves
+K = 128                             # min(LEAVES - 1, tpu_round_width=128)
+ROWS_1M = 1 << 20
+ROWS_10M = 12_582_912               # bucket_rows(10_500_000)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """SingleDeviceSharding on the first chip of a described v5e:2x2,
+    with the persistent compile cache off while this file runs (a
+    described-device compile can be written to it but never read back)."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def as_accelerator(monkeypatch):
+    """Steer ``on_accelerator()`` to True for code that asks the live
+    backend (which is the CPU here) which branch to take."""
+    from lightgbm_tpu.ops import histogram as H
+    monkeypatch.setattr(H, "ACCEL_BACKENDS", ("cpu",))
+
+
+def _compile(fn, *shapes):
+    import jax
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _shape(one_chip, shape, dtype):
+    import jax
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def test_planner_elects_the_fused_arm_at_higgs_width():
+    """The shape the cases below compile is the shape ``auto`` elects."""
+    from lightgbm_tpu.ops.planner import plan_histograms
+    plan = plan_histograms(ROWS_1M, F, B, num_leaves=LEAVES, method="auto",
+                           round_width=K, fused_ok=True, accel=True,
+                           budget_bytes=16 << 30)
+    assert plan.fused
+    assert (plan.fused_feat_tile, plan.fused_block_rows) == (8, 512)
+
+
+@pytest.mark.parametrize("rows", [ROWS_1M, ROWS_10M])
+def test_histogram_pallas_compiles(one_chip, rows):
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.histogram import histogram_pallas
+    c = _compile(lambda b, v: histogram_pallas(b, v, B, interpret=False),
+                 _shape(one_chip, (F, rows), jnp.uint8),
+                 _shape(one_chip, (3, rows), jnp.float32))
+    assert _kernels(c) == 1
+
+
+@pytest.mark.parametrize("rows", [ROWS_1M, ROWS_10M])
+@pytest.mark.parametrize("family", ["f32", "int8"])
+def test_fused_accumulate_compiles(one_chip, family, rows):
+    """The accumulate half at the planner's own {feat_tile, block_rows}
+    (None = plan_fused): the kernel ``auto`` runs every round."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.fused import fused_frontier_accumulate
+    ch, dt = (3, jnp.float32) if family == "f32" else (2, jnp.int8)
+    c = _compile(
+        lambda b, v, s: fused_frontier_accumulate(b, v, s, K, B,
+                                                  interpret=False),
+        _shape(one_chip, (F, rows), jnp.uint8),
+        _shape(one_chip, (ch, rows), dt),
+        _shape(one_chip, (rows,), jnp.int32))
+    assert _kernels(c) == 1
+
+
+@pytest.mark.parametrize("k,bins,bin_dtype", [
+    (30, 63, "uint8"),       # 31 leaves: slots off the sublane tiling
+    (128, 255, "uint8"),     # default max_bin: the planner drops to Ft=4
+    (128, 300, "uint16"),    # wide bins: 2-byte matrix
+])
+def test_fused_accumulate_compiles_off_the_aligned_shape(one_chip, k, bins,
+                                                         bin_dtype):
+    """``auto`` elects the fused arm at whatever width the data has, and
+    a refusal is no longer demoted — so the shapes just off HIGGS's
+    aligned one must compile too."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.fused import fused_frontier_accumulate
+    c = _compile(
+        lambda b, v, s: fused_frontier_accumulate(b, v, s, k, bins,
+                                                  interpret=False),
+        _shape(one_chip, (F, ROWS_1M), jnp.dtype(bin_dtype)),
+        _shape(one_chip, (3, ROWS_1M), jnp.float32),
+        _shape(one_chip, (ROWS_1M,), jnp.int32))
+    assert _kernels(c) == 1
+
+
+def _scan_operands(one_chip):
+    import jax.numpy as jnp
+    return (_shape(one_chip, (K, 3, F, B), jnp.float32),     # small hists
+            _shape(one_chip, (3, 2 * K), jnp.float32),       # child sums
+            _shape(one_chip, (K,), jnp.bool_),               # small_left
+            _shape(one_chip, (K, 3, F, B), jnp.float32))     # parent hists
+
+
+def _meta_vectors():
+    return (np.full((F,), B, np.int32), np.zeros((F,), np.int32),
+            np.zeros((F,), np.int32))
+
+
+def test_elected_scan_form_compiles(one_chip, as_accelerator):
+    """What "fused" means on the chip: the accumulate kernel, then the
+    sibling-derive + gain scan as plain XLA — one Mosaic kernel, and the
+    whole per-round program compiles."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.fused import fused_frontier_splits
+    from lightgbm_tpu.ops.split import SplitHyperparams
+    nb, mt, db = _meta_vectors()
+    hp = SplitHyperparams(min_data_in_leaf=20)
+    _, sums, small_left, parent = _scan_operands(one_chip)
+    c = _compile(
+        lambda b, v, s, su, sl, ph: fused_frontier_splits(
+            b, v, s, K, B, su, sl, ph, nb, mt, db, hp),
+        _shape(one_chip, (F, ROWS_1M), jnp.uint8),
+        _shape(one_chip, (3, ROWS_1M), jnp.float32),
+        _shape(one_chip, (ROWS_1M,), jnp.int32), sums, small_left, parent)
+    assert _kernels(c) == 1
+
+
+def test_scan_kernels_are_out_of_the_election(one_chip, as_accelerator):
+    """The in-kernel scan epilogue — the standalone half and the combined
+    kernel alike — has no TPU lowering.  Left to the election
+    (``interpret=None``) an accelerator traces no Pallas scan; forced
+    (``interpret=False``) the compiler's own error comes out."""
+    import jax
+
+    from lightgbm_tpu.ops.fused import (fused_segment_splits,
+                                        fused_sibling_scan)
+    from lightgbm_tpu.ops.split import SplitHyperparams
+    import jax.numpy as jnp
+    nb, mt, db = _meta_vectors()
+    hp = SplitHyperparams(min_data_in_leaf=20)
+    small, sums, small_left, parent = _scan_operands(one_chip)
+
+    def scan(interpret):
+        return lambda h, su, sl, ph: fused_sibling_scan(
+            h, su, nb, mt, db, hp, small_left=sl, parent_hist=ph,
+            interpret=interpret)
+
+    elected = jax.make_jaxpr(scan(None))(small, sums, small_left, parent)
+    assert "pallas_call" not in str(elected)
+    _compile(scan(None), small, sums, small_left, parent)
+    with pytest.raises(NotImplementedError, match="cumsum"):
+        _compile(scan(False), small, sums, small_left, parent)
+    with pytest.raises(NotImplementedError, match="cumsum"):
+        _compile(
+            lambda b, v, s, su: fused_segment_splits(
+                b, v, s, K, B, su, nb, mt, db, hp, interpret=False),
+            _shape(one_chip, (F, ROWS_1M), jnp.uint8),
+            _shape(one_chip, (3, ROWS_1M), jnp.float32),
+            _shape(one_chip, (ROWS_1M,), jnp.int32),
+            _shape(one_chip, (3, K), jnp.float32))
+
+
+def _higgs_ingest_tables():
+    from lightgbm_tpu.ops.ingest import FeatureSpec, IngestTables
+    specs = tuple(FeatureSpec(j, j, 1, False, B, j, False) for j in range(F))
+    bounds = np.sort(np.random.RandomState(0).rand(F, B - 1)
+                     .astype(np.float32), axis=1)
+    return IngestTables(specs, bounds, np.full((1, 1), -2, np.int32), F, F,
+                        np.dtype(np.uint8))
+
+
+def test_ingest_kernel_compiles_at_the_planned_tile(one_chip):
+    """``plan_ingest_tile`` must return a rung the compiler accepts: the
+    1024-row tile the old byte model elected asked for 25.74 MB of scoped
+    VMEM against a 16 MB limit."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.ingest import DeviceBinner
+    from lightgbm_tpu.ops.planner import plan_ingest_tile
+    tables = _higgs_ingest_tables()
+    tile = plan_ingest_tile(F, tables.bounds.shape[1], 1, F)
+    assert tile is not None and tile["tile_rows"] < 1024
+    binner = DeviceBinner(tables, tile["tile_rows"], interpret=False)
+    c = _compile(binner._run, _shape(one_chip, (ROWS_1M, F), jnp.float32))
+    assert _kernels(c) == 1
+    refused = DeviceBinner(tables, 1024, interpret=False)
+    with pytest.raises(Exception, match="(?i)vmem|scoped"):
+        _compile(refused._run, _shape(one_chip, (ROWS_1M, F), jnp.float32))
+
+
+@pytest.fixture(scope="module")
+def forest():
+    """A 500-tree x 255-leaf forest (a few trained trees, tiled)."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.predict import StackedForest
+    rng = np.random.RandomState(0)
+    X = rng.rand(20000, F).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0.8).astype(np.float32)
+    bst = lgb.train(dict(objective="binary", num_leaves=LEAVES, max_bin=63,
+                         min_data_in_leaf=5, verbosity=-1),
+                    lgb.Dataset(X, label=y), num_boost_round=2,
+                    verbose_eval=False)
+    return StackedForest([bst.models[i % 2] for i in range(500)])
+
+
+def test_fused_traversal_is_out_of_the_election(one_chip, forest,
+                                                tmp_path, monkeypatch):
+    """Mosaic's gather rule refuses the traversal kernel's table gathers,
+    so on an accelerator neither the analytic verdict nor a measured
+    "fused" entry elects it; ``fori`` — what is elected — compiles, and
+    the forced kernel raises the compiler's error."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops import planner as P
+    from lightgbm_tpu.ops import predict_kernels as PK
+    from lightgbm_tpu.predict import DeviceForest
+    shape = dict(num_trees=500, nodes_dim=LEAVES - 1, leaves_dim=LEAVES,
+                 features=F, rows=100_000)
+    monkeypatch.setenv("LGBM_TPU_AUTOTUNE_DIR", str(tmp_path))
+    assert P.plan_predict(accel=True, **shape).variant == "fori"
+    P.record_predict_timing(100_000, F, 500, 1, "f32", "fused", 1e-3)
+    P.record_predict_timing(100_000, F, 500, 1, "f32", "fori", 1.0)
+    on_chip = P.plan_predict(accel=True, **shape)
+    assert (on_chip.variant, on_chip.elected_by) == ("fori", "measured")
+    assert P.plan_predict(accel=False, **shape).variant == "fused"
+
+    dev = DeviceForest(forest, variant="fori", chunk_rows=1 << 16,
+                       tile_rows=512)
+    X = _shape(one_chip, (1 << 16, F), jnp.float32)
+    _compile(lambda x: PK.leaves_fori(dev, x), X)
+    with pytest.raises(AssertionError):
+        _compile(lambda x: PK.fused_traverse(dev, x, 512, interpret=False), X)

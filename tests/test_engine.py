@@ -518,19 +518,20 @@ def test_early_stopped_model_round_trips_at_best_iteration(binary_data):
     assert full.num_trees() == bst.num_trees()
 
 
-def test_compile_cache_env_wiring(tmp_path, monkeypatch):
-    """LGBM_TPU_COMPILE_CACHE=<dir> wires the persistent XLA compile
-    cache at engine init: the dir gets created and populated, and a
-    second (warm) training of the same shape reuses it byte-for-byte."""
+def test_compile_cache_on_by_default(tmp_path, monkeypatch):
+    """The persistent XLA compile cache is wired at engine init with no
+    flag: the resolved directory gets created and populated, and a second
+    (warm) training of the same shape reuses it byte-for-byte.  (Which
+    directory is resolved: tests/test_platform_rules.py.)"""
     import jax
 
-    from lightgbm_tpu.utils.platform import (compile_cache_entries,
-                                             enable_compile_cache)
+    from lightgbm_tpu.utils import platform as PF
     rng = np.random.RandomState(0)
     X = rng.randn(400, 6)
     y = (X[:, 0] + X[:, 1] * X[:, 2] > 0).astype(float)
     cache = tmp_path / "xla_cache"
-    monkeypatch.setenv("LGBM_TPU_COMPILE_CACHE", str(cache))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(PF, "compile_cache_dir", lambda: str(cache))
     prev = jax.config.jax_compilation_cache_dir
     try:
         params = {"objective": "binary", "num_leaves": 7, "verbosity": -1}
@@ -538,15 +539,10 @@ def test_compile_cache_env_wiring(tmp_path, monkeypatch):
                        verbose_eval=False).model_to_string()
         assert jax.config.jax_compilation_cache_dir == str(cache)
         assert cache.is_dir()
-        n_cold = compile_cache_entries(str(cache))
+        n_cold = PF.compile_cache_entries(str(cache))
         m2 = lgb.train(params, lgb.Dataset(X, label=y), 3,
                        verbose_eval=False).model_to_string()
         assert m1 == m2
-        assert compile_cache_entries(str(cache)) >= n_cold
-        # disabled spellings are no-ops
-        monkeypatch.setenv("LGBM_TPU_COMPILE_CACHE", "off")
-        assert enable_compile_cache() is None
-        monkeypatch.delenv("LGBM_TPU_COMPILE_CACHE")
-        assert enable_compile_cache() is None
+        assert PF.compile_cache_entries(str(cache)) >= n_cold
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)

@@ -106,36 +106,41 @@ def test_float64_raw_stays_on_host(monkeypatch):
     assert not ds._maybe_device_bin(X[:100].astype(np.float64), None, out)
 
 
-def test_parity_failure_demotes_for_good(monkeypatch):
+def test_parity_failure_demotes_for_good(monkeypatch, capsys):
     """A diverging probe must demote the dataset permanently (never
-    wrong bytes), leave the host result intact, and say why."""
+    wrong bytes), leave the host result intact, and say why — at warning
+    level, with the gauges naming what ran and who decided."""
+    from lightgbm_tpu.obs.metrics import global_registry
+    from lightgbm_tpu.utils import log
     X, y = _raw()
     host = _dataset(X.copy(), y)
+    monkeypatch.setattr(log, "_current_level", 0)     # warnings on
     monkeypatch.setenv("LGBM_TPU_INGEST_KERNEL", "kernel")
     monkeypatch.setattr(ING, "parity_probe", lambda *a, **k: False)
-    with pytest.warns(UserWarning, match="demoted"):
-        dev = _dataset(X.copy(), y)
+    dev = _dataset(X.copy(), y)
+    assert "device ingest demoted to host binning" in capsys.readouterr().err
     assert np.array_equal(dev.binned, host.binned)
     assert dev._ingest == {}                  # cached demotion
     story = ING.ingest_last()
     assert story.get("path") == "host"
     assert "parity" in story.get("reason", "")
+    gauges = global_registry.to_dict()["gauges"]
+    assert gauges["ingest_variant"] == "host"
+    assert gauges["ingest_elected_by"] == "parity_probe"
 
 
-def test_kernel_exception_falls_back_cleanly(monkeypatch):
-    """Any kernel exception mid-run re-zeroes the output and the host
-    oracle produces the exact host bytes."""
+def test_kernel_exception_propagates(monkeypatch):
+    """A kernel error is not a verdict: it never elects the host path —
+    a compile or runtime failure of the elected kernel reaches the
+    caller."""
     X, y = _raw()
-    host = _dataset(X.copy(), y)
     monkeypatch.setenv("LGBM_TPU_INGEST_KERNEL", "kernel")
 
     def boom(self, X):
-        raise RuntimeError("backend lost")
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
     monkeypatch.setattr(ING.DeviceBinner, "__call__", boom)
-    with pytest.warns(UserWarning, match="demoted"):
-        dev = _dataset(X.copy(), y)
-    assert np.array_equal(dev.binned, host.binned)
-    assert "RuntimeError" in ING.ingest_last().get("reason", "")
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        _dataset(X.copy(), y)
 
 
 def test_int32_overflow_categorical_unsupported():

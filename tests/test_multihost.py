@@ -32,8 +32,7 @@ from lightgbm_tpu.parallel.collectives import (DCN_AXIS, HYBRID_AXES,
                                                axis_size, psum_int_tiered,
                                                psum_tiered)
 from lightgbm_tpu.parallel.learners import (DATA_AXIS, data_axis_of,
-                                            make_hybrid_mesh, make_mesh,
-                                            shard_map_compat)
+                                            make_hybrid_mesh, make_mesh)
 from lightgbm_tpu.resilience import (ChaosRegistry, ResilienceConfig,
                                      SliceLostError, apply_world,
                                      membership_probe, plan_shrunk_world,
@@ -188,7 +187,7 @@ def test_tiered_psum_matches_flat(slices):
     xi = np.arange(8 * 24, dtype=np.int32).reshape(8, 24) - 91
 
     def run(body, arr):
-        f = shard_map_compat(body, mesh=mesh, in_specs=(P(HYBRID_AXES),),
+        f = jax.shard_map(body, mesh=mesh, in_specs=(P(HYBRID_AXES),),
                              out_specs=P(HYBRID_AXES), check_vma=False)
         return np.asarray(jax.jit(f)(jnp.asarray(arr)))
 
@@ -221,7 +220,7 @@ def test_axis_index_flat_is_linear_rank():
     def body(v):
         return v + axis_index_flat(HYBRID_AXES)
 
-    f = shard_map_compat(body, mesh=mesh, in_specs=(P(HYBRID_AXES),),
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P(HYBRID_AXES),),
                          out_specs=P(HYBRID_AXES), check_vma=False)
     got = np.asarray(jax.jit(f)(jnp.zeros(8, jnp.int32)))
     np.testing.assert_array_equal(got, np.arange(8))

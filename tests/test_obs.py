@@ -376,12 +376,13 @@ def test_devprof_measures_a_program():
 
     m = measure_program(f, (a,), reps=1)
     assert m["seconds_per_call"] > 0
-    assert m["peak_flops"] > 0 and m["peak_hbm_bw"] > 0
+    # a CPU run is a share of no device: no peak, no utilization
+    assert not {"peak_flops", "peak_hbm_bw", "mfu", "hbm_util"} & set(m)
     cost = program_cost(f, a)
     if not cost:        # backend without a cost model: degrade, not fail
         pytest.skip("cost_analysis unavailable on this backend")
     assert cost["flops"] > 0
-    assert m["mfu"] > 0
+    assert m["flops"] == cost["flops"]
 
 
 def test_devprof_histogram_table_small():

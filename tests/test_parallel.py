@@ -14,8 +14,7 @@ import jax.numpy as jnp
 from lightgbm_tpu.dataset import FeatureMeta
 from lightgbm_tpu.grower import GrowerConfig, grow_tree
 from lightgbm_tpu.ops.split import SplitHyperparams
-from lightgbm_tpu.parallel.learners import (shard_map_compat,
-                                            DATA_AXIS, FEATURE_AXIS,
+from lightgbm_tpu.parallel.learners import (DATA_AXIS, FEATURE_AXIS,
                                             create_parallel_grower, make_mesh,
                                             shard_dataset)
 
@@ -293,7 +292,7 @@ def test_voting_parallel_reduces_histogram_traffic(problem):
 
     def lower(cfg):
         @functools.partial(
-            shard_map_compat, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(jax.sharding.PartitionSpec(None, DATA_AXIS),)
             + (jax.sharding.PartitionSpec(DATA_AXIS),) * 3,
             out_specs=(jax.sharding.PartitionSpec(),

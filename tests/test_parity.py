@@ -332,7 +332,7 @@ def test_categorical_model_cross_load(reflgb, tmp_path):
 
 
 def test_large_scale_parity_150k(reflgb):
-    """Trajectory parity at >=100k rows (VERDICT round-3 item 9: previous
+    """Trajectory parity at >=100k rows (round-3 review, item 9: previous
     parity evidence topped out at 7k rows)."""
     import lightgbm_tpu as lgb
     rng = np.random.RandomState(0)

@@ -8,6 +8,8 @@ static bound; and the predicted peak must track reality on a
 scaled-down shape (the off-TPU acceptance path).
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -288,12 +290,15 @@ def test_autotune_corrupt_store_is_a_miss(tmp_path, monkeypatch):
 
 
 def test_autotune_disabled_and_no_store(monkeypatch):
-    """LGBM_TPU_AUTOTUNE=0 skips the election entirely; with no store
-    dir configured record_timing is a no-op and elections are cold."""
+    """LGBM_TPU_AUTOTUNE=0 skips the election entirely; with the store
+    switched off (LGBM_TPU_AUTOTUNE_DIR=off) record_timing is a no-op and
+    elections are cold.  Unset, the store lives beside the compile cache."""
     from lightgbm_tpu.ops import planner as P
+    from lightgbm_tpu.utils.platform import compile_cache_dir
     monkeypatch.delenv("LGBM_TPU_AUTOTUNE_DIR", raising=False)
-    monkeypatch.delenv("LGBM_TPU_COMPILE_CACHE", raising=False)
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert P.autotune_dir() == os.path.join(compile_cache_dir(), "autotune")
+    monkeypatch.setenv("LGBM_TPU_AUTOTUNE_DIR", "off")
+    assert P.autotune_dir() is None
     assert P.record_timing(10_000, 8, 64, False, 8, "scatter", 0.01) is None
     assert P.measured_election(10_000, 8, 64, False, 8) is None
     monkeypatch.setenv("LGBM_TPU_AUTOTUNE", "0")

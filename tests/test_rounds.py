@@ -15,7 +15,6 @@ import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.dataset import FeatureMeta
-from lightgbm_tpu.parallel.learners import shard_map_compat
 from lightgbm_tpu.grower import GrowerConfig, grow_tree
 from lightgbm_tpu.grower_rounds import grow_tree_rounds
 from lightgbm_tpu.ops.split import SplitHyperparams
@@ -160,7 +159,7 @@ def test_rounds_data_parallel_matches_single(problem):
 
     assert jax.device_count() >= 8
     mesh = Mesh(np.array(jax.devices()[:8]), ("d",))
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         lambda b, g, h, m: grow_tree_rounds(b, g, h, m, meta, cfg,
                                             axis_name="d"),
         mesh=mesh, in_specs=(P(None, "d"), P("d"), P("d"), P("d")),
@@ -359,7 +358,7 @@ def test_rounds_data_parallel_sorted_dispatch(problem, monkeypatch):
         jnp.asarray(mask), meta, cfg)
 
     mesh = Mesh(np.array(jax.devices()[:8]), ("d",))
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         lambda b, g, h, m: grow_tree_rounds(b, g, h, m, meta, cfg,
                                             axis_name="d"),
         mesh=mesh, in_specs=(P(None, "d"), P("d"), P("d"), P("d")),

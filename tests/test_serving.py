@@ -422,12 +422,15 @@ def test_serving_stress(binary_booster):
 # --------------------------------------- take_from_table on-device probe
 
 
-def test_table_matmul_probe_fallback(monkeypatch):
+def test_table_matmul_probe_fallback(monkeypatch, capsys):
     """A backend failing the one-time exactness probe must demote
-    take_from_table to the plain gather (ADVICE.md round 5)."""
+    take_from_table to the plain gather (ADVICE.md round 5), at warning
+    level; a probe that RAISES is a defect and propagates."""
     import jax.numpy as jnp
     import lightgbm_tpu.ops.histogram as H
+    from lightgbm_tpu.utils import log
 
+    monkeypatch.setattr(log, "_current_level", 0)     # warnings on
     monkeypatch.setattr(H, "on_accelerator", lambda: True)
     table = jnp.asarray(np.linspace(-2, 2, 9).astype(np.float32))
     idx = jnp.asarray(np.arange(9, dtype=np.int32))
@@ -446,13 +449,24 @@ def test_table_matmul_probe_fallback(monkeypatch):
         return real(t, i, leading, block) * 1.0000001
 
     monkeypatch.setattr(H, "_take_matmul", skewed)
-    with pytest.warns(UserWarning, match="NOT bit-exact"):
-        out = np.asarray(H.take_from_table(table, idx))
+    out = np.asarray(H.take_from_table(table, idx))
+    assert "NOT bit-exact" in capsys.readouterr().err
     np.testing.assert_array_equal(out, np.asarray(table))  # gather served
     assert H._TABLE_MATMUL_PROBE == {"cpu": False}
     # verdict is cached: no re-probe, still the gather
     out = np.asarray(H.take_from_table(table, idx))
     np.testing.assert_array_equal(out, np.asarray(table))
+
+    # a probe that cannot run is not a verdict
+    monkeypatch.setattr(H, "_TABLE_MATMUL_PROBE", {})
+
+    def refused(t, i, leading=False, block=65536):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(H, "_take_matmul", refused)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        H.take_from_table(table, idx)
+    assert H._TABLE_MATMUL_PROBE == {}
 
 
 # ------------------------------------------------- swap probe / quarantine

@@ -45,8 +45,7 @@ def run_probe(rows=200_000, features=28, max_bin=63, quant_bins=4,
     from lightgbm_tpu.ops.planner import plan_collectives
     from lightgbm_tpu.parallel.collectives import (DCN_AXIS, HYBRID_AXES,
                                                    ICI_AXIS)
-    from lightgbm_tpu.parallel.learners import (make_hybrid_mesh,
-                                                shard_map_compat)
+    from lightgbm_tpu.parallel.learners import make_hybrid_mesh
 
     nd = jax.device_count()
     s = max(1, min(int(num_slices), nd))
@@ -74,7 +73,7 @@ def run_probe(rows=200_000, features=28, max_bin=63, quant_bins=4,
         return (time.perf_counter() - t0) / reps * 1e3
 
     def sched(body):
-        return shard_map_compat(body, mesh=mesh, in_specs=(P(),),
+        return jax.shard_map(body, mesh=mesh, in_specs=(P(),),
                                 out_specs=P(), check_vma=False)
 
     def flat(h):

@@ -116,8 +116,8 @@ def autotune_probe(rows, features, num_groups, item_bytes,
 
     out = {"enabled": P.autotune_enabled(), "store_dir": P.autotune_dir()}
     if not (P.autotune_enabled() and P.autotune_dir()):
-        out["skipped"] = ("no autotune store configured: set "
-                          "LGBM_TPU_AUTOTUNE_DIR or LGBM_TPU_COMPILE_CACHE")
+        out["skipped"] = ("the autotune store is switched off "
+                          "(LGBM_TPU_AUTOTUNE / LGBM_TPU_AUTOTUNE_DIR)")
         return out
     P.autotune_counters(reset=True)
 

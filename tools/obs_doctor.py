@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """obs_doctor: automated bottleneck diagnosis over the banked bench
 journal + a metrics snapshot (lightgbm_tpu/obs/diagnose.py,
-docs/OBSERVABILITY.md verdict taxonomy).
+docs/OBSERVABILITY.md verdict catalogue).
 
 Joins measured signals (devprof MFU tables, compile-cache warmth,
 stream-probe overlap efficiency, straggler skew) with

@@ -101,8 +101,8 @@ def autotune_probe(table, rows, features, num_trees, num_class,
 
     out = {"enabled": P.autotune_enabled(), "store_dir": P.autotune_dir()}
     if not (P.autotune_enabled() and P.autotune_dir()):
-        out["skipped"] = ("no autotune store configured: set "
-                          "LGBM_TPU_AUTOTUNE_DIR or LGBM_TPU_COMPILE_CACHE")
+        out["skipped"] = ("the autotune store is switched off "
+                          "(LGBM_TPU_AUTOTUNE / LGBM_TPU_AUTOTUNE_DIR)")
         return out
     sec = {v: table[v]["seconds_per_call"] for v in ("while", "fori", "fused")
            if isinstance(table.get(v), dict) and "seconds_per_call" in table[v]}

@@ -15,8 +15,8 @@ import numpy as np
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from lightgbm_tpu.utils.platform import _cache_dir
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", _cache_dir())
+from lightgbm_tpu.utils.platform import enable_compile_cache
+enable_compile_cache()
 
 import jax
 import jax.numpy as jnp
