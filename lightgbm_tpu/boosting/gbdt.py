@@ -29,6 +29,7 @@ import numpy as np
 
 from ..config import Config
 from ..dataset import Dataset, FeatureMeta
+from ..obs.flight import note as _flight_note
 from ..obs.metrics import global_registry as _obs_registry
 from ..obs.trace import span as _span
 from ..ops.histogram import (on_accelerator, quantize_gradients,
@@ -105,7 +106,8 @@ class GBDT:
         # banks the stacked DEVICE trees here and _drain_pending converts
         # the whole backlog in one bulk transfer when the host list is
         # actually needed (predict/save/eval/len)
-        self._pending: List[tuple] = []    # (abs_iter, stacked device trees)
+        # (abs_iter, shrinkage, stacked device trees, grower counters)
+        self._pending: List[tuple] = []
         self._defer_host: Optional[bool] = None   # resolved on first iter
         self.shrinkage_rate = config.learning_rate
 
@@ -131,63 +133,67 @@ class GBDT:
         # spill store and every histogram pass streams blocks —
         # self.binned stays None and the streamed executor trains
         from ..data.stream import maybe_stream_setup
-        if maybe_stream_setup(self):
-            self.binned = None
-        elif self._mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            if self._data_axis is not None:
-                perm = self._row_perm
-                key = ("data", id(self._mesh), self._data_axis, n_pad,
-                       None if perm is None else hash(perm.tobytes()))
-                self.binned = self._cached_device_binned(key)
-                if self.binned is None:
-                    src = self.train_set.host_binned()
-                    if perm is not None:
-                        # query-aligned layout: gather rows (pads -> bin 0)
-                        b = np.concatenate(
-                            [src, np.zeros((1, src.shape[1]), src.dtype)]
-                        )[perm]
-                    else:
-                        b = np.pad(src, ((0, n_pad - n), (0, 0)))
-                    # feature-major device residency (ops/histogram.py LAYOUT
-                    # DOCTRINE): minor dim n stays unpadded in the (8,128)/
-                    # (32,128) tiles; [n, 28] u8 row-major would pad 4.6x
-                    self.binned = self._cache_device_binned(
-                        key, jax.device_put(
-                            np.ascontiguousarray(b.T),
-                            NamedSharding(self._mesh,
-                                          P(None, self._data_axis))))
+        # layout (pad, permute, transpose on the host) and the put of the
+        # binned matrix: host time until the put is dispatched
+        with _span("ingest.to_device", ring=True, rows=n):
+            if maybe_stream_setup(self):
+                self.binned = None
+            elif self._mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                if self._data_axis is not None:
+                    perm = self._row_perm
+                    key = ("data", id(self._mesh), self._data_axis, n_pad,
+                           None if perm is None else hash(perm.tobytes()))
+                    self.binned = self._cached_device_binned(key)
+                    if self.binned is None:
+                        src = self.train_set.host_binned()
+                        if perm is not None:
+                            # query-aligned layout: gather rows (pads -> bin 0)
+                            b = np.concatenate(
+                                [src, np.zeros((1, src.shape[1]), src.dtype)]
+                            )[perm]
+                        else:
+                            b = np.pad(src, ((0, n_pad - n), (0, 0)))
+                        # feature-major device residency (ops/histogram.py
+                        # LAYOUT DOCTRINE): minor dim n stays unpadded in the
+                        # (8,128)/(32,128) tiles; [n, 28] u8 row-major would
+                        # pad 4.6x
+                        self.binned = self._cache_device_binned(
+                            key, jax.device_put(
+                                np.ascontiguousarray(b.T),
+                                NamedSharding(self._mesh,
+                                              P(None, self._data_axis))))
+                else:
+                    perm = self._col_perm
+                    key = ("feat", id(self._mesh), self._feature_axis,
+                           self._f_pad,
+                           None if perm is None else hash(perm.tobytes()))
+                    self.binned = self._cached_device_binned(key)
+                    if self.binned is None:
+                        src = self.train_set.host_binned()
+                        if perm is not None:
+                            # shard-major EFB columns (pads -> all-zero column)
+                            b = np.concatenate(
+                                [src, np.zeros((src.shape[0], 1), src.dtype)],
+                                axis=1)[:, perm]
+                        else:
+                            b = np.pad(src, ((0, 0), (0, self._f_pad - F)))
+                        self.binned = self._cache_device_binned(
+                            key, jax.device_put(
+                                np.ascontiguousarray(b.T),
+                                NamedSharding(self._mesh,
+                                              P(self._feature_axis, None))))
             else:
-                perm = self._col_perm
-                key = ("feat", id(self._mesh), self._feature_axis,
-                       self._f_pad,
-                       None if perm is None else hash(perm.tobytes()))
+                # n_pad keys the cache: the shape-bucket ladder can pad the
+                # serial row axis too (pads -> bin 0, masked everywhere)
+                key = ("serial", n_pad)
                 self.binned = self._cached_device_binned(key)
                 if self.binned is None:
                     src = self.train_set.host_binned()
-                    if perm is not None:
-                        # shard-major EFB columns (pads -> all-zero column)
-                        b = np.concatenate(
-                            [src, np.zeros((src.shape[0], 1), src.dtype)],
-                            axis=1)[:, perm]
-                    else:
-                        b = np.pad(src, ((0, 0), (0, self._f_pad - F)))
+                    if n_pad > n:
+                        src = np.pad(src, ((0, n_pad - n), (0, 0)))
                     self.binned = self._cache_device_binned(
-                        key, jax.device_put(
-                            np.ascontiguousarray(b.T),
-                            NamedSharding(self._mesh,
-                                          P(self._feature_axis, None))))
-        else:
-            # n_pad keys the cache: the shape-bucket ladder can pad the
-            # serial row axis too (pads -> bin 0, masked everywhere)
-            key = ("serial", n_pad)
-            self.binned = self._cached_device_binned(key)
-            if self.binned is None:
-                src = self.train_set.host_binned()
-                if n_pad > n:
-                    src = np.pad(src, ((0, n_pad - n), (0, 0)))
-                self.binned = self._cache_device_binned(
-                    key, jnp.asarray(np.ascontiguousarray(src.T)))
+                        key, jnp.asarray(np.ascontiguousarray(src.T)))
         self._row_valid = jnp.asarray(self._pad_rows_np(np.ones(n, np.float32)))
         if objective is not None:
             objective.init(self.train_set.metadata, self.num_data)
@@ -576,6 +582,12 @@ class GBDT:
         self.valid_metrics = valid_metrics_per_set
 
     def _build_jit_fns(self) -> None:
+        """Election, grower config and the jitted closures of this
+        booster (host work; the programs compile at their first call)."""
+        with _span("jit.build", ring=True, what="iter_fns"):
+            self._build_jit_fns_inner()
+
+    def _build_jit_fns_inner(self) -> None:
         K = self.num_tree_per_iteration
         nmach = 1
         vote_k = 0
@@ -1017,11 +1029,14 @@ class GBDT:
             mode) — default to the closed-over constants otherwise.
             Returns (new_score, stacked trees, leaf_ids, cegb_used,
             cegb_rows, qscales [K, 2] — per-class quantization scales,
-            zeros when quantized training is off)."""
+            zeros when quantized training is off — and gstats [K, 3] i32:
+            the grower loop's (rounds, candidates offered, splits
+            applied) per tree, beside the tree and not in it)."""
             mc_in = mc if mc_arr is None else mc_arr
             trees = []
             leaf_ids = []
             qscale_rows = []
+            gstat_rows = []
             new_score = score
             for k in range(K):
                 # quantized-gradient mode: per-round discretization with
@@ -1038,13 +1053,15 @@ class GBDT:
                         from ..parallel.collectives import axis_index_flat
                         qkey = jax.random.fold_in(
                             qkey, axis_index_flat(axis_name))
-                    quant_vals = quantize_gradients(
-                        grad[k], hess[k], row_mask, quant_bins, qkey,
-                        stochastic=stoch_round, axis_name=axis_name)
+                    with jax.named_scope("lgbm.quantize"):
+                        quant_vals = quantize_gradients(
+                            grad[k], hess[k], row_mask, quant_bins, qkey,
+                            stochastic=stoch_round, axis_name=axis_name)
                     qscale_rows.append(jnp.stack([quant_vals[2],
                                                   quant_vals[3]]))
                 else:
                     quant_vals = None
+                gstat = None
                 if cegb_on:
                     tree, leaf_id, (cegb_used, cegb_rows) = grow_tree(
                         binned, grad[k], hess[k], row_mask, meta, cfg,
@@ -1060,12 +1077,13 @@ class GBDT:
                         meta_arrays=meta_args)
                 elif use_rounds:
                     from ..grower_rounds import grow_tree_rounds
-                    tree, leaf_id = grow_tree_rounds(
+                    tree, leaf_id, gstat = grow_tree_rounds(
                         binned, grad[k], hess[k], row_mask, meta, cfg,
                         feature_mask=fmask[k], monotone_constraints=mc_in,
                         axis_name=axis_name,
                         rng_key=jax.random.fold_in(rng, k),
-                        meta_arrays=meta_args, quant_vals=quant_vals)
+                        meta_arrays=meta_args, quant_vals=quant_vals,
+                        with_stats=True)
                 else:
                     tree, leaf_id = grow_tree(binned, grad[k], hess[k],
                                               row_mask, meta, cfg,
@@ -1077,6 +1095,11 @@ class GBDT:
                                               forced_plan=forced_plan,
                                               meta_arrays=meta_args,
                                               quant_vals=quant_vals)
+                if gstat is None:
+                    # the serial grower offers and commits one split a
+                    # trip of its loop
+                    gstat = jnp.broadcast_to(tree.num_leaves - 1, (3,))
+                gstat_rows.append(gstat.astype(jnp.int32))
                 if feat_perm_j is not None:
                     tree = tree._replace(
                         split_feature=feat_perm_j[tree.split_feature])
@@ -1107,19 +1130,21 @@ class GBDT:
                     active = jnp.arange(cfg.num_leaves) < tree.num_leaves
                     tree = tree._replace(
                         leaf_value=jnp.where(active, pct, tree.leaf_value))
-                tree = tree._replace(
-                    leaf_value=tree.leaf_value * lr,
-                    internal_value=tree.internal_value * lr,
-                )
-                new_score = new_score.at[k].add(
-                    take_from_table(tree.leaf_value, leaf_id))
+                with jax.named_scope("lgbm.leaf_values"):
+                    tree = tree._replace(
+                        leaf_value=tree.leaf_value * lr,
+                        internal_value=tree.internal_value * lr,
+                    )
+                with jax.named_scope("lgbm.score_update"):
+                    new_score = new_score.at[k].add(
+                        take_from_table(tree.leaf_value, leaf_id))
                 trees.append(tree)
                 leaf_ids.append(leaf_id)
             stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
             qscales = (jnp.stack(qscale_rows) if quant_on
                        else jnp.zeros((K, 2), jnp.float32))
             return (new_score, stacked, jnp.stack(leaf_ids), cegb_used,
-                    cegb_rows, qscales)
+                    cegb_rows, qscales, jnp.stack(gstat_rows))
 
         if self._stream is not None:
             # streamed executor (lightgbm_tpu/data/stream.py): the
@@ -1212,7 +1237,7 @@ class GBDT:
                 core, mesh=self._mesh,
                 in_specs=(P(ax_f, ax_d), krow, row, krow, krow, P(), P(),
                           P(), row, row, P(), rows_spec),
-                out_specs=(krow, P(), krow, P(), rows_spec, P()),
+                out_specs=(krow, P(), krow, P(), rows_spec, P(), P()),
                 check_vma=False)
 
             def one_iter(binned, score, row_mask, grad, hess, fmask, lr,
@@ -1236,6 +1261,7 @@ class GBDT:
         inv_perm_j = (jnp.asarray(self._inv_perm)
                       if self._inv_perm is not None else None)
 
+        @jax.named_scope("lgbm.gradients")
         def gradients_fn(score):
             if obj is None:
                 raise RuntimeError("no objective: gradients must be provided")
@@ -1458,15 +1484,15 @@ class GBDT:
 
         if self._stream is not None:
             return self._stream_step(grad, hess, mask)
-        with global_timer.section("TreeLearner::Train(dispatch)"), \
-                _span("gbdt.dispatch", iteration=self.iter):
+        with _span("gbdt.dispatch", ring=True, it=self.iter,
+                   timer="TreeLearner::Train(dispatch)"):
             (self.train_score, stacked, leaf_ids, cu, cr,
-             self._quant_scales) = self._iter_fn(
+             self._quant_scales, gstats) = self._iter_fn(
                 self.binned, self.train_score, mask, grad, hess,
                 self._feature_masks(), jnp.float32(self.shrinkage_rate),
                 self._node_key(), *self._cegb_state)
             self._cegb_state = (cu, cr)
-        return self._finish_iter(stacked)
+        return self._finish_iter(stacked, gstats)
 
     def _node_key(self):
         return jax.random.fold_in(self._node_key_base, self.iter)
@@ -1476,9 +1502,8 @@ class GBDT:
         executor (data/stream.py) — the streamed twin of the _iter_fn
         dispatch.  Identical RNG/mask draw order, identical bookkeeping
         via _finish_iter."""
-        from ..utils.timer import global_timer
-        with global_timer.section("TreeLearner::Train(dispatch)"), \
-                _span("stream.iteration", iteration=self.iter):
+        with _span("stream.iteration", it=self.iter,
+                   timer="TreeLearner::Train(dispatch)"):
             (self.train_score, stacked,
              self._quant_scales) = self._stream.grower.run_iteration(
                 grad, hess, mask, jnp.float32(self.shrinkage_rate),
@@ -1530,14 +1555,22 @@ class GBDT:
         return self._macro_valid_jit(vscore, stacked_seq, binned, its,
                                      np.int32(its.shape[0]))
 
-    def _finish_chunk(self, stacked_seq, c: int, shrinks, it0: int) -> bool:
+    def _finish_chunk(self, stacked_seq, c: int, shrinks, it0: int,
+                      gstats_seq=None) -> bool:
         """Chunk counterpart of _finish_iter: per-iteration bookkeeping
         from ONE stacked ``[c, ...]`` device tree bundle.  Same timer tag
-        as _finish_iter — it is the same role, amortized over c."""
-        from ..utils.timer import global_timer
-        with global_timer.section("GBDT::FinishIter(host trees)"), \
-                _span("macro.host_fetch", c=c, it0=it0):
-            return self._finish_chunk_inner(stacked_seq, c, shrinks, it0)
+        as _finish_iter — it is the same role, amortized over c.
+        ``gstats_seq``: the chunk's ``[c, K, 3]`` grower counters.  The
+        seam is host until a block on BOTH paths: the eager path's
+        ``device_get`` waits for the chunk, and on the deferred path the
+        eager ``x[j]`` slices of ``_chunk_slice`` are dispatches that wait
+        for the device once the runtime's queue is full (on the v5e one of
+        them held the rest of the round: PERF.md section 5).  Its duration
+        is therefore the device's time, not the host's work."""
+        with _span("macro.host_fetch", ring=True, it=it0, c=c, it0=it0,
+                   timer="GBDT::FinishIter(host trees)"):
+            return self._finish_chunk_inner(stacked_seq, c, shrinks, it0,
+                                            gstats_seq)
 
     def _chunk_slice(self, stacked_seq, j: int):
         return jax.tree_util.tree_map(lambda x: x[j], stacked_seq)
@@ -1550,7 +1583,8 @@ class GBDT:
             st = st._replace(leaf_value=st.leaf_value + bias)
         return st
 
-    def _finish_chunk_inner(self, stacked_seq, c, shrinks, it0) -> bool:
+    def _finish_chunk_inner(self, stacked_seq, c, shrinks, it0,
+                            gstats_seq=None) -> bool:
         K = self.num_tree_per_iteration
         if self._defer_enabled():
             # bank per-iteration device slices; host conversion stays one
@@ -1558,7 +1592,8 @@ class GBDT:
             # exactly as on the per-iteration deferred path
             for j in range(c):
                 self._pending.append(
-                    (it0 + j, shrinks[j], self._chunk_slice(stacked_seq, j)))
+                    (it0 + j, shrinks[j], self._chunk_slice(stacked_seq, j),
+                     None if gstats_seq is None else gstats_seq[j]))
             if self._history_mode == "all":
                 for j in range(c):
                     self.tree_history.append(self._chunk_bias_fold(
@@ -1576,7 +1611,7 @@ class GBDT:
             return False
         # eager path: ONE bulk device->host transfer for the whole chunk,
         # then the per-iteration host bookkeeping of _finish_iter_inner
-        bh = jax.device_get(stacked_seq)
+        bh, gh = jax.device_get((stacked_seq, gstats_seq))
         stopped = False
         kept = 0
         for j in range(c):
@@ -1599,6 +1634,7 @@ class GBDT:
                 stopped = True
                 break
             self.models.extend(new_models)
+            self._note_trees(abs_it, None if gh is None else gh[j])
             for k in range(K):
                 self.history_scale[len(self.models) - K + k] = 1.0
             kept = j + 1
@@ -1666,14 +1702,28 @@ class GBDT:
         """
         if not self._pending:
             return
-        with _span("gbdt.drain_pending", pending=len(self._pending)):
+        with _span("gbdt.drain_pending", ring=True,
+                   it=self._pending[-1][0], pending=len(self._pending)):
             self._drain_pending_inner()
+
+    def _note_trees(self, abs_it: int, gstats) -> None:
+        """One ``grower.tree`` flight-ring record a tree, where the host
+        takes it: ``gstats`` is the iteration's ``[K, 3]`` (rounds,
+        offered, applied) of the grower's loop, pulled beside the trees,
+        or None (streamed executor)."""
+        if gstats is None:
+            return
+        for k in range(self.num_tree_per_iteration):
+            rounds, offered, applied = (int(v) for v in gstats[k])
+            _flight_note("grower.tree", it=abs_it, k=k, rounds=rounds,
+                         offered=offered, applied=applied)
 
     def _drain_pending_inner(self) -> None:
         K = self.num_tree_per_iteration
         pend = self._pending
         self._pending = []
-        stackeds = [st for (_it, _sr, st) in pend]
+        # the grower's counters ride the trees' transfer
+        stackeds = [(st, gs) for (_it, _sr, st, gs) in pend]
         if len(stackeds) == 1:
             hosts = [jax.device_get(stackeds[0])]
         else:
@@ -1683,7 +1733,7 @@ class GBDT:
             hosts = [jax.tree_util.tree_map(lambda x: x[t], bh)
                      for t in range(len(stackeds))]
         stopped_at = None
-        for (abs_it, shrink, _), th in zip(pend, hosts):
+        for (abs_it, shrink, _, _), (th, gs) in zip(pend, hosts):
             new_models, any_split = [], False
             for k in range(K):
                 tree_k = jax.tree_util.tree_map(lambda x: np.asarray(x[k]),
@@ -1702,6 +1752,7 @@ class GBDT:
                 stopped_at = abs_it
                 break
             self._models.extend(new_models)
+            self._note_trees(abs_it, gs)
             for k in range(K):
                 self.history_scale[len(self._models) - K + k] = 1.0
         self.models_version += 1
@@ -1715,16 +1766,19 @@ class GBDT:
                 del self.tree_history[len(self.tree_history) - dropped:]
             self.iter = stopped_at
 
-    def _finish_iter(self, stacked) -> bool:
+    def _finish_iter(self, stacked, gstats=None) -> bool:
         """Post-step bookkeeping shared by GBDT/GOSS/DART/RF: host copies of
         the (tiny) tree arrays, first-iteration bias folding, valid-score
-        updates.  Returns True when training should stop."""
-        from ..utils.timer import global_timer
-        with global_timer.section("GBDT::FinishIter(host trees)"), \
-                _span("gbdt.finish_iter", iteration=self.iter):
-            return self._finish_iter_inner(stacked)
+        updates.  Returns True when training should stop.  ``gstats``: the
+        iteration's ``[K, 3]`` grower counters (device).  Host until a
+        block, like ``macro.host_fetch`` (``_finish_chunk``): the eager
+        path's host copies wait for the device, and the deferred path's
+        eager device ops may."""
+        with _span("gbdt.finish_iter", ring=True, it=self.iter,
+                   timer="GBDT::FinishIter(host trees)"):
+            return self._finish_iter_inner(stacked, gstats)
 
-    def _finish_iter_inner(self, stacked) -> bool:
+    def _finish_iter_inner(self, stacked, gstats=None) -> bool:
         K = self.num_tree_per_iteration
         if self._defer_enabled():
             # bank the device trees; host conversion happens in bulk at
@@ -1732,7 +1786,8 @@ class GBDT:
             # to the drain.
             # shrinkage is recorded NOW: a reset_parameter learning-rate
             # schedule changes self.shrinkage_rate between bank and drain
-            self._pending.append((self.iter, self.shrinkage_rate, stacked))
+            self._pending.append((self.iter, self.shrinkage_rate, stacked,
+                                  gstats))
             st = stacked
             if self.iter == 0 and any(abs(s) > K_EPSILON
                                       for s in self.init_scores):
@@ -1773,6 +1828,8 @@ class GBDT:
             return True
         self.models.extend(new_models)
         self.models_version += 1
+        self._note_trees(self.iter,
+                         None if gstats is None else np.asarray(gstats))
 
         # keep the device trees for drop/rollback re-evaluation; fold the
         # iter-0 init bias into the saved leaf values so a saved tree's
@@ -2070,9 +2127,8 @@ class GBDT:
                           self.valid_metrics[i], self.objective)
 
     def _eval(self, dataname, score, metrics, objective):
-        from ..utils.timer import global_timer
-        with global_timer.section("GBDT::EvalMetrics"), \
-                _span("gbdt.eval", dataset=dataname):
+        with _span("gbdt.eval", dataset=dataname,
+                   timer="GBDT::EvalMetrics"):
             return self._eval_inner(dataname, score, metrics, objective)
 
     def _eval_inner(self, dataname, score, metrics, objective):

@@ -100,9 +100,9 @@ class GOSS(GBDT):
             # same RNG order, streamed tree growth
             return self._stream_step(grad, hess, mask)
         (self.train_score, stacked, leaf_ids, cu, cr,
-         self._quant_scales) = self._iter_fn(
+         self._quant_scales, gstats) = self._iter_fn(
             self.binned, self.train_score, mask, grad, hess,
             self._feature_masks(), jnp.float32(self.shrinkage_rate),
             self._node_key(), *self._cegb_state)
         self._cegb_state = (cu, cr)
-        return self._finish_iter(stacked)
+        return self._finish_iter(stacked, gstats)

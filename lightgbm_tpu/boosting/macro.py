@@ -58,6 +58,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..obs.metrics import global_registry as _obs_registry
+from ..obs.trace import span as _span
+
 DEFAULT_CHUNK_CAP = 32
 
 
@@ -141,9 +144,12 @@ def make_chunk_fn(b):
         # exactly as per-iteration training does (the stochastic-rounding
         # keys derive from the stacked per-round key stream `keys`)
         qss0 = jnp.zeros((c, K, 2), jnp.float32)
+        # the grower loop's (rounds, offered, applied) per tree ride out
+        # the same way, beside the trees and not in them
+        gss0 = jnp.zeros((c, K, 3), jnp.int32)
 
         def body(j, state):
-            score, cu, cr, ys, qss = state
+            score, cu, cr, ys, qss, gss = state
             mask = _ix(masks, j)
             it = _ix(its, j)
             if kind == "rf":
@@ -157,7 +163,7 @@ def make_chunk_fn(b):
             if kind == "goss":
                 gm = goss_mask(g, h, _ix(gkeys, j), mask)
                 mask = jnp.where(_ix(gons, j), gm, mask)
-            new_score, stacked, _leaf_ids, cu, cr, qsc = core(
+            new_score, stacked, _leaf_ids, cu, cr, qsc, gst = core(
                 binned, score_in, mask, g, h, _ix(fmasks, j), _ix(lrs, j),
                 _ix(keys, j), cu, cr, label_r, weight_r)
             if kind == "rf":
@@ -167,11 +173,13 @@ def make_chunk_fn(b):
                 lambda buf, v: lax.dynamic_update_index_in_dim(buf, v, j, 0),
                 ys, stacked)
             qss = lax.dynamic_update_index_in_dim(qss, qsc, j, 0)
-            return new_score, cu, cr, ys, qss
+            gss = lax.dynamic_update_index_in_dim(gss, gst, j, 0)
+            return new_score, cu, cr, ys, qss, gss
 
-        score, cegb_used, cegb_rows, ys, qss = lax.fori_loop(
-            0, n_steps, body, (score, cegb_used, cegb_rows, ys0, qss0))
-        return score, cegb_used, cegb_rows, ys, qss
+        score, cegb_used, cegb_rows, ys, qss, gss = lax.fori_loop(
+            0, n_steps, body,
+            (score, cegb_used, cegb_rows, ys0, qss0, gss0))
+        return score, cegb_used, cegb_rows, ys, qss, gss
 
     return chunk
 
@@ -277,27 +285,30 @@ def run_chunk(b, c: int, lrs: Optional[Sequence[float]] = None) -> bool:
             "chunk scheduler falls back to c=1 automatically)")
     b.boost_from_average()
     it0 = b.iter
-    xs, lr_list = chunk_host_inputs(b, c, lrs)
-    grad_c, hess_c = b._macro_const_grads()
+    # masks, keys and the stacked [c, n_pad] row arrays, built on the
+    # host every round (their eager device ops are dispatches too)
+    with _span("macro.host_inputs", ring=True, it=it0, c=c):
+        xs, lr_list = chunk_host_inputs(b, c, lrs)
+        grad_c, hess_c = b._macro_const_grads()
 
     if b._macro_chunk_jit is None:
-        b._macro_chunk_jit = build_chunk_program(b)
+        with _span("jit.build", ring=True, what="chunk_program"):
+            b._macro_chunk_jit = build_chunk_program(b)
     cu, cr = b._cegb_state
-    from ..obs.metrics import global_registry as _obs_registry
-    from ..obs.trace import span as _span
-    from ..utils.timer import global_timer
     # chunk-size telemetry on the unified registry (obs_dump / bench
     # journal it instead of scraping logs)
     _obs_registry.counter("train_chunk_dispatches").inc()
     _obs_registry.histogram(
         "train_chunk_size",
         buckets=(1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)).observe(c)
-    with global_timer.section("TreeLearner::Train(dispatch)"), \
-            _span("macro.dispatch", c=c, it0=it0):
-        (b.train_score, cu, cr, stacked_seq, qss) = b._macro_chunk_jit(
+    # host time only: the dispatch returns before the device is done
+    # (the first one of a shape also traces, lowers and compiles)
+    with _span("macro.dispatch", ring=True, it=it0, c=c, it0=it0,
+               timer="TreeLearner::Train(dispatch)"):
+        (b.train_score, cu, cr, stacked_seq, qss, gss) = b._macro_chunk_jit(
             b.binned, b.train_score, cu, cr, np.int32(c), xs,
             b._macro_ctx["label"], b._macro_ctx["weight"], grad_c, hess_c)
     b._cegb_state = (cu, cr)
     if getattr(b, "_quant_on", False):
         b._quant_scales = qss[c - 1]   # last round's per-class scales
-    return b._finish_chunk(stacked_seq, c, lr_list, it0)
+    return b._finish_chunk(stacked_seq, c, lr_list, it0, gss)

@@ -60,13 +60,14 @@ class RF(GBDT):
         once-computed gradients as loop-invariant runtime inputs."""
         return self._grad, self._hess
 
-    def _finish_chunk_inner(self, stacked_seq, c, shrinks, it0) -> bool:
+    def _finish_chunk_inner(self, stacked_seq, c, shrinks, it0,
+                            gstats_seq=None) -> bool:
         """RF chunk finish: eager averaged extension per iteration from ONE
         bulk device fetch; valid scores renormalized by the fused
         running-mean scan (macro.build_chunk_valid's rf mode)."""
         import jax
         K = self.num_tree_per_iteration
-        bh = jax.device_get(stacked_seq)
+        bh, gh = jax.device_get((stacked_seq, gstats_seq))
         stopped = False
         kept = 0
         for j in range(c):
@@ -86,6 +87,7 @@ class RF(GBDT):
                 stopped = True
                 break
             self.models.extend(new_models)
+            self._note_trees(it0 + j, None if gh is None else gh[j])
             kept = j + 1
         self.models_version += 1
         if kept:
@@ -111,16 +113,16 @@ class RF(GBDT):
         # run the shared step on it*mean (so "+ tree" keeps the sum), then
         # renormalize to the running mean including the per-tree bias
         s1 = self.train_score * it
-        s2, stacked, _, cu, cr, self._quant_scales = self._iter_fn(
+        s2, stacked, _, cu, cr, self._quant_scales, gstats = self._iter_fn(
             self.binned, s1, mask, self._grad, self._hess,
             self._feature_masks(), jnp.float32(1.0),
             self._node_key(), *self._cegb_state)
         self._cegb_state = (cu, cr)
         init_col = jnp.asarray(self.init_scores, jnp.float32)[:, None]
         self.train_score = (s2 + init_col) / (it + 1)
-        return self._finish_iter(stacked)
+        return self._finish_iter(stacked, gstats)
 
-    def _finish_iter(self, stacked) -> bool:
+    def _finish_iter(self, stacked, gstats=None) -> bool:
         K = self.num_tree_per_iteration
         it = self.iter
         import jax
@@ -139,6 +141,7 @@ class RF(GBDT):
                         "that meet the split requirements")
             return True
         self.models.extend(new_models)
+        self._note_trees(it, None if gstats is None else np.asarray(gstats))
         init_col = jnp.asarray(self.init_scores, jnp.float32)[:, None]
         for i in range(len(self.valid_scores)):
             vs = self._valid_update(self.valid_scores[i] * it, stacked,

@@ -192,6 +192,9 @@ class IngestPump:
         self.depth = max(int(depth), 1)
         self.prefetch = prefetch
         self.devices = list(devices) if devices else None
+        # seconds the consumer waited for its next chunk: the part of the
+        # puts that the kernel did not hide
+        self.wait_s = 0.0
         if self.devices and len(self.devices) > 1:
             # describe the jax devices through the topology seam (device
             # i = spec i, the row-major mesh order), then round-robin
@@ -217,7 +220,10 @@ class IngestPump:
             for i in range(self.num_chunks):
                 _obs_registry.counter("ingest_blocks_total").inc()
                 _beat("ingest.pump", count=i + 1)
-                yield self._load(i)
+                with _span("ingest.wait_put", block=i) as wait:
+                    item = self._load(i)
+                self.wait_s += wait.seconds
+                yield item
             return
         q: "queue.Queue" = queue.Queue(maxsize=self.depth)
         stop = threading.Event()
@@ -227,7 +233,7 @@ class IngestPump:
                 for i in range(self.num_chunks):
                     if stop.is_set():
                         return
-                    with _span("ingest.block_put", block=i):
+                    with _span("ingest.put", block=i):
                         item = self._load(i)
                     q.put(item)
                 q.put(None)
@@ -240,7 +246,9 @@ class IngestPump:
         gauge = _obs_registry.gauge("ingest_blocks_inflight")
         try:
             while True:
-                item = q.get()
+                with _span("ingest.wait_put") as wait:
+                    item = q.get()
+                self.wait_s += wait.seconds
                 if item is None:
                     break
                 if isinstance(item, BaseException):

@@ -22,6 +22,7 @@ from typing import Dict, List, Optional, Sequence, Union
 import numpy as np
 
 from .binning import BinMapper, BinType, MissingType
+from .obs.trace import span as _span
 
 _BINARY_MAGIC = b"lgbm_tpu.dataset.v1\n"
 
@@ -355,8 +356,12 @@ class Dataset:
             self.group_num_bin = ref.group_num_bin
             self.max_group_bin = ref.max_group_bin
         else:
-            sample_idx = _sample_indices(self.num_data, sample_cnt, seed)
-            self._fit_bin_mappers(raw, sp, sample_idx, categorical)
+            # bin boundaries and EFB groups from the row sample (host)
+            with _span("ingest.edges", ring=True, rows=self.num_data,
+                       sample=sample_cnt):
+                sample_idx = _sample_indices(self.num_data, sample_cnt,
+                                             seed)
+                self._fit_bin_mappers(raw, sp, sample_idx, categorical)
 
         # second pass: bin every row into the per-GROUP merged columns —
         # on device when plan_ingest elects the bucketize+pack kernel
@@ -365,7 +370,9 @@ class Dataset:
         dtype = np.uint8 if self.max_group_bin <= 256 else np.uint16
         self.binned = np.zeros((self.num_data, G), dtype=dtype)
         if not self._maybe_device_bin(raw, sp, self.binned):
-            self._bin_block(raw, sp, self.binned)
+            # annotation only, like the kernel path's ingest.bin_chunk
+            with _span("ingest.host_bin", rows=self.num_data):
+                self._bin_block(raw, sp, self.binned)
 
         self.metadata.check(self.num_data)
         if self.metadata.label is None:
@@ -550,9 +557,6 @@ class Dataset:
         n = out.shape[0]
         if n == 0 or (n < 4096 and plan.elected_by != "env"):
             return False          # dispatch overhead beats tiny blocks
-        import time as _time
-
-        from .obs.trace import span as _span
         if not st["probed"]:
             with _span("ingest.parity_probe"):
                 if not ING.parity_probe(binner, self, raw):
@@ -567,14 +571,21 @@ class Dataset:
         from .data.stream import IngestPump
         local = jax.local_devices()
         devices = local if len(local) > 1 else None
-        t0 = _time.perf_counter()
-        with _span("ingest.device_bin", rows=n,
+        pump = IngestPump(raw, plan.chunk_rows, devices=devices)
+        bin_s = 0.0
+        # one ring record a construct: the per-chunk seams below and in
+        # the pump are annotations, their sums ride on this record
+        with _span("ingest.device_bin", ring=True, rows=n,
                    chunk_rows=plan.chunk_rows,
-                   tile_rows=plan.tile_rows):
-            for _i, start, rows, chunk in IngestPump(
-                    raw, plan.chunk_rows, devices=devices):
-                out[start:start + rows] = np.asarray(binner(chunk))
-        dt = _time.perf_counter() - t0
+                   tile_rows=plan.tile_rows) as seam:
+            for _i, start, rows, chunk in pump:
+                # kernel and pull back; np.asarray blocks, so the host
+                # clock holds the device time here
+                with _span("ingest.bin_chunk") as chunk_seam:
+                    out[start:start + rows] = np.asarray(binner(chunk))
+                bin_s += chunk_seam.seconds
+            seam.set(wait_put_s=pump.wait_s, bin_s=bin_s)
+        dt = seam.seconds
         rps = round(n / max(dt, 1e-9), 1)
         ING.record_ingest_story(
             path="kernel", elected_by=plan.elected_by, rows=n,

@@ -309,7 +309,7 @@ def train(params: dict, train_set: Dataset, num_boost_round: int = 100,
             t_step0 = time.perf_counter()
             if c > 1:
                 lrs = ([_lr_at(j) for j in range(i, i + c)] if lr_cbs else None)
-                with _span("engine.step", i=i, c=c):
+                with _span("engine.step", ring=True, it=i, c=c):
                     finished = booster.update_chunk(c, lrs)
                 if lrs is not None:
                     # replicate the last reset_parameter side effects so the
@@ -321,14 +321,13 @@ def train(params: dict, train_set: Dataset, num_boost_round: int = 100,
                 for cb in cbs_before:
                     cb(callback_mod.CallbackEnv(booster, params, i, 0,
                                                 num_boost_round, None))
-                with _span("engine.step", i=i, c=1):
+                with _span("engine.step", ring=True, it=i, c=1):
                     finished = booster.update(fobj=fobj)
                 i += 1
-            # step boundary: flight ring + live-rate gauges + heartbeat
-            # (cheap host-side accounting — no device work, no numerics)
+            # step boundary (the engine.step seam above is the flight
+            # ring's record of it): live-rate gauges + heartbeat (cheap
+            # host-side accounting — no device work, no numerics)
             step_s = time.perf_counter() - t_step0
-            _flight.note("engine.step", i=i - c, c=c,
-                         dur_us=step_s * 1e6)
             _flight.sample_metrics()
             _obs_registry.gauge("train_iter_seconds").set(
                 round(step_s / max(c, 1), 6))

@@ -478,6 +478,7 @@ def _grow_tree_traced(
         q_grad, q_hess, g_scale, h_scale = quant_vals
         q_levels = quant_levels(cfg.quant_bins)
 
+        @jax.named_scope("lgbm.hist")
         def hist_pass(w):
             return build_histogram_int(binned_t, q_grad, q_hess, w > 0, Bg,
                                        method=cfg.hist_method,
@@ -491,6 +492,7 @@ def _grow_tree_traced(
                                     method=cfg.hist_method,
                                     tile_rows=tile)
 
+        @jax.named_scope("lgbm.hist")
         def hist_pass(w):
             return hist_fn(binned_t, grad, hess, w)
 
@@ -784,6 +786,7 @@ def _grow_tree_traced(
             extra_rand_u=(eru[elected] if eru is not None else None))
         return r._replace(feature=elected[r.feature])
 
+    @jax.named_scope("lgbm.scan")
     def leaf_best(ghist, sg, sh, cnt, depth, bounds=None, key=None):
         fm_bn, eru = node_rand(key) if (use_rng and key is not None) \
             else (None, None)
@@ -816,6 +819,7 @@ def _grow_tree_traced(
             r = jax.tree_util.tree_map(lambda x: x[winner], gathered)
         return r
 
+    @jax.named_scope("lgbm.scan")
     def leaf_feats(ghist, sg, sh, cnt, depth, bounds=None, key=None):
         """Per-feature best candidates for one leaf, penalty-free (fills a
         row of the CEGB _LeafFeatBest cache)."""
@@ -1078,6 +1082,7 @@ def _grow_tree_traced(
             more = more | ((c.split_idx < cfg.n_forced) & ~c.forced_aborted)
         return (c.split_idx < L - 1) & more
 
+    @jax.named_scope("lgbm.commit")
     def apply_split(c: Carry, leaf, r: SplitResult) -> Carry:
         tree, best = c.tree, c.best
         s = c.split_idx                               # new internal node index
@@ -1341,28 +1346,29 @@ def _grow_tree_traced(
     # the TRUE f32 gradient sums (ops/renew.py seam), so the committed
     # leaves carry no discretization bias — only the SPLITS came from the
     # integer histograms (reference: RenewIntGradTreeOutput lineage).
-    tree = out.tree
-    leaf_sh_out = out.leaf_sh
-    if quant and cfg.quant_renew:
-        from .ops.renew import quant_train_renew_leaf
-        sg_t, sh_t = quant_train_renew_leaf(out.leaf_id, grad, hess,
-                                            row_mask, L)
-        sg_t = psum_(sg_t)
-        sh_t = psum_(sh_t)
-        lv = leaf_output(sg_t, sh_t, hp.lambda_l1, hp.lambda_l2,
-                         hp.max_delta_step)
-        leaf_sh_out = sh_t
-    else:
-        lv = leaf_output(out.leaf_sg, out.leaf_sh, hp.lambda_l1,
-                         hp.lambda_l2, hp.max_delta_step)
-    if use_mc:
-        lv = jnp.clip(lv, out.leaf_min, out.leaf_max)
-    active = jnp.arange(L) < tree.num_leaves
-    tree = tree._replace(
-        leaf_value=jnp.where(active, lv, 0.0),
-        leaf_weight=jnp.where(active, leaf_sh_out, 0.0),
-        leaf_count=jnp.where(active, out.leaf_cnt, 0.0),
-    )
+    with jax.named_scope("lgbm.leaf_values"):
+        tree = out.tree
+        leaf_sh_out = out.leaf_sh
+        if quant and cfg.quant_renew:
+            from .ops.renew import quant_train_renew_leaf
+            sg_t, sh_t = quant_train_renew_leaf(out.leaf_id, grad, hess,
+                                                row_mask, L)
+            sg_t = psum_(sg_t)
+            sh_t = psum_(sh_t)
+            lv = leaf_output(sg_t, sh_t, hp.lambda_l1, hp.lambda_l2,
+                             hp.max_delta_step)
+            leaf_sh_out = sh_t
+        else:
+            lv = leaf_output(out.leaf_sg, out.leaf_sh, hp.lambda_l1,
+                             hp.lambda_l2, hp.max_delta_step)
+        if use_mc:
+            lv = jnp.clip(lv, out.leaf_min, out.leaf_max)
+        active = jnp.arange(L) < tree.num_leaves
+        tree = tree._replace(
+            leaf_value=jnp.where(active, lv, 0.0),
+            leaf_weight=jnp.where(active, leaf_sh_out, 0.0),
+            leaf_count=jnp.where(active, out.leaf_cnt, 0.0),
+        )
     if cegb_enabled:
         # hand the cross-tree CEGB state back to the caller (the reference
         # keeps it in the tree learner across Train calls)

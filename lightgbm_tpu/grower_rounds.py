@@ -99,8 +99,12 @@ def _grow_tree_rounds_traced(
                                             # same-shaped datasets
     quant_vals: Optional[tuple] = None,     # cfg.quant: (gq, hq, g_scale,
                                             # h_scale) — see grower.grow_tree
+    with_stats: bool = False,
 ):
-    """Grow one tree; returns (TreeArrays, leaf_id [n] i32)."""
+    """Grow one tree; returns (TreeArrays, leaf_id [n] i32), and with
+    ``with_stats`` a third [3] i32: the loop's trips, the candidates it
+    offered (a round builds that many smaller-child histograms) and the
+    splits it committed."""
     meta = meta.resolved()
     G, n = binned_t.shape
     L = cfg.num_leaves
@@ -295,36 +299,37 @@ def _grow_tree_rounds_traced(
     def psum_(x):
         return _psum(x, axis_name, hier_rd, pinned_rd)
 
-    if quant:
-        member = row_mask > 0
-        if use_fused and use_shared_root:
-            root_local = fused_frontier_accumulate(
-                binned_t, fused_vals, jnp.where(member, 0, KCAP), KCAP,
-                Bg, feat_tile=fused_ftile, block_rows=fused_brows,
-                tile_rows=tile)[0]
+    with jax.named_scope("lgbm.hist"):
+        if quant:
+            member = row_mask > 0
+            if use_fused and use_shared_root:
+                root_local = fused_frontier_accumulate(
+                    binned_t, fused_vals, jnp.where(member, 0, KCAP), KCAP,
+                    Bg, feat_tile=fused_ftile, block_rows=fused_brows,
+                    tile_rows=tile)[0]
+            else:
+                root_local = build_histogram_int(
+                    binned_t, q_grad, q_hess, member, Bg,
+                    method=cfg.hist_method, levels=q_levels, tile_rows=tile)
+            root_hist = psum_quant_hist(root_local, axis_name, rows_global,
+                                        cfg.quant_bins, hierarchical=hier_rd)
+            root_sg = psum_(jnp.sum(jnp.where(member, q_grad, 0).astype(
+                jnp.int32))).astype(jnp.float32) * g_scale
+            root_sh = psum_(jnp.sum(jnp.where(member, q_hess, 0).astype(
+                jnp.int32))).astype(jnp.float32) * h_scale
+            root_cnt = psum_(jnp.sum(member.astype(jnp.float32)))
         else:
-            root_local = build_histogram_int(
-                binned_t, q_grad, q_hess, member, Bg,
-                method=cfg.hist_method, levels=q_levels, tile_rows=tile)
-        root_hist = psum_quant_hist(root_local, axis_name, rows_global,
-                                    cfg.quant_bins, hierarchical=hier_rd)
-        root_sg = psum_(jnp.sum(jnp.where(member, q_grad, 0).astype(
-            jnp.int32))).astype(jnp.float32) * g_scale
-        root_sh = psum_(jnp.sum(jnp.where(member, q_hess, 0).astype(
-            jnp.int32))).astype(jnp.float32) * h_scale
-        root_cnt = psum_(jnp.sum(member.astype(jnp.float32)))
-    else:
-        if use_fused and use_shared_root:
-            root_local = fused_frontier_accumulate(
-                binned_t, fused_vals, jnp.where(row_mask > 0, 0, KCAP),
-                KCAP, Bg, feat_tile=fused_ftile, block_rows=fused_brows,
-                tile_rows=tile)[0]
-        else:
-            root_local = hist_fn(binned_t, grad, hess, row_mask)
-        root_hist = psum_(root_local)
-        root_sg = psum_(jnp.sum(grad * row_mask))
-        root_sh = psum_(jnp.sum(hess * row_mask))
-        root_cnt = psum_(jnp.sum(row_mask))
+            if use_fused and use_shared_root:
+                root_local = fused_frontier_accumulate(
+                    binned_t, fused_vals, jnp.where(row_mask > 0, 0, KCAP),
+                    KCAP, Bg, feat_tile=fused_ftile, block_rows=fused_brows,
+                    tile_rows=tile)[0]
+            else:
+                root_local = hist_fn(binned_t, grad, hess, row_mask)
+            root_hist = psum_(root_local)
+            root_sg = psum_(jnp.sum(grad * row_mask))
+            root_sh = psum_(jnp.sum(hess * row_mask))
+            root_cnt = psum_(jnp.sum(row_mask))
 
     tree = TreeArrays.empty(L)
     hist_cache = jnp.zeros((L, 2, G, Bg), jnp.int32).at[0].set(root_hist) \
@@ -338,9 +343,10 @@ def _grow_tree_rounds_traced(
     leaf_max = jnp.full(L, jnp.inf, jnp.float32)
     leaf_id = jnp.zeros(n, jnp.int32)
 
-    best = cache_from(search_all(
-        hist_cache, leaf_sg, leaf_sh, leaf_cnt, tree.leaf_depth,
-        leaf_min, leaf_max, tree.leaf_parent, leaf_parent_side))
+    with jax.named_scope("lgbm.scan"):
+        best = cache_from(search_all(
+            hist_cache, leaf_sg, leaf_sh, leaf_cnt, tree.leaf_depth,
+            leaf_min, leaf_max, tree.leaf_parent, leaf_parent_side))
 
     class Carry(NamedTuple):
         tree: TreeArrays
@@ -354,6 +360,9 @@ def _grow_tree_rounds_traced(
         split_idx: jax.Array
         leaf_min: jax.Array
         leaf_max: jax.Array
+        rounds: jax.Array       # trips of the loop so far
+        offered: jax.Array      # sum of k: candidates built
+        applied: jax.Array      # sum of m: splits committed
 
     iota_L = jnp.arange(L, dtype=jnp.int32)
 
@@ -452,7 +461,8 @@ def _grow_tree_rounds_traced(
 
         return Carry(tree, c.best, hist, leaf_sg, leaf_sh, leaf_cnt,
                      leaf_parent_side, new_leaf_id, c.split_idx + k,
-                     leaf_min, leaf_max)
+                     leaf_min, leaf_max, c.rounds, c.offered,
+                     c.applied + k)
 
     def child_bounds(c: Carry):
         """Per-leaf monotone bounds the two children of each leaf's cached
@@ -483,298 +493,312 @@ def _grow_tree_rounds_traced(
             lambda b_, v: _pad_scatter(b_, ids, v, valid), base, new)
 
     def body(c: Carry) -> Carry:
-        gains = active_gains(c)
-        pos = gains > 0.0
-        npos = jnp.sum(pos.astype(jnp.int32))
-        budget = (L - c.tree.num_leaves).astype(jnp.int32)
-        k = jnp.minimum(jnp.minimum(npos, budget), KCAP)
-        # total order (gain desc, leaf asc) = successive best-first ArgMax
-        # picks (reference: SerialTreeLearner::Train loop :175-193)
-        order = jnp.argsort(-gains, stable=True)
-        rank = jnp.zeros(L, jnp.int32).at[order].set(iota_L)
+        with jax.named_scope("lgbm.route"):
+            gains = active_gains(c)
+            pos = gains > 0.0
+            npos = jnp.sum(pos.astype(jnp.int32))
+            budget = (L - c.tree.num_leaves).astype(jnp.int32)
+            k = jnp.minimum(jnp.minimum(npos, budget), KCAP)
+            # total order (gain desc, leaf asc) = successive best-first ArgMax
+            # picks (reference: SerialTreeLearner::Train loop :175-193)
+            order = jnp.argsort(-gains, stable=True)
+            rank = jnp.zeros(L, jnp.int32).at[order].set(iota_L)
 
-        # -- candidate routing: per-row goes-left bit, candidate rank, and
-        # smaller-child membership for the whole batch.
-        b = c.best
-        idl = jnp.clip(order[:KCAP], 0, L - 1)          # candidate leaves
+            # -- candidate routing: per-row goes-left bit, candidate rank, and
+            # smaller-child membership for the whole batch.
+            b = c.best
+            idl = jnp.clip(order[:KCAP], 0, L - 1)          # candidate leaves
 
-        if use_router:
-            # ROUTER MATMUL (numeric features, accelerator path): ONE
-            # [9, n] take_from_table one-hot matmul hands every row its
-            # leaf's split params, then one fused [G, n] select-reduce
-            # reads the row's split-feature bin — O(G*n) total per round
-            # (~one binned-matrix stream, the cost the expanded segment
-            # histogram already pays) vs the scan's O(k*n) column passes:
-            # a clear win on the wide rounds (k up to 128) and a ~one-
-            # stream overhead on narrow ones.  All table values are
-            # integers < 2^16 or flags: exact in f32.
-            feat_l = jnp.clip(b.feature, 0, F - 1)
-            live_l = pos & (rank < k)
-            tbl = jnp.stack([
-                jnp.where(live_l, rank, KCAP).astype(jnp.float32),   # crank
-                feat_group[feat_l].astype(jnp.float32),              # group
-                b.threshold.astype(jnp.float32),
-                b.default_left.astype(jnp.float32),
-                missing_type[feat_l].astype(jnp.float32),
-                default_bin[feat_l].astype(jnp.float32),
-                num_bin[feat_l].astype(jnp.float32),
-                feat_start[feat_l].astype(jnp.float32),
-                (b.left_count <= b.right_count).astype(jnp.float32),
-            ], axis=1)                                   # [L, 9]
-            prm = take_from_table(tbl, c.leaf_id, leading=True)  # [9, n]
-            crank = prm[0].astype(jnp.int32)
-            grp = prm[1].astype(jnp.int32)
-            thr_r = prm[2].astype(jnp.int32)
-            dl_r = prm[3] > 0.5
-            mt_r = prm[4].astype(jnp.int32)
-            db_r = prm[5].astype(jnp.int32)
-            nb_r = prm[6].astype(jnp.int32)
-            fs_r = prm[7].astype(jnp.int32)
-            sl_r = prm[8] > 0.5
-            # row's bin of its leaf's split feature: a select-reduce over
-            # the feature-major matrix (exactly one group matches; fused —
-            # no [n, G] intermediate, no serialized gather)
-            iota_G = jnp.arange(G, dtype=jnp.int32)
-            col = jnp.sum(jnp.where(iota_G[:, None] == grp[None, :],
-                                    binned_t.astype(jnp.int32), 0), axis=0)
-            dec = col - fs_r + 1
-            binf = jnp.where((dec >= 1) & (dec < nb_r), dec, 0)
-            # the numeric fast path of the one documented decision-rule
-            # mirror (DenseBin::SplitInner) — per-row params broadcast
-            gl = row_goes_left(binf, thr_r, dl_r, None, None,
-                               mt_r, db_r, nb_r)
-            row_small = gl == sl_r
-        else:
-            # candidate scan: one step per candidate reads its split
-            # feature as a CONTIGUOUS column of the transposed matrix and
-            # broadcasts scalar split params (kept for categorical splits
-            # — the per-row bitset test doesn't ride an f32 table — and
-            # for CPU, where one-hot matmuls lose)
-            def cstep(carry, kk):
-                def live(carry):
-                    gl_a, crank_a, small_a = carry
-                    leaf = idl[kk]
-                    feat = jnp.clip(b.feature[leaf], 0, F - 1)
-                    col = lax.dynamic_index_in_dim(binned_t,
-                                                   feat_group[feat], 0,
-                                                   keepdims=False)   # [n]
-                    nb = num_bin[feat]
-                    dec = col.astype(jnp.int32) - feat_start[feat] + 1
-                    binf = jnp.where((dec >= 1) & (dec < nb), dec, 0)
-                    glk = row_goes_left(
-                        binf, b.threshold[leaf], b.default_left[leaf],
-                        b.is_categorical[leaf] if has_cat else None,
-                        b.cat_bitset[leaf] if has_cat else None,
-                        missing_type[feat], default_bin[feat], nb)
-                    mk = c.leaf_id == leaf
-                    sl = b.left_count[leaf] <= b.right_count[leaf]
-                    return (jnp.where(mk, glk, gl_a),
-                            jnp.where(mk, kk, crank_a),
-                            jnp.where(mk, glk == sl, small_a))
-                # skip the O(n) column read + masking for dead candidate
-                # lanes (late-tree rounds often have k of 1-2 of KCAP)
-                return lax.cond(kk < k, live, lambda c_: c_, carry), None
+            if use_router:
+                # ROUTER MATMUL (numeric features, accelerator path): ONE
+                # [9, n] take_from_table one-hot matmul hands every row its
+                # leaf's split params, then one fused [G, n] select-reduce
+                # reads the row's split-feature bin — O(G*n) total per round
+                # (~one binned-matrix stream, the cost the expanded segment
+                # histogram already pays) vs the scan's O(k*n) column passes:
+                # a clear win on the wide rounds (k up to 128) and a ~one-
+                # stream overhead on narrow ones.  All table values are
+                # integers < 2^16 or flags: exact in f32.
+                feat_l = jnp.clip(b.feature, 0, F - 1)
+                live_l = pos & (rank < k)
+                tbl = jnp.stack([
+                    jnp.where(live_l, rank, KCAP).astype(jnp.float32),  # crank
+                    feat_group[feat_l].astype(jnp.float32),             # group
+                    b.threshold.astype(jnp.float32),
+                    b.default_left.astype(jnp.float32),
+                    missing_type[feat_l].astype(jnp.float32),
+                    default_bin[feat_l].astype(jnp.float32),
+                    num_bin[feat_l].astype(jnp.float32),
+                    feat_start[feat_l].astype(jnp.float32),
+                    (b.left_count <= b.right_count).astype(jnp.float32),
+                ], axis=1)                                   # [L, 9]
+                prm = take_from_table(tbl, c.leaf_id, leading=True)  # [9, n]
+                crank = prm[0].astype(jnp.int32)
+                grp = prm[1].astype(jnp.int32)
+                thr_r = prm[2].astype(jnp.int32)
+                dl_r = prm[3] > 0.5
+                mt_r = prm[4].astype(jnp.int32)
+                db_r = prm[5].astype(jnp.int32)
+                nb_r = prm[6].astype(jnp.int32)
+                fs_r = prm[7].astype(jnp.int32)
+                sl_r = prm[8] > 0.5
+                # row's bin of its leaf's split feature: a select-reduce over
+                # the feature-major matrix (exactly one group matches; fused —
+                # no [n, G] intermediate, no serialized gather)
+                iota_G = jnp.arange(G, dtype=jnp.int32)
+                col = jnp.sum(jnp.where(iota_G[:, None] == grp[None, :],
+                                        binned_t.astype(jnp.int32), 0), axis=0)
+                dec = col - fs_r + 1
+                binf = jnp.where((dec >= 1) & (dec < nb_r), dec, 0)
+                # the numeric fast path of the one documented decision-rule
+                # mirror (DenseBin::SplitInner) — per-row params broadcast
+                gl = row_goes_left(binf, thr_r, dl_r, None, None,
+                                   mt_r, db_r, nb_r)
+                row_small = gl == sl_r
+            else:
+                # candidate scan: one step per candidate reads its split
+                # feature as a CONTIGUOUS column of the transposed matrix and
+                # broadcasts scalar split params (kept for categorical splits
+                # — the per-row bitset test doesn't ride an f32 table — and
+                # for CPU, where one-hot matmuls lose)
+                def cstep(carry, kk):
+                    def live(carry):
+                        gl_a, crank_a, small_a = carry
+                        leaf = idl[kk]
+                        feat = jnp.clip(b.feature[leaf], 0, F - 1)
+                        col = lax.dynamic_index_in_dim(binned_t,
+                                                       feat_group[feat], 0,
+                                                       keepdims=False)   # [n]
+                        nb = num_bin[feat]
+                        dec = col.astype(jnp.int32) - feat_start[feat] + 1
+                        binf = jnp.where((dec >= 1) & (dec < nb), dec, 0)
+                        glk = row_goes_left(
+                            binf, b.threshold[leaf], b.default_left[leaf],
+                            b.is_categorical[leaf] if has_cat else None,
+                            b.cat_bitset[leaf] if has_cat else None,
+                            missing_type[feat], default_bin[feat], nb)
+                        mk = c.leaf_id == leaf
+                        sl = b.left_count[leaf] <= b.right_count[leaf]
+                        return (jnp.where(mk, glk, gl_a),
+                                jnp.where(mk, kk, crank_a),
+                                jnp.where(mk, glk == sl, small_a))
+                    # skip the O(n) column read + masking for dead candidate
+                    # lanes (late-tree rounds often have k of 1-2 of KCAP)
+                    return lax.cond(kk < k, live, lambda c_: c_, carry), None
 
-            (gl, crank, row_small), _ = lax.scan(
-                cstep,
-                (jnp.zeros(n, jnp.bool_), jnp.full(n, KCAP, jnp.int32),
-                 jnp.zeros(n, jnp.bool_)),
-                jnp.arange(KCAP, dtype=jnp.int32))
+                (gl, crank, row_small), _ = lax.scan(
+                    cstep,
+                    (jnp.zeros(n, jnp.bool_), jnp.full(n, KCAP, jnp.int32),
+                     jnp.zeros(n, jnp.bool_)),
+                    jnp.arange(KCAP, dtype=jnp.int32))
 
-        # smaller-child segment histograms: one pass for the whole
-        # candidate batch (slot r = the round's r-th candidate)
-        small_left = b.left_count <= b.right_count
-        slot = jnp.where(row_small, crank, KCAP)
-        if use_fused:
-            seg = None      # the fused megakernel produces it below
-        elif quant:
-            seg = psum_quant_hist(compacted_segment_histogram_int(
-                binned_t, q_grad, q_hess, row_mask, slot, KCAP, Bg, caps,
-                num_live=k, packed=packed, levels=q_levels,
-                tile_rows=tile),
-                axis_name, rows_global, cfg.quant_bins,
-                hierarchical=hier_rd)
-        else:
-            seg = _psum(compacted_segment_histogram(
-                binned_t, grad, hess, row_mask, slot, KCAP, Bg, caps,
-                f32_vals=seg_f32, num_live=k, packed=packed,
-                tile_rows=tile), axis_name, hier_rd, pinned_rd)
+            # smaller-child segment histograms: one pass for the whole
+            # candidate batch (slot r = the round's r-th candidate)
+            small_left = b.left_count <= b.right_count
+            slot = jnp.where(row_small, crank, KCAP)
+        with jax.named_scope("lgbm.hist"):
+            if use_fused:
+                seg = None      # the fused megakernel produces it below
+            elif quant:
+                seg = psum_quant_hist(compacted_segment_histogram_int(
+                    binned_t, q_grad, q_hess, row_mask, slot, KCAP, Bg, caps,
+                    num_live=k, packed=packed, levels=q_levels,
+                    tile_rows=tile),
+                    axis_name, rows_global, cfg.quant_bins,
+                    hierarchical=hier_rd)
+            else:
+                seg = _psum(compacted_segment_histogram(
+                    binned_t, grad, hess, row_mask, slot, KCAP, Bg, caps,
+                    f32_vals=seg_f32, num_live=k, packed=packed,
+                    tile_rows=tile), axis_name, hier_rd, pinned_rd)
 
         # -- candidate children's best splits, BEFORE committing anything:
         # per-leaf candidates are independent, so lane i's results are
         # valid under any commit that includes candidate i.  Left children
         # keep the parent's leaf slot; stats come from the cache.
-        ph = c.hist[idl]                                # [K, 3, G, Bg]
-        lg_, lh_, lc_ = (b.left_sum_grad[idl], b.left_sum_hess[idl],
-                         b.left_count[idl])
-        rg_, rh_, rc_ = (b.right_sum_grad[idl], b.right_sum_hess[idl],
-                         b.right_count[idl])
-        depth_c = c.tree.leaf_depth[idl] + 1
-        if use_fused:
-            # fused megakernel (ops/fused.py): one streamed pass builds
-            # the K smaller-child histograms in VMEM, derives each
-            # sibling from the parent arena in-kernel and scans both
-            # children; only `seg` + the [2K, F] per-feature-best
-            # tuples return — the staged arm's seg/scan HBM round-trip
-            # is deleted.  The pick + depth gate mirror search_all's
-            # best_split_for_leaf + gain gating exactly.
-            csums = jnp.stack([jnp.concatenate([lg_, rg_]),
-                               jnp.concatenate([lh_, rh_]),
-                               jnp.concatenate([lc_, rc_])])   # [3, 2K]
-            if use_mc:
-                bl_min, bl_max, br_min, br_max = child_bounds(c)
-                f_bounds = (jnp.concatenate([bl_min[idl], br_min[idl]]),
-                            jnp.concatenate([bl_max[idl], br_max[idl]]))
-            else:
-                f_bounds = None
-            if axis_name is None:
-                seg, nfb = fused_frontier_splits(
-                    binned_t, fused_vals, slot, KCAP, Bg, csums,
-                    small_left[idl], ph, num_bin, missing_type,
-                    default_bin, hp, quant_scales=fused_scales,
-                    monotone_constraints=mc_j, child_bounds=f_bounds,
-                    feat_tile=fused_ftile, block_rows=fused_brows,
-                    tile_rows=tile)
-            else:
-                # THE COLLECTIVE SEAM (sharded data-parallel): gains are
-                # not summable across shards but the smaller-child hists
-                # are — accumulate LOCALLY in the VMEM arena, reduce
-                # exactly those [K, ch, G, Bg] hists over the (possibly
-                # tiered) data axes, then sibling-derive + scan the
-                # REDUCED arena in the standalone epilogue kernel.  The
-                # reduction routing is byte-identical to the staged arm's
-                # (psum_quant_hist / _psum), and integer accumulation is
-                # associative, so sharded fused == sharded staged
-                # bit-for-bit in quantized mode.
-                seg_local = fused_frontier_accumulate(
-                    binned_t, fused_vals, slot, KCAP, Bg,
-                    feat_tile=fused_ftile, block_rows=fused_brows,
-                    tile_rows=tile)
-                if quant:
-                    seg = psum_quant_hist(seg_local, axis_name,
-                                          rows_global, cfg.quant_bins,
-                                          hierarchical=hier_rd)
+        with jax.named_scope("lgbm.scan"):
+            ph = c.hist[idl]                                # [K, 3, G, Bg]
+            lg_, lh_, lc_ = (b.left_sum_grad[idl], b.left_sum_hess[idl],
+                             b.left_count[idl])
+            rg_, rh_, rc_ = (b.right_sum_grad[idl], b.right_sum_hess[idl],
+                             b.right_count[idl])
+            depth_c = c.tree.leaf_depth[idl] + 1
+            if use_fused:
+                # fused megakernel (ops/fused.py): one streamed pass builds
+                # the K smaller-child histograms in VMEM, derives each
+                # sibling from the parent arena in-kernel and scans both
+                # children; only `seg` + the [2K, F] per-feature-best
+                # tuples return — the staged arm's seg/scan HBM round-trip
+                # is deleted.  The pick + depth gate mirror search_all's
+                # best_split_for_leaf + gain gating exactly.
+                csums = jnp.stack([jnp.concatenate([lg_, rg_]),
+                                   jnp.concatenate([lh_, rh_]),
+                                   jnp.concatenate([lc_, rc_])])   # [3, 2K]
+                if use_mc:
+                    bl_min, bl_max, br_min, br_max = child_bounds(c)
+                    f_bounds = (jnp.concatenate([bl_min[idl], br_min[idl]]),
+                                jnp.concatenate([bl_max[idl], br_max[idl]]))
                 else:
-                    seg = psum_(seg_local)
-                nfb = fused_sibling_scan(
-                    seg, csums, num_bin, missing_type, default_bin, hp,
-                    small_left=small_left[idl], parent_hist=ph,
-                    quant_scales=fused_scales,
-                    monotone_constraints=mc_j, child_bounds=f_bounds,
-                    feat_tile=fused_ftile)
-            if has_cat:
-                # categorical merge: the arena accumulated the cat
-                # columns too (same segment reduction) — derive the
-                # children's cat slices from the cached parents, rescale
-                # (the slice's default count factor is bit-identical to
-                # the full hist's: integer hess totals match across
-                # features), and run the SHARED cat scan; the tuples
-                # override the kernel's numeric ones in the pick below.
-                ci = jnp.asarray(cat_idx, jnp.int32)
-                sm_c = seg[:, :, ci, :]
-                ph_c = ph[:, :, ci, :]
-                slc = small_left[idl][:, None, None, None]
-                hl_c = jnp.where(slc, sm_c, ph_c - sm_c)
-                chc = jnp.concatenate([hl_c, ph_c - hl_c])  # [2K,ch,Fc,B]
-                if quant:
-                    chc = quant_rescale_hist(chc, g_scale, h_scale,
-                                             csums[2])
-                nb_c, mt_c, db_c = (num_bin[ci], missing_type[ci],
-                                    default_bin[ci])
-                ic_c = is_cat[ci]
-                cat_fb = jax.vmap(
-                    lambda hh, sg_, sh_, cn_: feature_best_splits(
-                        hh, sg_, sh_, cn_, nb_c, mt_c, db_c, ic_c, hp,
-                        has_categorical=True))(
-                    chc, csums[0], csums[1], csums[2])
+                    f_bounds = None
+                if axis_name is None:
+                    with jax.named_scope("lgbm.hist"):
+                        seg, nfb = fused_frontier_splits(
+                            binned_t, fused_vals, slot, KCAP, Bg, csums,
+                            small_left[idl], ph, num_bin, missing_type,
+                            default_bin, hp, quant_scales=fused_scales,
+                            monotone_constraints=mc_j, child_bounds=f_bounds,
+                            feat_tile=fused_ftile, block_rows=fused_brows,
+                            tile_rows=tile)
+                else:
+                    # THE COLLECTIVE SEAM (sharded data-parallel): gains are
+                    # not summable across shards but the smaller-child hists
+                    # are — accumulate LOCALLY in the VMEM arena, reduce
+                    # exactly those [K, ch, G, Bg] hists over the (possibly
+                    # tiered) data axes, then sibling-derive + scan the
+                    # REDUCED arena in the standalone epilogue kernel.  The
+                    # reduction routing is byte-identical to the staged arm's
+                    # (psum_quant_hist / _psum), and integer accumulation is
+                    # associative, so sharded fused == sharded staged
+                    # bit-for-bit in quantized mode.
+                    with jax.named_scope("lgbm.hist"):
+                        seg_local = fused_frontier_accumulate(
+                            binned_t, fused_vals, slot, KCAP, Bg,
+                            feat_tile=fused_ftile, block_rows=fused_brows,
+                            tile_rows=tile)
+                        if quant:
+                            seg = psum_quant_hist(seg_local, axis_name,
+                                                  rows_global, cfg.quant_bins,
+                                                  hierarchical=hier_rd)
+                        else:
+                            seg = psum_(seg_local)
+                    nfb = fused_sibling_scan(
+                        seg, csums, num_bin, missing_type, default_bin, hp,
+                        small_left=small_left[idl], parent_hist=ph,
+                        quant_scales=fused_scales,
+                        monotone_constraints=mc_j, child_bounds=f_bounds,
+                        feat_tile=fused_ftile)
+                if has_cat:
+                    # categorical merge: the arena accumulated the cat
+                    # columns too (same segment reduction) — derive the
+                    # children's cat slices from the cached parents, rescale
+                    # (the slice's default count factor is bit-identical to
+                    # the full hist's: integer hess totals match across
+                    # features), and run the SHARED cat scan; the tuples
+                    # override the kernel's numeric ones in the pick below.
+                    ci = jnp.asarray(cat_idx, jnp.int32)
+                    sm_c = seg[:, :, ci, :]
+                    ph_c = ph[:, :, ci, :]
+                    slc = small_left[idl][:, None, None, None]
+                    hl_c = jnp.where(slc, sm_c, ph_c - sm_c)
+                    chc = jnp.concatenate([hl_c, ph_c - hl_c])  # [2K,ch,Fc,B]
+                    if quant:
+                        chc = quant_rescale_hist(chc, g_scale, h_scale,
+                                                 csums[2])
+                    nb_c, mt_c, db_c = (num_bin[ci], missing_type[ci],
+                                        default_bin[ci])
+                    ic_c = is_cat[ci]
+                    cat_fb = jax.vmap(
+                        lambda hh, sg_, sh_, cn_: feature_best_splits(
+                            hh, sg_, sh_, cn_, nb_c, mt_c, db_c, ic_c, hp,
+                            has_categorical=True))(
+                        chc, csums[0], csums[1], csums[2])
+                else:
+                    cat_fb = None
+                res = pick_fused_best(nfb, csums[0], csums[1], csums[2],
+                                      feature_mask=feature_mask,
+                                      cat_best=cat_fb, cat_idx=cat_idx)
+                if cfg.max_depth > 0:
+                    dd = jnp.concatenate([depth_c, depth_c])
+                    res = res._replace(gain=jnp.where(
+                        dd >= cfg.max_depth, -jnp.inf, res.gain))
             else:
-                cat_fb = None
-            res = pick_fused_best(nfb, csums[0], csums[1], csums[2],
-                                  feature_mask=feature_mask,
-                                  cat_best=cat_fb, cat_idx=cat_idx)
-            if cfg.max_depth > 0:
-                dd = jnp.concatenate([depth_c, depth_c])
-                res = res._replace(gain=jnp.where(
-                    dd >= cfg.max_depth, -jnp.inf, res.gain))
-        else:
-            sl = small_left[idl][:, None, None, None]
-            h_left = jnp.where(sl, seg, ph - seg)
-            h_right = ph - h_left
-            if use_mc:
-                bl_min, bl_max, br_min, br_max = child_bounds(c)
-                bmin = jnp.concatenate([bl_min[idl], br_min[idl]])
-                bmax = jnp.concatenate([bl_max[idl], br_max[idl]])
-            else:
-                bmin = bmax = jnp.zeros(2 * KCAP, jnp.float32)
-            node_of_k = c.split_idx + iota_K            # candidate node ids
-            res = search_all(
-                jnp.concatenate([h_left, h_right]),
-                jnp.concatenate([lg_, rg_]), jnp.concatenate([lh_, rh_]),
-                jnp.concatenate([lc_, rc_]),
-                jnp.concatenate([depth_c, depth_c]), bmin, bmax,
-                jnp.concatenate([node_of_k, node_of_k]),
-                jnp.concatenate([jnp.zeros(KCAP, jnp.int32),
-                                 jnp.ones(KCAP, jnp.int32)]))
+                sl = small_left[idl][:, None, None, None]
+                h_left = jnp.where(sl, seg, ph - seg)
+                h_right = ph - h_left
+                if use_mc:
+                    (bl_min, bl_max,
+                     br_min, br_max) = child_bounds(c)
+                    bmin = jnp.concatenate([bl_min[idl], br_min[idl]])
+                    bmax = jnp.concatenate([bl_max[idl], br_max[idl]])
+                else:
+                    bmin = bmax = jnp.zeros(2 * KCAP, jnp.float32)
+                node_of_k = c.split_idx + iota_K            # candidate node ids
+                res = search_all(
+                    jnp.concatenate([h_left, h_right]),
+                    jnp.concatenate([lg_, rg_]), jnp.concatenate([lh_, rh_]),
+                    jnp.concatenate([lc_, rc_]),
+                    jnp.concatenate([depth_c, depth_c]), bmin, bmax,
+                    jnp.concatenate([node_of_k, node_of_k]),
+                    jnp.concatenate([jnp.zeros(KCAP, jnp.int32),
+                                     jnp.ones(KCAP, jnp.int32)]))
 
-        # -- maximal exact prefix: candidate i (in gain order) is the
-        # best-first pop at step i iff its gain >= every child spawned by
-        # candidates 0..i-1 (ties go to the existing leaf: children's leaf
-        # numbers are always larger, and the reference ArgMax takes the
-        # smallest leaf number).
-        cg = jnp.where(jnp.isnan(res.gain), -jnp.inf, res.gain)
-        pair_max = jnp.maximum(cg[:KCAP], cg[KCAP:])
-        pair_max = jnp.where(iota_K < k, pair_max, -jnp.inf)
-        pcm = jax.lax.cummax(pair_max)                  # children of 0..i
-        sel_sorted = gains[idl]                         # gains by rank
-        follow = (iota_K == 0) | (sel_sorted >= jnp.concatenate(
-            [jnp.full((1,), -jnp.inf), pcm[:-1]]))
-        if cfg.rounds_relaxed:
-            # "fast" mode: always commit the whole batch.  Deviates from
-            # strict best-first only when a child would have outranked a
-            # batched candidate AND the leaf budget later binds — the same
-            # class of tree-shape deviation the reference accepts between
-            # its CPU and GPU learners.  ~log2(num_leaves) rounds, never a
-            # short prefix.
-            m = k
-        else:
-            m = jnp.minimum(k, jnp.cumprod(
-                follow.astype(jnp.int32)).sum().astype(jnp.int32))
+            # -- maximal exact prefix: candidate i (in gain order) is the
+            # best-first pop at step i iff its gain >= every child spawned by
+            # candidates 0..i-1 (ties go to the existing leaf: children's leaf
+            # numbers are always larger, and the reference ArgMax takes the
+            # smallest leaf number).
+            cg = jnp.where(jnp.isnan(res.gain), -jnp.inf, res.gain)
+            pair_max = jnp.maximum(cg[:KCAP], cg[KCAP:])
+            pair_max = jnp.where(iota_K < k, pair_max, -jnp.inf)
+            pcm = jax.lax.cummax(pair_max)                  # children of 0..i
+            sel_sorted = gains[idl]                         # gains by rank
+            follow = (iota_K == 0) | (sel_sorted >= jnp.concatenate(
+                [jnp.full((1,), -jnp.inf), pcm[:-1]]))
+            if cfg.rounds_relaxed:
+                # "fast" mode: always commit the whole batch.  Deviates from
+                # strict best-first only when a child would have outranked a
+                # batched candidate AND the leaf budget later binds — the same
+                # class of tree-shape deviation the reference accepts between
+                # its CPU and GPU learners.  ~log2(num_leaves) rounds, never a
+                # short prefix.
+                m = k
+            else:
+                m = jnp.minimum(k, jnp.cumprod(
+                    follow.astype(jnp.int32)).sum().astype(jnp.int32))
 
         sel_m = pos & (rank < m)
-        cm = apply_round(c, sel_m, rank, m, gl, seg, crank)
-        idc = jnp.concatenate([idl, jnp.clip(c.tree.num_leaves + iota_K,
-                                             0, L - 1)])
-        valid_m = jnp.concatenate([iota_K < m, iota_K < m])
-        return cm._replace(best=cache_scatter(c.best, idc, res, valid_m))
+        with jax.named_scope("lgbm.commit"):
+            cm = apply_round(c, sel_m, rank, m, gl, seg, crank)
+            idc = jnp.concatenate([idl, jnp.clip(
+                c.tree.num_leaves + iota_K, 0, L - 1)])
+            valid_m = jnp.concatenate([iota_K < m, iota_K < m])
+            return cm._replace(
+                best=cache_scatter(c.best, idc, res, valid_m),
+                rounds=c.rounds + 1, offered=c.offered + k)
 
+    zero = jnp.array(0, jnp.int32)
     init = Carry(tree, best, hist_cache, leaf_sg, leaf_sh, leaf_cnt,
-                 leaf_parent_side, leaf_id, jnp.array(0, jnp.int32),
-                 leaf_min, leaf_max)
+                 leaf_parent_side, leaf_id, zero, leaf_min, leaf_max,
+                 zero, zero, zero)
     out = lax.while_loop(cond, body, init)
 
     # finalize leaf values (reference: CalculateSplittedLeafOutput; clamped
     # to monotone bounds like grower.py; quantized renewal re-fits from
     # TRUE f32 sums — see grower.grow_tree's finalize)
-    tree = out.tree
-    leaf_sh_out = out.leaf_sh
-    if quant and cfg.quant_renew:
-        from .ops.renew import quant_train_renew_leaf
-        sg_t, sh_t = quant_train_renew_leaf(out.leaf_id, grad, hess,
-                                            row_mask, L)
-        sg_t = _psum(sg_t, axis_name, hier_rd, pinned_rd)
-        sh_t = _psum(sh_t, axis_name, hier_rd, pinned_rd)
-        lv = leaf_output(sg_t, sh_t, hp.lambda_l1, hp.lambda_l2,
-                         hp.max_delta_step)
-        leaf_sh_out = sh_t
-    else:
-        lv = leaf_output(out.leaf_sg, out.leaf_sh, hp.lambda_l1,
-                         hp.lambda_l2, hp.max_delta_step)
-    if use_mc:
-        lv = jnp.clip(lv, out.leaf_min, out.leaf_max)
-    active = iota_L < tree.num_leaves
-    tree = tree._replace(
-        leaf_value=jnp.where(active, lv, 0.0),
-        leaf_weight=jnp.where(active, leaf_sh_out, 0.0),
-        leaf_count=jnp.where(active, out.leaf_cnt, 0.0),
-    )
+    with jax.named_scope("lgbm.leaf_values"):
+        tree = out.tree
+        leaf_sh_out = out.leaf_sh
+        if quant and cfg.quant_renew:
+            from .ops.renew import quant_train_renew_leaf
+            sg_t, sh_t = quant_train_renew_leaf(out.leaf_id, grad, hess,
+                                                row_mask, L)
+            sg_t = _psum(sg_t, axis_name, hier_rd, pinned_rd)
+            sh_t = _psum(sh_t, axis_name, hier_rd, pinned_rd)
+            lv = leaf_output(sg_t, sh_t, hp.lambda_l1, hp.lambda_l2,
+                             hp.max_delta_step)
+            leaf_sh_out = sh_t
+        else:
+            lv = leaf_output(out.leaf_sg, out.leaf_sh, hp.lambda_l1,
+                             hp.lambda_l2, hp.max_delta_step)
+        if use_mc:
+            lv = jnp.clip(lv, out.leaf_min, out.leaf_max)
+        active = iota_L < tree.num_leaves
+        tree = tree._replace(
+            leaf_value=jnp.where(active, lv, 0.0),
+            leaf_weight=jnp.where(active, leaf_sh_out, 0.0),
+            leaf_count=jnp.where(active, out.leaf_cnt, 0.0),
+        )
+    if with_stats:
+        return tree, out.leaf_id, jnp.stack(
+            [out.rounds, out.offered, out.applied])
     return tree, out.leaf_id

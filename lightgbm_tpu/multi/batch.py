@@ -177,15 +177,14 @@ class BatchedChunkProgram:
 
         from ..obs.metrics import global_registry as _obs_registry
         from ..obs.trace import span as _span
-        from ..utils.timer import global_timer
         _obs_registry.counter("multi_chunk_dispatches").inc()
         _obs_registry.histogram(
             "multi_batch_lanes",
             buckets=(1.0, 2.0, 4.0, 8.0, 16.0, 32.0)).observe(len(bs))
-        with global_timer.section("TreeLearner::Train(dispatch)"), \
-                _span("multi.dispatch", lanes=len(bs), c=c,
-                      live=sum(map(bool, live))):
-            score_B, cu_B, cr_B, ys_B, qss_B = self._fn(
+        with _span("multi.dispatch", lanes=len(bs), c=c,
+                   live=sum(map(bool, live)),
+                   timer="TreeLearner::Train(dispatch)"):
+            score_B, cu_B, cr_B, ys_B, qss_B, gss_B = self._fn(
                 self._binned_B, score_B, cu_B, cr_B, np.int32(c), xs_B,
                 self._label_B, self._weight_B, grad_c, hess_c,
                 self._obj_arrs_B)
@@ -199,5 +198,6 @@ class BatchedChunkProgram:
             if getattr(b, "_quant_on", False):
                 b._quant_scales = qss_B[i][c - 1]
             seq_i = jax.tree_util.tree_map(lambda a, _i=i: a[_i], ys_B)
-            stopped[i] = b._finish_chunk(seq_i, c, lane_lrs[i], it0s[i])
+            stopped[i] = b._finish_chunk(seq_i, c, lane_lrs[i], it0s[i],
+                                         gss_B[i])
         return stopped

@@ -20,15 +20,14 @@ compiler's estimate and its availability varies by backend, and the
 utilization figures exist only on a device the peak tables know: a CPU
 run reports seconds and the cost model's counts, never an MFU.
 
-``jax.profiler`` trace capture (the XLA-level timeline, complementary to
-obs/trace.py's host spans) is wrapped behind ``profiler_trace``.  jax
-imports are lazy: importing this module never initializes a backend.
+A device timeline is ``jax.profiler.start_trace`` around ``lgb.train``:
+the program's ``lgbm.*`` seams (obs/trace.py) and named scopes show there.
+jax imports are lazy: importing this module never initializes a backend.
 """
 
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
 from typing import Callable, Optional
 
 # peak dense bf16 compute per chip, FLOP/s, keyed by a substring of
@@ -182,29 +181,6 @@ def measure_program(fn: Callable, args: tuple, reps: int = 3,
         out["hbm_gbps"] = out["bytes_accessed"] / sec / 1e9
         out["hbm_util"] = out["bytes_accessed"] / sec / pb
     return out
-
-
-@contextmanager
-def profiler_trace(logdir: str):
-    """Optional ``jax.profiler`` capture around a block; yields True when
-    the profiler started (False = unavailable on this backend — the block
-    still runs)."""
-    started = False
-    try:
-        import jax
-        jax.profiler.start_trace(logdir)
-        started = True
-    except Exception:
-        pass
-    try:
-        yield started
-    finally:
-        if started:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
 
 
 def histogram_utilization_table(rows: int = 200_000, features: int = 28,

@@ -279,7 +279,7 @@ def diagnose(signals: dict) -> List[Verdict]:
                             f"{il.get('elected_by')}) and binning still "
                             "dominates: grow the chunk "
                             "(LGBM_TPU_INGEST_CHUNK) or check H2D "
-                            "bandwidth (ingest.block_put spans)")
+                            "bandwidth (ingest.put spans)")
                 else:
                     ev["ingest_fallback_reason"] = il.get("reason")
                     cure = ("ingest fell back to host NumPy binning ("

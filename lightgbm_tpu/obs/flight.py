@@ -15,7 +15,8 @@ bounded ``deque.append`` per fed event:
   instants) feed it DIRECTLY via ``note``/``note_instant`` even with
   tracing off, so the ring is never empty when it matters.  O(1)
   memory, no growth, no numerics touched — recorder-on training is
-  byte-identical by construction.
+  byte-identical by construction.  What a full ring pushes out is
+  counted in ``dropped`` (and a bundle's ``ring_dropped``).
 - **metric marks** — a small deque of periodic counter/gauge snapshots
   (``sample_metrics``) so a bundle can show metric DELTAS across the
   final minutes, not just the terminal values.
@@ -108,6 +109,9 @@ class FlightRecorder:
         self.max_dumps = (int(max_dumps) if max_dumps is not None
                           else _env_int(_MAX_DUMPS_ENV, 8))
         self.dumps = 0
+        # records the full ring has pushed out: a reader that needs the
+        # whole run (set-up seams) checks it is 0; never silent
+        self.dropped = 0
         self._seq = 0
         self._last_sample = 0.0
         self._context: dict = {}
@@ -115,11 +119,16 @@ class FlightRecorder:
 
     # ------------------------------------------------------------- feeding
 
+    def _push(self, ev: dict) -> None:
+        if len(self._ring) == self._ring.maxlen:
+            self.dropped += 1
+        self._ring.append(ev)
+
     def feed(self, ev: dict) -> None:
         """Tee one already-formatted trace event into the ring (called by
         the tracer on every recorded span/instant)."""
         if self.enabled:
-            self._ring.append(ev)
+            self._push(ev)
 
     def note(self, name: str, **args) -> None:
         """Record a complete-style event directly (instrumented seams:
@@ -134,7 +143,7 @@ class FlightRecorder:
               "dur": float(args.pop("dur_us", 0.0))}
         if args:
             ev["args"] = args
-        self._ring.append(ev)
+        self._push(ev)
 
     def note_instant(self, name: str, args: dict) -> None:
         """Point-in-time twin of ``note`` (trace.instant tees here when
@@ -147,7 +156,7 @@ class FlightRecorder:
               * 1e6}
         if args:
             ev["args"] = dict(args)
-        self._ring.append(ev)
+        self._push(ev)
 
     def set_context(self, **ctx) -> None:
         """Attach run context (training params, serving config, mesh
@@ -239,6 +248,7 @@ class FlightRecorder:
             "trigger": trigger,
             "ring": ring,
             "ring_events": len(evs),
+            "ring_dropped": self.dropped,
             "metric_deltas": self._metric_deltas(),
             "fingerprint": self.fingerprint(),
         }

@@ -10,11 +10,25 @@ construction, checkpoint save/load, ``resilient_allgather`` attempts,
 serving batcher admission -> dispatch -> completion) and dump as one JSON
 file that chrome://tracing or ui.perfetto.dev loads directly.
 
-Gate: ``LIGHTGBM_TPU_TRACE`` — unset/"0" disables (a disabled call site
-costs one attribute check and returns a shared null context manager, the
-same contract as ``global_timer``); "1" enables recording; any other
-value enables AND names the file the trace is dumped to at interpreter
-exit.  ``global_tracer.dump(path)`` dumps on demand.
+``span(name, **args)`` is the ONE seam API of the program.  Every span
+enters a ``jax.profiler.TraceAnnotation("lgbm." + name)``, so it lies on
+the profiler's clock beside the device ops whenever a profiler session
+runs (``jax.profiler.start_trace`` around ``lgb.train``); with no session
+an annotation is a flag check, which is what "tracing off" costs.  A
+span opened with ``ring=True`` (the coarse seams: once a round, a chunk,
+a construct — never once a row or a leaf) also lands as one record in
+the always-on flight ring (``obs/flight.py``): name, start, duration,
+``parent`` (the span it ran under, a thread-local stack) and ``it`` (the
+boosting iteration, inherited from the enclosing span when not given).
+``timer="<tag>"`` feeds the seam's seconds to ``utils.timer.global_timer``
+under that tag, so one ``with`` serves both.
+
+Gate of the Chrome-JSON recorder: ``LIGHTGBM_TPU_TRACE`` — unset/"0"
+disables (a seam then records no event; there is one span
+implementation, and ``Tracer.span`` hands out the same seam bound to its
+own tracer); "1" enables recording; any other value enables AND names
+the file the trace is dumped to at interpreter exit.
+``global_tracer.dump(path)`` dumps on demand.
 
 Event format (Chrome trace-event "JSON object format"): complete events
 ``{"name", "ph": "X", "ts", "dur", "pid", "tid", "args"}`` with ``ts``/
@@ -25,8 +39,10 @@ peaks, request admissions).  Events are timestamp-sorted at dump time.
 Because device work is asynchronous under jit, spans measure HOST time:
 dispatch cost lands in the dispatch span and device time surfaces in
 whichever span first blocks on a result (the same decomposition
-``global_timer`` reports, now with per-occurrence timing).  This module
-is dependency-free (stdlib only) and never imports jax.
+``global_timer`` reports, now with per-occurrence timing).  A span that
+only dispatches holds host time only.  This module is stdlib-only at
+import; ``jax.profiler`` is looked up at the first span and its absence
+is tolerated.
 """
 
 from __future__ import annotations
@@ -37,6 +53,8 @@ import os
 import threading
 import time
 from typing import List, Optional
+
+from ..utils.timer import global_timer
 
 _TRACE_ENV = "LIGHTGBM_TPU_TRACE"
 _MAX_EVENTS_ENV = "LIGHTGBM_TPU_TRACE_MAX_EVENTS"
@@ -64,54 +82,6 @@ def _max_events_env() -> int:
     except ValueError:
         return _DEFAULT_MAX_EVENTS
     return v if v > 0 else _DEFAULT_MAX_EVENTS
-
-
-class _NullSpan:
-    """Shared no-op context manager for the disabled path (one instance
-    for the whole process: disabled tracing never allocates)."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set(self, **args):
-        return self
-
-
-_NULL_SPAN = _NullSpan()
-
-
-class _Span:
-    """One live span; records a complete event on ``__exit__`` (always —
-    an exception inside the span closes it and tags ``args["error"]``,
-    so span trees stay well-nested under raises)."""
-
-    __slots__ = ("_tracer", "name", "args", "_t0")
-
-    def __init__(self, tracer: "Tracer", name: str, args: dict):
-        self._tracer = tracer
-        self.name = name
-        self.args = args
-
-    def set(self, **args) -> "_Span":
-        """Attach attributes mid-span (e.g. a result size known late)."""
-        self.args.update(args)
-        return self
-
-    def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        t1 = time.perf_counter()
-        if exc_type is not None:
-            self.args["error"] = exc_type.__name__
-        self._tracer._record(self.name, self._t0, t1, self.args)
-        return False
 
 
 class Tracer:
@@ -153,11 +123,9 @@ class Tracer:
     # ----------------------------------------------------------- recording
 
     def span(self, name: str, **args):
-        """``with tracer.span("grow_tree", leaves=255): ...`` — returns
-        the shared null context manager when disabled."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, args)
+        """``with tracer.span("grow_tree", leaves=255): ...`` — the same
+        seam as the module's ``span``, recording into THIS tracer."""
+        return _Seam(self, name, args)
 
     def instant(self, name: str, **args) -> None:
         """Point-in-time event (Chrome "i" phase, thread scope)."""
@@ -170,14 +138,17 @@ class Tracer:
             ev["args"] = args
         self._append(ev)
 
-    def _record(self, name: str, t0: float, t1: float, args: dict) -> None:
+    def _event(self, name: str, t0: float, t1: float, args: dict) -> dict:
         ev = {"name": name, "ph": "X", "pid": self._pid,
               "tid": threading.get_ident(),
               "ts": (t0 - self._epoch) * 1e6,
               "dur": (t1 - t0) * 1e6}
         if args:
             ev["args"] = args
-        self._append(ev)
+        return ev
+
+    def _record(self, name: str, t0: float, t1: float, args: dict) -> None:
+        self._append(self._event(name, t0, t1, args))
 
     def _append(self, ev: dict) -> None:
         dropped_now = None
@@ -243,12 +214,92 @@ global_tracer = Tracer()
 global_tracer._flight_tee = True
 
 
-def span(name: str, **args):
-    """Module-level span against the process tracer — the instrumentation
-    entry point: ``with span("engine.step", i=i): ...``."""
-    if not global_tracer.enabled:
-        return _NULL_SPAN
-    return _Span(global_tracer, name, args)
+_tls = threading.local()
+_annotation = None      # jax.profiler.TraceAnnotation; False = no jax here
+
+
+def _annotation_cls():
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        except ImportError:
+            _annotation = False
+    return _annotation
+
+
+class _Seam:
+    """One live seam of the program (see the module docstring): a
+    profiler annotation always, a flight-ring record when ``ring``, a
+    ``global_timer`` section when ``timer``, a Chrome-trace event in its
+    tracer when that records (always closed: an exception inside the span
+    tags ``args["error"]``, so span trees stay well-nested under raises).
+    Holds HOST time: a seam that only dispatches ends when the dispatch
+    returns, not when the device does."""
+
+    __slots__ = ("_tracer", "name", "args", "timer", "ring", "seconds",
+                 "_ann", "_t0", "_parent")
+
+    def __init__(self, tracer: Tracer, name: str, args: dict, timer=None,
+                 ring: bool = False):
+        self._tracer = tracer
+        self.name = name
+        self.args = args
+        self.timer = timer
+        self.ring = ring
+
+    def set(self, **args) -> "_Seam":
+        """Attach attributes mid-span (e.g. sums known at the end)."""
+        self.args.update(args)
+        return self
+
+    def __enter__(self) -> "_Seam":
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        self._parent = None
+        if stack:
+            self._parent, it = stack[-1]
+            if it is not None:
+                self.args.setdefault("it", it)
+        stack.append((self.name, self.args.get("it")))
+        cls = _annotation_cls()
+        self._ann = cls("lgbm." + self.name) if cls else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.perf_counter()
+        self.seconds = t1 - self._t0     # for a caller that sums its seams
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        _tls.stack.pop()
+        if self.timer is not None and global_timer.enabled:
+            global_timer.add(self.timer, self.seconds)
+        tracer = self._tracer
+        recording = tracer.enabled
+        sink = _flight_sink if self.ring and not recording else None
+        if recording or sink is not None:
+            args = self.args
+            if self._parent is not None:
+                args["parent"] = self._parent
+            if exc_type is not None:
+                args["error"] = exc_type.__name__
+            if recording:     # the process tracer tees into the flight ring
+                tracer._record(self.name, self._t0, t1, args)
+            else:
+                sink.feed(tracer._event(self.name, self._t0, t1, args))
+        return False
+
+
+def span(name: str, *, timer: Optional[str] = None, ring: bool = False,
+         **args):
+    """The seam API: ``with span("macro.dispatch", ring=True, it=it0,
+    timer="TreeLearner::Train(dispatch)"): ...`` (module docstring)."""
+    return _Seam(global_tracer, name, args, timer, ring)
 
 
 def instant(name: str, **args) -> None:

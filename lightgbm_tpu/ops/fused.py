@@ -239,15 +239,17 @@ def _fused_call(
     from jax.experimental.pallas import tpu as pltpu
 
     if with_scan and _scan_in_xla(interpret):
-        hist = _fused_call(
-            binned_t, vals_t, slot, num_slots, num_bins, None, None, None,
-            feat_tile=feat_tile, block_rows=block_rows,
-            tile_rows=tile_rows, with_scan=False)
-        return hist, fused_sibling_scan(
-            hist, child_sums, *meta_vecs, hp, small_left=small_left,
-            parent_hist=parent_hist, quant_scales=quant_scales,
-            monotone_constraints=monotone_constraints,
-            child_bounds=child_bounds)
+        with jax.named_scope("lgbm.hist"):
+            hist = _fused_call(
+                binned_t, vals_t, slot, num_slots, num_bins, None, None,
+                None, feat_tile=feat_tile, block_rows=block_rows,
+                tile_rows=tile_rows, with_scan=False)
+        with jax.named_scope("lgbm.scan"):
+            return hist, fused_sibling_scan(
+                hist, child_sums, *meta_vecs, hp, small_left=small_left,
+                parent_hist=parent_hist, quant_scales=quant_scales,
+                monotone_constraints=monotone_constraints,
+                child_bounds=child_bounds)
 
     quant = vals_t.dtype == jnp.int8
     ch = int(vals_t.shape[0])
@@ -409,6 +411,7 @@ def _fused_call(
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((ch * Kp, Ft * Bp), acc_dtype)],
         interpret=_interp(interpret),
+        name="lgbm_hist_accum",
     )(*in_arrays)
     hist = out[0].reshape(ch, Kp, F_pad, Bp)[
         :, :K, :F, :B].transpose(1, 0, 2, 3)               # [K, ch, F, B]
@@ -621,6 +624,7 @@ def fused_sibling_scan(
         out_shape=[jax.ShapeDtypeStruct((nf_blocks, NC, Ft), dt)
                    for dt in _TUPLE_DTYPES],
         interpret=_interp(interpret),
+        name="lgbm_sibling_scan",
     )(*in_arrays)
     gain, thr, dl, lgs, lhs_, lcs = (_feature_unblocked(o) for o in out)
     return NumericFeatureBest(

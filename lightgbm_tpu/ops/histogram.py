@@ -285,6 +285,7 @@ def histogram_pallas(
         out_specs=pl.BlockSpec((Ft, 3, B), lambda j, i: (j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((F_pad, 3, B), jnp.float32),
         interpret=interpret,
+        name="lgbm_hist_staged",
     )(bt, vt)
     return out[:F].transpose(1, 0, 2)                   # [3, F, B]
 

@@ -253,7 +253,8 @@ class DeviceBinner:
                       _full(self._bounds), _full(self._cats)],
             out_specs=pl.BlockSpec((tile, G), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((npad, G), jnp.int32),
-            interpret=self.interpret)(X, self._bounds, self._cats)
+            interpret=self.interpret,
+            name="lgbm_ingest_bin")(X, self._bounds, self._cats)
         return out[:n].astype(self.tables.out_dtype)
 
     def __call__(self, X):

@@ -283,7 +283,8 @@ def fused_traverse(dev, Xpad, tile_rows: int = 512, num_class: int = 1,
                               dev.forest.has_cat, K, emit_scores)
     out = pl.pallas_call(
         kernel, grid=(ntiles,), in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=_interp(interpret))(*operands)
+        out_shape=out_shape, interpret=_interp(interpret),
+        name="lgbm_traverse")(*operands)
     return out[:, :n]
 
 

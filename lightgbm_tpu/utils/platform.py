@@ -11,7 +11,10 @@ them at its top; ``cpu_mesh_env`` builds the environment of a child).
 """
 from __future__ import annotations
 
+import collections
 import os
+import threading
+import time
 
 _DEVICE_COUNT_FLAG = "--xla_force_host_platform_device_count"
 
@@ -97,7 +100,68 @@ def enable_compile_cache(family=None) -> str:
         jax.config.update("jax_compilation_cache_dir", d)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+    _count_compiles()
     return d
+
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_compile_listener = None
+
+
+def _count_compiles() -> None:
+    """Register (once a process) the listener on ``jax.monitoring`` that
+    says what a start cost: registry counters ``compile_trace_seconds``,
+    ``compile_lower_seconds``, ``compile_backend_seconds`` (real backend
+    compiles), ``compile_cache_read_seconds`` (persistent-cache hits) and
+    ``compile_programs_total`` (programs compiled or read).
+    JAX times a cache hit's read inside its backend-compile event, and an
+    inner jitted function's trace inside the trace that called it (the
+    inner event ends first); both are taken out, so the four seconds are
+    disjoint and add up."""
+    global _compile_listener
+    if _compile_listener is not None:
+        return
+    import jax.monitoring as monitoring
+
+    from ..obs.metrics import global_registry
+    # per thread: the cache read since its last backend event, and the
+    # (start, seconds) of its outermost traces so far
+    reading = threading.local()
+
+    def on_duration(event, seconds, **_kw):
+        if event == _TRACE_EVENT:
+            tops = getattr(reading, "tops", None)
+            if tops is None:
+                tops = reading.tops = collections.deque(maxlen=1 << 16)
+            start = time.monotonic() - seconds
+            inner = 0.0
+            while tops and tops[-1][0] >= start - 1e-4:
+                inner += tops.pop()[1]
+            tops.append((start, seconds))
+            global_registry.counter("compile_trace_seconds").inc(
+                max(seconds - inner, 0.0))
+        elif event == _LOWER_EVENT:
+            global_registry.counter("compile_lower_seconds").inc(seconds)
+        elif event == _CACHE_READ_EVENT:
+            reading.s = getattr(reading, "s", 0.0) + seconds
+            global_registry.counter(
+                "compile_cache_read_seconds").inc(seconds)
+        elif event == _BACKEND_EVENT:
+            read, reading.s = getattr(reading, "s", 0.0), 0.0
+            global_registry.counter("compile_programs_total").inc()
+            global_registry.counter("compile_backend_seconds").inc(
+                max(seconds - read, 0.0))
+
+    # made now, so that a start that read or compiled nothing says 0
+    for name in ("compile_trace_seconds", "compile_lower_seconds",
+                 "compile_backend_seconds", "compile_cache_read_seconds",
+                 "compile_programs_total"):
+        global_registry.counter(name)
+    monitoring.register_event_duration_secs_listener(on_duration)
+    _compile_listener = on_duration
 
 
 # reserved non-JIT subtrees of the cache dir: the serving AOT export

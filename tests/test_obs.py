@@ -3,10 +3,10 @@ structured tracing, the unified metrics registry + Prometheus exposition,
 measured device profiling, and the timer satellite features.
 
 The tracing layer's acceptance bar (ISSUE 6): spans nest and close
-correctly under exceptions, the disabled path is a shared null context
-manager (no allocation, no events), the Chrome-trace JSON validates
-(timestamp-sorted, pid/tid on every event), and allgather-retry /
-checkpoint spans appear in a chaos-injected run.
+correctly under exceptions, the disabled path records no event and is
+bounded in cost (a profiler annotation, ISSUE 25), the Chrome-trace JSON
+validates (timestamp-sorted, pid/tid on every event), and
+allgather-retry / checkpoint spans appear in a chaos-injected run.
 """
 
 import json
@@ -17,8 +17,8 @@ import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.obs.metrics import MetricsRegistry, global_registry
-from lightgbm_tpu.obs.trace import (Tracer, _NULL_SPAN, global_tracer,
-                                    span, span_coverage)
+from lightgbm_tpu.obs.trace import (Tracer, global_tracer, span,
+                                    span_coverage)
 from lightgbm_tpu.utils.timer import Timer, global_timer
 
 pytestmark = pytest.mark.obs
@@ -55,19 +55,45 @@ def test_span_closes_under_exception():
     assert evs["outer"]["args"]["error"] == "ValueError"
 
 
-def test_disabled_mode_is_shared_null_span():
+def test_disabled_mode_records_nothing():
     t = Tracer(enabled=False)
-    cm = t.span("x", a=1)
-    assert cm is _NULL_SPAN          # no per-call allocation when disabled
-    with cm:
+    with t.span("x", a=1):
         pass
     t.instant("y")
     assert t.events() == []
-    # the module-level helper takes the same fast path
+    # the module-level seam is the same object kind: a profiler
+    # annotation whether or not the recorder is on; off, no event
+    was = global_tracer.enabled
+    global_tracer.disable()
+    global_tracer.reset()
+    try:
+        with span("z", a=1) as s:
+            s.set(b=2)
+        assert type(s) is type(t.span("x"))
+        assert global_tracer.events() == []
+    finally:
+        global_tracer.enabled = was
+
+
+def test_span_cost_with_tracing_off_is_bounded():
+    """What "tracing off" costs: with no profiler session and the recorder
+    off a span is one small object, a thread-local push/pop and a
+    ``TraceAnnotation`` that finds no session (~1.5 us here).  The bound
+    is loose (shared CI cores) and still two orders under the cheapest
+    thing a span wraps (a dispatch, a serving request)."""
+    import time
     was = global_tracer.enabled
     global_tracer.disable()
     try:
-        assert span("z") is _NULL_SPAN
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for i in range(2000):
+                with span("cost.probe", it=i):
+                    pass
+            best = min(best, (time.perf_counter() - t0) / 2000)
+        assert global_tracer.enabled is False
+        assert best < 50e-6, f"{best * 1e6:.1f} us a span with tracing off"
     finally:
         global_tracer.enabled = was
 

@@ -1,0 +1,260 @@
+"""The training path measures itself (docs/OBSERVABILITY.md): seams on the
+profiler's clock, named scopes and kernel names in the round program, the
+grower's round counters beside the tree, and the compile counters."""
+import ast
+import glob
+import math
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.obs.flight import global_flight
+from lightgbm_tpu.obs.metrics import global_registry
+from lightgbm_tpu.obs.trace import global_tracer
+
+pytestmark = pytest.mark.obs
+
+REPO = Path(__file__).resolve().parents[1]
+SCOPES = ("lgbm.gradients", "lgbm.quantize", "lgbm.route", "lgbm.hist",
+          "lgbm.scan", "lgbm.commit", "lgbm.leaf_values",
+          "lgbm.score_update")
+KERNELS = [("ops/fused.py", ["lgbm_hist_accum", "lgbm_sibling_scan"]),
+           ("ops/histogram.py", ["lgbm_hist_staged"]),
+           ("ops/ingest.py", ["lgbm_ingest_bin"]),
+           ("ops/predict_kernels.py", ["lgbm_traverse"])]
+
+
+def _data(seed, rows=3000, features=6):
+    rng = np.random.RandomState(seed)
+    X = rng.rand(rows, features).astype(np.float32)
+    y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] + 0.1 * rng.randn(rows)
+         > 0.8).astype(np.float32)
+    return X, y
+
+
+def _counters(prefix):
+    return {k: v for k, v in global_registry.to_dict()["counters"].items()
+            if k.startswith(prefix)}
+
+
+# ------------------------------------------------ (a) the profiler's clock
+
+
+def test_seams_lie_on_the_profilers_clock(tmp_path, monkeypatch):
+    """A tiny train under a profiler session: the host plane holds the
+    program's seams by name, while the Chrome recorder (its flag unset)
+    records nothing."""
+    from jax.profiler import ProfileData
+    monkeypatch.setenv("LGBM_TPU_INGEST_KERNEL", "kernel")
+    monkeypatch.setenv("LGBT_DEFER_HOST_TREES", "1")
+    assert not global_tracer.enabled
+    global_tracer.reset()
+    X, y = _data(0, rows=5000)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        bst = lgb.train({"objective": "binary", "num_leaves": 7,
+                         "verbosity": -1}, lgb.Dataset(X, label=y),
+                        num_boost_round=2)
+        assert bst.num_trees() == 2     # drains the deferred trees
+    finally:
+        jax.profiler.stop_trace()
+    assert global_tracer.events() == []
+    xplane = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                       recursive=True)[-1]
+    names = {ev.name for plane in ProfileData.from_file(xplane).planes
+             if plane.name.startswith("/host")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("lgbm.")}
+    for want in ("lgbm.macro.host_inputs", "lgbm.macro.dispatch",
+                 "lgbm.macro.host_fetch", "lgbm.gbdt.drain_pending",
+                 "lgbm.jit.build", "lgbm.ingest.edges",
+                 "lgbm.ingest.device_bin", "lgbm.ingest.put",
+                 "lgbm.ingest.wait_put", "lgbm.ingest.bin_chunk",
+                 "lgbm.ingest.to_device"):
+        assert want in names, (want, sorted(names))
+
+
+def test_ring_holds_the_coarse_seams_with_parent_and_it():
+    """With no session and no flag the flight ring still takes the coarse
+    seams: one ``ingest.device_bin`` a construct carrying its chunks'
+    sums, and the round's seams sharing ``it`` under ``engine.step``."""
+    assert not global_tracer.enabled
+    global_flight._ring.clear()
+    X, y = _data(1)
+    lgb.train({"objective": "binary", "num_leaves": 7, "verbosity": -1},
+              lgb.Dataset(X, label=y), num_boost_round=3)
+    recs = [e for e in global_flight.ring_events() if e.get("ph") == "X"]
+    by_name = {}
+    for e in recs:
+        by_name.setdefault(e["name"], []).append(e)
+    for name in ("macro.host_inputs", "macro.dispatch", "macro.host_fetch"):
+        its = [e["args"]["it"] for e in by_name[name]]
+        assert its == [0, 2], (name, its)     # a c=2 chunk, then c=1
+        assert all(e["args"]["parent"] == "engine.step"
+                   for e in by_name[name])
+    assert [e["args"]["it"] for e in by_name["engine.step"]] == [0, 2]
+    assert "ingest.edges" in by_name and "ingest.to_device" in by_name
+    # per-chunk seams are annotations only
+    assert not {"ingest.put", "ingest.wait_put",
+                "ingest.bin_chunk"} & set(by_name)
+
+
+def test_device_bin_record_carries_its_chunks_sums(monkeypatch):
+    monkeypatch.setenv("LGBM_TPU_INGEST_KERNEL", "kernel")
+    monkeypatch.setenv("LGBM_TPU_INGEST_CHUNK", "1500")
+    global_flight._ring.clear()
+    X, y = _data(2, rows=5000)
+    lgb.Dataset(X, label=y, params={"verbosity": -1}).construct()
+    recs = [e for e in global_flight.ring_events()
+            if e["name"] == "ingest.device_bin"]
+    assert len(recs) == 1               # one a construct, not one a chunk
+    args = recs[0]["args"]
+    assert args["wait_put_s"] >= 0 and args["bin_s"] > 0
+    assert args["wait_put_s"] + args["bin_s"] <= recs[0]["dur"] / 1e6 + 1e-3
+
+
+# ------------------------------------- (b) scopes and kernel names
+
+
+def test_round_program_carries_every_scope():
+    from lightgbm_tpu.boosting.macro import (build_chunk_program,
+                                             chunk_host_inputs)
+    X, y = _data(3)
+    params = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
+              "tpu_tree_growth": "rounds", "use_quantized_grad": True,
+              "num_grad_quant_bins": 4}
+    b = lgb.Booster(params, lgb.Dataset(X, label=y, params=params)).boosting
+    b.boost_from_average()
+    xs, _ = chunk_host_inputs(b, 1)
+    cu, cr = b._cegb_state
+    gc, hc = b._macro_const_grads()
+    text = build_chunk_program(b).lower(
+        b.binned, b.train_score, cu, cr, np.int32(1), xs,
+        b._macro_ctx["label"], b._macro_ctx["weight"], gc, hc
+    ).compile().as_text()
+    op_names = "\n".join(line for line in text.splitlines()
+                         if "op_name=" in line)
+    for scope in SCOPES:
+        assert scope in op_names, scope
+
+
+@pytest.mark.parametrize("rel, names", KERNELS)
+def test_every_pallas_call_is_named(rel, names):
+    tree = ast.parse((REPO / "lightgbm_tpu" / rel).read_text())
+    found = []
+    for node in ast.walk(tree):
+        if (isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "pallas_call"):
+            kw = {k.arg: k.value for k in node.keywords}
+            assert "name" in kw, f"{rel}:{node.lineno} has no name="
+            found.append(kw["name"].value)
+    assert found == names
+
+
+def test_no_other_file_calls_pallas():
+    known = {rel for rel, _ in KERNELS}
+    for path in (REPO / "lightgbm_tpu").rglob("*.py"):
+        rel = str(path.relative_to(REPO / "lightgbm_tpu"))
+        if rel not in known:
+            assert "pallas_call(" not in path.read_text(), rel
+
+
+# --------------------------------------------- (c) the grower's counters
+
+
+@pytest.mark.parametrize("seed", [11, 12, 13])
+def test_grower_counters(seed):
+    num_leaves = 31
+    X, y = _data(seed, rows=4000)
+    params = {"objective": "binary", "num_leaves": num_leaves,
+              "min_data_in_leaf": 5, "verbosity": -1,
+              "tpu_tree_growth": "rounds"}
+    global_flight._ring.clear()
+    bst = lgb.train(params, lgb.Dataset(X, label=y), num_boost_round=3)
+    trees = [e["args"] for e in global_flight.ring_events()
+             if e["name"] == "grower.tree"]
+    assert [t["it"] for t in trees] == [0, 1, 2]
+    for t, model in zip(trees, bst.boosting.models):
+        assert model.num_leaves == num_leaves
+        assert t["applied"] == num_leaves - 1
+        assert t["offered"] >= t["applied"]
+        assert math.ceil(math.log2(num_leaves)) <= t["rounds"] \
+            <= num_leaves - 1
+    # the ring record is the one carrier: no counter doubles it
+    assert not [k for k in _counters("train_") if "grower" in k]
+
+
+def test_serial_grower_counts_one_split_a_trip():
+    X, y = _data(14)
+    global_flight._ring.clear()
+    bst = lgb.train({"objective": "binary", "num_leaves": 7,
+                     "verbosity": -1, "tpu_tree_growth": "serial"},
+                    lgb.Dataset(X, label=y), num_boost_round=1)
+    (t,) = [e["args"] for e in global_flight.ring_events()
+            if e["name"] == "grower.tree"]
+    splits = bst.boosting.models[0].num_leaves - 1
+    assert (t["rounds"], t["offered"], t["applied"]) == (splits,) * 3
+
+
+def test_counters_leave_the_tree_as_it_was():
+    """with_stats adds an output, nothing else: same tree, same rows."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.dataset import FeatureMeta
+    from lightgbm_tpu.grower import GrowerConfig
+    from lightgbm_tpu.grower_rounds import grow_tree_rounds
+    from lightgbm_tpu.ops.split import SplitHyperparams
+    rng = np.random.RandomState(5)
+    n, F, B = 4096, 8, 32
+    binned = rng.randint(0, B, size=(n, F)).astype(np.uint8)
+    grad = (rng.randn(n) + 0.7 * (binned[:, 1] > 16)).astype(np.float32)
+    meta = FeatureMeta(
+        num_bin=np.full(F, B, np.int32), missing_type=np.zeros(F, np.int32),
+        default_bin=np.zeros(F, np.int32),
+        most_freq_bin=np.zeros(F, np.int32),
+        is_categorical=np.zeros(F, bool), max_num_bin=B)
+    cfg = GrowerConfig(num_leaves=31, num_bins=B, hp=SplitHyperparams(),
+                       hist_method="scatter")
+    args = (jnp.asarray(binned.T), jnp.asarray(grad),
+            jnp.ones(n, jnp.float32), jnp.ones(n, jnp.float32), meta, cfg)
+    t0, lid0 = grow_tree_rounds(*args)
+    t1, lid1, stats = grow_tree_rounds(*args, with_stats=True)
+    for a, b in zip(jax.tree_util.tree_leaves(t0),
+                    jax.tree_util.tree_leaves(t1)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(lid0), np.asarray(lid1))
+    rounds, offered, applied = (int(v) for v in stats)
+    assert applied == int(t1.num_leaves) - 1
+    assert 1 <= rounds <= applied <= offered
+
+
+# ----------------------------------------------- (d) the compile counters
+
+
+def test_compile_counters_rise_once():
+    """The first round of a new shape traces, lowers and compiles; an
+    identical later round does none of it."""
+    from lightgbm_tpu.utils.platform import enable_compile_cache
+    enable_compile_cache()
+    X, y = _data(15, rows=2777, features=7)     # a shape of this test's
+    params = {"objective": "binary", "num_leaves": 9, "verbosity": -1}
+    c0 = _counters("compile_")
+    bst = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))
+    bst.update()
+    c1 = _counters("compile_")
+    assert c1["compile_programs_total"] > c0["compile_programs_total"]
+    assert c1["compile_trace_seconds"] > c0["compile_trace_seconds"]
+    assert c1["compile_lower_seconds"] > c0["compile_lower_seconds"]
+    spent = (c1["compile_backend_seconds"] + c1["compile_cache_read_seconds"]
+             - c0["compile_backend_seconds"]
+             - c0["compile_cache_read_seconds"])
+    assert spent > 0
+    bst.update()        # the second round may still meet a first (it > 0)
+    c2 = _counters("compile_")
+    bst.update()
+    assert _counters("compile_") == c2
