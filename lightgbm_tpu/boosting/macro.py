@@ -146,7 +146,7 @@ def make_chunk_fn(b):
         qss0 = jnp.zeros((c, K, 2), jnp.float32)
         # the grower loop's (rounds, offered, applied) per tree ride out
         # the same way, beside the trees and not in them
-        gss0 = jnp.zeros((c, K, 3), jnp.int32)
+        gss0 = jnp.zeros((c, K, 4), jnp.int32)
 
         def body(j, state):
             score, cu, cr, ys, qss, gss = state

@@ -31,6 +31,19 @@ order than the serial kernels — the same class of difference as the
 reference's CPU vs GPU histograms (docs/GPU-Performance.rst accuracy
 tables).  Structure can differ only on exact float ties in gains.
 
+Histogram passes.  A round builds its candidates' smaller-child
+histograms in one pass over the rows.  Under the fused arm
+(``hist_method="fused"``, what ``auto`` elects on an accelerator) the
+root and every round share ONE accumulate program family, on one chip
+and sharded alike (``ops/fused.frontier_accumulator``): the pass runs at
+the narrowest compiled slot width that holds the round's ``k`` live
+candidates (the root: one slot, every member row), because the kernel
+pays for every slot of its width; everything after it — the collective,
+the sibling scan, the pick, the prefix, the commit — stays at the round
+cap ``KCAP = tpu_round_width``.  The loop's fourth counter, ``slots``,
+sums the widths run.  The staged family keeps ``build_histogram*`` for
+the root and runs every segment pass at the cap.
+
 Support matrix: EFB bundles, bagging/GOSS weights, per-tree and per-node
 column sampling, extra_trees, monotone constraints, max_depth, and
 data-parallel row sharding (``axis_name`` -> histogram/scalar psums).
@@ -102,9 +115,11 @@ def _grow_tree_rounds_traced(
     with_stats: bool = False,
 ):
     """Grow one tree; returns (TreeArrays, leaf_id [n] i32), and with
-    ``with_stats`` a third [3] i32: the loop's trips, the candidates it
-    offered (a round builds that many smaller-child histograms) and the
-    splits it committed."""
+    ``with_stats`` a third [4] i32: the loop's trips, the candidates it
+    offered (a round builds that many smaller-child histograms), the
+    splits it committed, and the slot widths its histogram passes ran at,
+    summed (the fused arm's root pass included; a staged pass runs at the
+    round cap)."""
     meta = meta.resolved()
     G, n = binned_t.shape
     L = cfg.num_leaves
@@ -227,26 +242,28 @@ def _grow_tree_rounds_traced(
     if use_rng and rng_key is None:
         rng_key = jax.random.PRNGKey(0)
     if use_fused:
-        from .ops.fused import (fused_frontier_accumulate,
-                                fused_frontier_splits, fused_sibling_scan,
-                                pick_fused_best, shared_frontier_enabled)
+        from .ops.fused import (frontier_accumulator, fused_sibling_scan,
+                                pick_fused_best)
         from .ops.histogram import _vals_t, _vals_t_int
         from .ops.split import feature_best_splits
-        fused_vals = (_vals_t_int(q_grad, q_hess, row_mask > 0) if quant
-                      else _vals_t(grad, hess, row_mask))
         fused_scales = (g_scale, h_scale) if quant else None
         fused_ftile = cfg.fused_feat_tile or None
-        fused_brows = cfg.fused_block_rows or None
+        # ONE accumulate program family for the root and every round, on
+        # one chip and on the sharded seam alike: each pass runs at the
+        # narrowest compiled slot width that holds its live candidates
+        # (the root: one), over one feature-blocked copy of the binned
+        # matrix made here, once a tree (ops/fused.frontier_accumulator)
+        with jax.named_scope("lgbm.hist"):
+            fused_accumulate = frontier_accumulator(
+                binned_t,
+                (_vals_t_int(q_grad, q_hess, row_mask > 0) if quant
+                 else _vals_t(grad, hess, row_mask)),
+                KCAP, Bg, feat_tile=fused_ftile,
+                block_rows=cfg.fused_block_rows or None, tile_rows=tile)
         # static categorical column index set for pick_fused_best's merge
         cat_idx = (tuple(int(i) for i, v in
                          enumerate(meta.is_categorical) if v)
                    if has_cat else None)
-        # the shared frontier program (docs/PERF.md): on the sharded seam
-        # the ROOT histogram rides the SAME accumulate program as every
-        # level (slot 0 = all member rows), so one Mosaic kernel serves
-        # root + levels and the compile ladder shrinks by one program
-        use_shared_root = (axis_name is not None
-                           and shared_frontier_enabled())
 
     # ---- per-leaf best-split search, vmapped over all L slots ----------
     def leaf_key(parent, side):
@@ -302,11 +319,10 @@ def _grow_tree_rounds_traced(
     with jax.named_scope("lgbm.hist"):
         if quant:
             member = row_mask > 0
-            if use_fused and use_shared_root:
-                root_local = fused_frontier_accumulate(
-                    binned_t, fused_vals, jnp.where(member, 0, KCAP), KCAP,
-                    Bg, feat_tile=fused_ftile, block_rows=fused_brows,
-                    tile_rows=tile)[0]
+            if use_fused:
+                root_arena, root_width = fused_accumulate(
+                    jnp.where(member, 0, KCAP), 1)
+                root_local = root_arena[0]
             else:
                 root_local = build_histogram_int(
                     binned_t, q_grad, q_hess, member, Bg,
@@ -319,11 +335,10 @@ def _grow_tree_rounds_traced(
                 jnp.int32))).astype(jnp.float32) * h_scale
             root_cnt = psum_(jnp.sum(member.astype(jnp.float32)))
         else:
-            if use_fused and use_shared_root:
-                root_local = fused_frontier_accumulate(
-                    binned_t, fused_vals, jnp.where(row_mask > 0, 0, KCAP),
-                    KCAP, Bg, feat_tile=fused_ftile, block_rows=fused_brows,
-                    tile_rows=tile)[0]
+            if use_fused:
+                root_arena, root_width = fused_accumulate(
+                    jnp.where(row_mask > 0, 0, KCAP), 1)
+                root_local = root_arena[0]
             else:
                 root_local = hist_fn(binned_t, grad, hess, row_mask)
             root_hist = psum_(root_local)
@@ -363,6 +378,7 @@ def _grow_tree_rounds_traced(
         rounds: jax.Array       # trips of the loop so far
         offered: jax.Array      # sum of k: candidates built
         applied: jax.Array      # sum of m: splits committed
+        slots: jax.Array        # sum of the slot widths the passes ran at
 
     iota_L = jnp.arange(L, dtype=jnp.int32)
 
@@ -462,7 +478,7 @@ def _grow_tree_rounds_traced(
         return Carry(tree, c.best, hist, leaf_sg, leaf_sh, leaf_cnt,
                      leaf_parent_side, new_leaf_id, c.split_idx + k,
                      leaf_min, leaf_max, c.rounds, c.offered,
-                     c.applied + k)
+                     c.applied + k, c.slots)
 
     def child_bounds(c: Carry):
         """Per-leaf monotone bounds the two children of each leaf's cached
@@ -596,9 +612,16 @@ def _grow_tree_rounds_traced(
             # candidate batch (slot r = the round's r-th candidate)
             small_left = b.left_count <= b.right_count
             slot = jnp.where(row_small, crank, KCAP)
+        width = jnp.int32(KCAP)
         with jax.named_scope("lgbm.hist"):
             if use_fused:
-                seg = None      # the fused megakernel produces it below
+                # the accumulate half of the fused megakernel, at the
+                # narrowest compiled width that holds the k candidates;
+                # sharded, exactly these (padded) hists cross the wire
+                seg, width = fused_accumulate(slot, k)
+                seg = (psum_quant_hist(seg, axis_name, rows_global,
+                                       cfg.quant_bins, hierarchical=hier_rd)
+                       if quant else psum_(seg))
             elif quant:
                 seg = psum_quant_hist(compacted_segment_histogram_int(
                     binned_t, q_grad, q_hess, row_mask, slot, KCAP, Bg, caps,
@@ -624,13 +647,18 @@ def _grow_tree_rounds_traced(
                              b.right_count[idl])
             depth_c = c.tree.leaf_depth[idl] + 1
             if use_fused:
-                # fused megakernel (ops/fused.py): one streamed pass builds
-                # the K smaller-child histograms in VMEM, derives each
-                # sibling from the parent arena in-kernel and scans both
-                # children; only `seg` + the [2K, F] per-feature-best
-                # tuples return — the staged arm's seg/scan HBM round-trip
-                # is deleted.  The pick + depth gate mirror search_all's
-                # best_split_for_leaf + gain gating exactly.
+                # fused megakernel (ops/fused.py), split at THE COLLECTIVE
+                # SEAM on one chip and sharded alike: gains are not
+                # summable across shards but the smaller-child hists are,
+                # so `seg` was accumulated in the VMEM arena (and reduced
+                # over the data axes) above, and the sibling-derive + scan
+                # runs here on the (reduced) arena at the round cap,
+                # whatever width the pass ran at.  The reduction routing
+                # is byte-identical to the staged arm's (psum_quant_hist /
+                # _psum) and integer accumulation is associative, so fused
+                # == staged bit-for-bit in quantized mode.  The pick +
+                # depth gate mirror search_all's best_split_for_leaf +
+                # gain gating exactly.
                 csums = jnp.stack([jnp.concatenate([lg_, rg_]),
                                    jnp.concatenate([lh_, rh_]),
                                    jnp.concatenate([lc_, rc_])])   # [3, 2K]
@@ -640,43 +668,12 @@ def _grow_tree_rounds_traced(
                                 jnp.concatenate([bl_max[idl], br_max[idl]]))
                 else:
                     f_bounds = None
-                if axis_name is None:
-                    with jax.named_scope("lgbm.hist"):
-                        seg, nfb = fused_frontier_splits(
-                            binned_t, fused_vals, slot, KCAP, Bg, csums,
-                            small_left[idl], ph, num_bin, missing_type,
-                            default_bin, hp, quant_scales=fused_scales,
-                            monotone_constraints=mc_j, child_bounds=f_bounds,
-                            feat_tile=fused_ftile, block_rows=fused_brows,
-                            tile_rows=tile)
-                else:
-                    # THE COLLECTIVE SEAM (sharded data-parallel): gains are
-                    # not summable across shards but the smaller-child hists
-                    # are — accumulate LOCALLY in the VMEM arena, reduce
-                    # exactly those [K, ch, G, Bg] hists over the (possibly
-                    # tiered) data axes, then sibling-derive + scan the
-                    # REDUCED arena in the standalone epilogue kernel.  The
-                    # reduction routing is byte-identical to the staged arm's
-                    # (psum_quant_hist / _psum), and integer accumulation is
-                    # associative, so sharded fused == sharded staged
-                    # bit-for-bit in quantized mode.
-                    with jax.named_scope("lgbm.hist"):
-                        seg_local = fused_frontier_accumulate(
-                            binned_t, fused_vals, slot, KCAP, Bg,
-                            feat_tile=fused_ftile, block_rows=fused_brows,
-                            tile_rows=tile)
-                        if quant:
-                            seg = psum_quant_hist(seg_local, axis_name,
-                                                  rows_global, cfg.quant_bins,
-                                                  hierarchical=hier_rd)
-                        else:
-                            seg = psum_(seg_local)
-                    nfb = fused_sibling_scan(
-                        seg, csums, num_bin, missing_type, default_bin, hp,
-                        small_left=small_left[idl], parent_hist=ph,
-                        quant_scales=fused_scales,
-                        monotone_constraints=mc_j, child_bounds=f_bounds,
-                        feat_tile=fused_ftile)
+                nfb = fused_sibling_scan(
+                    seg, csums, num_bin, missing_type, default_bin, hp,
+                    small_left=small_left[idl], parent_hist=ph,
+                    quant_scales=fused_scales,
+                    monotone_constraints=mc_j, child_bounds=f_bounds,
+                    feat_tile=fused_ftile)
                 if has_cat:
                     # categorical merge: the arena accumulated the cat
                     # columns too (same segment reduction) — derive the
@@ -764,12 +761,13 @@ def _grow_tree_rounds_traced(
             valid_m = jnp.concatenate([iota_K < m, iota_K < m])
             return cm._replace(
                 best=cache_scatter(c.best, idc, res, valid_m),
-                rounds=c.rounds + 1, offered=c.offered + k)
+                rounds=c.rounds + 1, offered=c.offered + k,
+                slots=c.slots + width)
 
     zero = jnp.array(0, jnp.int32)
     init = Carry(tree, best, hist_cache, leaf_sg, leaf_sh, leaf_cnt,
                  leaf_parent_side, leaf_id, zero, leaf_min, leaf_max,
-                 zero, zero, zero)
+                 zero, zero, zero, root_width if use_fused else zero)
     out = lax.while_loop(cond, body, init)
 
     # finalize leaf values (reference: CalculateSplittedLeafOutput; clamped
@@ -800,5 +798,5 @@ def _grow_tree_rounds_traced(
         )
     if with_stats:
         return tree, out.leaf_id, jnp.stack(
-            [out.rounds, out.offered, out.applied])
+            [out.rounds, out.offered, out.applied, out.slots])
     return tree, out.leaf_id

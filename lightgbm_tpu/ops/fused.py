@@ -49,6 +49,18 @@ kernel (``_accumulate_tile`` / ``_derive_and_scan``), so sharded fused
 staged ``[L, ch, F, B]`` HBM scan round-trip disappears from the
 data-parallel path too — only hists cross the wire.
 
+**The width the round needs** (the rounds grower, one chip and sharded
+alike).  The accumulate half is a one-hot matmul: a pass costs rows x F x
+(ch · slots) x B multiply-adds whatever the rows hold, and at 128 int8
+slots it runs at ~90% of a v5e's int8 peak (root PERF.md section 5) —
+compute-bound, so the slot axis is the lever.  ``frontier_accumulator``
+compiles the pass at ``NARROW_SLOT_WIDTHS`` and at the round cap, over
+ONE feature-blocked copy of the binned matrix built once a tree
+(``fused_blocked_bins``), and runs each round — and the root, whose one
+slot is every member row — at the narrowest width that holds its live
+candidates; the arena is zero-padded to the cap, so the collective and
+``fused_sibling_scan`` keep one shape.
+
 Scope: numeric AND categorical features (per-category stats are the
 same segment reduction — the kernel accumulates every column and the
 growers override the in-kernel numeric tuples on categorical columns
@@ -197,6 +209,41 @@ def _feature_unblocked(a: jax.Array) -> jax.Array:
     return a.transpose(1, 0, 2).reshape(R, nf * Ft)
 
 
+def fused_blocked_bins(binned_t: jax.Array, feat_tile: int,
+                       row_multiple: int) -> jax.Array:
+    """[F, n] -> the accumulate kernel's operand ``[F_pad // Ft, Ft,
+    n_pad]`` (features padded to whole blocks, rows to ``row_multiple``;
+    padded cells are bin 0 of rows every caller drops).  A caller that
+    runs several passes over one matrix builds it ONCE and hands it to
+    each ``fused_frontier_accumulate`` — at F = 67 the pad is a copy of
+    the whole matrix, which the kernel's own call would make again
+    before every pass."""
+    F, n = binned_t.shape
+    Ft = max(1, min(int(feat_tile), F))
+    F_pad, n_pad = _pad_rows(F, Ft), _pad_rows(n, int(row_multiple))
+    if n_pad != n or F_pad != F:
+        binned_t = jnp.pad(binned_t, ((0, F_pad - F), (0, n_pad - n)))
+    # a free leading-dim split: the per-step window then spans the whole
+    # Ft axis, which the TPU lowering takes at any feature tile — a bare
+    # (Ft, C) window of [F, n] would need Ft % 8 == 0
+    return binned_t.reshape(F_pad // Ft, Ft, n_pad)
+
+
+def _row_tile(block_rows: int, tile_rows: Optional[int], n: int) -> int:
+    """Rows per grid step: ``block_rows``, CAPPED by ``tile_rows`` (the
+    planner's row-tile budget, like the staged family's _tile_block: peak
+    per-step bytes track the tile), then halved while padding the ``n``
+    rows to whole tiles would add more than a sixteenth to them (row
+    counts off the planner's bucket ladder: small data, CPU runs)."""
+    T = resolve_tile_rows(tile_rows, n)
+    C = max(128, int(block_rows))
+    if T is not None:
+        C = min(C, _pad_rows(T, 128))
+    while C % 256 == 0 and _pad_rows(n, C) - n > n // 16:
+        C //= 2
+    return C
+
+
 def _arena_dims(K: int, B: int, Ft: int, quant: bool):
     """(K_pad, B_pad): the slot and bin axes padded so the VMEM arena
     ``[ch*K_pad, Ft*B_pad]`` and both one-hot operands sit on whole
@@ -210,7 +257,8 @@ def _arena_dims(K: int, B: int, Ft: int, quant: bool):
 
 
 def _fused_call(
-    binned_t: jax.Array,          # [F, n] uint8/uint16 feature-major
+    binned_t: jax.Array,          # [F, n] uint8/uint16 feature-major, or
+                                  # its fused_blocked_bins() [nf, Ft, n_pad]
     vals_t: jax.Array,            # f32 [3, n] (g,h,1)*w  |  int8 [2, n]
     slot: jax.Array,              # [n] i32 in [0, K]; K = dropped
     num_slots: int,
@@ -228,6 +276,7 @@ def _fused_call(
     tile_rows: Optional[int] = None,
     interpret: Optional[bool] = None,
     with_scan: bool = True,
+    num_features: Optional[int] = None,   # F of a blocked ``binned_t``
 ):
     """One megakernel invocation; returns ``(slot_hist [K, ch, F, B],
     NumericFeatureBest [NC, F])`` with NC = 2K (parent mode: children are
@@ -243,7 +292,8 @@ def _fused_call(
             hist = _fused_call(
                 binned_t, vals_t, slot, num_slots, num_bins, None, None,
                 None, feat_tile=feat_tile, block_rows=block_rows,
-                tile_rows=tile_rows, with_scan=False)
+                tile_rows=tile_rows, with_scan=False,
+                num_features=num_features)
         with jax.named_scope("lgbm.scan"):
             return hist, fused_sibling_scan(
                 hist, child_sums, *meta_vecs, hp, small_left=small_left,
@@ -254,7 +304,9 @@ def _fused_call(
     quant = vals_t.dtype == jnp.int8
     ch = int(vals_t.shape[0])
     acc_dtype = jnp.int32 if quant else jnp.float32
-    F, n = binned_t.shape
+    blocked = binned_t.ndim == 3
+    n = int(vals_t.shape[1])
+    F = int(num_features) if blocked else int(binned_t.shape[0])
     K = int(num_slots)
     B = int(num_bins)
     with_parent = parent_hist is not None
@@ -264,39 +316,30 @@ def _fused_call(
     has_mono = with_scan and monotone_constraints is not None
     has_bounds = with_scan and child_bounds is not None
 
-    if feat_tile is None or block_rows is None:
+    if (feat_tile is None and not blocked) or block_rows is None:
         from .planner import plan_fused
-        fp = plan_fused(K, B, quant, with_parent=with_parent)
+        fp = plan_fused(K, B, quant, with_parent=with_parent,
+                        feat_tile=int(binned_t.shape[1]) if blocked else None,
+                        num_features=F)
         if feat_tile is None:
             feat_tile = fp["feat_tile"] if fp else 1
         if block_rows is None:
             block_rows = fp["block_rows"] if fp else 128
-    Ft = max(1, min(int(feat_tile), F))
-    # tile_rows (the planner's row-tile budget) CAPS the VMEM block like
-    # the staged family's _tile_block: peak per-step bytes track the tile
-    T = resolve_tile_rows(tile_rows, n)
-    C = int(block_rows)
-    if T is not None:
-        C = min(C, max(128, _pad_rows(T, 128)))
-    C = max(128, C)
+    C = _row_tile(block_rows, tile_rows, n)
 
-    n_pad = _pad_rows(n, C)
-    F_pad = _pad_rows(F, Ft)
+    bt = binned_t if blocked else fused_blocked_bins(binned_t, feat_tile, C)
+    nf_blocks, Ft, n_pad = (int(d) for d in bt.shape)
+    if n_pad < n or n_pad % C:
+        raise ValueError(f"blocked bins of {n_pad} rows do not hold {n} "
+                         f"rows in whole tiles of {C}")
+    F_pad = nf_blocks * Ft
     Kp, Bp = _arena_dims(K, B, Ft, quant)
-    bt = binned_t
-    if n_pad != n or F_pad != F:
-        bt = jnp.pad(bt, ((0, F_pad - F), (0, n_pad - n)))
     vt = jnp.pad(vals_t, ((0, 0), (0, n_pad - n))) if n_pad != n else vals_t
     st = jnp.pad(slot.astype(jnp.int32), (0, n_pad - n),
                  constant_values=K)[None, :]               # [1, n_pad]
-    nf_blocks = F_pad // Ft
     nt = n_pad // C
 
-    # the binned matrix rides feature-BLOCKED [nf, Ft, n] (a free
-    # leading-dim split): the per-step window then spans the whole Ft
-    # axis, which the TPU lowering takes at any feature tile — a bare
-    # (Ft, C) window of [F, n] would need Ft % 8 == 0
-    in_arrays = [bt.reshape(nf_blocks, Ft, n_pad), vt, st]
+    in_arrays = [bt, vt, st]
     in_specs = [
         pl.BlockSpec((None, Ft, C), lambda j, i: (j, 0, i)),
         pl.BlockSpec((ch, C), lambda j, i: (0, i)),
@@ -476,22 +519,100 @@ def fused_frontier_accumulate(
     block_rows: Optional[int] = None,
     tile_rows: Optional[int] = None,
     interpret: Optional[bool] = None,
+    num_features: Optional[int] = None,
 ) -> jax.Array:
     """The accumulate HALF of the collective seam: build the K
     smaller-child (or slot) histograms in the VMEM arena and emit them —
     no scan, no parent.  Returns ``hist [K, ch, F, B]`` (int32 when
-    ``vals_t`` is int8, f32 otherwise).
+    ``vals_t`` is int8, f32 otherwise).  ``binned_t`` is the ``[F, n]``
+    matrix, or its ``fused_blocked_bins`` operand with ``num_features``
+    = F (``feat_tile`` is then the operand's own).
 
     Sharded training runs THIS program per shard, reduces exactly its
     output over the data axes (``psum_int_tiered`` / tiered ``psum``),
     then hands the reduced arena to ``fused_sibling_scan`` — gains stay
-    local, only hists cross the wire.  One program also serves every
-    frontier level AND the root (slot 0 = all member rows): the shared
-    frontier program of the compile-time ladder (docs/PERF.md)."""
+    local, only hists cross the wire.  One program family also serves
+    every frontier round AND the root (slot 0 = all member rows), one
+    member a compiled slot width: ``frontier_accumulator`` (docs/PERF.md
+    "shared frontier programs")."""
     return _fused_call(
         binned_t, vals_t, slot, num_slots, num_bins, None, None, None,
         feat_tile=feat_tile, block_rows=block_rows, tile_rows=tile_rows,
-        interpret=interpret, with_scan=False)
+        interpret=interpret, with_scan=False, num_features=num_features)
+
+
+# Slot widths the rounds grower compiles the accumulate pass at BELOW its
+# round cap.  The one-hot matmul costs rows x F x (ch * slots) x B
+# multiply-adds whatever the rows hold, so a round of k live candidates
+# runs the narrowest of these that holds k.  Measured on one v5e at
+# 25.2M x 67, 64 bins, 2048-row tiles (root PERF.md section 5): int8 79 /
+# 87 / 106 / 180 ms a pass at 16 / 32 / 64 / 128 slots, f32 473 (16), 1439
+# (64, 512-row tiles), 2796 (128).  A width stays only where its pass is
+# at least 20% faster than the next wider kept one, so 32 goes; under 64
+# slots the int8 pass is bound by building the bin one-hot, not the MXU.
+NARROW_SLOT_WIDTHS = (16, 64)
+
+
+def frontier_accumulator(
+    binned_t: jax.Array,           # [F, n]
+    vals_t: jax.Array,
+    kcap: int,                     # the round cap: the arena's slot axis
+    num_bins: int,
+    feat_tile: Optional[int] = None,
+    block_rows: Optional[int] = None,   # row tile AT ``kcap`` slots
+    tile_rows: Optional[int] = None,
+):
+    """``accumulate(slot, k) -> (hist [kcap, ch, F, B], width)``: one
+    ``fused_frontier_accumulate`` pass at the narrowest compiled slot
+    width that holds the ``k`` live slots of ``slot`` ([n] i32 in
+    [0, k) or >= k = dropped), zero-padded to ``kcap`` slots so whatever
+    follows (the collective, the scan) keeps one shape.  ``k`` is a
+    Python int (the root: one slot) or a traced i32 (a round: the pass
+    runs under ``lax.switch``); ``width`` is the slot width that ran.
+
+    Built once a tree, outside the grower's loop: every width reads ONE
+    feature-blocked operand (``fused_blocked_bins``), at the feature
+    tile of the widest, with its own row tile from ``plan_fused``.
+    Integer accumulation is associative, so the int8 arena is
+    bit-identical at every width; the f32 arena differs by summation
+    order where the row tiles differ."""
+    from .planner import plan_fused
+    quant = vals_t.dtype == jnp.int8
+    F, n = binned_t.shape
+    kcap = int(kcap)
+    widths = tuple(w for w in NARROW_SLOT_WIDTHS if w < kcap) + (kcap,)
+    if feat_tile is None:
+        fp = plan_fused(kcap, num_bins, quant, num_features=F)
+        feat_tile = fp["feat_tile"] if fp else 1
+    feat_tile = max(1, min(int(feat_tile), F))
+
+    def planned_rows(W):
+        if W == kcap and block_rows is not None:
+            return block_rows
+        fp = plan_fused(W, num_bins, quant, feat_tile=feat_tile)
+        return fp["block_rows"] if fp else 128
+
+    tiles = tuple(_row_tile(planned_rows(W), tile_rows, n) for W in widths)
+    bins = fused_blocked_bins(binned_t, feat_tile, math.lcm(*tiles))
+
+    def at(W, C):
+        def run(slot):
+            hist = fused_frontier_accumulate(
+                bins, vals_t, jnp.minimum(slot, W), W, num_bins,
+                block_rows=C, num_features=F)
+            return jnp.pad(hist, ((0, kcap - W),) + ((0, 0),) * 3)
+        return run
+
+    branches = [at(W, C) for W, C in zip(widths, tiles)]
+
+    def accumulate(slot, k):
+        i = sum(k > W for W in widths[:-1])     # the narrowest width >= k
+        if isinstance(i, int):                  # static k, or one width
+            return branches[i](slot), jnp.int32(widths[i])
+        return (lax.switch(i, branches, slot),
+                jnp.asarray(widths, jnp.int32)[i])
+
+    return accumulate
 
 
 def fused_sibling_scan(
@@ -853,12 +974,3 @@ def fused_enabled_env() -> bool:
     """LGBM_TPU_FUSED=0 drops the fused arm (compile-cost bisect hook,
     mirroring LGBM_TPU_SEGHIST / LGBM_TPU_ROUTER)."""
     return os.environ.get("LGBM_TPU_FUSED") != "0"
-
-
-def shared_frontier_enabled() -> bool:
-    """LGBM_TPU_SHARED_FRONTIER=0 turns off the shared frontier program
-    (the sharded fused root riding the SAME ``fused_frontier_accumulate``
-    program as every level — slot 0 = all member rows — so one Mosaic
-    kernel serves root + levels and the compile ladder shrinks by one
-    program; docs/PERF.md "shared frontier programs")."""
-    return os.environ.get("LGBM_TPU_SHARED_FRONTIER") != "0"

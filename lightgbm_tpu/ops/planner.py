@@ -192,16 +192,21 @@ def fused_vmem_bytes(num_slots: int, num_bins: int, feat_tile: int,
                      with_parent: bool = True) -> int:
     """Predicted VMEM bytes of one fused-megakernel step (ops/fused.py).
 
-    Resident across the row loop: the [ch·K, Ft·B] accumulator arena,
-    the parent block and the double-buffered input tile windows; the
-    epilogue additionally materializes the 2K children (+ their rescale/
-    prefix transients) and the tiny tuple blocks.  Deliberately simple —
-    the right ORDER for the fits/doesn't verdict, like
-    ``predict_peak_bytes``."""
-    K = max(int(num_slots), 1)
-    B = max(int(num_bins), 2)
+    Resident across the row loop: the [ch·K, Ft·B] accumulator arena at
+    its PADDED dims (``fused._arena_dims``: an odd feature tile pads the
+    bins to whole 128-lane groups), the parent block, the double-buffered
+    input tile windows and the two one-hot operands the dot consumes
+    (bins ``[Ft·B, C]`` and slots x values ``[ch·K, C]``: they grow with
+    the row tile); the epilogue additionally materializes the 2K children
+    (+ their rescale/prefix transients) and the tiny tuple blocks.
+    Deliberately simple — the right ORDER for the fits/doesn't verdict,
+    like ``predict_peak_bytes`` — and held to the chip's compiler at every
+    tile ``plan_fused`` elects by tests/test_chip_compile.py."""
+    from .fused import _arena_dims
     Ft = max(int(feat_tile), 1)
     C = max(int(block_rows), 128)
+    K, B = _arena_dims(max(int(num_slots), 1), max(int(num_bins), 2), Ft,
+                       quant)
     ch = 2 if quant else 3
     nc = 2 * K if with_parent else K
     acc = ch * K * Ft * B * 4
@@ -209,25 +214,43 @@ def fused_vmem_bytes(num_slots: int, num_bins: int, feat_tile: int,
     small_out = K * ch * Ft * B * 4
     # epilogue: children + one prefix/rescale transient of the same shape
     children = 2 * nc * 3 * Ft * B * 4
-    # double-buffered tile DMA windows: binned (1B), vals (<=4B), slot,
-    # plus the one-hot operand the dot consumes
+    # double-buffered tile DMA windows: binned (1B), vals (<=4B), slot
     tiles = 2 * (Ft * C + ch * C * 4 + C * 4)
-    onehot = C * Ft * B * (1 if quant else 4)
+    onehot = C * (Ft * B + ch * K) * (1 if quant else 4)
     tuples = 6 * nc * Ft * 4
     return acc + parent + small_out + children + tiles + onehot + tuples
 
 
+# row tiles plan_fused walks, longest first: the accumulate kernel pays
+# ~0.2 us a grid step, 442,368 steps a pass at 25.2M x 67 in 512-row
+# tiles.  Measured on one v5e at 16 / 64 / 128 int8 slots, ms a pass: 143 /
+# 163 / 235 at 512 rows, 96 / 122 / 197 at 1024, 79 / 106 / 180 at 2048,
+# 72 / 99 / 172 at 4096, 68 / 95 / 168 at 8192 (root PERF.md section 5).
+# The VMEM model stops the f32 family earlier (the chip's compiler refuses
+# its 128-slot kernel at 4096 rows).
+FUSED_BLOCK_ROWS = (8192, 4096, 2048, 1024, 512, 256, 128)
+
+
 def plan_fused(num_slots: int, num_bins: int, quant: bool = False,
                with_parent: bool = True,
-               vmem_bytes: Optional[int] = None) -> Optional[dict]:
+               vmem_bytes: Optional[int] = None,
+               feat_tile: Optional[int] = None,
+               num_features: Optional[int] = None) -> Optional[dict]:
     """Pick {feat_tile, block_rows} for the fused megakernel, or None
     when no shape fits the VMEM budget (the staged family then keeps the
     level).  Preference order: widest feature block first (fewer grid
-    columns, better MXU occupancy), then the larger row tile."""
+    columns, better MXU occupancy), then the longer row tile.
+    ``feat_tile`` pins the feature block (the rounds grower's narrower
+    slot widths share one blocked operand with its widest);
+    ``num_features`` caps it, as the kernel does (a matrix of 3 columns
+    runs a 3-feature block, whose bins pad to whole lane groups)."""
     limit = int(vmem_bytes if vmem_bytes is not None else vmem_limit_bytes())
     budget = int(limit * VMEM_HEADROOM)
-    for ft in (8, 4, 2, 1):
-        for c in (512, 256, 128):
+    fts = (8, 4, 2, 1) if feat_tile is None else (int(feat_tile),)
+    if num_features is not None:
+        fts = tuple(dict.fromkeys(min(ft, int(num_features)) for ft in fts))
+    for ft in fts:
+        for c in FUSED_BLOCK_ROWS:
             need = fused_vmem_bytes(num_slots, num_bins, ft, c, quant,
                                     with_parent)
             if need <= budget:
@@ -637,7 +660,7 @@ def plan_histograms(
         # arena is sized by the EFFECTIVE round width (grower KCAP)
         kcap = max(min(int(round_width), int(num_leaves) - 1), 1)
         fp = plan_fused(kcap, num_bins, quant, with_parent=True,
-                        vmem_bytes=vmem_bytes)
+                        vmem_bytes=vmem_bytes, num_features=features)
     variant = "fused" if fp is not None else _resolved_variant(method, quant)
     analytic_variant = variant
     elected_by, measured_variant, autotune_key = "analytic", "", ""

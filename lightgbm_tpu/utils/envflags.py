@@ -51,9 +51,6 @@ FLAGS: Dict[str, EnvFlag] = {f.name: f for f in [
     # ------------------------------------------------ kernel/planner gates
     _f("LGBM_TPU_FUSED", "1", "ops/fused.py",
        "fused histogram->split megakernel eligibility ('0' disables)", _PERF),
-    _f("LGBM_TPU_SHARED_FRONTIER", "1", "ops/fused.py",
-       "sharded fused training reuses ONE accumulate program for root "
-       "and every level ('0' disables)", _PERF),
     _f("LGBM_TPU_AUTOTUNE", "1", "ops/planner.py",
        "measured-timings kernel election ('0' = analytic model only)",
        _PERF),

@@ -77,7 +77,22 @@ def test_planner_elects_the_fused_arm_at_higgs_width():
                            round_width=K, fused_ok=True, accel=True,
                            budget_bytes=16 << 30)
     assert plan.fused
-    assert (plan.fused_feat_tile, plan.fused_block_rows) == (8, 512)
+    assert (plan.fused_feat_tile, plan.fused_block_rows) == (8, 1024)
+    # the narrower slot widths of the rounds grower's accumulate pass
+    # share the widest's feature tile, each with its own row tile: the
+    # narrower the arena, the longer the tile the VMEM model admits
+    from lightgbm_tpu.ops.fused import NARROW_SLOT_WIDTHS
+    from lightgbm_tpu.ops.planner import plan_fused
+    assert NARROW_SLOT_WIDTHS == (16, 64)
+
+    def tiles(bins, quant, ft):
+        return [plan_fused(w, bins, quant, feat_tile=ft)["block_rows"]
+                for w in NARROW_SLOT_WIDTHS + (K,)]
+
+    assert tiles(B, False, 8) == [4096, 2048, 1024]
+    assert tiles(B, True, 8) == [8192, 8192, 8192]
+    assert tiles(255, False, 2) == [4096, 2048, 1024]
+    assert tiles(255, True, 4) == [8192, 4096, 1024]
 
 
 @pytest.mark.parametrize("rows", [ROWS_1M, ROWS_10M])
@@ -109,9 +124,40 @@ def test_fused_accumulate_compiles(one_chip, family, rows):
     assert _kernels(c) == 1
 
 
+@pytest.mark.parametrize("bins", [B, 255])
+@pytest.mark.parametrize("width", [16, 64, 128])
+@pytest.mark.parametrize("family", ["f32", "int8"])
+def test_fused_accumulate_compiles_at_every_slot_width(one_chip, family,
+                                                       width, bins):
+    """The rounds grower runs a pass at the narrowest of these widths that
+    holds its candidates, over ONE feature-blocked operand at the widest
+    width's feature tile, each width at ``plan_fused``'s own row tile: at
+    the Criteo cell's 67 columns (a ragged last feature block) and 10.5M
+    -bucket rows."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.fused import (NARROW_SLOT_WIDTHS,
+                                        fused_frontier_accumulate)
+    from lightgbm_tpu.ops.planner import plan_fused
+    assert width in NARROW_SLOT_WIDTHS + (K,)
+    quant = family == "int8"
+    ch, dt = (2, jnp.int8) if quant else (3, jnp.float32)
+    ft = plan_fused(K, bins, quant)["feat_tile"]
+    tile = plan_fused(width, bins, quant, feat_tile=ft)
+    cols, nf = 67, -(-67 // ft)
+    c = _compile(
+        lambda b, v, s: fused_frontier_accumulate(
+            b, v, s, width, bins, block_rows=tile["block_rows"],
+            num_features=cols, interpret=False),
+        _shape(one_chip, (nf, ft, ROWS_10M), jnp.uint8),
+        _shape(one_chip, (ch, ROWS_10M), dt),
+        _shape(one_chip, (ROWS_10M,), jnp.int32))
+    assert _kernels(c) == 1
+
+
 @pytest.mark.parametrize("k,bins,bin_dtype", [
     (30, 63, "uint8"),       # 31 leaves: slots off the sublane tiling
-    (128, 255, "uint8"),     # default max_bin: the planner drops to Ft=4
+    (128, 255, "uint8"),     # default max_bin: the planner drops to Ft=2
     (128, 300, "uint16"),    # wide bins: 2-byte matrix
 ])
 def test_fused_accumulate_compiles_off_the_aligned_shape(one_chip, k, bins,

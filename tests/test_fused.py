@@ -256,6 +256,84 @@ def test_fused_grower_f32_structurally_identical(grower, tile):
                                rtol=3e-5, atol=1e-7)
 
 
+def _wide_problem(seed=1, n=12000, F=6, B=32):
+    """Rows whose 255-leaf tree takes rounds of every size: k climbs from
+    1 past 64 and falls back to the last few leaves."""
+    rng = np.random.RandomState(seed)
+    binned = rng.randint(0, B - 1, (n, F)).astype(np.uint8)
+    y = (np.sin(binned[:, 0] * 0.3) + 0.2 * binned[:, 1]
+         + 0.1 * binned[:, 2] * np.cos(binned[:, 3] * 0.2)
+         + rng.randn(n) * 0.3)
+    grad = (-y).astype(np.float32)
+    return binned, grad, np.ones(n, np.float32), np.ones(n, np.float32)
+
+
+@pytest.mark.parametrize("family", ["int8", "f32"])
+def test_width_election_matches_the_fixed_width(family, monkeypatch):
+    """The rounds grower's accumulate pass at the narrowest compiled slot
+    width that holds the round's candidates (root: one) against every
+    pass at the round cap: bit-identical trees for the integer family
+    (associative sums), the same structure for f32 (the row tiles, hence
+    the summation order, differ by width).  The stats say which widths
+    ran."""
+    B, F = 32, 6
+    binned, grad, hess, mask = _wide_problem(B=B, F=F)
+    quant = family == "int8"
+    kw = {}
+    if quant:
+        kw["quant_vals"] = H.quantize_gradients(
+            jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(mask), 8,
+            jax.random.PRNGKey(7))
+    cfg = GrowerConfig(num_leaves=255, num_bins=B, hist_method="fused",
+                       hp=SplitHyperparams(min_data_in_leaf=2),
+                       quant=quant, quant_bins=8)
+    args = (jnp.asarray(binned.T), jnp.asarray(grad), jnp.asarray(hess),
+            jnp.asarray(mask), _meta(B, F), cfg)
+    assert FU.NARROW_SLOT_WIDTHS == (16, 64)
+    t_el, lid_el, st_el = grow_tree_rounds(*args, with_stats=True, **kw)
+    monkeypatch.setattr(FU, "NARROW_SLOT_WIDTHS", ())
+    t_fx, lid_fx, st_fx = grow_tree_rounds(*args, with_stats=True, **kw)
+
+    assert int(t_el.num_leaves) == 255
+    exact = t_el._fields if quant else (
+        "split_feature", "threshold_bin", "default_left", "left_child",
+        "right_child", "num_leaves")
+    for name in exact:
+        assert np.array_equal(np.asarray(getattr(t_el, name)),
+                              np.asarray(getattr(t_fx, name))), name
+    assert np.array_equal(np.asarray(lid_el), np.asarray(lid_fx))
+    np.testing.assert_allclose(np.asarray(t_el.leaf_value),
+                               np.asarray(t_fx.leaf_value),
+                               rtol=3e-5, atol=1e-7)
+    rounds, offered, applied, slots = (int(v) for v in st_el)
+    assert (rounds, offered, applied) == tuple(int(v) for v in st_fx[:3])
+    assert int(st_fx[3]) == 128 * (rounds + 1)      # the root, then rounds
+    # the root ran at 16; the rounds neither all under 128 nor all at it
+    assert 64 * rounds < slots - 16 < 128 * rounds
+    assert offered <= slots - 16
+
+
+def test_root_through_the_kernel_equals_the_staged_root():
+    """The root histogram as one accumulate pass at the narrowest width
+    (slot 0 = every member row) is ``build_histogram_int``'s, exactly,
+    and the arena it sits in is empty elsewhere."""
+    B, F, KCAP = 32, 6, 128
+    binned, grad, hess, _ = _wide_problem(seed=3, n=5000, B=B, F=F)
+    member = np.random.RandomState(4).rand(len(grad)) > 0.25
+    gq, hq, _, _ = H.quantize_gradients(
+        jnp.asarray(grad), jnp.asarray(hess),
+        jnp.asarray(member.astype(np.float32)), 8, jax.random.PRNGKey(9))
+    bt = jnp.asarray(binned.T)
+    accumulate = FU.frontier_accumulator(
+        bt, H._vals_t_int(gq, hq, jnp.asarray(member)), KCAP, B)
+    arena, width = accumulate(jnp.where(jnp.asarray(member), 0, KCAP), 1)
+    assert int(width) == 16 and arena.shape == (KCAP, 2, F, B)
+    ref = H.build_histogram_int(bt, gq, hq, jnp.asarray(member), B,
+                                levels=H.quant_levels(8))
+    assert np.array_equal(np.asarray(arena[0]), np.asarray(ref))
+    assert not np.asarray(arena[1:]).any()
+
+
 def _strip_param_lines(text):
     return "\n".join(ln for ln in text.splitlines()
                      if not ln.startswith("[tpu_hist_method"))

@@ -397,3 +397,63 @@ def test_router_matmul_matches_scan(problem, monkeypatch):
         jnp.asarray(mask), meta, cfg)
     _assert_trees_equal(t_scan, t_rt)
     np.testing.assert_array_equal(np.asarray(lid_scan), np.asarray(lid_rt))
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one_chip", "axis_name"])
+def test_rounds_width_election_equals_serial_quantized(sharded):
+    """The fused arm's accumulate pass runs at the narrowest compiled slot
+    width that holds the round's candidates (the root at the narrowest),
+    on one chip and on the sharded seam (where the padded arena is what is
+    reduced).  Integer sums are associative, so at 255 leaves, through
+    rounds of every width, the tree is the serial oracle's bit for bit."""
+    import jax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from lightgbm_tpu.ops import fused as FU
+    from lightgbm_tpu.ops import histogram as H
+
+    rng = np.random.RandomState(21)
+    n, F, B = 8192, 6, 32
+    binned = rng.randint(0, B - 1, (n, F)).astype(np.uint8)
+    y = (np.sin(binned[:, 0] * 0.3) + 0.2 * binned[:, 1]
+         + 0.1 * binned[:, 2] * np.cos(binned[:, 3] * 0.2)
+         + rng.randn(n) * 0.3)
+    grad, hess = (-y).astype(np.float32), np.ones(n, np.float32)
+    mask = np.ones(n, np.float32)
+    meta = _meta(B, F)
+    gq, hq, gs, hs = H.quantize_gradients(
+        jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(mask), 8,
+        jax.random.PRNGKey(3))
+    cfg = GrowerConfig(num_leaves=255, num_bins=B, quant=True, quant_bins=8,
+                       hp=SplitHyperparams(min_data_in_leaf=2),
+                       hist_method="scatter")
+    bt = jnp.asarray(binned.T)
+    t_s, lid_s = grow_tree(bt, jnp.asarray(grad), jnp.asarray(hess),
+                           jnp.asarray(mask), meta, cfg,
+                           quant_vals=(gq, hq, gs, hs))
+    fused = cfg._replace(hist_method="fused")
+    assert FU.NARROW_SLOT_WIDTHS == (16, 64)
+    if sharded:
+        mesh = Mesh(np.array(jax.devices()[:4]), ("d",))
+        grow = jax.jit(jax.shard_map(
+            lambda b, g, h, m, q, r, a, c: grow_tree_rounds(
+                b, g, h, m, meta, fused._replace(num_machines=4),
+                axis_name="d", quant_vals=(q, r, a, c), with_stats=True),
+            mesh=mesh,
+            in_specs=(P(None, "d"), P("d"), P("d"), P("d"), P("d"), P("d"),
+                      P(), P()),
+            out_specs=(P(), P("d"), P()), check_vma=False))
+        t_r, lid_r, stats = grow(bt, grad, hess, mask, gq, hq, gs, hs)
+    else:
+        t_r, lid_r, stats = grow_tree_rounds(
+            bt, jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(mask),
+            meta, fused, quant_vals=(gq, hq, gs, hs), with_stats=True)
+    assert int(t_r.num_leaves) == 255
+    for name in t_s._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(t_s, name)),
+                                      np.asarray(getattr(t_r, name)),
+                                      err_msg=name)
+    np.testing.assert_array_equal(np.asarray(lid_s), np.asarray(lid_r))
+    rounds, offered, _, slots = (int(v) for v in stats)
+    assert offered <= slots - 16 < 128 * rounds

@@ -200,6 +200,37 @@ def test_serial_grower_counts_one_split_a_trip():
             if e["name"] == "grower.tree"]
     splits = bst.boosting.models[0].num_leaves - 1
     assert (t["rounds"], t["offered"], t["applied"]) == (splits,) * 3
+    assert t["slots"] == splits          # a pass one slot wide a split
+
+
+@pytest.mark.parametrize("method", ["scatter", "fused"])
+def test_grower_tree_carries_slots(method):
+    """The fourth counter of the grower's carry lands on the
+    ``grower.tree`` record: the slot widths the histogram passes ran at.
+    The fused arm's passes (root included) run at the narrowest compiled
+    width that holds the round's candidates; a staged pass at the cap."""
+    num_leaves = 63
+    X, y = _data(15, rows=4000)
+    global_flight._ring.clear()
+    bst = lgb.train({"objective": "binary", "num_leaves": num_leaves,
+                     "min_data_in_leaf": 5, "verbosity": -1, "max_bin": 31,
+                     "tpu_tree_growth": "rounds",
+                     "tpu_hist_method": method},
+                    lgb.Dataset(X, label=y), num_boost_round=2)
+    assert bst.boosting.grower_cfg.hist_method == method
+    trees = [e["args"] for e in global_flight.ring_events()
+             if e["name"] == "grower.tree"]
+    assert len(trees) == 2
+    cap = num_leaves - 1
+    for t in trees:
+        assert t["offered"] <= t["slots"]
+        if method == "fused":
+            # root at 16, then rounds at 16 or the cap (62 < 64)
+            assert 16 * (t["rounds"] + 1) <= t["slots"] \
+                < 16 + cap * t["rounds"]
+            assert (t["slots"] - 16 * (t["rounds"] + 1)) % (cap - 16) == 0
+        else:
+            assert t["slots"] == cap * t["rounds"]
 
 
 def test_counters_leave_the_tree_as_it_was():
@@ -228,9 +259,12 @@ def test_counters_leave_the_tree_as_it_was():
                     jax.tree_util.tree_leaves(t1)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_array_equal(np.asarray(lid0), np.asarray(lid1))
-    rounds, offered, applied = (int(v) for v in stats)
+    rounds, offered, applied, slots = (int(v) for v in stats)
     assert applied == int(t1.num_leaves) - 1
     assert 1 <= rounds <= applied <= offered
+    # the staged family builds every pass at the round cap (30 of 31
+    # leaves); its root is no segment pass
+    assert slots == 30 * rounds
 
 
 # ----------------------------------------------- (d) the compile counters
