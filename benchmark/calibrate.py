@@ -17,12 +17,12 @@ The builder runs it on the chip; no benchmark run and no test calls it at
 the cell's size.  Each line of stdout is one reading as JSON:
 ``{"what": "program"|"control"|<fault>, "seed": n, "numbers": {...}}``.
 
-Training: every reading drives the cell's own traffic loop (same entry,
-same sizes) for its warm rounds and a short window, which are what the
-reference follows.  The control is the program with the parameters of
-``controls/<config>.json`` laid over the configuration's.  Scoring: a short window at the cell's own load; the
-control is the plain reference with its comparisons made in bfloat16, read
-at the same sampled rows.
+Every reading drives the cell's own kind of traffic (``kinds/<kind>.py``:
+same entry, same sizes) for its warm-up and a short window, which are what
+the reference compares.  The control is the program with the parameters of
+``controls/<config>.json`` laid over the configuration's, or, for a kind
+that has ``control_numbers``, the plain reference in the program's place
+one precision step down, read from the program's own run.
 """
 import argparse
 import json
@@ -66,10 +66,9 @@ def main(argv=None):
         config = dict(config, rows=args.rows)
     lookup.apply_env(config)
     import jax
-    import ml_dtypes
     import numpy as np
     from lightgbm_tpu.utils.platform import enable_compile_cache
-    from benchmark.lib import correct, faults, traffic as traffic_lib
+    from benchmark.lib import traffic as traffic_lib
     from benchmark.lib.spans import CompileCounter, Spans
 
     if jax.devices()[0].platform != "tpu" and not manifest.get("rehearsal"):
@@ -77,7 +76,11 @@ def main(argv=None):
         return 3
     enable_compile_cache()
     compiles = CompileCounter()
-    kind = traffic["kind"]
+    kind = traffic_lib.kind_module(manifest, traffic)
+    # a kind whose control is the reference put in the program's place
+    # reads it from the program's own run; the others take the control as
+    # parameters laid over the configuration's
+    reference_control = hasattr(kind, "control_numbers")
     control_path = lookup.find_optional(
         manifest, f"controls/{cell['config']}.json")
     control = lookup.load_json(control_path) if control_path else {}
@@ -85,48 +88,38 @@ def main(argv=None):
     def reading(what, seed, cfg, fault=None):
         t0 = time.perf_counter()
         spans = Spans()
-        run = traffic_lib.KINDS[kind](
+        run = kind.run(
             manifest, cfg, traffic, cell_file, seed,
             args.seconds if what == "program" else args.other_seconds,
             spans, compiles, jax.devices(), None, fault)
         run.free()
         t1 = time.perf_counter()
-        out = []
-        if kind == "train_loop":
-            detail = []
-            numbers = correct.train_numbers(
-                run, float(run.params["learning_rate"]),
-                float(run.params.get("lambda_l2", 0.0)), detail=detail)
-            numbers["failed"] = float(run.failed)
-            if args.keep:
-                os.makedirs(args.keep, exist_ok=True)
-                np.savez_compressed(
-                    os.path.join(args.keep, f"{what}_{seed}.npz"),
-                    **{f"t{t}_{k}": v for t, d in enumerate(detail)
-                       for k, v in d.items()})
-            out.append((what, numbers))
-        else:
-            exact = correct.score_reference(run)
-            numbers = correct.score_numbers(run)
-            numbers["failed"] = float(run.failed)
-            out.append((what, numbers))
-            if what == "program" and seed in args.control_seeds:
-                low = correct.score_reference(run, ml_dtypes.bfloat16)
-                out.append(("control", {"score_gap": correct.score_gap(
-                    list(enumerate(low)), exact)}))
+        detail = []
+        numbers = kind.numbers(run, detail=detail)
+        numbers["failed"] = float(run.failed)
+        if args.keep and detail:
+            os.makedirs(args.keep, exist_ok=True)
+            np.savez_compressed(
+                os.path.join(args.keep, f"{what}_{seed}.npz"),
+                **{f"t{t}_{k}": v for t, d in enumerate(detail)
+                   for k, v in d.items()})
+        out = [(what, numbers)]
+        if reference_control and what == "program" \
+                and seed in args.control_seeds:
+            out.append(("control", kind.control_numbers(run)))
         for w, numbers in out:
             print(json.dumps({
                 "what": w, "seed": seed, "numbers": numbers,
                 "run_s": t1 - t0, "compare_s": time.perf_counter() - t1,
                 "attempted": run.attempted,
                 "step_seconds": getattr(run, "step_seconds", None),
-                "spans": spans.seconds,
+                "spans": spans.seconds, "info": run.info,
                 "peak_bytes": run.peak_bytes}), flush=True)
 
-    table = faults.TRAIN if kind == "train_loop" else faults.SCORE
+    table = getattr(kind, "FAULTS", {})
     for seed in args.seeds:
         reading("program", seed, config)
-    if kind == "train_loop":
+    if not reference_control:
         if args.control_seeds and not control:
             print("calibrate.py: no controls file", file=sys.stderr)
             return 2

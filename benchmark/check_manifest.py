@@ -184,6 +184,49 @@ def check(manifest, allow_extra=()):
             errors.append(f"metric {m.get('name')}: better")
     if len(json.dumps(manifest)) > 64 * 1024:
         errors.append("the manifest is over 64 KiB")
+    return errors + check_files(manifest)
+
+
+def check_files(manifest, configs=None, traffics=None):
+    """Every cell's traffic ``kind`` is a file ``kinds/<kind>.py`` under the
+    manifest's paths, and the objective its configuration's parameters name
+    a file ``objectives/<objective>.py`` there: what ``run.py`` looks up by
+    name.  ``configs`` and ``traffics`` ({name: dict}) stand in for the
+    files in tests."""
+    errors = []
+    paths = manifest["paths"]
+
+    def under(rel):
+        return any((REPO / p / rel).is_file() for p in paths)
+
+    def load(rel):
+        for p in paths:
+            if (REPO / p / rel).is_file():
+                return json.loads((REPO / p / rel).read_text())
+        return None
+
+    files = {c["name"]: c["file"] for c in manifest.get("configs", [])}
+    for w in manifest["workloads"]:
+        traffic = (traffics or {}).get(w["traffic"]) \
+            or load(f"traffic/{w['traffic']}.json")
+        if traffic is None:
+            continue            # reported by check()
+        kind = traffic.get("kind")
+        if not (isinstance(kind, str) and NAME.match(kind)
+                and under(f"kinds/{kind}.py")):
+            errors.append(f"workload {w['name']}: no kind file "
+                          f"kinds/{kind}.py under paths")
+        config = (configs or {}).get(w["config"])
+        if config is None and (REPO / files.get(w["config"], "")).is_file():
+            config = json.loads((REPO / files[w["config"]]).read_text())
+        if config is None:
+            continue            # reported by check()
+        objective = dict(config.get("params", {}),
+                         **traffic.get("params", {})).get("objective")
+        if not (isinstance(objective, str) and NAME.match(objective)
+                and under(f"objectives/{objective}.py")):
+            errors.append(f"workload {w['name']}: no reference objective "
+                          f"objectives/{objective}.py under paths")
     return errors
 
 

@@ -16,9 +16,10 @@ def _worst(gap):
 
 
 CHECK_NODES = (0, 1, 2)     # the first tree's first three splits
+NOTHING_TO_COMPARE = 1e30   # a gap with one side missing (finite: it is printed as JSON)
 
 
-def train_numbers(run, learning_rate, lambda_l2=0.0, detail=None):
+def train_numbers(run, detail=None):
     """Follow the first trees the timed path grew and the last one the
     window finished (see reference_gbdt); each number is the worst over
     the followed trees.  ``detail``, a list, is given one dict of per-leaf
@@ -35,7 +36,10 @@ def train_numbers(run, learning_rate, lambda_l2=0.0, detail=None):
         out["trees_missing"] = 1.0
         return out
     out["trees_missing"] = 0.0
-    bias0 = ref.init_score(run.y)
+    objective, aux = run.objective, getattr(run, "aux", None)
+    learning_rate = float(run.params["learning_rate"])
+    lambda_l2 = float(run.params.get("lambda_l2", 0.0))
+    bias0 = objective.init_score(run.y, aux)
     min_hess = float(run.params.get("min_sum_hessian_in_leaf", 1e-3))
     min_rows = int(run.params.get("min_data_in_leaf", 20))
 
@@ -83,7 +87,7 @@ def train_numbers(run, learning_rate, lambda_l2=0.0, detail=None):
                                    ).reshape(-1, 4)})
         if snap is not None:
             out["loss_gap"] = max(out["loss_gap"], abs(
-                ref.binary_logloss(snap, y64) - r["loss"]) / r["loss"])
+                objective.loss(snap, y64, aux) - r["loss"]) / r["loss"])
             # in units of the rms move of the score: over all trees so
             # far for the first ones, of this tree for a later one
             moved = r["score"] - (bias0 if before is None else before)
@@ -107,11 +111,36 @@ def train_numbers(run, learning_rate, lambda_l2=0.0, detail=None):
                        starts={len(run.answers): last["before"]}
                        if len(trees) > len(run.answers) else None,
                        check_nodes=CHECK_NODES, min_hess=min_hess,
-                       min_rows=min_rows)
+                       min_rows=min_rows, objective=objective, aux=aux)
     for tree, r, snap, before in zip(trees, steps, snaps, befores):
         compare(tree, r, snap, before)
     out["hess_noise"] = float(np.sqrt(np.mean(np.concatenate(hz) ** 2)))
     out["grad_noise"] = float(np.sqrt(np.mean(np.concatenate(gz) ** 2)))
+    return out
+
+
+def eval_numbers(ev, objective):
+    """What the program recorded for the validation set at the last counted
+    round against the reference's own: every validation row routed through
+    every tree the host held at the close by real-valued thresholds, the
+    loss and the AUC of those scores in float64.  ``ev`` is the kind's
+    record: ``recorded`` {metric: [a value a round]}, ``rounds`` counted
+    (warm ones included), ``X``, ``y``, ``aux``, ``trees``, and under
+    ``loss`` and ``auc`` the recorded metrics' names."""
+    lists = [ev["recorded"].get(ev[k], []) for k in ("loss", "auc")]
+    out = {"eval_rounds_missing":
+           float(max(ev["rounds"] - min(map(len, lists)), 0)),
+           "eval_logloss_gap": NOTHING_TO_COMPARE,
+           "eval_auc_gap": NOTHING_TO_COMPARE}
+    if not ev["trees"] or not all(lists):
+        return out
+    y64 = np.asarray(ev["y"], np.float64)
+    score = ref.raw_scores(ev["X"], ev["trees"])
+    loss = objective.loss(score, y64, ev["aux"])
+    auc = ref.auc(score, y64)
+    out["eval_logloss_gap"] = abs(lists[0][-1] - loss) / loss
+    out["eval_auc_gap"] = abs(lists[1][-1] - auc) / auc
+    out["eval_loss_ref"], out["eval_auc_ref"] = loss, auc
     return out
 
 

@@ -2,7 +2,7 @@
 false (``tests/test_correct.py`` on the CPU twins, ``calibrate.py --fault``
 on the chip at the cell's own size).  No benchmark run plants one.
 
-A fault is an object with hooks the traffic loops call when they are given
+A fault is an object with hooks the traffic kinds call when they are given
 one: ``after_build(booster)``, ``before_step(booster)`` ->  token,
 ``after_step(booster, token)``, ``after_pull(booster)``.
 """
@@ -83,6 +83,39 @@ class LateAlteredAnswer(AlteredAnswer):
     first = 2
 
 
+class StaleEval(Fault):
+    """An evaluation one round stale: every evaluation of the validation
+    sets after the first answers with the round before's values."""
+    name = "stale_eval"
+
+    def after_build(self, bst):
+        inner = bst.boosting.eval_valid
+        kept = []
+
+        def eval_valid():
+            kept.append(inner())
+            return kept[-2] if len(kept) > 1 else kept[-1]
+        bst.boosting.eval_valid = eval_valid
+
+
+class ValidTreeSkipped(Fault):
+    """A validation score that skipped a tree: the update of the validation
+    scores for round ``skip`` (the first of the window) hands them back as
+    they were."""
+    name = "valid_tree_skipped"
+    skip = 2
+
+    def after_build(self, bst):
+        b = bst.boosting
+        inner = b._chunk_valid_update
+
+        def update(vscore, stacked_seq, binned, its):
+            if int(its[0]) == self.skip:
+                return vscore
+            return inner(vscore, stacked_seq, binned, its)
+        b._chunk_valid_update = update
+
+
 class ScoreFault(Fault):
     """Scoring faults wrap ``Booster.predict`` where the answer is made."""
 
@@ -132,4 +165,5 @@ class StaleAnswer(ScoreFault):
 
 TRAIN = {f.name: f for f in (StateUnchanged, HalfBatch, AlteredAnswer,
                              LateStateUnchanged, LateAlteredAnswer)}
+EVAL = {f.name: f for f in (StaleEval, ValidTreeSkipped)}
 SCORE = {f.name: f for f in (StaleAnswer, HalfRequest, AlteredScore)}

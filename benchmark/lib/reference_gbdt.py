@@ -1,6 +1,10 @@
-"""The plain reference for the training cells: binary-logloss GBDT in
-float64 NumPy, written from LightGBM's published equations, importing
-nothing of the program and taking no table of it.
+"""The plain reference for the training cells: GBDT in float64 NumPy,
+written from LightGBM's published equations, importing nothing of the
+program and taking no table of it.  The objective is a module of its own
+(``objectives/<objective>.py``: ``init_score(y, aux)``, ``gradients(score,
+y, aux)``, ``loss(score, y, aux)``), found by the name in the
+configuration's parameters and handed in; ``aux`` is the dict of dataset
+fields the generator made beside the label.
 
 It does not grow trees of its own: a leaf-wise grower breaks near-ties on
 the last bits of a histogram sum, so two correct growers disagree from the
@@ -28,29 +32,6 @@ from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 
 
-def sigmoid(s):
-    return 1.0 / (1.0 + np.exp(-s))
-
-
-def binary_gradients(score, y):
-    """LightGBM's binary objective with sigmoid=1: g = p - y, h = p(1-p)."""
-    p = sigmoid(score)
-    return p - y, p * (1.0 - p)
-
-
-def binary_logloss(score, y):
-    # log(1 + exp(-z)) with z = +-score, stable in float64
-    z = np.where(y > 0, score, -score)
-    return float(np.mean(np.logaddexp(0.0, -z)))
-
-
-def init_score(y):
-    """BoostFromAverage for binary logloss: the log-odds of the label mean."""
-    p = float(np.mean(y, dtype=np.float64))
-    p = min(max(p, 1e-15), 1.0 - 1e-15)
-    return float(np.log(p / (1.0 - p)))
-
-
 def route(Xt, tree, keep_nodes=None):
     """Rows of each leaf: ``{leaf: sorted row indices}``.  ``Xt`` is the raw
     matrix feature-major [F, n] float32; the decision is LightGBM's
@@ -75,6 +56,48 @@ def route(Xt, tree, keep_nodes=None):
             else:
                 stack.append((child, rows))
     return leaves
+
+
+def raw_scores(X, trees, blocks=12):
+    """Raw score of every row of ``X`` [n, F] float32 under ``trees``: each
+    row routed through every tree by its real-valued thresholds (``route``),
+    the leaves' values added up in float64.  The values are the host
+    model's, so the first tree's carry the boost-from-average bias."""
+    n = len(X)
+    score = np.zeros(n, np.float64)
+    cuts = np.linspace(0, n, blocks + 1).astype(np.int64)
+    spans = [(int(a), int(b)) for a, b in zip(cuts[:-1], cuts[1:]) if b > a]
+
+    def part(span):
+        lo, hi = span
+        Xt = np.ascontiguousarray(X[lo:hi].T)
+        out = score[lo:hi]
+        for tree in trees:
+            for leaf, rows in route(Xt, tree).items():
+                out[rows] += tree["leaf_value"][leaf]
+
+    with ThreadPoolExecutor(max_workers=max(len(spans), 1)) as pool:
+        list(pool.map(part, spans))
+    return score
+
+
+def auc(score, y):
+    """Area under the ROC curve from the rank sum, tied scores sharing
+    their mean rank (what LightGBM's ``auc`` computes, unweighted)."""
+    score = np.asarray(score, np.float64)
+    order = np.argsort(score, kind="stable")
+    s = score[order]
+    pos = np.asarray(y)[order] > 0
+    first = np.flatnonzero(np.r_[True, s[1:] != s[:-1]])
+    last = np.r_[first[1:], len(s)]
+    mean_rank = 0.5 * (first + last + 1)            # 1-based
+    ranks = np.repeat(mean_rank, last - first)
+    n_pos = float(pos.sum())
+    n_neg = float(len(s) - n_pos)
+    if n_pos == 0 or n_neg == 0:
+        return 1.0
+    return float((ranks[pos].sum() - n_pos * (n_pos + 1) / 2)
+                 / (n_pos * n_neg))
 
 
 def leaf_sums(leaves, num_leaves, g, h):
@@ -192,7 +215,7 @@ def best_splits(slices, tree, lam, min_hess, min_rows, stride):
 
 def follow(X, y, trees, learning_rate, lambda_l2=0.0, blocks=12,
            starts=None, check_nodes=(), min_hess=0.0, min_rows=0,
-           check_rows=1 << 20):
+           check_rows=1 << 20, *, objective, aux=None):
     """Follow ``trees`` in order.  Yields, per tree, a dict with the
     reference's per-leaf ``G``, ``H``, ``count``, ``value`` (the leaf's
     output without the first tree's bias), ``gain`` per split, and after
@@ -210,7 +233,7 @@ def follow(X, y, trees, learning_rate, lambda_l2=0.0, blocks=12,
     n = len(y)
     starts = starts or {}
     stride = max(1, n // check_rows)
-    bias = init_score(y)
+    bias = objective.init_score(y, aux)
     score = np.full(n, bias, np.float64)
     cuts = np.linspace(0, n, blocks + 1).astype(np.int64)
     spans = [(int(a), int(b)) for a, b in zip(cuts[:-1], cuts[1:]) if b > a]
@@ -221,7 +244,7 @@ def follow(X, y, trees, learning_rate, lambda_l2=0.0, blocks=12,
         for t, tree in enumerate(trees):
             if t in starts:
                 score[:] = starts[t]
-            g, h = binary_gradients(score, y)
+            g, h = objective.gradients(score, y, aux)
             nl = len(tree["leaf_value"])
             want = [k for k in check_nodes
                     if t == 0 and k < len(tree["split_feature"])]
@@ -260,6 +283,6 @@ def follow(X, y, trees, learning_rate, lambda_l2=0.0, blocks=12,
                 "G": G, "H": H, "count": C, "value": value,
                 "bias": bias if t == 0 and 0 not in starts else 0.0,
                 "gain": split_gains(tree, G, H, C, lambda_l2),
-                "score": score, "loss": binary_logloss(score, y),
+                "score": score, "loss": objective.loss(score, y, aux),
                 "splits": splits,
             }

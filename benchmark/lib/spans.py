@@ -16,6 +16,11 @@ class Spans:
         with TraceAnnotation("bench." + name):
             yield
         dt = time.perf_counter() - t0
+        self.add(name, dt)
+
+    def add(self, name, dt):
+        """Seconds measured by the caller (a span that opens in one
+        callback and closes in another)."""
         self.seconds[name] = self.seconds.get(name, 0.0) + dt
         self.samples.setdefault(name, []).append(dt)
 

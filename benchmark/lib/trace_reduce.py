@@ -20,7 +20,11 @@ its duration less its direct children's.
 - ``window_s``      first to last device-or-annotation event, in seconds
 - ``devices``       per chip: ``busy_s`` (union of op intervals),
                     ``ops`` {name: own seconds}, ``kernel_s`` (custom calls),
-                    ``collective_s``, ``collective_exposed_s``
+                    ``collective_s``, ``collective_exposed_s``, ``modules``
+                    {program name: [runs, seconds]} from the line
+                    ``XLA Modules`` (``jit_<function>``, the fingerprint in
+                    brackets cut off)
+- ``modules``       the first chip's ``modules``, the twenty with most time
 - ``busy_s``        mean of the chips' ``busy_s``
 - ``device_ops``    the ten ops with most time, [[name, seconds], ...]
 - ``idle_gaps``     device-idle seconds by what the host was doing,
@@ -33,6 +37,8 @@ from pathlib import Path
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+MODULE_ID = re.compile(r"\(\d+\)$")
 KERNEL_NAME = re.compile(r"^custom-call:tpu_custom_call ")
 COLLECTIVE_NAME = re.compile(
     r"^(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)")
@@ -136,7 +142,7 @@ def read_planes(path):
         device = bool(DEVICE_PLANE.match(plane.name))
         lines = {}
         for line in plane.lines:
-            if device and line.name != OPS_LINE:
+            if device and line.name not in (OPS_LINE, MODULES_LINE):
                 continue
             lines.setdefault(line.name, []).extend(
                 (short_name(ev.name), float(ev.start_ns),
@@ -173,6 +179,12 @@ def reduce_planes(planes):
                    if not COLLECTIVE_NAME.search(n)]
         coll_ns, coll_merged = _union(coll)
         _, compute_merged = _union(compute)
+        modules = {}
+        for n, s, d in planes[pname].get(MODULES_LINE, []):
+            if lo is None or (s + d > lo and s < hi):
+                m = modules.setdefault(MODULE_ID.sub("", n), [0, 0.0])
+                m[0] += 1
+                m[1] += d / 1e9
         devices.append({
             "plane": pname, "busy_s": busy_ns / 1e9,
             "ops": {n: d / 1e9 for n, d in ops.items()},
@@ -181,7 +193,7 @@ def reduce_planes(planes):
             "collective_s": coll_ns / 1e9,
             "collective_exposed_s":
                 (coll_ns - _overlap(coll_merged, compute_merged)) / 1e9,
-            "_merged": merged,
+            "modules": modules, "_merged": merged,
         })
         for n, d in ops.items():
             all_ops[n] = all_ops.get(n, 0.0) + d / 1e9
@@ -224,6 +236,8 @@ def reduce_planes(planes):
         "idle_gaps": [[n, s] for n, s in
                       sorted(by_host.items(), key=lambda kv: -kv[1])[:10]],
         "annotations": ann,
+        "modules": dict(sorted(devices[0]["modules"].items(),
+                               key=lambda kv: -kv[1][1])[:20]),
     }
 
 

@@ -6,8 +6,8 @@ import statistics
 
 def read(ctx):
     run = ctx["run"]
-    first = ctx["samples"].get(
-        "warm_round" if run.kind == "train_loop" else "warm_request")
+    first = (ctx["samples"].get("warm_round")
+             or ctx["samples"].get("warm_request"))
     steady = getattr(run, "step_seconds", None)
     if not first or not steady:
         return None

@@ -4,11 +4,14 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 One process, the only one that touches JAX.  It finds the cell's files by
-the names in the manifest (``lib/lookup.py``), makes the inputs from the
+the names in the manifest (``lib/lookup.py``), among them the kind of its
+traffic (``kinds/<kind>.py``: the run up to the close of the window, the
+end-to-end metric it reports, the comparison), makes the inputs from the
 seed, sets up and warms the cell's own shapes (all of that is ``setup_s``),
 measures for ``--seconds``, reads the peak memory, frees the program's
 state, then runs the plain reference over what the timed path produced and
-prints every number compared beside its limit.  Earlier stdout lines are
+prints every number compared beside its limit, and the seconds of every
+phase.  Earlier stdout lines are
 JSON information (phases, elected variants, cache warmth); the last line is
 the result.  Without a TPU, or with fewer chips than the cell asks for, it
 says why on stderr and exits non-zero with no result: there is no CPU
@@ -81,6 +84,17 @@ def main(argv=None, fault=None):
     except (FileNotFoundError, KeyError) as e:
         fail(2, str(e))
     rehearsal = bool(manifest.get("rehearsal"))
+    from benchmark.lib import traffic as traffic_lib
+    try:
+        kind = traffic_lib.kind_module(manifest, traffic)
+    except FileNotFoundError as e:
+        fail(2, str(e))
+    e2e_names = [m["name"] for m in lookup.metrics_for(
+        manifest, args.workload, "end_to_end")]
+    if kind.PRIMARY not in e2e_names:
+        fail(2, f"traffic kind {traffic['kind']!r} reports {kind.PRIMARY!r}, "
+                f"which is no end-to-end metric of {args.workload} in the "
+                f"manifest (it has {e2e_names})")
     lookup.apply_env(config)
     try:
         import jax
@@ -89,7 +103,7 @@ def main(argv=None, fault=None):
                                                  enable_compile_cache)
     except ImportError as e:
         fail(4, f"the system under test is not importable here: {e}")
-    from benchmark.lib import correct, traffic as traffic_lib
+    from benchmark.lib import correct
     from benchmark.lib.spans import CompileCounter, Spans
 
     devices = jax.devices()
@@ -101,6 +115,7 @@ def main(argv=None, fault=None):
         fail(3, f"the cell asks for {cell['chips']} chips, JAX reports "
                 f"{len(devices)}")
     cache_dir = enable_compile_cache()
+    t_ready = time.perf_counter()
     entries_start = compile_cache_entries(cache_dir)
     peaks = None if rehearsal else lookup.peaks_for(
         manifest, devices[0].device_kind)
@@ -128,7 +143,7 @@ def main(argv=None, fault=None):
         else:
             jax.profiler.stop_trace()
 
-    run = traffic_lib.KINDS[traffic["kind"]](
+    run = kind.run(
         manifest, config, traffic, cell_file, args.seed, args.seconds,
         spans, compiles, devices, on_window, fault)
     setup_s = marks["start"] - T_PROCESS
@@ -141,15 +156,13 @@ def main(argv=None, fault=None):
     run.free()
 
     # ---- metrics -----------------------------------------------------
-    if run.kind == "train_loop":
-        primary = ("train_s_per_tree", run.window_s / max(run.trees, 1))
-    else:
-        primary = ("score_rows_per_s", run.rows / max(run.window_s, 1e-9))
+    primary = kind.primary(run)
     e2e_values = {"setup_s": setup_s, primary[0]: primary[1]}
     device = {"platform": platform, "kind": devices[0].device_kind,
               "count": len(devices), "memory_peak_bytes": run.peak_bytes}
     result = {"correct": False, "attempted": run.attempted,
               "failed": run.failed, "metrics": {}, "device": device}
+    t_reduce = time.perf_counter()
     if rehearsal:
         pass
     elif not args.trace:
@@ -164,6 +177,7 @@ def main(argv=None, fault=None):
         emit("trace", file_bytes=xplane.stat().st_size,
              reduce_s=time.perf_counter() - t0,
              annotations=reduced["annotations"],
+             modules=reduced["modules"],
              kernel_calls=[d["kernel_calls"] for d in reduced["devices"]])
         keep = os.environ.get("BENCH_KEEP_TRACE")
         if keep:
@@ -185,22 +199,31 @@ def main(argv=None, fault=None):
 
     # ---- the comparison, once the window has closed ------------------
     t0 = time.perf_counter()
-    if run.kind == "train_loop":
-        numbers = correct.train_numbers(
-            run, float(run.params["learning_rate"]),
-            float(run.params.get("lambda_l2", 0.0)))
-    else:
-        numbers = correct.score_numbers(run)
+    numbers = kind.numbers(run)
     numbers["window_compiles"] = float(compiles.count)
     numbers["nothing_done"] = float(run.attempted == 0)
     limits = dict(cell_file.get("limits", {}))
-    limits.setdefault("window_compiles", 0)
-    limits.setdefault("nothing_done", 0)
-    if run.kind == "train_loop":
-        limits.setdefault("window_tree_missing", 0)
+    for name, limit in {"window_compiles": 0, "nothing_done": 0,
+                        **getattr(kind, "LIMITS", {})}.items():
+        limits.setdefault(name, limit)
     ok, compared = correct.judge(numbers, limits)
     result["correct"] = ok and run.failed == 0
-    emit("compare", seconds=time.perf_counter() - t0, numbers=numbers)
+    t_end = time.perf_counter()
+    # every phase of the run on the host clock, end to end: they add up to
+    # the process so far (PERF.md section 4 holds a cell's sum to 300 s)
+    warm = sum(v for k, v in spans.seconds.items() if k.startswith("warm_"))
+    phases = {"imports": t_ready - T_PROCESS,
+              "data": spans.seconds.get("data", 0.0),
+              "ingest": spans.seconds.get("ingest", 0.0),
+              "warm": warm,
+              "other_setup": (marks["start"] - t_ready)
+              - spans.seconds.get("data", 0.0)
+              - spans.seconds.get("ingest", 0.0) - warm,
+              "window": marks["stop"] - marks["start"],
+              "peak": t_reduce - marks["stop"],
+              "reduce": t0 - t_reduce, "compare": t_end - t0}
+    emit("compare", seconds=t_end - t0, numbers=numbers, phases=phases,
+         phases_sum=sum(phases.values()), process_s=t_end - T_PROCESS)
     result["compared"] = compared
     print("compared (value, limit): " + json.dumps(compared),
           file=sys.stderr, flush=True)

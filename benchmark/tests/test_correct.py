@@ -4,7 +4,6 @@ run driven end to end through ``run.main`` with the timed path broken
 underneath comes out not correct, once for each fault a cell can have."""
 import json
 
-import ml_dtypes
 import pytest
 
 from benchmark import run as bench_run
@@ -12,7 +11,8 @@ from benchmark.lib import correct, faults, lookup
 
 TWIN = "benchmark/tests/data/BENCHMARK.json"
 TRAIN_CELLS = ["higgs-dense.train", "higgs-quant.train",
-               "criteo-quant.train"]
+               "criteo-quant.train", "criteo-quant.monitored"]
+MONITORED = "criteo-quant.monitored"
 
 
 def drive(capsys, workload, fault=None, seed=11):
@@ -42,6 +42,28 @@ def test_training_fault_is_not_correct(capsys, workload, fault):
     assert result["correct"] is False, result["compared"]
     over = [k for k, (v, lim) in result["compared"].items() if v > lim]
     assert over, result["compared"]
+
+
+@pytest.mark.parametrize("fault", sorted(faults.EVAL))
+def test_evaluation_fault_is_not_correct(capsys, fault):
+    """An evaluation one round stale, and a validation score that skipped
+    a tree: the recorded metrics of the last counted round part from the
+    reference's, and nothing else does."""
+    result = drive(capsys, MONITORED, faults.EVAL[fault]())
+    assert result["correct"] is False, result["compared"]
+    over = {k for k, (v, lim) in result["compared"].items() if v > lim}
+    assert over and over <= {"eval_logloss_gap", "eval_auc_gap"}, over
+
+
+def test_monitored_run_records_every_round(capsys):
+    """One ``lgb.train`` call: the window's trees and the warm ones are
+    all evaluated, and the numbers of both kinds of comparison are held."""
+    result = drive(capsys, MONITORED)
+    compared = result["compared"]
+    assert compared["eval_rounds_missing"] == [0.0, 0]
+    assert {"eval_logloss_gap", "eval_auc_gap", "loss_gap",
+            "window_tree_missing"} <= set(compared)
+    assert result["correct"] is True and result["attempted"] >= 1
 
 
 @pytest.mark.parametrize("fault", sorted(faults.SCORE))
@@ -80,14 +102,13 @@ def test_scoring_control_is_not_correct(seed):
     manifest = lookup.load_manifest(TWIN)
     _, _, config, traffic, cell_file = lookup.cell_files(
         manifest, "higgs-dense.score")
-    run = traffic_lib.run_score_loop(
+    kind = traffic_lib.kind_module(manifest, traffic)
+    run = kind.run(
         manifest, config, traffic, cell_file, seed, 0.05, Spans(),
         CompileCounter(), jax.devices())
-    exact = correct.score_reference(run)
-    low = correct.score_reference(run, ml_dtypes.bfloat16)
     limit = cell_file["limits"]["score_gap"]
-    assert correct.score_gap(run.sampled, exact) <= limit
-    assert correct.score_gap(list(enumerate(low)), exact) > 3 * limit
+    assert kind.numbers(run)["score_gap"] <= limit
+    assert kind.control_numbers(run)["score_gap"] > 3 * limit
 
 
 @pytest.mark.parametrize("feature,wide", [(3, False), (5, True)])
@@ -103,7 +124,9 @@ def test_split_choice_reads_a_split_on_the_wrong_feature(feature, wide):
     stump = {"split_feature": np.array([feature]),
              "threshold": np.array([0.5]), "left_child": np.array([-1]),
              "right_child": np.array([-2]), "leaf_value": np.zeros(2)}
-    r = next(ref.follow(X, y, [stump], 0.1, check_nodes=(0,), blocks=3))
+    binary = lookup.load_module(lookup.REPO / "benchmark/objectives/binary.py")
+    r = next(ref.follow(X, y, [stump], 0.1, check_nodes=(0,), blocks=3,
+                        objective=binary))
     got, best, runner = r["splits"][0]
     assert ((best - got) / best > 0.9) is wide
     assert (abs(best - got) / best < 0.01) is not wide

@@ -63,7 +63,8 @@ def test_manifest_holds_the_metric():
     assert m == {"name": "hist_slot_fill", "unit": "%", "better": "higher",
                  "source": "program_counter", "layer": "hist_kernel",
                  "moves": "train_s_per_tree",
-                 "workloads": ["criteo-quant.train"]}
+                 "workloads": ["criteo-quant.train",
+                               "criteo-quant.monitored"]}
 
 
 def test_on_the_cpu_twin(capsys):

@@ -165,6 +165,7 @@ def test_manifest_holds_the_new_metrics():
     assert check_manifest.check(MANIFEST) == []
     by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
     for name in NEW:
-        assert by_name[name]["workloads"] == ["criteo-quant.train"]
+        assert by_name[name]["workloads"] == ["criteo-quant.train",
+                                              "criteo-quant.monitored"]
         assert by_name[name]["source"] in ("program_counter",
                                            "program_span")
