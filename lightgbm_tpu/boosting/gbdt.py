@@ -1263,7 +1263,9 @@ class GBDT:
                       if self._inv_perm is not None else None)
 
         @jax.named_scope("lgbm.gradients")
-        def gradients_fn(score):
+        def gradients_fn(score, obj_tables):
+            # obj_tables: the objective's ``device_tables``, a runtime
+            # argument so that they are no constants of the program
             if obj is None:
                 raise RuntimeError("no objective: gradients must be provided")
             if perm_j is not None:
@@ -1272,7 +1274,7 @@ class GBDT:
             else:
                 s = score if n_pad == n else score[:, :n]
             s = s if K > 1 else s[0]
-            g, h = obj.get_gradients(s)
+            g, h = obj.get_gradients(s, obj_tables)
             g = g.reshape(K, n)
             h = h.reshape(K, n)
             if perm_j is not None:
@@ -1284,7 +1286,9 @@ class GBDT:
                 h = jnp.pad(h, ((0, 0), (0, n_pad - n)))
             return g, h
 
-        self._gradients_fn = jax.jit(gradients_fn)
+        obj_tables = getattr(obj, "device_tables", None)
+        gradients_jit = jax.jit(gradients_fn)
+        self._gradients_fn = lambda score: gradients_jit(score, obj_tables)
 
         # fused macro-step context (boosting/macro.py): the SAME iter_body
         # (serial or shard_map'd) and the same gradient closure, re-traced
@@ -1292,7 +1296,8 @@ class GBDT:
         # per-iteration programs so reset_parameter invalidates both
         self._macro_core = macro_core
         self._macro_grad = gradients_fn
-        self._macro_ctx = {"label": label_a, "weight": weight_a}
+        self._macro_ctx = {"label": label_a, "weight": weight_a,
+                           "obj_tables": obj_tables}
         self._macro_chunk_jit = None
         self._macro_valid_jit = None
         self._has_forced_plan = forced_plan is not None

@@ -11,7 +11,8 @@ score-donating program, so ``num_boost_round`` trees cost
 Everything the scan needs is device-resident or precomputable per chunk:
 
 - gradients recompute from the carried score (the booster's
-  ``gradients_fn`` closure, traced INSIDE the scan body);
+  ``gradients_fn`` closure, traced INSIDE the scan body; an objective's
+  ``device_tables`` ride in as a runtime argument, ``obj_tables``);
 - bagging masks are host-RNG draws -> stacked ``[c, n_pad]`` input;
 - per-tree feature masks -> stacked ``[c, K, F]`` input;
 - learning-rate schedules (reset_parameter) -> ``[c]`` array;
@@ -132,7 +133,7 @@ def make_chunk_fn(b):
     L = b.grower_cfg.num_leaves
 
     def chunk(binned, score, cegb_used, cegb_rows, n_steps, xs,
-              label_r, weight_r, grad_c, hess_c):
+              label_r, weight_r, grad_c, hess_c, obj_tables):
         masks, fmasks, lrs, keys, its, gkeys, gons = xs
         c = lrs.shape[0]
         tmpl = TreeArrays.empty(L)
@@ -158,7 +159,7 @@ def make_chunk_fn(b):
                 g, h = grad_c, hess_c
                 score_in = score * it.astype(jnp.float32)
             else:
-                g, h = grad_fn(score)
+                g, h = grad_fn(score, obj_tables)
                 score_in = score
             if kind == "goss":
                 gm = goss_mask(g, h, _ix(gkeys, j), mask)
@@ -307,7 +308,8 @@ def run_chunk(b, c: int, lrs: Optional[Sequence[float]] = None) -> bool:
                timer="TreeLearner::Train(dispatch)"):
         (b.train_score, cu, cr, stacked_seq, qss, gss) = b._macro_chunk_jit(
             b.binned, b.train_score, cu, cr, np.int32(c), xs,
-            b._macro_ctx["label"], b._macro_ctx["weight"], grad_c, hess_c)
+            b._macro_ctx["label"], b._macro_ctx["weight"], grad_c, hess_c,
+            b._macro_ctx["obj_tables"])
     b._cegb_state = (cu, cr)
     if getattr(b, "_quant_on", False):
         b._quant_scales = qss[c - 1]   # last round's per-class scales

@@ -370,8 +370,7 @@ class Dataset:
         dtype = np.uint8 if self.max_group_bin <= 256 else np.uint16
         self.binned = np.zeros((self.num_data, G), dtype=dtype)
         if not self._maybe_device_bin(raw, sp, self.binned):
-            # annotation only, like the kernel path's ingest.bin_chunk
-            with _span("ingest.host_bin", rows=self.num_data):
+            with _span("ingest.host_bin", ring=True, rows=self.num_data):
                 self._bin_block(raw, sp, self.binned)
 
         self.metadata.check(self.num_data)

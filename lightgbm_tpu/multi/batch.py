@@ -78,7 +78,7 @@ class BatchedChunkProgram:
         obj = b0.objective
 
         def wrapped(binned, score, cu, cr, n_steps, xs, label_r, weight_r,
-                    grad_c, hess_c, obj_arrs):
+                    grad_c, hess_c, obj_tables, obj_arrs):
             # rebind-at-trace: vmap traces this body once with ``obj_arrs``
             # as lane-batched tracers; gradients_fn reads the objective's
             # arrays at trace time, so pointing them at the tracers makes
@@ -89,7 +89,8 @@ class BatchedChunkProgram:
                 setattr(obj, k, v)
             try:
                 return chunk_fn(binned, score, cu, cr, n_steps, xs,
-                                label_r, weight_r, grad_c, hess_c)
+                                label_r, weight_r, grad_c, hess_c,
+                                obj_tables)
             finally:
                 for k, v in saved.items():
                     setattr(obj, k, v)
@@ -98,7 +99,7 @@ class BatchedChunkProgram:
         self._fn = jax.jit(
             jax.vmap(wrapped,
                      in_axes=(data_ax, 0, 0, 0, None, 0, data_ax, data_ax,
-                              None, None, 0)),
+                              None, None, None, 0)),
             donate_argnums=(1,))
         if self.stacked:
             self._binned_B = stack_lanes(
@@ -187,7 +188,7 @@ class BatchedChunkProgram:
             score_B, cu_B, cr_B, ys_B, qss_B, gss_B = self._fn(
                 self._binned_B, score_B, cu_B, cr_B, np.int32(c), xs_B,
                 self._label_B, self._weight_B, grad_c, hess_c,
-                self._obj_arrs_B)
+                self.b0._macro_ctx["obj_tables"], self._obj_arrs_B)
 
         stopped = [False] * len(bs)
         for i, (b, is_live) in enumerate(zip(bs, live)):

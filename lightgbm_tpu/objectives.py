@@ -7,7 +7,8 @@ implementations cited per class.  Scores/gradients for multiclass use
 [K, n] layout (class-major, like the reference's flattened num_data*k+i).
 
 Each objective provides:
-- ``get_gradients(score) -> (grad, hess)`` — jittable, shapes [n] or [K, n]
+- ``get_gradients(score, tables) -> (grad, hess)`` — jittable, shapes [n] or
+  [K, n]; ``tables`` is the objective's ``device_tables`` (None but for ranking)
 - ``boost_from_score(class_id)`` — host-side init score
 - ``convert_output(score)`` — raw score -> prediction space (jittable)
 - ``renew_percentile`` — not None for objectives that re-fit leaf outputs
@@ -33,6 +34,10 @@ class ObjectiveFunction:
     is_constant_hessian = False
     renew_percentile: Optional[float] = None
     need_group = False
+    # device arrays ``get_gradients(score, tables)`` takes as runtime
+    # arguments of the jitted round program (the ranking objectives' query
+    # tables); None for an objective whose state is the label array
+    device_tables = None
 
     def __init__(self, config: Config):
         self.config = config
@@ -49,7 +54,10 @@ class ObjectiveFunction:
             return g * self.weight, h * self.weight
         return g, h
 
-    def get_gradients(self, score: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    def get_gradients(self, score: jax.Array,
+                      tables=None) -> Tuple[jax.Array, jax.Array]:
+        """``tables``: the objective's ``device_tables``, which the booster
+        always passes."""
         raise NotImplementedError
 
     def boost_from_score(self, class_id: int = 0) -> float:
@@ -82,7 +90,7 @@ class RegressionL2(ObjectiveFunction):
             lbl = np.asarray(metadata.label, np.float64)
             self.label = jnp.asarray(np.sign(lbl) * np.sqrt(np.abs(lbl)), jnp.float32)
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         return self._w(score - self.label, jnp.ones_like(score))
 
     def boost_from_score(self, class_id=0):
@@ -100,7 +108,7 @@ class RegressionL1(RegressionL2):
     name = "regression_l1"
     renew_percentile = 0.5
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         return self._w(jnp.sign(score - self.label), jnp.ones_like(score))
 
     def boost_from_score(self, class_id=0):
@@ -115,7 +123,7 @@ class RegressionHuber(RegressionL2):
     name = "huber"
     renew_percentile = 0.5
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         diff = score - self.label
         a = self.config.alpha
         g = jnp.where(jnp.abs(diff) <= a, diff, jnp.sign(diff) * a)
@@ -127,7 +135,7 @@ class RegressionFair(ObjectiveFunction):
 
     name = "fair"
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         c = self.config.fair_c
         x = score - self.label
         g = c * x / (jnp.abs(x) + c)
@@ -140,7 +148,7 @@ class RegressionPoisson(ObjectiveFunction):
 
     name = "poisson"
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         g = jnp.exp(score) - self.label
         h = jnp.exp(score + self.config.poisson_max_delta_step)
         return self._w(g, h)
@@ -162,7 +170,7 @@ class RegressionQuantile(ObjectiveFunction):
     def renew_percentile(self):
         return self.config.alpha
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         a = self.config.alpha
         g = jnp.where(score > self.label, 1.0 - a, -a)
         return self._w(g, jnp.ones_like(score))
@@ -184,7 +192,7 @@ class RegressionMAPE(ObjectiveFunction):
         lw = 1.0 / np.maximum(1.0, np.abs(np.asarray(metadata.label, np.float64)))
         self.label_weight = jnp.asarray(lw, jnp.float32)
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         diff = score - self.label
         g = jnp.sign(diff) * self.label_weight
         h = jnp.ones_like(score) if self.weight is None else self.weight
@@ -205,7 +213,7 @@ class RegressionGamma(RegressionPoisson):
 
     name = "gamma"
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         g = 1.0 - self.label * jnp.exp(-score)
         h = self.label * jnp.exp(-score)
         return self._w(g, h)
@@ -216,7 +224,7 @@ class RegressionTweedie(RegressionPoisson):
 
     name = "tweedie"
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         rho = self.config.tweedie_variance_power
         e1 = jnp.exp((1.0 - rho) * score)
         e2 = jnp.exp((2.0 - rho) * score)
@@ -257,7 +265,7 @@ class BinaryLogloss(ObjectiveFunction):
             else:
                 self._pavg = cnt_pos / (cnt_pos + cnt_neg)
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         sig = self.config.sigmoid
         lb = self.label_sign
         lw = jnp.where(lb > 0, self.label_weight_pos, self.label_weight_neg)
@@ -302,7 +310,7 @@ class MulticlassSoftmax(ObjectiveFunction):
         probs = np.array([(w * (lbl == k)).sum() for k in range(self.num_class)])
         self.class_init_probs = probs / w.sum()
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         # score: [K, n]
         p = jax.nn.softmax(score, axis=0)
         g = p - self.label_onehot
@@ -348,7 +356,7 @@ class MulticlassOVA(ObjectiveFunction):
             sub.init(md, num_data)
             self.binary_objs.append(sub)
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         gs, hs = [], []
         for k in range(self.num_class):
             g, h = self.binary_objs[k].get_gradients(score[k])
@@ -376,7 +384,7 @@ class CrossEntropy(ObjectiveFunction):
         if lbl.min() < 0 or lbl.max() > 1:
             raise ValueError("cross_entropy labels must be in [0, 1]")
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         z = 1.0 / (1.0 + jnp.exp(-score))
         return self._w(z - self.label, z * (1.0 - z))
 
@@ -393,7 +401,7 @@ class CrossEntropyLambda(ObjectiveFunction):
 
     name = "cross_entropy_lambda"
 
-    def get_gradients(self, score):
+    def get_gradients(self, score, tables=None):
         # reference: xentropy_objective.hpp:185-212 (weighted branch; the
         # unweighted branch degenerates to plain sigmoid cross-entropy)
         if self.weight is None:

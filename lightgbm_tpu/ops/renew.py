@@ -90,6 +90,7 @@ def quant_train_renew_leaf(
     (and psums them under data sharding).
     """
     w = weight
-    sg = jax.ops.segment_sum(grad * w, leaf_id, num_segments=num_leaves)
-    sh = jax.ops.segment_sum(hess * w, leaf_id, num_segments=num_leaves)
+    with jax.named_scope("lgbm.renew"):
+        sg = jax.ops.segment_sum(grad * w, leaf_id, num_segments=num_leaves)
+        sh = jax.ops.segment_sum(hess * w, leaf_id, num_segments=num_leaves)
     return sg.astype(jnp.float32), sh.astype(jnp.float32)

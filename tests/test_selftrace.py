@@ -104,6 +104,21 @@ def test_ring_holds_the_coarse_seams_with_parent_and_it():
                 "ingest.bin_chunk"} & set(by_name)
 
 
+def test_host_bin_is_one_ring_record_a_construct():
+    """Where the host bins (here: no accelerator) the pass is on the ring,
+    one record a construct, and the kernel's record is not."""
+    global_flight._ring.clear()
+    X, y = _data(2, rows=5000)
+    lgb.Dataset(X, label=y, params={"verbosity": -1}).construct()
+    names = [e["name"] for e in global_flight.ring_events()
+             if e.get("ph") == "X"]
+    assert names.count("ingest.host_bin") == 1
+    assert "ingest.device_bin" not in names
+    (rec,) = [e for e in global_flight.ring_events()
+              if e["name"] == "ingest.host_bin"]
+    assert rec["args"]["rows"] == 5000 and rec["dur"] > 0
+
+
 def test_device_bin_record_carries_its_chunks_sums(monkeypatch):
     monkeypatch.setenv("LGBM_TPU_INGEST_KERNEL", "kernel")
     monkeypatch.setenv("LGBM_TPU_INGEST_CHUNK", "1500")
@@ -135,8 +150,8 @@ def test_round_program_carries_every_scope():
     gc, hc = b._macro_const_grads()
     text = build_chunk_program(b).lower(
         b.binned, b.train_score, cu, cr, np.int32(1), xs,
-        b._macro_ctx["label"], b._macro_ctx["weight"], gc, hc
-    ).compile().as_text()
+        b._macro_ctx["label"], b._macro_ctx["weight"], gc, hc,
+        b._macro_ctx["obj_tables"]).compile().as_text()
     op_names = "\n".join(line for line in text.splitlines()
                          if "op_name=" in line)
     for scope in SCOPES:

@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
 """Device time of a traced training window by the round program's named
-scopes (``lgbm.gradients`` ... ``lgbm.score_update``).
+scopes (``lgbm.gradients`` ... ``lgbm.score_update``; a dotted scope such
+as ``lgbm.rank.pairs`` is one name).
 
     python3 tools/scope_table.py <trace.xplane.pb> <hlo.txt> [<hlo.txt> ...]
 
@@ -28,7 +29,7 @@ from benchmark.lib import trace_reduce as tr  # noqa: E402
 
 INSTRUCTION = re.compile(
     r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*\bop_name="([^"]*)"')
-SCOPE = re.compile(r"lgbm\.[a-z_]+")
+SCOPE = re.compile(r"lgbm\.[a-z_]+(?:\.[a-z_]+)*")
 
 
 def scopes_by_instruction(texts):

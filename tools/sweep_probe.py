@@ -85,10 +85,10 @@ def run_probe(rows=200_000, features=28, max_bin=63, leaves=31,
         # donation: measure_program re-invokes with the same buffers
         fn_B = jax.jit(jax.vmap(
             make_chunk_fn(bs[0]),
-            in_axes=(None, 0, 0, 0, None, 0, None, None, None, None)))
+            in_axes=(None, 0, 0, 0, None, 0, None, None, None, None, None)))
         args = (bs[0].binned, score_B, cu_B, cr_B, np.int32(c), xs_B,
                 bs[0]._macro_ctx["label"], bs[0]._macro_ctx["weight"],
-                gc, hc)
+                gc, hc, bs[0]._macro_ctx["obj_tables"])
         m = measure_program(fn_B, args, reps=reps, device=device)
         sec = m["seconds_per_call"]
         out[f"B{B}"] = {
