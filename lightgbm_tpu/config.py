@@ -326,8 +326,10 @@ class Config:
                                         # unsharded case, with the staged
                                         # family as the fallback arm
     tpu_compact_hist: bool = True       # bucketed leaf-row compaction
-    tpu_round_width: int = 128          # rounds grower: max splits batched
-                                        # per round (see grower_rounds.py)
+    tpu_round_width: int = 128          # rounds grower: cap on the splits
+                                        # batched per round; the width under
+                                        # it is the grower's own choice
+                                        # (grower_rounds.next_offer)
     tpu_tree_growth: str = "auto"       # auto|serial|rounds|fast: one split
                                         # per while_loop step vs batched
                                         # frontier rounds (structurally

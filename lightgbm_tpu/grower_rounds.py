@@ -44,6 +44,22 @@ cap ``KCAP = tpu_round_width``.  The loop's fourth counter, ``slots``,
 sums the widths run.  The staged family keeps ``build_histogram*`` for
 the root and runs every segment pass at the cap.
 
+The offer.  How wide the pass has to be is set by what the round offers,
+and a round that offers the whole frontier commits a fraction of it (the
+prefix ends at the first child that outranks a candidate: 3 of ~57 on
+quantized lambdarank gains, ~11 of ~50 on binary log-loss at 25M rows).
+So ``k = min(#positive-gain leaves, leaf budget, KCAP, offer)``, where
+``offer`` rides the loop's carry: one of the pass's widths, the narrowest
+at a tree's first round, afterwards the narrowest that holds what each
+of the two rounds before COMMITTED with a quarter to spare, hence a rung
+up at least when a whole offer committed (``next_offer``).  It is a
+function of the reduced histograms' results alone, so every shard of a
+mesh computes the same value with no collective.  A smaller offer only ends a round's
+prefix earlier; what it left out is offered again with the same cached
+gains and commits in the same order, so the tree does not depend on it.
+The fifth counter, ``clipped``, counts the rounds that the offer (not a
+child) ended: the passes it may have cost.
+
 Support matrix: EFB bundles, bagging/GOSS weights, per-tree and per-node
 column sampling, extra_trees, monotone constraints, max_depth, and
 data-parallel row sharding (``axis_name`` -> histogram/scalar psums).
@@ -83,6 +99,29 @@ def _pad_scatter(arr: jax.Array, idx: jax.Array, val: jax.Array,
     return ext.at[safe].set(val.astype(arr.dtype))[:M]
 
 
+# The offer's rule.  A round may offer no more candidates than ``offer``,
+# one of the widths its histogram pass can run at (ops/fused.slot_widths);
+# the next round's offer is the narrowest width that holds what the last
+# TWO rounds committed, each, with a quarter to spare.  A pass costs its
+# width whatever it holds and a clipped round costs a whole pass more, so
+# where commits hover at a width's edge the wider one is kept; and two
+# rounds, because late in a tree a round that commits one split (a child
+# outranked everything) is as a rule followed by one that commits dozens.
+# Read on one v5e from the per-round (k, m) of both benchmark
+# configurations: root PERF.md section 5.
+OFFER_SPARE_NUM, OFFER_SPARE_DEN = 3, 4
+
+
+def next_offer(rungs: jax.Array, m, m_before) -> jax.Array:
+    """The offer that follows rounds which committed ``m_before`` and then
+    ``m`` splits: the narrowest of ``rungs`` ([R] i32, ascending, the last
+    the round cap) that holds the larger with a quarter to spare.  A round
+    whose whole offer committed (the offer, not a child, ended its prefix)
+    therefore goes up a rung at least: no rung holds itself with room."""
+    need = OFFER_SPARE_DEN * jnp.maximum(m, m_before)
+    return rungs[jnp.sum(need > OFFER_SPARE_NUM * rungs[:-1])]
+
+
 def grow_tree_rounds(binned_t, *args, **kwargs):
     """Grow one tree, batched-frontier (full signature:
     ``_grow_tree_rounds_traced``).  Span-wrapped like ``grow_tree``:
@@ -115,11 +154,12 @@ def _grow_tree_rounds_traced(
     with_stats: bool = False,
 ):
     """Grow one tree; returns (TreeArrays, leaf_id [n] i32), and with
-    ``with_stats`` a third [4] i32: the loop's trips, the candidates it
+    ``with_stats`` a third [5] i32: the loop's trips, the candidates it
     offered (a round builds that many smaller-child histograms), the
-    splits it committed, and the slot widths its histogram passes ran at,
+    splits it committed, the slot widths its histogram passes ran at,
     summed (the fused arm's root pass included; a staged pass runs at the
-    round cap)."""
+    round cap), and the trips the offer clipped (it bound ``k`` and all
+    ``k`` committed: the trips it may have cost a pass)."""
     meta = meta.resolved()
     G, n = binned_t.shape
     L = cfg.num_leaves
@@ -237,6 +277,13 @@ def _grow_tree_rounds_traced(
     # check still guards interleaving); it bounds the changed-slot search
     # width and the segment-histogram slot axis.
     KCAP = min(Lm1, max(1, cfg.round_width))
+    # under the cap a round offers no more than the carry's ``offer``: one
+    # of the pass's widths, following what the last rounds committed
+    # (next_offer), so the pass is as wide as the commits need and not as
+    # wide as the frontier.  A smaller offer only ends the prefix earlier;
+    # what is left is offered again with the same cached gains.
+    from .ops.fused import slot_widths
+    rungs = jnp.asarray(slot_widths(KCAP), jnp.int32)
 
     mc_j = jnp.asarray(monotone_constraints) if use_mc else None
     if use_rng and rng_key is None:
@@ -379,6 +426,9 @@ def _grow_tree_rounds_traced(
         offered: jax.Array      # sum of k: candidates built
         applied: jax.Array      # sum of m: splits committed
         slots: jax.Array        # sum of the slot widths the passes ran at
+        offer: jax.Array        # most candidates the next round may offer
+        last_m: jax.Array       # splits the last round committed
+        clipped: jax.Array      # trips the offer bound and wholly committed
 
     iota_L = jnp.arange(L, dtype=jnp.int32)
 
@@ -478,7 +528,8 @@ def _grow_tree_rounds_traced(
         return Carry(tree, c.best, hist, leaf_sg, leaf_sh, leaf_cnt,
                      leaf_parent_side, new_leaf_id, c.split_idx + k,
                      leaf_min, leaf_max, c.rounds, c.offered,
-                     c.applied + k, c.slots)
+                     c.applied + k, c.slots, c.offer, c.last_m,
+                     c.clipped)
 
     def child_bounds(c: Carry):
         """Per-leaf monotone bounds the two children of each leaf's cached
@@ -514,7 +565,8 @@ def _grow_tree_rounds_traced(
             pos = gains > 0.0
             npos = jnp.sum(pos.astype(jnp.int32))
             budget = (L - c.tree.num_leaves).astype(jnp.int32)
-            k = jnp.minimum(jnp.minimum(npos, budget), KCAP)
+            room = jnp.minimum(jnp.minimum(npos, budget), KCAP)
+            k = jnp.minimum(room, c.offer)
             # total order (gain desc, leaf asc) = successive best-first ArgMax
             # picks (reference: SerialTreeLearner::Train loop :175-193)
             order = jnp.argsort(-gains, stable=True)
@@ -762,12 +814,15 @@ def _grow_tree_rounds_traced(
             return cm._replace(
                 best=cache_scatter(c.best, idc, res, valid_m),
                 rounds=c.rounds + 1, offered=c.offered + k,
-                slots=c.slots + width)
+                slots=c.slots + width,
+                offer=next_offer(rungs, m, c.last_m), last_m=m,
+                clipped=c.clipped + ((c.offer < room) & (m == k)))
 
     zero = jnp.array(0, jnp.int32)
     init = Carry(tree, best, hist_cache, leaf_sg, leaf_sh, leaf_cnt,
                  leaf_parent_side, leaf_id, zero, leaf_min, leaf_max,
-                 zero, zero, zero, root_width if use_fused else zero)
+                 zero, zero, zero, root_width if use_fused else zero,
+                 rungs[0], zero, zero)
     out = lax.while_loop(cond, body, init)
 
     # finalize leaf values (reference: CalculateSplittedLeafOutput; clamped
@@ -798,5 +853,5 @@ def _grow_tree_rounds_traced(
         )
     if with_stats:
         return tree, out.leaf_id, jnp.stack(
-            [out.rounds, out.offered, out.applied, out.slots])
+            [out.rounds, out.offered, out.applied, out.slots, out.clipped])
     return tree, out.leaf_id

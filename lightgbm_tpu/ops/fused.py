@@ -60,6 +60,8 @@ ONE feature-blocked copy of the binned matrix built once a tree
 slot is every member row — at the narrowest width that holds its live
 candidates; the arena is zero-padded to the cap, so the collective and
 ``fused_sibling_scan`` keep one shape.
+How many candidates a round offers, hence the width, is the grower's
+(``grower_rounds.next_offer``: what its last rounds committed).
 
 Scope: numeric AND categorical features (per-category stats are the
 same segment reduction — the kernel accumulates every column and the
@@ -553,6 +555,13 @@ def fused_frontier_accumulate(
 NARROW_SLOT_WIDTHS = (16, 64)
 
 
+def slot_widths(kcap: int) -> tuple:
+    """The slot widths a rounds grower of round cap ``kcap`` has, narrowest
+    first: the rungs its passes run at and its offer moves on."""
+    kcap = int(kcap)
+    return tuple(w for w in NARROW_SLOT_WIDTHS if w < kcap) + (kcap,)
+
+
 def frontier_accumulator(
     binned_t: jax.Array,           # [F, n]
     vals_t: jax.Array,
@@ -580,7 +589,7 @@ def frontier_accumulator(
     quant = vals_t.dtype == jnp.int8
     F, n = binned_t.shape
     kcap = int(kcap)
-    widths = tuple(w for w in NARROW_SLOT_WIDTHS if w < kcap) + (kcap,)
+    widths = slot_widths(kcap)
     if feat_tile is None:
         fp = plan_fused(kcap, num_bins, quant, num_features=F)
         feat_tile = fp["feat_tile"] if fp else 1

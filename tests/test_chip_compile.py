@@ -315,3 +315,34 @@ def test_fused_traversal_is_out_of_the_election(one_chip, forest,
     _compile(lambda x: PK.leaves_fori(dev, x), X)
     with pytest.raises(AssertionError):
         _compile(lambda x: PK.fused_traverse(dev, x, 512, interpret=False), X)
+
+
+def test_rounds_grower_compiles_with_the_offer(one_chip, as_accelerator):
+    """One whole tree of the rounds grower, int8 gradients, as the chip
+    runs it: the root pass, then a loop whose round elects the width of
+    its accumulate pass from the ``k`` its offer allowed — one Mosaic
+    kernel a width and the root's, and the whole program compiles."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.dataset import FeatureMeta
+    from lightgbm_tpu.grower import GrowerConfig
+    from lightgbm_tpu.grower_rounds import grow_tree_rounds
+    from lightgbm_tpu.ops.fused import slot_widths
+    from lightgbm_tpu.ops.split import SplitHyperparams
+    nb, mt, db = _meta_vectors()
+    meta = FeatureMeta(num_bin=nb, missing_type=mt, default_bin=db,
+                       most_freq_bin=np.zeros((F,), np.int32),
+                       is_categorical=np.zeros((F,), bool), max_num_bin=B)
+    cfg = GrowerConfig(num_leaves=LEAVES, num_bins=B + 1, quant=True,
+                       quant_bins=4, hist_method="fused",
+                       hp=SplitHyperparams(min_data_in_leaf=20))
+    rows = _shape(one_chip, (ROWS_1M,), jnp.float32)
+    q = _shape(one_chip, (ROWS_1M,), jnp.int8)
+    scale = _shape(one_chip, (), jnp.float32)
+    c = _compile(
+        lambda b, g, h, m, gq, hq, gs, hs: grow_tree_rounds(
+            b, g, h, m, meta, cfg, quant_vals=(gq, hq, gs, hs),
+            with_stats=True),
+        _shape(one_chip, (F, ROWS_1M), jnp.uint8), rows, rows, rows,
+        q, q, scale, scale)
+    assert _kernels(c) == 1 + len(slot_widths(K))

@@ -275,7 +275,8 @@ def test_width_election_matches_the_fixed_width(family, monkeypatch):
     pass at the round cap: bit-identical trees for the integer family
     (associative sums), the same structure for f32 (the row tiles, hence
     the summation order, differ by width).  The stats say which widths
-    ran."""
+    ran: with the rungs there, the offer follows the commits
+    (``grower_rounds.next_offer``); with the cap alone it is the cap."""
     B, F = 32, 6
     binned, grad, hess, mask = _wide_problem(B=B, F=F)
     quant = family == "int8"
@@ -305,11 +306,20 @@ def test_width_election_matches_the_fixed_width(family, monkeypatch):
     np.testing.assert_allclose(np.asarray(t_el.leaf_value),
                                np.asarray(t_fx.leaf_value),
                                rtol=3e-5, atol=1e-7)
-    rounds, offered, applied, slots = (int(v) for v in st_el)
-    assert (rounds, offered, applied) == tuple(int(v) for v in st_fx[:3])
-    assert int(st_fx[3]) == 128 * (rounds + 1)      # the root, then rounds
-    # the root ran at 16; the rounds neither all under 128 nor all at it
-    assert 64 * rounds < slots - 16 < 128 * rounds
+    rounds, offered, applied, slots, clipped = (int(v) for v in st_el)
+    rounds_fx, offered_fx, applied_fx, slots_fx, clipped_fx = (
+        int(v) for v in st_fx)
+    assert applied == applied_fx == 254
+    assert slots_fx == 128 * (rounds_fx + 1)        # the root, then rounds
+    assert clipped_fx == 0                          # one rung: no offer binds
+    # with the rungs, a round offers what the last one committed, not the
+    # frontier: fewer candidates built, a clipped round a trip more at most
+    assert offered < offered_fx
+    assert rounds_fx <= rounds <= rounds_fx + clipped
+    # the root ran at 16, the rounds at their offers' rungs: these rows
+    # commit ~4 of the ~60 candidates a round at the cap builds, so the
+    # offer stays narrow and a round costs a quarter of the cap or less
+    assert 16 * rounds <= slots - 16 < 32 * rounds
     assert offered <= slots - 16
 
 

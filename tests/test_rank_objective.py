@@ -267,8 +267,9 @@ def test_round_width_changes_no_tree():
     """The rounds grower commits the strict best-first prefix of what a
     round offers, so ``tpu_round_width`` decides how many passes a tree
     takes and not which tree it is: quantized, renewed lambdarank models
-    at widths 4, 16 and 128 are the same trees (``istella-rank`` states 16,
-    PERF.md section 6)."""
+    at widths 4, 16 and 128 are the same trees.  The key is a cap (no
+    configuration of the benchmark states it): under it the grower's offer
+    follows its commits round by round, on the same ground."""
     import lightgbm_tpu as lgb
     rng = np.random.default_rng(8)
     sizes = rng.integers(20, 120, 60)

@@ -455,5 +455,158 @@ def test_rounds_width_election_equals_serial_quantized(sharded):
                                       np.asarray(getattr(t_r, name)),
                                       err_msg=name)
     np.testing.assert_array_equal(np.asarray(lid_s), np.asarray(lid_r))
-    rounds, offered, _, slots = (int(v) for v in stats)
-    assert offered <= slots - 16 < 128 * rounds
+    rounds, offered, _, slots, clipped = (int(v) for v in stats)
+    # the root at 16, then every round at the rung its offer named: with
+    # the offer following the commits, under 64 slots a round on average
+    assert offered <= slots - 16 < 64 * rounds
+    assert 0 <= clipped <= rounds
+
+
+def _quantized_255(binned, grad, B, F):
+    """(serial oracle's tree and rows, the fused rounds grower's arguments)
+    for one 255-leaf tree on 8-level int8 gradients."""
+    import jax
+
+    from lightgbm_tpu.ops import histogram as H
+    n = len(grad)
+    hess, mask = np.ones(n, np.float32), np.ones(n, np.float32)
+    meta = _meta(B, F)
+    qv = H.quantize_gradients(jnp.asarray(grad), jnp.asarray(hess),
+                              jnp.asarray(mask), 8, jax.random.PRNGKey(3))
+    cfg = GrowerConfig(num_leaves=255, num_bins=B, quant=True, quant_bins=8,
+                       hp=SplitHyperparams(min_data_in_leaf=2),
+                       hist_method="scatter")
+    args = (jnp.asarray(binned.T), jnp.asarray(grad), jnp.asarray(hess),
+            jnp.asarray(mask), meta)
+    return (grow_tree(*args, cfg, quant_vals=qv),
+            args + (cfg._replace(hist_method="fused"),), {"quant_vals": qv})
+
+
+@pytest.mark.parametrize("gains", ["noise", "levels"])
+def test_offer_equals_serial_quantized(gains):
+    """The offer on int8 gradients against the serial oracle, bit for bit.
+
+    ``noise``: gradients that are noise alone (the shape ``istella-rank``
+    has under 4-level gradients): every leaf's best gain sits on the noise
+    floor, a child outranks its parent's neighbours as often as not, and a
+    round commits a couple of the candidates it builds.  The offer stays at
+    the narrowest rung, so every pass is a narrow one, and no round is
+    clipped that the cap would not have ended as early.
+
+    ``levels``: gains that fall by level until the 8-level rounding drowns
+    them: the first rounds commit all they offer and the offer climbs, the
+    late ones commit a few and it comes down again, a clipped round on the
+    way."""
+    from lightgbm_tpu.ops import fused as FU
+    rng = np.random.RandomState(5)
+    n, B = 8192, 32
+    if gains == "noise":
+        F = 6
+        binned = rng.randint(0, B - 1, (n, F)).astype(np.uint8)
+        grad = rng.randn(n)
+    else:
+        F = 8
+        binned = rng.randint(0, 2, (n, F)).astype(np.uint8)
+        grad = -(binned * 0.5 ** np.arange(F)).sum(1)
+        grad = grad - grad.mean()
+    (t_s, lid_s), args, kw = _quantized_255(
+        binned, grad.astype(np.float32), B, F)
+    t_r, lid_r, stats = grow_tree_rounds(*args, with_stats=True, **kw)
+    assert int(t_r.num_leaves) == int(t_s.num_leaves) > 200
+    for name in t_s._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(t_s, name)),
+                                      np.asarray(getattr(t_r, name)),
+                                      err_msg=name)
+    np.testing.assert_array_equal(np.asarray(lid_s), np.asarray(lid_r))
+    rounds, offered, applied, slots, clipped = (int(v) for v in stats)
+    assert applied == int(t_r.num_leaves) - 1
+    narrow, cap = FU.NARROW_SLOT_WIDTHS[0], 128
+    wider = slots - narrow * (rounds + 1)       # what passes over 16 added
+    if gains == "noise":
+        assert rounds > 60                      # ~2 commits a round
+        assert offered <= narrow * rounds
+        # well under the cap's cost: nearly every pass at the narrowest
+        assert wider < narrow * rounds / 4
+        assert 0 <= clipped <= rounds // 10
+    else:
+        assert wider > 0 and 1 <= clipped <= rounds // 4
+    assert slots - narrow < cap * rounds / 4
+
+
+def test_offer_climbs_when_the_rounds_fill_the_rung(monkeypatch):
+    """Gains that fall by level (each feature worth half the one before):
+    every candidate a round offers commits, the frontier doubles, and the
+    offer has to climb with it: 16 -> 64 when 16 of 16 commit, 64 -> the cap
+    when 64 of 64 do.  No round is clipped and the loop takes the trips it
+    takes with every pass at the cap: 1, 2, 4, ..., 64, 127 splits."""
+    from lightgbm_tpu.ops import fused as FU
+    rng = np.random.RandomState(5)
+    n, F, B = 8192, 8, 32
+    bits = rng.randint(0, 2, (n, F)).astype(np.uint8)
+    grad = -(bits * 0.5 ** np.arange(F)).sum(1)
+    grad = (grad - grad.mean()).astype(np.float32)
+    cfg = GrowerConfig(num_leaves=255, num_bins=B, hist_method="fused",
+                       hp=SplitHyperparams(min_data_in_leaf=2))
+    args = (jnp.asarray(bits.T), jnp.asarray(grad),
+            jnp.ones(n, jnp.float32), jnp.ones(n, jnp.float32), _meta(B, F),
+            cfg)
+    assert FU.NARROW_SLOT_WIDTHS == (16, 64)
+    t_of, _, st_of = grow_tree_rounds(*args, with_stats=True)
+    monkeypatch.setattr(FU, "NARROW_SLOT_WIDTHS", ())      # every pass: cap
+    t_fx, _, st_fx = grow_tree_rounds(*args, with_stats=True)
+    # the same 255 leaves (a level's leaves gain within 1e-4 of each other,
+    # and the f32 arena's summation order differs by width: their numbers
+    # may differ, the rows they hold may not)
+    assert int(t_of.num_leaves) == int(t_fx.num_leaves) == 255
+    np.testing.assert_array_equal(np.sort(np.asarray(t_of.leaf_count)),
+                                  np.sort(np.asarray(t_fx.leaf_count)))
+    rounds, offered, applied, slots, clipped = (int(v) for v in st_of)
+    assert (rounds, offered, applied) == tuple(int(v) for v in st_fx[:3])
+    assert (rounds, offered, applied, clipped) == (8, 254, 254, 0)
+    # root, 1, 2, 4, 8, 16 at 16 slots; 32 and 64 at 64; 127 at the cap
+    assert slots == 6 * 16 + 2 * 64 + 128
+    assert int(st_fx[3]) == 128 * (rounds + 1) and int(st_fx[4]) == 0
+
+
+RUNGS = (16, 64, 128)
+
+
+@pytest.mark.parametrize("before,m,nxt", [
+    (0, 1, 16),         # the tree's first rounds: the frontier binds
+    (2, 3, 16),         # noisy gains: a few of 16 commit
+    (3, 12, 16),        # the narrow rung holds it with a quarter to spare
+    (3, 13, 64),        # at the rung's edge: the wider one
+    (5, 16, 64),        # the whole offer committed: up a rung
+    (16, 5, 64),        # one quiet round does not bring it down
+    (1, 29, 64),        # late in a tree: 1, 29, 1, 43 ...
+    (29, 1, 64),
+    (5, 1, 16),         # two quiet rounds do
+    (40, 48, 64),
+    (40, 49, 128),
+    (30, 64, 128),      # 64 of 64: up again
+    (128, 30, 128),
+    (30, 5, 64),        # and down a rung at a time as the memory drains
+    (127, 127, 128),    # nothing over the cap
+])
+def test_next_offer(before, m, nxt):
+    from lightgbm_tpu.grower_rounds import next_offer
+    rungs = jnp.asarray(RUNGS, jnp.int32)
+    assert int(next_offer(rungs, jnp.int32(m), jnp.int32(before))) == nxt
+    # the rule is symmetric in the two rounds
+    assert int(next_offer(rungs, jnp.int32(before), jnp.int32(m))) == nxt
+
+
+@pytest.mark.parametrize("kcap", [1, 12, 16, 17, 20, 62, 64, 65, 128, 254])
+def test_a_wholly_committed_offer_goes_up(kcap):
+    """At every round cap: a round that committed all of an offer under
+    the cap is followed by a wider one, and the cap by the cap."""
+    from lightgbm_tpu.grower_rounds import next_offer
+    from lightgbm_tpu.ops.fused import slot_widths
+    widths = slot_widths(kcap)
+    assert widths[-1] == kcap and list(widths) == sorted(set(widths))
+    rungs = jnp.asarray(widths, jnp.int32)
+    for lower, upper in zip(widths, widths[1:]):
+        assert int(next_offer(rungs, jnp.int32(lower), jnp.int32(0))) \
+            >= upper
+    assert int(next_offer(rungs, jnp.int32(kcap), jnp.int32(kcap))) == kcap
+    assert int(next_offer(rungs, jnp.int32(0), jnp.int32(0))) == widths[0]

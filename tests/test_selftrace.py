@@ -216,12 +216,14 @@ def test_serial_grower_counts_one_split_a_trip():
     splits = bst.boosting.models[0].num_leaves - 1
     assert (t["rounds"], t["offered"], t["applied"]) == (splits,) * 3
     assert t["slots"] == splits          # a pass one slot wide a split
+    assert t["clipped"] == 0             # and no offer to clip
 
 
 @pytest.mark.parametrize("method", ["scatter", "fused"])
 def test_grower_tree_carries_slots(method):
-    """The fourth counter of the grower's carry lands on the
-    ``grower.tree`` record: the slot widths the histogram passes ran at.
+    """The fourth and fifth counters of the grower's carry land on the
+    ``grower.tree`` record: the slot widths the histogram passes ran at,
+    and the trips the offer clipped.
     The fused arm's passes (root included) run at the narrowest compiled
     width that holds the round's candidates; a staged pass at the cap."""
     num_leaves = 63
@@ -239,11 +241,19 @@ def test_grower_tree_carries_slots(method):
     cap = num_leaves - 1
     for t in trees:
         assert t["offered"] <= t["slots"]
+        # the fifth counter: trips in which the offer bound and all of it
+        # committed.  The offer moves on the same rungs in both families
+        assert 0 <= t["clipped"] <= t["rounds"]
         if method == "fused":
-            # root at 16, then rounds at 16 or the cap (62 < 64)
+            # root at 16, then each round at the rung its offer named:
+            # 16 or the cap (62 < 64)
             assert 16 * (t["rounds"] + 1) <= t["slots"] \
                 < 16 + cap * t["rounds"]
             assert (t["slots"] - 16 * (t["rounds"] + 1)) % (cap - 16) == 0
+            # an offer of 16 holds at most 16: the rounds at the cap are
+            # those the slots count, and only they may offer more
+            wide = (t["slots"] - 16 * (t["rounds"] + 1)) // (cap - 16)
+            assert t["offered"] <= 16 * (t["rounds"] - wide) + cap * wide
         else:
             assert t["slots"] == cap * t["rounds"]
 
@@ -274,9 +284,10 @@ def test_counters_leave_the_tree_as_it_was():
                     jax.tree_util.tree_leaves(t1)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_array_equal(np.asarray(lid0), np.asarray(lid1))
-    rounds, offered, applied, slots = (int(v) for v in stats)
+    rounds, offered, applied, slots, clipped = (int(v) for v in stats)
     assert applied == int(t1.num_leaves) - 1
     assert 1 <= rounds <= applied <= offered
+    assert 0 <= clipped <= rounds
     # the staged family builds every pass at the round cap (30 of 31
     # leaves); its root is no segment pass
     assert slots == 30 * rounds

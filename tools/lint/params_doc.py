@@ -22,6 +22,14 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 OUT = os.path.join(REPO, "docs", "Parameters.rst")
 
 
+# one sentence under a field's line, where the type and default alone
+# would mislead
+NOTES = {
+    "tpu_round_width": "a cap: the rounds grower chooses each round's "
+                       "width under it, from what its last rounds committed",
+}
+
+
 def _config(root: str = REPO):
     if root not in sys.path:
         sys.path.insert(0, root)
@@ -82,6 +90,8 @@ def generate(root: str = REPO) -> str:
         if al:
             w(f", aliases: {', '.join('``%s``' % a for a in sorted(al))}")
         w("\n")
+        if f.name in NOTES:
+            w(f"\n  {NOTES[f.name]}\n\n")
     return buf.getvalue()
 
 
