@@ -229,8 +229,7 @@ class GBDT:
         # last round's per-class (g_scale, h_scale) quantization factors —
         # device [K, 2] (zeros when quantized training is off); carried
         # alongside the score state, through macro chunk outputs and
-        # checkpoint capture/restore (telemetry + the hist_probe payload
-        # accounting read it)
+        # checkpoint capture/restore (telemetry reads it)
         self._quant_scales = None
 
         # device-resident history of this run's stacked TreeArrays, so DART
@@ -859,8 +858,7 @@ class GBDT:
         self.grower_cfg, self.hist_plan = apply_plan(
             self.grower_cfg, shard_rows, shard_feats, fused_ok=want_fused)
         # unified-registry training gauges (the planner.plan trace event
-        # itself is emitted inside apply_plan; the bench logs the measured
-        # peak next to it — docs/OBSERVABILITY.md predicted-vs-measured)
+        # itself is emitted inside apply_plan)
         _obs_registry.gauge("train_hist_method").set(
             self.hist_plan.variant)   # resolved variant, never "auto"
         _obs_registry.gauge("train_tile_rows").set(self.hist_plan.tile_rows)
@@ -868,9 +866,8 @@ class GBDT:
             int(self.hist_plan.predicted_peak_bytes))
         _obs_registry.gauge("train_hbm_budget_bytes").set(
             int(self.hist_plan.budget_bytes))
-        # shape-bucket ladder + autotune provenance: which rung the row
-        # axis landed on and whether the variant came from measurements
-        # (bench_diff gates election quality on these)
+        # shape-bucket ladder + election provenance: which rung the row
+        # axis landed on and what elected the variant
         _obs_registry.gauge("train_rows_bucketed").set(int(self._n_pad))
         _obs_registry.gauge("train_shape_buckets").set(
             int(getattr(self, "_shape_buckets", False)))

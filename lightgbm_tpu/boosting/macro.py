@@ -296,8 +296,8 @@ def run_chunk(b, c: int, lrs: Optional[Sequence[float]] = None) -> bool:
         with _span("jit.build", ring=True, what="chunk_program"):
             b._macro_chunk_jit = build_chunk_program(b)
     cu, cr = b._cegb_state
-    # chunk-size telemetry on the unified registry (obs_dump / bench
-    # journal it instead of scraping logs)
+    # chunk-size telemetry on the unified registry (obs_dump snapshots
+    # it instead of scraping logs)
     _obs_registry.counter("train_chunk_dispatches").inc()
     _obs_registry.histogram(
         "train_chunk_size",

@@ -107,7 +107,7 @@ def forest_precision_bytes(forest, precision: str) -> dict:
     """Rough host-side accounting of what the grid move saves on device:
     {threshold_bytes, leaf_bytes} at the given precision vs f32 — the
     planner's ``predict_forest_bytes`` is the authoritative (padded)
-    model; this is the human-readable smoke/bench twin."""
+    model; this is the human-readable smoke twin."""
     T, I = forest.threshold.shape
     L = forest.leaf_value.shape[1]
     thr_item = {"f32": 4, "bf16": 2, "int8": 1}[precision]

@@ -99,7 +99,7 @@ class TopologyPlan(NamedTuple):
     feasible: bool
 
     def summary(self) -> dict:
-        """JSON-friendly form for bench journals / flight fingerprints."""
+        """JSON-friendly form for flight fingerprints."""
         return {
             "devices": [
                 {"device": d.device_id, "slice": d.slice_id,

@@ -1,7 +1,7 @@
 """train_many: B boosters, one device dispatch — the model-axis driver.
 
 A single booster's macro-chunk program leaves most of the chip idle at
-small-data shapes (the bench's MFU column): one tree's histogram passes
+small-data shapes: one tree's histogram passes
 cannot fill the MXU.  CV folds, hyperparameter sweeps and per-segment
 model families are embarrassingly parallel ACROSS MODELS, so this driver
 trains them along a vmapped lane axis of ONE program over one (shared

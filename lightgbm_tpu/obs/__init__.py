@@ -1,18 +1,13 @@
-"""Unified observability plane: seams on the profiler's clock, compiler
-cost probes, and the single process metrics registry
-(docs/OBSERVABILITY.md).
+"""Unified observability plane: seams on the profiler's clock and the
+single process metrics registry (docs/OBSERVABILITY.md).
 
-Three pillars, shared by training, serving, resilience and the bench:
+Two pillars, shared by training, serving and resilience:
 
 - ``obs.trace`` — ``span(name, ...)``, the one seam API: a
   ``jax.profiler.TraceAnnotation`` on the profiler's clock always, a
   flight-ring record for the coarse seams, a ``global_timer`` section on
   request, and a Chrome trace-event / Perfetto JSON recorder behind
   ``LIGHTGBM_TPU_TRACE``;
-- ``obs.devprof`` — per-program FLOP/byte counts from
-  ``Compiled.cost_analysis()`` (the compiler's estimate) over a
-  host-blocking clock, for the probe tools; not a measurement of the
-  training path (the root ``PERF.md`` has those, from device traces);
 - ``obs.metrics`` — the ``MetricsRegistry`` promoted from serving as the
   process-wide instrument registry (``global_registry``), with JSON
   snapshots and Prometheus text exposition.
@@ -31,8 +26,7 @@ The ACTIVE layer on top (docs/OBSERVABILITY.md):
 - ``obs.http`` — opt-in stdlib HTTP exposition of the process registry.
 
 ``metrics``/``flight``/``watchdog``/``http`` are stdlib-only; ``trace``
-is stdlib-only at import and looks ``jax.profiler`` up at the first span;
-``devprof`` imports jax lazily.
+is stdlib-only at import and looks ``jax.profiler`` up at the first span.
 """
 
 from .metrics import (LATENCY_BUCKETS_MS, RATIO_BUCKETS, Counter, Gauge,

@@ -44,7 +44,7 @@ METRIC_LAYOUT = (
     "trees_per_sec",         # live training rate
     "ici_payload_bytes",     # per-sync ICI tier bytes (planner model)
     "dcn_payload_bytes",     # per-sync DCN tier bytes (planner model)
-    "mfu",                   # measured MFU (devprof), 0 when unmeasured
+    "mfu",                   # measured MFU, 0 when unmeasured
     "host_rss_peak_bytes",   # streaming host watermark
     "compile_cache_warm",    # 0/1
     "slo_breach_total",      # watchdog breaches seen by this rank

@@ -2,14 +2,13 @@
 signals into RANKED verdicts with the evidence behind each.
 
 ROADMAP item 3 says "stop trusting analytic models alone"; PR 6-10 left
-the raw material everywhere — measured MFU/HBM-BW tables
-(obs/devprof.py), per-tier payload accounting (``ops/planner.py``
-``plan_collectives``), compile-cache warmth, streaming
-``overlap_efficiency`` (tools/stream_probe.py), straggler skew
+the raw material everywhere — measured MFU tables, per-tier payload
+accounting (``ops/planner.py`` ``plan_collectives``), compile-cache
+warmth, streaming ``overlap_efficiency``, straggler skew
 (obs/aggregate.py).  This module is the judgment layer: one pure
 function from a flat signal dict to an ordered list of verdicts, so the
-same rules serve the ``obs_doctor`` CLI, the journaled bench stage, and
-the tests that inject each bottleneck.
+same rules serve the ``obs_doctor`` CLI and the tests that inject each
+bottleneck.
 
 Verdict catalogue (docs/OBSERVABILITY.md):
 
@@ -35,7 +34,7 @@ Verdict catalogue (docs/OBSERVABILITY.md):
 Each verdict carries ``score`` in [0, 1] (comparable across verdicts:
 the ranking IS the diagnosis), a one-line human summary, and the raw
 numbers as ``evidence``.  ``collect_signals`` assembles the dict from
-the live registry and/or a bench journal; pure stdlib.
+the live registry and/or a journal of banked stages; pure stdlib.
 """
 
 from __future__ import annotations
@@ -76,7 +75,7 @@ def _num(v, default=0.0):
 
 def collect_signals(registry=None, stages: Optional[dict] = None) -> dict:
     """Assemble the diagnoser's flat signal dict from the live process
-    registry and/or a bench journal's banked stages (either may be
+    registry and/or a journal's banked stages (either may be
     None/empty; absent signals simply don't fire their rules)."""
     sig: dict = {}
     if registry is None:
@@ -120,7 +119,7 @@ def collect_signals(registry=None, stages: Optional[dict] = None) -> dict:
             sig["ledger_lease_table"] = lg.table()
     except Exception:  # noqa: BLE001 — forensics only
         pass
-    # bench journal stages refine / supply the workload-scale numbers
+    # journal stages refine / supply the workload-scale numbers
     stages = stages or {}
     full = None
     for key, st in stages.items():
@@ -169,15 +168,6 @@ def collect_signals(registry=None, stages: Optional[dict] = None) -> dict:
     except Exception:  # noqa: BLE001
         sig.setdefault("ici_gbps", 100.0)
         sig.setdefault("dcn_gbps", 6.25)
-    # the autotuner's most recent election: the kernel-underutilized
-    # verdict names the measured-best variant as its concrete cure
-    try:
-        from ..ops.planner import autotune_last
-        al = autotune_last()
-        if al:
-            sig["autotune_last"] = al
-    except Exception:  # noqa: BLE001
-        pass
     # the ingest election's last outcome (ops/ingest.py): the input-bound
     # verdict names whether binning ran on the kernel or fell back + why
     try:
@@ -335,29 +325,14 @@ def diagnose(signals: dict) -> List[Verdict]:
     if mfu is not None and _num(mfu) < MFU_HEALTHY_FLOOR and not out:
         mfu = _num(mfu)
         ev = {"mfu_measured_best": mfu, "floor": MFU_HEALTHY_FLOOR}
-        cure = ("batch boosters over a model axis or widen the fused "
-                "frontier")
-        al = s.get("autotune_last")
-        if isinstance(al, dict) and al.get("measured_variant"):
-            # the autotuner already knows the concrete cure: the variant
-            # its stopwatch ranked fastest for this shape-bucket
-            ev["measured_best_variant"] = al["measured_variant"]
-            ev["elected_variant"] = al.get("elected_variant")
-            ev["autotune_key"] = al.get("key")
-            if al["measured_variant"] != al.get("elected_variant"):
-                cure = (f"run the measured-best kernel variant "
-                        f"{al['measured_variant']!r} (autotuner store, "
-                        f"bucket {al.get('key')}) — the election "
-                        f"declined it, so fix the context that blocked "
-                        "it (VMEM budget / hist_method force / "
-                        "LGBM_TPU_FUSED)")
         out.append(Verdict(
             "kernel-underutilized",
             min(0.3 + (MFU_HEALTHY_FLOOR - mfu) / MFU_HEALTHY_FLOOR * 0.4,
                 0.7),
             f"best measured kernel MFU {mfu:.5f} (< {MFU_HEALTHY_FLOOR})"
             " with no specific bottleneck: per-level work is too small "
-            f"for the MXU — {cure}",
+            "for the MXU — batch boosters over a model axis or widen "
+            "the fused frontier",
             ev))
 
     if not out:
@@ -370,7 +345,7 @@ def diagnose(signals: dict) -> List[Verdict]:
 
 def diagnosis_summary(verdicts: List[Verdict],
                       signals: Optional[dict] = None) -> dict:
-    """JSON-ready report (the bench stage / CLI last-line shape)."""
+    """JSON-ready report (the CLI last-line shape)."""
     out = {
         "top_verdict": verdicts[0].name if verdicts else "healthy",
         "verdicts": [v.to_dict() for v in verdicts],
@@ -382,7 +357,7 @@ def diagnosis_summary(verdicts: List[Verdict],
 
 
 def run_doctor(registry=None, stages: Optional[dict] = None) -> dict:
-    """collect -> diagnose -> summarize in one call (bench stage +
-    tools/obs_doctor.py entry point)."""
+    """collect -> diagnose -> summarize in one call
+    (tools/obs_doctor.py entry point)."""
     signals = collect_signals(registry=registry, stages=stages)
     return diagnosis_summary(diagnose(signals), signals)

@@ -1,7 +1,7 @@
 """The single process metrics registry: counters, gauges, histograms.
 
 Promoted from ``serving/metrics.py`` (which now re-exports from here) so
-training, serving, resilience and the bench all report through ONE
+training, serving and resilience all report through ONE
 instrument model:
 
 - serving keeps per-``Server`` registries (tests assert per-server
@@ -279,7 +279,7 @@ class MetricsRegistry:
     def dump_json(self, path: Optional[str] = None, indent: int = 1) -> str:
         s = json.dumps(self.to_dict(), indent=indent, sort_keys=True)
         if path is not None:
-            # bench stages and operators read these snapshots back; the
+            # tools and operators read these snapshots back; the
             # atomic seam means a scrape never sees a half-written one
             from ..utils.file_io import write_atomic
             write_atomic(path, s)

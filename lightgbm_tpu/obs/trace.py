@@ -178,7 +178,7 @@ class Tracer:
     def to_chrome_trace(self, events: Optional[List[dict]] = None) -> dict:
         """Loadable-by-chrome://tracing dict: timestamp-sorted events plus
         a process-name metadata record.  ``events`` restricts the export
-        to a subset (e.g. one bench stage's slice of a shared tracer)."""
+        to a subset (e.g. one phase's slice of a shared tracer)."""
         evs = sorted(self.events() if events is None else events,
                      key=lambda e: e.get("ts", 0.0))
         meta = [{"name": "process_name", "ph": "M", "pid": self._pid,
@@ -326,7 +326,7 @@ def trace_path() -> Optional[str]:
 def span_coverage(events: List[dict], root_name: str) -> Optional[float]:
     """Fraction of the longest ``root_name`` span's wall-clock covered by
     the union of every other span overlapping it — the "does the span
-    tree account for the stage?" number the bench reports."""
+    tree account for the stage?" number tools/obs_dump.py reports."""
     roots = [e for e in events
              if e.get("name") == root_name and e.get("ph") == "X"]
     if not roots:

@@ -1,6 +1,6 @@
 """Fused Pallas histogram→split megakernel: one HBM pass per level.
 
-Why this module exists (ROADMAP item 2, bench telemetry): the staged
+Why this module exists (ROADMAP item 2): the staged
 pipeline runs histogram build and split-gain scan as SEPARATE device
 programs with the materialized ``[L, ch, F, B]`` histogram round-tripping
 through HBM between them — ``mfu_histogram_lower_bound`` pinned at
@@ -146,9 +146,8 @@ def hist_scan_traffic_bytes(num_candidates: int, num_features: int,
     Staged, per round of K candidates: the split scan re-reads both
     children's histograms (2K·ch·F·B cells) and the sibling histograms
     are written+read through the cache (K·ch·F·B each way).  Fused scans
-    in VMEM and derives siblings in-kernel, so exactly this term drops;
-    ``tools/hist_probe.py --fused`` journals it next to the measured
-    ``bytes_accessed`` delta.  The SHARDED seam keeps the same drop: the
+    in VMEM and derives siblings in-kernel, so exactly this term drops.
+    The SHARDED seam keeps the same drop: the
     psum moves only the ``[K, ch, F, B]`` smaller-child arena the staged
     sharded arm already moves, while the scan re-read + sibling
     write/read still never touch HBM."""

@@ -1257,8 +1257,7 @@ def psum_quant_hist(hist: jax.Array, axis_name,
     fast tier first (parallel/collectives.py); the narrowing bound covers
     every partial sum, so each stage rides the same narrowed payload.
     The ICI payload is 2 channels x {2,4} bytes vs the f32 path's 3 x 4
-    (``hist_payload_bytes`` is the accounting twin used by
-    tools/hist_probe.py and the bench stage)."""
+    (``hist_payload_bytes`` is the accounting twin)."""
     if axis_name is None:
         return hist
     from ..parallel.collectives import psum_int_tiered
@@ -1274,9 +1273,9 @@ def hist_payload_bytes(num_features: int, num_bins: int,
 
     ``quant_bins=None`` = the f32 pipeline (3 channels x f32); otherwise
     the integer pipeline (2 channels, int16 when the static bound
-    narrows, else int32).  Pure accounting — shared by the growers'
-    documentation, tools/hist_probe.py and tests so the claimed payload
-    can never drift from the psum'd dtypes."""
+    narrows, else int32).  Pure accounting — shared by the planner,
+    the telemetry gauges and tests so the claimed payload can never
+    drift from the psum'd dtypes."""
     if quant_bins is None:
         return 3 * num_features * num_bins * 4
     item = 2 if quant_psum_narrow(rows_global, quant_bins) else 4

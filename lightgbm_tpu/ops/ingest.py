@@ -11,7 +11,7 @@ EFB group fold packs the per-feature bins straight into the [tile, G]
 output block — raw floats cross HBM once and the binned matrix comes
 back, nothing in between.
 
-Bit-parity contract (tests/test_ingest.py, tools/ingest_probe.py): the
+Bit-parity contract (tests/test_ingest.py): the
 device matrix is BYTE-identical to the host ``BinMapper.value_to_bin``
 + ``Dataset._bin_block`` path.  Three constructions make that exact
 rather than approximate:
@@ -302,7 +302,7 @@ def parity_probe(binner: DeviceBinner, ds, raw_head: np.ndarray) -> bool:
 
 
 # last construct's election + outcome, for obs/diagnose.py's
-# input-bound verdict (mirrors the planner's _AUTOTUNE_LAST story)
+# input-bound verdict
 _INGEST_LAST: dict = {}
 _INGEST_LAST_LOCK = threading.Lock()
 

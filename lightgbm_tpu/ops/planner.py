@@ -1,6 +1,6 @@
 """HBM budget planner: pick histogram execution parameters at trace time.
 
-The r5 bench died in compile with an HBM OOM — a lane-padded
+The r5 run died in compile with an HBM OOM — a lane-padded
 ``f32[308000000, 3]`` whole-dataset record arena (157.7 GB requested vs
 17.2 GB HBM) — because every kernel materialized O(n*F) intermediates
 and nothing MODELED whether they fit.  This module is the model: it
@@ -23,8 +23,7 @@ The same plan governs serial and sharded training: the GBDT layer plans
 with PER-SHARD rows and threads the result through ``GrowerConfig``
 (tile_rows / hist_pack), so the serial grower, the batched-frontier
 grower, the fused macro-chunk program and the data-/voting-parallel
-learners all execute under one verdict.  bench.py gates its >=10M-row
-stage on ``feasible`` and journals the chosen tile instead of crashing.
+learners all execute under one verdict.
 
 Env overrides:
 - ``LGBM_TPU_TILE_ROWS``: force a tile size (``0``/``off`` forces
@@ -41,7 +40,6 @@ an operator-tuned chunk count.
 
 from __future__ import annotations
 
-import json
 import os
 import threading
 from contextlib import contextmanager
@@ -116,12 +114,10 @@ class HistPlan(NamedTuple):
     fused_block_rows: int = 0   # rows per double-buffered tile DMA
     fused_vmem_bytes: int = 0   # predicted VMEM arena bytes at that shape
     vmem_limit_bytes: int = 0   # VMEM limit the fused election ran against
-    elected_by: str = "analytic"   # "analytic" | "measured" (autotuner)
-    measured_variant: str = ""  # store's best for this bucket ("" = cold)
-    autotune_key: str = ""      # shape-bucket key the election ran under
+    elected_by: str = "analytic"
 
     def summary(self) -> dict:
-        """JSON-friendly form for bench journals / telemetry."""
+        """JSON-friendly form for telemetry."""
         return {
             "tile_rows": self.tile_rows,
             "use_pack": self.use_pack,
@@ -141,8 +137,6 @@ class HistPlan(NamedTuple):
             "fused_vmem_bytes": self.fused_vmem_bytes,
             "vmem_limit_bytes": self.vmem_limit_bytes,
             "elected_by": self.elected_by,
-            "measured_variant": self.measured_variant,
-            "autotune_key": self.autotune_key,
         }
 
 
@@ -395,7 +389,7 @@ def _tile_override():
 
 
 # ======================================================================
-# Compile-time war, part 1: shape-bucket ladders.  Every distinct row
+# Compile-time war: shape-bucket ladders.  Every distinct row
 # count is a distinct XLA program, so a pipeline of nearby dataset sizes
 # recompiles everything from scratch each time.  Padding training rows
 # up to a coarse ladder rung (the serving-bucket trick from predict,
@@ -437,169 +431,6 @@ def bucket_rows(n: int) -> int:
         if rung >= n:
             return rung
     return base << 1                        # unreachable
-
-
-# ======================================================================
-# Compile-time war, part 2 — measured election: the autotuner.  The
-# analytic models above answer "does it fit"; only a stopwatch answers
-# "which variant is FASTEST here".  tools/hist_probe.py and bench record
-# measured sec/level per (shape-bucket, variant) from
-# obs.devprof.measure_program into an atomic JSON store beside the
-# persistent compile cache; plan_histograms then elects the kernel
-# variant (and the fused kernel's {feat_tile, block_rows}) from
-# measurements when they exist, keeping the analytic model as the
-# cold-start fallback.  A corrupt, stale or version-mismatched store is
-# ALWAYS a miss, never a crash.
-# ======================================================================
-
-AUTOTUNE_STORE_VERSION = 1
-_AUTOTUNE_STORE_FILE = "hist_timings.json"
-# election outcomes since process start (or last reset):
-#   hit  = a valid measurement keyed this shape and drove the election
-#   miss = no usable measurement (cold start / stale name / bad context)
-#   flip = a hit elected a DIFFERENT variant than the analytic model
-_AUTOTUNE_STATS = {"hits": 0, "misses": 0, "flips": 0}
-# the most recent election's full story — obs/diagnose.py feeds the
-# kernel-underutilized verdict its concrete cure from here
-_AUTOTUNE_LAST: dict = {}
-_AUTOTUNE_LOCK = threading.Lock()
-
-
-def autotune_enabled() -> bool:
-    """LGBM_TPU_AUTOTUNE != "0" (default on; measurements only steer an
-    election when the store actually holds some)."""
-    return os.environ.get("LGBM_TPU_AUTOTUNE", "").strip().lower() \
-        not in ("0", "off", "false", "no")
-
-
-def autotune_dir():
-    """Directory of the measured-timings store, or None (analytic-only:
-    ``LGBM_TPU_AUTOTUNE_DIR=off``).
-
-    ``LGBM_TPU_AUTOTUNE_DIR`` wins; otherwise an ``autotune/`` sibling
-    inside the persistent compile-cache dir — the measurements describe
-    the same machine the cached programs were compiled for, so they
-    share a home and a lifetime.
-    """
-    d = os.environ.get("LGBM_TPU_AUTOTUNE_DIR", "").strip()
-    if d:
-        return None if d.lower() in ("0", "off", "none") else d
-    from ..utils.platform import compile_cache_dir
-    return os.path.join(compile_cache_dir(), "autotune")
-
-
-def shape_bucket_key(rows: int, features: int, num_bins: int,
-                     quant: bool, round_width: int) -> str:
-    """Store key: the shape-bucket a measurement generalizes over.
-
-    Rows go through ``bucket_rows`` so a 1.05M-row run reuses the
-    1M-bucket measurement — exact-shape keys would never warm up.
-    """
-    return (f"r{bucket_rows(rows)}-f{int(features)}-b{int(num_bins)}"
-            f"-q{int(bool(quant))}-w{int(round_width)}")
-
-
-def _autotune_path(path=None):
-    d = path or autotune_dir()
-    return os.path.join(d, _AUTOTUNE_STORE_FILE) if d else None
-
-
-def _load_autotune_store(path=None) -> dict:
-    """{key: {variant: {"seconds": s, "params": {...}}}} — {} on ANY
-    problem: missing file, corrupt JSON, wrong version, wrong shape."""
-    p = _autotune_path(path)
-    if not p:
-        return {}
-    try:
-        with open(p, "r", encoding="utf-8") as f:
-            doc = json.load(f)
-        if not isinstance(doc, dict) \
-                or doc.get("version") != AUTOTUNE_STORE_VERSION:
-            return {}
-        entries = doc.get("entries")
-        return entries if isinstance(entries, dict) else {}
-    except Exception:
-        return {}
-
-
-def record_timing(rows: int, features: int, num_bins: int, quant: bool,
-                  round_width: int, variant: str, seconds: float,
-                  params=None, path=None):
-    """Bank one measured (shape-bucket, variant) timing; returns the
-    store file path, or None when no store dir is configured.
-
-    Read-merge-write under the process lock, landed via
-    ``file_io.write_atomic`` so a crashed writer can never leave a torn
-    store for the next election to trip over.
-    """
-    p = _autotune_path(path)
-    if not p:
-        return None
-    from ..utils.file_io import write_atomic
-    key = shape_bucket_key(rows, features, num_bins, quant, round_width)
-    with _AUTOTUNE_LOCK:
-        entries = _load_autotune_store(path)
-        slot = dict(entries.get(key) or {})
-        slot[str(variant)] = {"seconds": float(seconds),
-                              "params": dict(params or {})}
-        entries[key] = slot
-        write_atomic(p, json.dumps(
-            {"version": AUTOTUNE_STORE_VERSION, "entries": entries},
-            indent=1, sort_keys=True))
-    return p
-
-
-def measured_election(rows, features, num_bins, quant, round_width,
-                      path=None):
-    """Fastest measured variant for this shape-bucket, or None (cold).
-
-    Returns {"key", "variant", "seconds", "params"}; a malformed entry
-    inside an otherwise-good slot is skipped, not fatal.
-    """
-    key = shape_bucket_key(rows, features, num_bins, quant, round_width)
-    slot = _load_autotune_store(path).get(key)
-    if not isinstance(slot, dict):
-        return None
-    best_v, best = None, None
-    for v, rec in slot.items():
-        try:
-            s = float(rec["seconds"])
-        except Exception:
-            continue
-        if s > 0 and (best is None or s < best["seconds"]):
-            params = rec.get("params")
-            best_v = str(v)
-            best = {"seconds": s,
-                    "params": params if isinstance(params, dict) else {}}
-    if best_v is None:
-        return None
-    return {"key": key, "variant": best_v, **best}
-
-
-def autotune_counters(reset: bool = False) -> dict:
-    """Election-outcome counters {hits, misses, flips} since last reset."""
-    with _AUTOTUNE_LOCK:
-        out = dict(_AUTOTUNE_STATS)
-        if reset:
-            for k in _AUTOTUNE_STATS:
-                _AUTOTUNE_STATS[k] = 0
-    return out
-
-
-def autotune_last() -> dict:
-    """The most recent election's story (diagnose's cure feed)."""
-    with _AUTOTUNE_LOCK:
-        return dict(_AUTOTUNE_LAST)
-
-
-def _adoptable_methods(quant: bool):
-    """Measured staged variants plan_histograms may promote directly to
-    ``hist_method`` (must be names resolve_hist_method accepts for the
-    family; dispatch-level names like "sorted" steer via the family
-    verdict "staged" instead)."""
-    if quant:
-        return ("matmul_int8", "scatter_int")
-    return ("matmul", "matmul_f32", "scatter", "pallas")
 
 
 def plan_histograms(
@@ -662,65 +493,6 @@ def plan_histograms(
         fp = plan_fused(kcap, num_bins, quant, with_parent=True,
                         vmem_bytes=vmem_bytes, num_features=features)
     variant = "fused" if fp is not None else _resolved_variant(method, quant)
-    analytic_variant = variant
-    elected_by, measured_variant, autotune_key = "analytic", "", ""
-    if autotune_enabled() and method == "auto":
-        # measured election: adopt the store's fastest variant for this
-        # shape-bucket when it is valid IN CONTEXT — fused only if the
-        # VMEM election ran and passed, staged names only within the
-        # right kernel family; anything else is a stale name → a miss.
-        autotune_key = shape_bucket_key(rows, features, num_bins, quant,
-                                        round_width)
-        m = measured_election(rows, features, num_bins, quant, round_width)
-        adopted = False
-        if m is not None:
-            measured_variant = m["variant"]
-            if measured_variant == "fused":
-                if fp is not None:
-                    adopted = True
-                    ft = int(m["params"].get("feat_tile") or 0)
-                    br = int(m["params"].get("block_rows") or 0)
-                    if ft > 0 and br > 0:
-                        # measured {feat_tile, block_rows} override the
-                        # analytic walk — but only if they still fit the
-                        # VMEM model (a store written on a bigger core
-                        # must not OOM this one)
-                        kcap = max(min(int(round_width),
-                                       int(num_leaves) - 1), 1)
-                        need = fused_vmem_bytes(kcap, num_bins, ft, br,
-                                                quant, True)
-                        lim = int(vmem_bytes if vmem_bytes is not None
-                                  else vmem_limit_bytes())
-                        if need <= int(lim * VMEM_HEADROOM):
-                            fp = {"feat_tile": ft, "block_rows": br,
-                                  "vmem_bytes": need,
-                                  "vmem_limit_bytes": lim}
-            elif measured_variant == "staged":
-                # family-level verdict: the staged arm measured faster
-                # than the fused kernel here — decline fused even when
-                # its arena fits
-                adopted = True
-                fp = None
-                variant = _resolved_variant("auto", quant)
-            elif measured_variant in _adoptable_methods(quant):
-                adopted = True
-                fp = None
-                variant = measured_variant
-        elected = "fused" if fp is not None else variant
-        with _AUTOTUNE_LOCK:
-            if adopted:
-                _AUTOTUNE_STATS["hits"] += 1
-                elected_by = "measured"
-                if elected != analytic_variant:
-                    _AUTOTUNE_STATS["flips"] += 1
-            else:
-                _AUTOTUNE_STATS["misses"] += 1
-            _AUTOTUNE_LAST.clear()
-            _AUTOTUNE_LAST.update(
-                key=autotune_key, analytic_variant=analytic_variant,
-                measured_variant=measured_variant or None,
-                measured_seconds=(m or {}).get("seconds"),
-                elected_by=elected_by, elected_variant=elected)
     narrow = bool(quant and quant_psum_narrow(rows * machines, quant_bins))
     # the fused grower never hoists the pack_cols_u32 record arena (it
     # gathers nothing), so its plan must not charge — or report — it
@@ -748,9 +520,7 @@ def plan_histograms(
             fused_feat_tile=fp["feat_tile"] if fp else 0,
             fused_block_rows=fp["block_rows"] if fp else 0,
             fused_vmem_bytes=fp["vmem_bytes"] if fp else 0,
-            vmem_limit_bytes=fp["vmem_limit_bytes"] if fp else 0,
-            elected_by=elected_by, measured_variant=measured_variant,
-            autotune_key=autotune_key)
+            vmem_limit_bytes=fp["vmem_limit_bytes"] if fp else 0)
 
     if forced is not None:
         if forced == 0 or forced >= rows:
@@ -786,9 +556,8 @@ def apply_plan(cfg, rows: int, features: int, accel: Optional[bool] = None,
         quant_bins=cfg.quant_bins, method=cfg.hist_method,
         round_width=cfg.round_width, machines=max(cfg.num_machines, 1),
         accel=accel, fused_ok=fused_ok)
-    # first-class predicted-peak event (docs/OBSERVABILITY.md): the bench
-    # logs the allocator's MEASURED peak next to it, so memory-model
-    # drift is visible per run on the same timeline
+    # first-class predicted-peak event (docs/OBSERVABILITY.md): beside
+    # the allocator's peak it shows memory-model drift per run
     from ..obs.trace import instant
     instant("planner.plan", rows=rows, features=features, **plan.summary())
     cfg = cfg._replace(tile_rows=plan.tile_rows,
@@ -812,12 +581,6 @@ def apply_plan(cfg, rows: int, features: int, accel: Optional[bool] = None,
                 f"(limit {vmem_limit_bytes()} bytes; LGBM_TPU_VMEM_BYTES "
                 "overrides); falling back to the staged kernel family")
         cfg = cfg._replace(hist_method="auto")
-    elif (plan.elected_by == "measured" and cfg.hist_method == "auto"
-          and plan.variant in _adoptable_methods(cfg.quant)):
-        # measured election of a staged POINT kernel: promote it so the
-        # dispatch sites run what the stopwatch picked, not what "auto"
-        # resolves to ("staged"/"sorted" family verdicts stay on auto)
-        cfg = cfg._replace(hist_method=plan.variant)
     return cfg, plan
 
 
@@ -867,7 +630,7 @@ class ModelBatchPlan(NamedTuple):
     forced: bool                # LGBM_TPU_MODEL_BATCH capped the election
 
     def summary(self) -> dict:
-        """JSON-friendly form for bench journals / telemetry."""
+        """JSON-friendly form for telemetry."""
         return {
             "b_total": self.b_total,
             "b_chunk": self.b_chunk,
@@ -1031,9 +794,7 @@ class CollectivePlan(NamedTuple):
     #                             | "hierarchical+voting"
 
     def summary(self) -> dict:
-        """JSON-friendly form for bench journals / checkpoint manifests
-        (the MULTICHIP journal's {mesh_shape, ici_bytes, dcn_bytes,
-        hierarchy_elected, voting_k} fields read from here)."""
+        """JSON-friendly form for telemetry / checkpoint manifests."""
         return {
             "mesh_shape": [self.num_slices, self.devices_per_slice],
             "num_slices": self.num_slices,
@@ -1224,7 +985,7 @@ class StreamPlan(NamedTuple):
     reason: str                        # why streaming was/wasn't elected
 
     def summary(self) -> dict:
-        """JSON-friendly form for bench journals / checkpoint provenance."""
+        """JSON-friendly form for telemetry / checkpoint provenance."""
         return {
             "stream": self.stream,
             "block_rows": self.block_rows,
@@ -1427,7 +1188,7 @@ class FleetPlan(NamedTuple):
     feasible: bool              # every model got device residency
 
     def summary(self) -> dict:
-        """JSON-friendly form for bench journals / telemetry."""
+        """JSON-friendly form for telemetry."""
         return {
             "models": [
                 {"name": m.name, "resident": m.resident,
@@ -1841,9 +1602,7 @@ def active_ledger() -> Optional[ResidencyLedger]:
 
 # ======================================================================
 # Inference kernel + chunk election: plan_predict.  The predict path's
-# analogue of plan_histograms — byte models answer "does it fit", the
-# measured-timings store (a new "p-..." key namespace in the SAME
-# hist_timings.json) answers "which traversal variant is fastest", and
+# analogue of plan_histograms — byte models answer "does it fit", and
 # LGBM_TPU_PREDICT_KERNEL is the bisect gate over the whole election.
 # ======================================================================
 
@@ -1857,7 +1616,7 @@ FUSED_PREDICT_TILES = (2048, 1024, 512, 256, 128)
 
 def _predict_kernel_override():
     """LGBM_TPU_PREDICT_KERNEL: pin the traversal variant, bypassing
-    measured and analytic election (the bisect gate)."""
+    the analytic election (the bisect gate)."""
     v = os.environ.get("LGBM_TPU_PREDICT_KERNEL", "").strip().lower()
     return v if v in PREDICT_VARIANTS else None
 
@@ -1872,65 +1631,6 @@ def _predict_chunk_override():
     except ValueError:
         return None
     return max(n, 8) if n > 0 else None
-
-
-def predict_bucket_key(rows: int, features: int, num_trees: int,
-                       num_class: int, precision: str) -> str:
-    """Store key of the predict autotune family — prefixed "p-" so it
-    can never collide with histogram shape-bucket keys in the shared
-    store file."""
-    return (f"p-r{bucket_rows(max(int(rows), 1))}-f{int(features)}"
-            f"-t{int(num_trees)}-k{max(int(num_class), 1)}-{precision}")
-
-
-def record_predict_timing(rows, features, num_trees, num_class, precision,
-                          variant, seconds, params=None, path=None):
-    """Bank one measured (predict shape-bucket, variant) timing in the
-    shared store; returns the store path or None (no store dir).  Same
-    read-merge-write-atomic discipline as ``record_timing``."""
-    p = _autotune_path(path)
-    if not p:
-        return None
-    from ..utils.file_io import write_atomic
-    key = predict_bucket_key(rows, features, num_trees, num_class, precision)
-    with _AUTOTUNE_LOCK:
-        entries = _load_autotune_store(path)
-        slot = dict(entries.get(key) or {})
-        slot[str(variant)] = {"seconds": float(seconds),
-                              "params": dict(params or {})}
-        entries[key] = slot
-        write_atomic(p, json.dumps(
-            {"version": AUTOTUNE_STORE_VERSION, "entries": entries},
-            indent=1, sort_keys=True))
-    return p
-
-
-def measured_predict_election(rows, features, num_trees, num_class,
-                              precision, path=None, skip=()):
-    """Fastest measured traversal variant for this predict bucket, or
-    None (cold).  Unknown variant names (a store written by a future
-    version) and the names in ``skip`` (variants this platform cannot
-    elect) are passed over, not adopted."""
-    key = predict_bucket_key(rows, features, num_trees, num_class, precision)
-    slot = _load_autotune_store(path).get(key)
-    if not isinstance(slot, dict):
-        return None
-    best_v, best = None, None
-    for v, rec in slot.items():
-        if str(v) not in PREDICT_VARIANTS or str(v) in skip:
-            continue
-        try:
-            s = float(rec["seconds"])
-        except Exception:
-            continue
-        if s > 0 and (best is None or s < best["seconds"]):
-            params = rec.get("params")
-            best_v = str(v)
-            best = {"seconds": s,
-                    "params": params if isinstance(params, dict) else {}}
-    if best_v is None:
-        return None
-    return {"key": key, "variant": best_v, **best}
 
 
 def predict_fused_vmem_bytes(num_trees: int, nodes_dim: int, features: int,
@@ -2029,12 +1729,10 @@ class PredictPlan(NamedTuple):
     limit_bytes: int
     limit_source: str
     feasible: bool
-    elected_by: str             # "env" | "measured" | "analytic"
-    measured_variant: str = ""  # store's best for this bucket ("" = cold)
-    autotune_key: str = ""      # predict-bucket key the election ran under
+    elected_by: str             # "env" | "analytic"
 
     def summary(self) -> dict:
-        """JSON-friendly form for bench journals / telemetry."""
+        """JSON-friendly form for telemetry."""
         return {
             "variant": self.variant,
             "tile_rows": self.tile_rows,
@@ -2047,8 +1745,6 @@ class PredictPlan(NamedTuple):
             "limit_source": self.limit_source,
             "feasible": self.feasible,
             "elected_by": self.elected_by,
-            "measured_variant": self.measured_variant,
-            "autotune_key": self.autotune_key,
         }
 
 
@@ -2063,15 +1759,13 @@ def plan_predict(num_trees: int, nodes_dim: int, leaves_dim: int,
 
     Budget: the ledger's remaining bytes when one is leased against
     (serving co-residency, PR 17), else HEADROOM x the device limit.
-    Variant: ``LGBM_TPU_PREDICT_KERNEL`` > the measured predict family
-    > analytic (``fori`` — the while arm is never elected, only pinned).
-    The fused Pallas traversal is NOT in the accelerator election: the
-    chip's compiler refuses its in-kernel table gathers (an
-    ``AssertionError`` in Mosaic's gather lowering rule — docs/PERF.md
-    "what compiles on the chip"), so there neither the analytic verdict
-    nor a measured entry may pick it; only the env pin can, and it then
-    raises the compiler's error.  Where Pallas interprets it stays
-    electable through the measured store.
+    Variant: ``LGBM_TPU_PREDICT_KERNEL`` > analytic (``fori`` — the
+    while arm and the fused Pallas traversal are never elected, only
+    pinned).  The chip's compiler refuses the fused traversal's
+    in-kernel table gathers (an ``AssertionError`` in Mosaic's gather
+    lowering rule — docs/PERF.md "what compiles on the chip"), so there
+    the pin raises the compiler's error; where Pallas interprets, the
+    pin runs it.
     """
     if accel is None:
         from .histogram import on_accelerator
@@ -2097,28 +1791,9 @@ def plan_predict(num_trees: int, nodes_dim: int, leaves_dim: int,
                                  emit_scores=not routing_only,
                                  vmem_bytes=vmem_bytes)
     variant, elected_by = "fori", "analytic"
-    measured_variant, autotune_key = "", ""
-    if autotune_enabled():
-        autotune_key = predict_bucket_key(rows or chunk, features,
-                                          num_trees, num_class, precision)
-        m = measured_predict_election(
-            rows or chunk, features, num_trees, num_class, precision,
-            skip=("fused",) if accel else ())
-        with _AUTOTUNE_LOCK:
-            if m is not None:
-                measured_variant = m["variant"]
-                variant, elected_by = measured_variant, "measured"
-                _AUTOTUNE_STATS["hits"] += 1
-                if variant != "fori":       # the analytic verdict
-                    _AUTOTUNE_STATS["flips"] += 1
-            else:
-                _AUTOTUNE_STATS["misses"] += 1
     o = _predict_kernel_override()
     if o is not None:
         variant, elected_by = o, "env"
-    if variant == "fused" and ft is None and elected_by != "env":
-        # a measured "fused" from a bigger core must not OOM this one
-        variant = "fori"
     tile = (ft["tile_rows"] if ft is not None else FUSED_PREDICT_TILES[-1]) \
         if variant == "fused" else 0
     peak = fb + pb
@@ -2126,16 +1801,14 @@ def plan_predict(num_trees: int, nodes_dim: int, leaves_dim: int,
         variant=variant, tile_rows=tile, chunk_rows=chunk,
         forest_bytes=fb, program_bytes=pb, predicted_peak_bytes=peak,
         budget_bytes=budget, limit_bytes=limit, limit_source=source,
-        feasible=peak <= budget, elected_by=elected_by,
-        measured_variant=measured_variant, autotune_key=autotune_key)
+        feasible=peak <= budget, elected_by=elected_by)
 
 
 # ======================================================================
 # Ingest kernel + chunk election: plan_ingest.  The binning pass's
 # analogue of plan_predict — byte models answer "what chunk fits the
-# ledger remainder", the measured-timings store (an "i-..." key
-# namespace in the SAME hist_timings.json) answers "kernel or host",
-# and LGBM_TPU_INGEST_KERNEL is the bisect gate over the election.
+# ledger remainder" and "kernel or host", and LGBM_TPU_INGEST_KERNEL is
+# the bisect gate over the election.
 # ======================================================================
 
 INGEST_VARIANTS = ("kernel", "host")
@@ -2145,13 +1818,13 @@ MAX_INGEST_CHUNK_ROWS = 1 << 21
 INGEST_TILES = (2048, 1024, 512, 256, 128)
 # past this width the unrolled per-feature kernel stops being the
 # analytic default (compile time grows with the feature loop); the env
-# pin and the measured store can still elect it
+# pin can still elect it
 MAX_INGEST_KERNEL_FEATURES = 1024
 
 
 def _ingest_kernel_override():
     """LGBM_TPU_INGEST_KERNEL: pin the binning arm ("kernel" | "host"),
-    bypassing measured and analytic election (the bisect gate)."""
+    bypassing the analytic election (the bisect gate)."""
     v = os.environ.get("LGBM_TPU_INGEST_KERNEL", "").strip().lower()
     return v if v in INGEST_VARIANTS else None
 
@@ -2166,61 +1839,6 @@ def _ingest_chunk_override():
     except ValueError:
         return None
     return max(n, 8) if n > 0 else None
-
-
-def ingest_bucket_key(rows: int, features: int, num_groups: int,
-                      item_bytes: int) -> str:
-    """Store key of the ingest autotune family — prefixed "i-" so it
-    can never collide with the histogram or predict namespaces."""
-    return (f"i-r{bucket_rows(max(int(rows), 1))}-f{int(features)}"
-            f"-g{int(num_groups)}-u{max(int(item_bytes), 1)}")
-
-
-def record_ingest_timing(rows, features, num_groups, item_bytes,
-                         variant, seconds, params=None, path=None):
-    """Bank one measured (ingest shape-bucket, variant) timing in the
-    shared store; returns the store path or None (no store dir)."""
-    p = _autotune_path(path)
-    if not p:
-        return None
-    from ..utils.file_io import write_atomic
-    key = ingest_bucket_key(rows, features, num_groups, item_bytes)
-    with _AUTOTUNE_LOCK:
-        entries = _load_autotune_store(path)
-        slot = dict(entries.get(key) or {})
-        slot[str(variant)] = {"seconds": float(seconds),
-                              "params": dict(params or {})}
-        entries[key] = slot
-        write_atomic(p, json.dumps(
-            {"version": AUTOTUNE_STORE_VERSION, "entries": entries},
-            indent=1, sort_keys=True))
-    return p
-
-
-def measured_ingest_election(rows, features, num_groups, item_bytes,
-                             path=None):
-    """Fastest measured ingest arm for this shape bucket, or None
-    (cold).  Unknown variant names are skipped, not adopted."""
-    key = ingest_bucket_key(rows, features, num_groups, item_bytes)
-    slot = _load_autotune_store(path).get(key)
-    if not isinstance(slot, dict):
-        return None
-    best_v, best = None, None
-    for v, rec in slot.items():
-        if str(v) not in INGEST_VARIANTS:
-            continue
-        try:
-            s = float(rec["seconds"])
-        except Exception:
-            continue
-        if s > 0 and (best is None or s < best["seconds"]):
-            params = rec.get("params")
-            best_v = str(v)
-            best = {"seconds": s,
-                    "params": params if isinstance(params, dict) else {}}
-    if best_v is None:
-        return None
-    return {"key": key, "variant": best_v, **best}
 
 
 def ingest_vmem_bytes(features: int, tile_rows: int, bounds_width: int,
@@ -2306,12 +1924,10 @@ class IngestPlan(NamedTuple):
     limit_bytes: int
     limit_source: str
     feasible: bool
-    elected_by: str             # "env" | "measured" | "analytic"
-    measured_variant: str = ""  # store's best for this bucket ("" = cold)
-    autotune_key: str = ""      # ingest-bucket key the election ran under
+    elected_by: str             # "env" | "analytic"
 
     def summary(self) -> dict:
-        """JSON-friendly form for bench journals / telemetry."""
+        """JSON-friendly form for telemetry."""
         return {
             "variant": self.variant,
             "tile_rows": self.tile_rows,
@@ -2322,8 +1938,6 @@ class IngestPlan(NamedTuple):
             "limit_source": self.limit_source,
             "feasible": self.feasible,
             "elected_by": self.elected_by,
-            "measured_variant": self.measured_variant,
-            "autotune_key": self.autotune_key,
         }
 
 
@@ -2337,9 +1951,9 @@ def plan_ingest(rows: int, features: int, num_groups: int,
 
     Budget: the ledger's remaining bytes when one is leased against
     (co-residency, PR 17), else HEADROOM x the device limit.  Variant:
-    ``LGBM_TPU_INGEST_KERNEL`` > the measured "i-..." family > analytic
-    (kernel on accelerators when its VMEM tile fits and the feature
-    width is kernel-sized, host everywhere else).
+    ``LGBM_TPU_INGEST_KERNEL`` > analytic (kernel on accelerators when
+    its VMEM tile fits and the feature width is kernel-sized, host
+    everywhere else).
     """
     if accel is None:
         from .histogram import on_accelerator
@@ -2361,27 +1975,9 @@ def plan_ingest(rows: int, features: int, num_groups: int,
                             and features <= MAX_INGEST_KERNEL_FEATURES) \
         else "host"
     variant, elected_by = analytic, "analytic"
-    measured_variant, autotune_key = "", ""
-    if autotune_enabled():
-        autotune_key = ingest_bucket_key(rows or chunk, features,
-                                         num_groups, item_bytes)
-        m = measured_ingest_election(rows or chunk, features, num_groups,
-                                     item_bytes)
-        with _AUTOTUNE_LOCK:
-            if m is not None:
-                measured_variant = m["variant"]
-                variant, elected_by = measured_variant, "measured"
-                _AUTOTUNE_STATS["hits"] += 1
-                if variant != analytic:
-                    _AUTOTUNE_STATS["flips"] += 1
-            else:
-                _AUTOTUNE_STATS["misses"] += 1
     o = _ingest_kernel_override()
     if o is not None:
         variant, elected_by = o, "env"
-    if variant == "kernel" and tile is None and elected_by != "env":
-        # a measured "kernel" from a bigger core must not OOM this one
-        variant = "host"
     cb = ingest_chunk_bytes(chunk, features, num_groups, item_bytes)
     return IngestPlan(
         variant=variant,
@@ -2389,5 +1985,4 @@ def plan_ingest(rows: int, features: int, num_groups: int,
                    else INGEST_TILES[-1]) if variant == "kernel" else 0,
         chunk_rows=chunk, chunk_bytes=cb,
         budget_bytes=budget, limit_bytes=limit, limit_source=source,
-        feasible=cb <= budget, elected_by=elected_by,
-        measured_variant=measured_variant, autotune_key=autotune_key)
+        feasible=cb <= budget, elected_by=elected_by)

@@ -61,8 +61,7 @@ def fused_best_payload_bytes(num_features: int) -> int:
     ops/fused.py) — what a collective would move if it exchanged
     candidates instead of histograms: F·~6 cells vs the histogram
     payload's F·B·ch (``ops.histogram.hist_payload_bytes``).  Pure
-    accounting, reported by tools/hist_probe.py next to the histogram
-    payloads; the EXACT data-parallel reduction still psums histograms
+    accounting; the EXACT data-parallel reduction still psums histograms
     (gains are not summable across shards — the same reason
     voting-parallel exchanges elected candidates, PV-Tree).  This is the
     DCN/ICI headroom figure the voting/fused combination targets."""

@@ -1,9 +1,7 @@
 """Threaded mixed-shape load driver for the serving subsystem.
 
-The one request-storm implementation shared by ``bench.py``'s serving
-stage and ``tools/serve_smoke.py`` (their drivers used to be near-twins;
-a fix to one — e.g. dead-thread error accounting — kept missing the
-other).  Deliberately not a benchmark harness: it fires, optionally
+The one request-storm implementation, shared by the ``tools/*_smoke.py``
+drivers.  Deliberately not a benchmark harness: it fires, optionally
 verifies bit-equality, and reports honest completed counts.
 
 ``fire_requests`` additionally speaks **shadow mode** for the model
@@ -174,7 +172,7 @@ def fire_fleet_requests(fleet, mix: dict, n_requests: int, n_threads: int,
     ``fleet.router.PodFleet``.
 
     ``mix`` maps model name -> traffic weight: every request picks its
-    model by weighted draw, so the fleet bench models a real mixed
+    model by weighted draw, so the fleet smoke models a real mixed
     workload instead of N sequential single-model storms.  Sheds
     (``QueueFull`` — the fleet's weighted-admission or brownout
     verdict) and deadline expiries (``DeadlineExceeded`` — the model's
@@ -193,8 +191,9 @@ def fire_fleet_requests(fleet, mix: dict, n_requests: int, n_threads: int,
     completed/shed/expired/failed), and **availability** = 1 −
     failed / (completed + failed) — typed shed/expired excluded from
     both sides, because rejecting work you cannot serve on time is
-    correct behavior, not unavailability.  Failover tests and the bench
-    assert this number, not a vibe (None before any non-typed outcome).
+    correct behavior, not unavailability.  Failover tests and the fleet
+    smoke assert this number, not a vibe (None before any non-typed
+    outcome).
     """
     from .errors import DeadlineExceeded, QueueFull
 

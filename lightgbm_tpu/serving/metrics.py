@@ -1,6 +1,6 @@
 """Back-compat shim: the serving metrics registry was promoted to
 ``lightgbm_tpu.obs.metrics`` as the single process-wide instrument
-registry (training, serving, resilience and the bench all report through
+registry (training, serving and resilience all report through
 it — docs/OBSERVABILITY.md).
 
 This module re-exports the full historical surface so every existing

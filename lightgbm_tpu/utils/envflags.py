@@ -1,7 +1,7 @@
 """Central registry of every environment flag the project reads.
 
-The codebase is steered by ``LGBM_TPU_*`` / ``LIGHTGBM_TPU_*`` (library
-behavior) and ``BENCH_*`` (bench driver) env gates.  Before this module
+The codebase is steered by ``LGBM_TPU_*`` / ``LIGHTGBM_TPU_*`` env
+gates (library behavior).  Before this module
 they lived as string literals scattered over ~20 files with no single
 place answering "what knobs exist, what do they default to, and where
 are they documented".  Every flag must be declared here — ``tpulint``'s
@@ -51,12 +51,6 @@ FLAGS: Dict[str, EnvFlag] = {f.name: f for f in [
     # ------------------------------------------------ kernel/planner gates
     _f("LGBM_TPU_FUSED", "1", "ops/fused.py",
        "fused histogram->split megakernel eligibility ('0' disables)", _PERF),
-    _f("LGBM_TPU_AUTOTUNE", "1", "ops/planner.py",
-       "measured-timings kernel election ('0' = analytic model only)",
-       _PERF),
-    _f("LGBM_TPU_AUTOTUNE_DIR", "", "ops/planner.py",
-       "measured-timings store dir (default: <compile cache>/autotune)",
-       _PERF),
     _f("LGBM_TPU_SHAPE_BUCKETS", "", "ops/planner.py",
        "pad training rows to ladder rungs so nearby sizes share one "
        "compiled program ('1' on, '0' off; default: accelerators only)",
@@ -184,85 +178,6 @@ FLAGS: Dict[str, EnvFlag] = {f.name: f for f in [
     _f("LGBM_TPU_CORESIDENT_RECOVERY_S", "1.0", "coresident/scheduler.py",
        "quiet time after the last breach ping before throttled/paused "
        "training resumes at full cap (seconds)", _PERF),
-    # ------------------------------------------------------ bench workload
-    _f("BENCH_ROWS", "11000000", "bench.py",
-       "full-stage training rows", _PERF),
-    _f("BENCH_TREES", "500", "bench.py", "full-stage tree count", _PERF),
-    _f("BENCH_LEAVES", "255", "bench.py", "num_leaves for bench stages",
-       _PERF),
-    _f("BENCH_BIN", "63", "bench.py", "max_bin for bench stages", _PERF),
-    _f("BENCH_SMOKE_ROWS", "500000", "bench.py", "smoke-stage rows", _PERF),
-    _f("BENCH_SMOKE_TREES", "3", "bench.py",
-       "smoke-stage tree count", _PERF),
-    _f("BENCH_RANK_QUERIES", "12000", "bench.py",
-       "ranking-stage query count", _PERF),
-    _f("BENCH_RANK_DOCS", "100", "bench.py",
-       "ranking-stage docs per query", _PERF),
-    _f("BENCH_RANK_TREES", "100", "bench.py",
-       "ranking-stage tree count", _PERF),
-    _f("BENCH_STREAM_ROWS", "100000000", "bench.py",
-       "out-of-core streaming stage rows", _PERF),
-    _f("BENCH_STREAM_TREES", "3", "bench.py",
-       "out-of-core streaming stage tree count", _PERF),
-    _f("BENCH_BULK_ROWS", "10000000", "bench.py",
-       "bulk offline-scoring stage rows", _PERF),
-    _f("BENCH_TOTAL_BUDGET", "6600", "bench.py",
-       "wall-clock budget (seconds) the stage gates spend against", _PERF),
-    _f("BENCH_EXTRA_PARAMS", "", "bench.py",
-       "JSON dict merged into every bench stage's train params", _PERF),
-    # ------------------------------------------------------ bench plumbing
-    _f("BENCH_JOURNAL", "", "bench.py",
-       "journal path ('0' disables; default ./bench_journal.json)", _PERF),
-    _f("BENCH_ONLY", "", "bench.py",
-       "comma list of worker stages to run exclusively", _PERF),
-    _f("BENCH_WORKER_ALLOW_CPU", "", "bench.py",
-       "'1' lets bench.py walk its stages on a CPU backend (CI)", _PERF),
-    _f("BENCH_PROFILE", "", "bench.py",
-       "'1' captures a jax.profiler trace around the train loop", _OBS),
-    # ------------------------------------------------------ bench skips
-    _f("BENCH_SKIP_KERNEL_PROBE", "", "bench.py",
-       "'1' skips the kernel bit-exactness probe", _PERF),
-    _f("BENCH_SKIP_DISPATCH_PROBE", "", "bench.py",
-       "'1' skips the dispatch-latency probe", _PERF),
-    _f("BENCH_SKIP_HIST_PROBE", "", "bench.py",
-       "'1' skips the histogram-variant probe", _PERF),
-    _f("BENCH_SKIP_STREAM_PROBE", "", "bench.py",
-       "'1' skips the streaming-plane probe", _PERF),
-    _f("BENCH_SKIP_COLLECTIVE_PROBE", "", "bench.py",
-       "'1' skips the collective-plane probe", _PERF),
-    _f("BENCH_SKIP_SMOKE", "", "bench.py", "'1' skips the smoke stage",
-       _PERF),
-    _f("BENCH_SKIP_STREAM", "", "bench.py",
-       "'1' skips the out-of-core streaming stage", _PERF),
-    _f("BENCH_SKIP_RANKING", "", "bench.py",
-       "'1' skips the ranking stage", _PERF),
-    _f("BENCH_SKIP_SERVING", "", "bench.py",
-       "'1' skips the serving stage", _PERF),
-    _f("BENCH_SKIP_FLEET", "", "bench.py",
-       "'1' skips the fleet AND fleet_failover stages", _PERF),
-    _f("BENCH_FLEET_DEVICES", "3", "bench.py",
-       "simulated device count for the fleet_failover drill", _PERF),
-    _f("BENCH_SKIP_RESILIENCE", "", "bench.py",
-       "'1' skips the resilience stage", _PERF),
-    _f("BENCH_SKIP_LIFECYCLE", "", "bench.py",
-       "'1' skips the model-lifecycle stage", _PERF),
-    _f("BENCH_SKIP_CORESIDENT", "", "bench.py",
-       "'1' skips the co-resident train+serve stage", _PERF),
-    _f("BENCH_SKIP_OBS", "", "bench.py",
-       "'1' skips obs_dump/obs_doctor stages + the measured-MFU table",
-       _OBS),
-    _f("BENCH_SKIP_LINT", "", "bench.py",
-       "'1' skips the journaled tpulint stage", _PERF),
-    _f("BENCH_SKIP_SWEEP", "", "bench.py",
-       "'1' skips the batched model-axis sweep probe", _PERF),
-    _f("BENCH_SKIP_PREDICT_PROBE", "", "bench.py",
-       "'1' skips the inference-kernel probe", _PERF),
-    _f("BENCH_SKIP_BULK_SCORE", "", "bench.py",
-       "'1' skips the bulk offline-scoring stage", _PERF),
-    _f("BENCH_SKIP_INGEST_PROBE", "", "bench.py",
-       "'1' skips the device-ingest binning probe", _PERF),
-    _f("BENCH_SKIP_INGEST_11M", "", "bench.py",
-       "'1' skips the streamed 11M-row ingest stage", _PERF),
 ]}
 
 

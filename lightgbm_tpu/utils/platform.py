@@ -43,7 +43,7 @@ def force_cpu_inprocess(n_devices: int = 8) -> None:
 
 # ----------------------------------------------------------------------
 # the persistent compile cache: ONE rule, for lgb.train / cv / serve, the
-# multi driver, bench.py and chip_smoke.py alike
+# multi driver and chip_smoke.py alike
 # ----------------------------------------------------------------------
 
 def _repo_root() -> str:
@@ -56,8 +56,7 @@ def compile_cache_dir() -> str:
     the environment sets it, else the fixed ``<checkout>/.jax_cache``.
     The path is part of the cache key, so it never carries a temporary
     name, a pid or a time.  The serving AOT store (``serving/``,
-    fleet/aot.py) and the autotune store (``autotune/``, ops/planner.py)
-    hang off the same directory."""
+    fleet/aot.py) hangs off the same directory."""
     return (os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
             or os.path.join(_repo_root(), ".jax_cache"))
 
@@ -76,8 +75,8 @@ def enable_compile_cache(family=None) -> str:
     ``family`` ("train", "serving") keys the warmth GAUGES by program
     family so a cold start is attributable: the train family's warmth
     counts JIT blobs only, the serving family's counts its AOT export
-    store, and the reserved subtrees (``serving/``, ``autotune/``) never
-    inflate another family's count.
+    store, and the reserved subtree (``serving/``) never inflates
+    another family's count.
     """
     import jax
 
@@ -164,10 +163,10 @@ def _count_compiles() -> None:
     _compile_listener = on_duration
 
 
-# reserved non-JIT subtrees of the cache dir: the serving AOT export
-# store (fleet/aot.py) and the autotuner's timing store (ops/planner.py)
-# live BESIDE the XLA blob pool and must never count as JIT warmth
-_CACHE_RESERVED_SUBDIRS = ("serving", "autotune")
+# reserved non-JIT subtree of the cache dir: the serving AOT export
+# store (fleet/aot.py) lives BESIDE the XLA blob pool and must never
+# count as JIT warmth
+_CACHE_RESERVED_SUBDIRS = ("serving",)
 
 
 def compile_cache_entries(path=None) -> int:
@@ -175,9 +174,8 @@ def compile_cache_entries(path=None) -> int:
     not exist yet) — the cold-vs-warm discriminator.
 
     Counts the JIT pool ONLY: the reserved ``serving/`` (AOT exports)
-    and ``autotune/`` (timing store) subtrees are excluded, so a
-    serving-only or probe-only prior run cannot make a training cold
-    start report warm."""
+    subtree is excluded, so a serving-only prior run cannot make a
+    training cold start report warm."""
     d = path or compile_cache_dir()
     total = 0
     for root, dirs, files in os.walk(d):
@@ -189,13 +187,11 @@ def compile_cache_entries(path=None) -> int:
 
 def compile_cache_entries_by_family(path=None) -> dict:
     """Entry counts under the cache dir, keyed by what each entry IS:
-    ``jit`` for the shared XLA blob pool, ``serving_aot`` for the
-    exported-program store (``<dir>/serving``, fleet/aot.py) and
-    ``autotune`` for the planner's measured-timings store."""
+    ``jit`` for the shared XLA blob pool and ``serving_aot`` for the
+    exported-program store (``<dir>/serving``, fleet/aot.py)."""
     d = path or compile_cache_dir()
     out = {"jit": compile_cache_entries(d)}
-    for name, key in (("serving", "serving_aot"), ("autotune", "autotune")):
-        sub = os.path.join(d, name)
-        if os.path.isdir(sub):
-            out[key] = sum(len(files) for _, _, files in os.walk(sub))
+    sub = os.path.join(d, "serving")
+    if os.path.isdir(sub):
+        out["serving_aot"] = sum(len(files) for _, _, files in os.walk(sub))
     return out

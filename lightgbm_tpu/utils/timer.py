@@ -13,7 +13,7 @@ one attribute check.
 
 Machine-readable exit dump: ``LIGHTGBM_TPU_TIMETAG=json`` emits a JSON
 object to stderr instead of the table; ``LIGHTGBM_TPU_TIMETAG=json:<path>``
-writes it to ``<path>`` — so bench stages and CI journal timer totals
+writes it to ``<path>`` — so tools and CI journal timer totals
 instead of scraping the human table.  ``publish()`` mirrors the totals
 into the unified process metrics registry (``obs.metrics``,
 docs/OBSERVABILITY.md) as ``timer.<name>.{calls,total_s}`` gauges.
@@ -102,7 +102,7 @@ class Timer:
         """Mirror the totals into the unified process metrics registry
         (default: ``obs.metrics.global_registry``) as
         ``timer.<name>.calls`` / ``timer.<name>.total_s`` gauges, so
-        bench stages journal them with the rest of the snapshot instead
+        tools journal them with the rest of the snapshot instead
         of scraping stderr.  Returns the mirrored totals."""
         if registry is None:
             from ..obs.metrics import global_registry as registry

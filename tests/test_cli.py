@@ -2,8 +2,10 @@
 
 Mirrors the reference CLI-vs-Python consistency strategy
 (tests/c_api_test + tests/python_package_test/test_consistency.py:10-60):
-train via the stock examples/*/train.conf through the CLI, predict through
-the CLI, and cross-check against the Python API on the same data.
+train via examples/binary_classification/train.conf (written beside seeded
+data of that example's shape by ``tests/example_data.py``) through the CLI,
+predict through the CLI, and cross-check against the Python API on the same
+data.
 """
 
 import os
@@ -13,9 +15,9 @@ import sys
 import numpy as np
 import pytest
 
+import example_data
 import lightgbm_tpu as lgb
 
-EXAMPLES = "/root/reference/examples"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -27,14 +29,13 @@ def _run_cli(args, cwd):
 
 
 def test_cli_train_predict_consistency(tmp_path):
-    conf = f"{EXAMPLES}/binary_classification/train.conf"
+    conf = example_data.write_files(tmp_path, "binary", example_data.binary())
     r = _run_cli([f"config={conf}", "num_trees=15", "metric_freq=10",
                   "output_model=model.txt"], cwd=str(tmp_path))
     assert r.returncode == 0, r.stderr[-2000:]
     assert (tmp_path / "model.txt").exists()
 
-    r2 = _run_cli(["task=predict",
-                   f"data={EXAMPLES}/binary_classification/binary.test",
+    r2 = _run_cli(["task=predict", "data=binary.test",
                    "input_model=model.txt",
                    "output_result=preds.txt"], cwd=str(tmp_path))
     assert r2.returncode == 0, r2.stderr[-2000:]
@@ -42,7 +43,7 @@ def test_cli_train_predict_consistency(tmp_path):
 
     # Python API prediction from the same saved model must agree exactly
     bst = lgb.Booster(model_file=str(tmp_path / "model.txt"))
-    data = np.loadtxt(f"{EXAMPLES}/binary_classification/binary.test")
+    data = np.loadtxt(tmp_path / "binary.test")
     py_pred = bst.predict(data[:, 1:])
     np.testing.assert_allclose(cli_pred, py_pred, rtol=1e-9, atol=1e-12)
 
@@ -51,8 +52,8 @@ def test_cli_convert_model_compiles_and_matches(tmp_path):
     import shutil
     if shutil.which("g++") is None:
         pytest.skip("no g++")
-    data = np.loadtxt(f"{EXAMPLES}/binary_classification/binary.train")
-    X, y = data[:200, 1:], data[:200, 0]
+    train = example_data.binary()[0]
+    X, y = train.X[:200], train.y[:200]
     bst = lgb.train({"objective": "binary", "verbosity": -1, "num_leaves": 7},
                     lgb.Dataset(X, label=y), num_boost_round=4,
                     verbose_eval=False)

@@ -1,29 +1,26 @@
-"""End-to-end training tests on the reference example datasets.
+"""End-to-end training tests on seeded data in the shapes of the reference
+example datasets (``tests/example_data.py``).
 
 Mirrors the reference test strategy (tests/python_package_test/test_engine.py):
-train small models per objective and assert metric thresholds.
+train small models per objective and assert metric thresholds.  A threshold
+with "measured" beside it was measured on that data at commit 961dc8b.
 """
-
-import os
 
 import numpy as np
 import pytest
 
+import example_data
 import lightgbm_tpu as lgb
 
-EXAMPLES = "/root/reference/examples"
 
-
-def _load(path):
-    data = np.loadtxt(path)
-    return data[:, 1:], data[:, 0]
+def _arrays(splits):
+    train, test = splits
+    return train.X, train.y, test.X, test.y
 
 
 @pytest.fixture(scope="module")
 def binary_data():
-    X, y = _load(f"{EXAMPLES}/binary_classification/binary.train")
-    Xt, yt = _load(f"{EXAMPLES}/binary_classification/binary.test")
-    return X, y, Xt, yt
+    return _arrays(example_data.binary())
 
 
 def test_binary(binary_data):
@@ -35,9 +32,9 @@ def test_binary(binary_data):
     evals = {}
     bst = lgb.train(params, train, num_boost_round=50, valid_sets=[valid],
                     evals_result=evals, verbose_eval=False)
-    # the reference example reaches ~0.83 AUC on this test split
+    # measured 0.8073 on example_data.binary()'s test split
     auc = evals["valid_0"]["auc"][-1]
-    assert auc > 0.80
+    assert auc > 0.78
     pred = bst.predict(Xt)
     assert pred.min() >= 0 and pred.max() <= 1
     from sklearn.metrics import roc_auc_score
@@ -45,8 +42,7 @@ def test_binary(binary_data):
 
 
 def test_regression():
-    X, y = _load(f"{EXAMPLES}/regression/regression.train")
-    Xt, yt = _load(f"{EXAMPLES}/regression/regression.test")
+    X, y, Xt, yt = _arrays(example_data.regression())
     params = {"objective": "regression", "metric": "l2", "verbosity": -1}
     evals = {}
     train = lgb.Dataset(X, label=y)
@@ -56,11 +52,13 @@ def test_regression():
     l2_start = evals["valid_0"]["l2"][0]
     l2_end = evals["valid_0"]["l2"][-1]
     assert l2_end < l2_start
-    assert l2_end < 0.2
+    # measured 2.5047 -> 1.0415 on example_data.regression()'s test split
+    # (label variance 2.84, of which the law's noise is 1.0)
+    assert l2_end < 1.10
 
 
 def test_regression_l1():
-    X, y = _load(f"{EXAMPLES}/regression/regression.train")
+    X, y, _, _ = _arrays(example_data.regression())
     params = {"objective": "regression_l1", "metric": "l1", "verbosity": -1}
     evals = {}
     train = lgb.Dataset(X, label=y)
@@ -71,7 +69,7 @@ def test_regression_l1():
 
 
 def test_multiclass():
-    X, y = _load(f"{EXAMPLES}/multiclass_classification/multiclass.train")
+    X, y, _, _ = _arrays(example_data.multiclass())
     params = {"objective": "multiclass", "num_class": 5,
               "metric": "multi_logloss", "verbosity": -1}
     evals = {}
@@ -79,20 +77,17 @@ def test_multiclass():
     bst = lgb.train(params, train, num_boost_round=30,
                     valid_sets=[lgb.Dataset(X, label=y, reference=train)],
                     evals_result=evals, verbose_eval=False)
-    # measured 1.1104 @30 rounds; reference at identical config: 1.1089
-    # (with the reference's flat-2.0 softmax hessian; see test_parity.py)
-    assert evals["valid_0"]["multi_logloss"][-1] < 1.15
+    # measured 1.0412 @30 rounds on example_data.multiclass()
+    assert evals["valid_0"]["multi_logloss"][-1] < 1.08
     pred = bst.predict(X)
     assert pred.shape == (len(y), 5)
     np.testing.assert_allclose(pred.sum(axis=1), 1.0, rtol=1e-5)
     acc = (pred.argmax(axis=1) == y).mean()
-    assert acc > 0.6
+    assert acc > 0.65  # measured 0.6871
 
 
 def test_lambdarank():
-    from lightgbm_tpu.io_utils import _load_libsvm
-    X, y = _load_libsvm(f"{EXAMPLES}/lambdarank/rank.train")
-    group = np.loadtxt(f"{EXAMPLES}/lambdarank/rank.train.query")
+    X, y, _, group = example_data.rank()[0]
     params = {"objective": "lambdarank", "metric": "ndcg", "verbosity": -1,
               "eval_at": [1, 3, 5]}
     evals = {}
@@ -100,12 +95,13 @@ def test_lambdarank():
     lgb.train(params, train, num_boost_round=30,
               valid_sets=[lgb.Dataset(X, label=y, group=group, reference=train)],
               evals_result=evals, verbose_eval=False)
-    assert evals["valid_0"]["ndcg@3"][-1] > 0.6
+    # measured 0.6973 after one round, 0.9939 after 30, on
+    # example_data.rank()'s train split (the validation set is the train set)
+    assert evals["valid_0"]["ndcg@3"][-1] > 0.95
 
 
 def test_early_stopping():
-    X, y = _load(f"{EXAMPLES}/binary_classification/binary.train")
-    Xt, yt = _load(f"{EXAMPLES}/binary_classification/binary.test")
+    X, y, Xt, yt = _arrays(example_data.binary())
     params = {"objective": "binary", "metric": "binary_logloss", "verbosity": -1}
     train = lgb.Dataset(X, label=y)
     bst = lgb.train(params, train, num_boost_round=500,
@@ -154,7 +150,7 @@ def test_categorical_feature():
 
 
 def test_goss():
-    X, y = _load(f"{EXAMPLES}/binary_classification/binary.train")
+    X, y, _, _ = _arrays(example_data.binary())
     params = {"objective": "binary", "boosting": "goss", "metric": "auc",
               "verbosity": -1, "learning_rate": 0.1}
     evals = {}
@@ -162,16 +158,15 @@ def test_goss():
     lgb.train(params, train, num_boost_round=30,
               valid_sets=[lgb.Dataset(X, label=y, reference=train)],
               evals_result=evals, verbose_eval=False)
-    # measured 0.8679.  Parity note (see tests/test_parity.py docstring): in
-    # this reference checkout GOSS never actually samples (gbdt.cpp:214 guard
-    # vs goss.hpp:129), so reference "goss" == plain gbdt == 0.8826 here;
-    # this repo implements the intended sampling, which costs ~0.015 train
-    # AUC at 30 rounds on this small dataset by design.
-    assert evals["valid_0"]["auc"][-1] > 0.86
+    # measured 0.9013 on example_data.binary()'s train split (plain gbdt at
+    # the same config: 0.9042).  This repo implements GOSS's intended
+    # sampling; what the reference checkout does instead is in
+    # tests/test_parity.py's docstring.
+    assert evals["valid_0"]["auc"][-1] > 0.893
 
 
 def test_bagging():
-    X, y = _load(f"{EXAMPLES}/binary_classification/binary.train")
+    X, y, _, _ = _arrays(example_data.binary())
     params = {"objective": "binary", "metric": "auc", "verbosity": -1,
               "bagging_fraction": 0.7, "bagging_freq": 1, "bagging_seed": 7}
     evals = {}
@@ -179,9 +174,8 @@ def test_bagging():
     lgb.train(params, train, num_boost_round=30,
               valid_sets=[lgb.Dataset(X, label=y, reference=train)],
               evals_result=evals, verbose_eval=False)
-    # measured 0.8817; reference at identical config measures 0.8821
-    # (parity verified in tests/test_parity.py)
-    assert evals["valid_0"]["auc"][-1] > 0.87
+    # measured 0.9049 on example_data.binary()'s train split
+    assert evals["valid_0"]["auc"][-1] > 0.893
 
 
 def test_model_save_load_roundtrip(tmp_path, binary_data):
@@ -224,25 +218,25 @@ def test_custom_objective(binary_data):
     bst = lgb.train(params, train, num_boost_round=30, fobj=logloss_obj,
                     verbose_eval=False)
     auc = _auc(yt, bst.predict(Xt, raw_score=True))
-    # test-split ceiling on this dataset is ~0.83 (see test_binary)
-    assert auc > 0.80
+    # measured 0.8033 on example_data.binary()'s test split (test_binary's
+    # 50 rounds reach 0.8073)
+    assert auc > 0.775
 
 
 def test_weights():
-    X, y = _load(f"{EXAMPLES}/binary_classification/binary.train")
-    w = np.loadtxt(f"{EXAMPLES}/binary_classification/binary.train.weight")
+    X, y, w, _ = example_data.binary()[0]
     params = {"objective": "binary", "metric": "auc", "verbosity": -1}
     evals = {}
     train = lgb.Dataset(X, label=y, weight=w)
     lgb.train(params, train, num_boost_round=20,
               valid_sets=[lgb.Dataset(X, label=y, weight=w, reference=train)],
               evals_result=evals, verbose_eval=False)
-    # measured 0.8574; reference at identical config measures 0.8575
-    assert evals["valid_0"]["auc"][-1] > 0.85
+    # measured 0.8917 on example_data.binary()'s train split and weights
+    assert evals["valid_0"]["auc"][-1] > 0.884
 
 
 def test_cv():
-    X, y = _load(f"{EXAMPLES}/binary_classification/binary.train")
+    X, y, _, _ = _arrays(example_data.binary())
     params = {"objective": "binary", "metric": "binary_logloss", "verbosity": -1}
     res = lgb.cv(params, lgb.Dataset(X, label=y), num_boost_round=10, nfold=3,
                  stratified=True, shuffle=True)
@@ -262,7 +256,7 @@ def test_feature_importance(binary_data):
 
 
 def test_dataset_save_binary(tmp_path):
-    X, y = _load(f"{EXAMPLES}/binary_classification/binary.train")
+    X, y, _, _ = _arrays(example_data.binary())
     ds = lgb.Dataset(X, label=y)
     ds.construct()
     path = str(tmp_path / "data.bin")
@@ -282,7 +276,7 @@ def _auc(y, p):
 
 
 def test_dart():
-    X, y = _load(f"{EXAMPLES}/binary_classification/binary.train")
+    X, y, _, _ = _arrays(example_data.binary())
     params = {"objective": "binary", "boosting": "dart", "metric": "auc",
               "verbosity": -1, "drop_rate": 0.5, "skip_drop": 0.0}
     evals = {}
@@ -291,14 +285,15 @@ def test_dart():
                     valid_sets=[lgb.Dataset(X, label=y, reference=train)],
                     evals_result=evals, verbose_eval=False)
     traj = evals["valid_0"]["auc"]
-    # drop_rate=0.5 + skip_drop=0 is aggressive dropout; measured 0.798
-    assert traj[-1] > 0.78
+    # drop_rate=0.5 + skip_drop=0 is aggressive dropout; measured 0.8525
+    # on example_data.binary()'s train split
+    assert traj[-1] > 0.834
     p = bst.predict(X)
     assert np.isfinite(p).all() and 0 <= p.min() and p.max() <= 1
 
 
 def test_random_forest():
-    X, y = _load(f"{EXAMPLES}/binary_classification/binary.train")
+    X, y, _, _ = _arrays(example_data.binary())
     params = {"objective": "binary", "boosting": "rf", "metric": "auc",
               "verbosity": -1, "bagging_freq": 1, "bagging_fraction": 0.6,
               "feature_fraction": 0.8}
@@ -307,8 +302,8 @@ def test_random_forest():
     bst = lgb.train(params, train, num_boost_round=20,
                     valid_sets=[lgb.Dataset(X, label=y, reference=train)],
                     evals_result=evals, verbose_eval=False)
-    # measured 0.8165; sklearn RandomForest at matched capacity gets 0.8121
-    assert evals["valid_0"]["auc"][-1] > 0.80
+    # measured 0.8721 on example_data.binary()'s train split
+    assert evals["valid_0"]["auc"][-1] > 0.855
     p = bst.predict(X)
     # averaged probabilities, not a boosted sum
     assert np.isfinite(p).all() and 0 <= p.min() and p.max() <= 1
@@ -319,7 +314,7 @@ def test_random_forest():
 
 
 def test_dart_rf_model_roundtrip(tmp_path):
-    X, y = _load(f"{EXAMPLES}/binary_classification/binary.train")
+    X, y, _, _ = _arrays(example_data.binary())
     for boosting, extra in (("dart", {"drop_rate": 0.3, "skip_drop": 0.2}),
                             ("rf", {"bagging_freq": 1, "bagging_fraction": 0.7})):
         params = {"objective": "binary", "verbosity": -1, "boosting": boosting,
@@ -393,7 +388,7 @@ def test_dart_boost_from_average_applied_once():
 def test_dart_continue_training_drops_only_new_trees(tmp_path):
     # reference: dart.hpp:108 drops num_init_iteration_ + i — init-model
     # trees are never dropped/rescaled during continued DART training
-    X, y = _load(f"{EXAMPLES}/binary_classification/binary.train")
+    X, y, _, _ = _arrays(example_data.binary())
     params = {"objective": "binary", "verbosity": -1, "num_leaves": 15}
     base = lgb.train(params, lgb.Dataset(X, label=y, free_raw_data=False),
                      num_boost_round=5)
@@ -439,8 +434,9 @@ def test_extra_trees(binary_data):
         and np.array_equal(mn.split_feature, mx.split_feature)
         for mn, mx in zip(bst_n.boosting.models, bst_x.boosting.models))
     assert not same, "extra_trees must alter threshold selection"
-    # and still learn (measured: 0.779 at 10 rounds; exact search 0.787)
-    assert ev_x["valid_0"]["auc"][-1] > 0.74
+    # and still learn (measured on example_data.binary()'s test split:
+    # 0.7935 at 10 rounds; exact search 0.7941)
+    assert ev_x["valid_0"]["auc"][-1] > 0.754
 
 
 def test_feature_fraction_bynode(binary_data):
@@ -463,8 +459,10 @@ def test_feature_fraction_bynode(binary_data):
     feats_bn = [m.split_feature.copy() for m in bst_bn.boosting.models]
     assert any(not np.array_equal(a, b) for a, b in zip(feats_full, feats_bn))
     # a single node sees only ~7 of 28 features, but across nodes coverage
-    # stays broad and the model still learns (measured: 0.798 at 10 rounds)
-    assert ev["valid_0"]["auc"][-1] > 0.76
+    # stays broad and the model still learns (measured on
+    # example_data.binary()'s test split: 0.7954 at 10 rounds; all
+    # features at every node 0.7995)
+    assert ev["valid_0"]["auc"][-1] > 0.757
 
 
 def test_refit(binary_data, tmp_path):

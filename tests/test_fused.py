@@ -657,23 +657,6 @@ def test_fused_monotone_scan_matches_staged():
                                   np.asarray(fb.threshold)))
 
 
-def test_fused_probe_json():
-    """tools/hist_probe.py --fused column: staged vs fused sec/level +
-    accounting fields ride the bench hist_probe stage journal."""
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "tools"))
-    from hist_probe import run_probe
-    out = run_probe(rows=8000, features=6, max_bin=15, quant_bins=4,
-                    leaves=15, reps=1)
-    f = out["fused"]
-    assert f["hist_scan_traffic_bytes"] > 0
-    assert f["best_tuple_payload_bytes"] == 6 * 6 * 4
-    assert "staged" in f and "fused" in f
-    if "error" not in f["fused"]:
-        assert f["fused"]["sec_per_level"] > 0
-
-
 def test_histogram_pallas_tile_rows_parity():
     """Satellite: the bin-only Pallas kernel under the tile_rows regime —
     capping the block must leave results equal to the scatter reference,

@@ -6,8 +6,8 @@ types, categorical lookup, EFB bundles, uint8/uint16 group dtypes and
 ragged last blocks.  Off-accelerator the kernel interprets as the same
 jnp math, so these tests pin ``LGBM_TPU_INGEST_KERNEL=kernel`` (the
 bisect gate) to force the device arm on tiny CPU-sized data; the
-planner tests exercise the ``"i-..."`` autotune family, the ledger
-budget arm, and the env pins directly.
+planner tests exercise the analytic election, the ledger budget arm,
+and the env pins directly.
 """
 
 import numpy as np
@@ -220,7 +220,7 @@ def test_chunk_election_under_tight_ledger(monkeypatch):
     assert small.chunk_rows < big.chunk_rows
     assert small.chunk_rows >= P.MIN_BUCKET_ROWS
     assert big.chunk_rows <= P.MAX_INGEST_CHUNK_ROWS
-    # chunks are ladder rungs: stable autotune keys across nearby shapes
+    # chunks are ladder rungs: nearby shapes share one compiled program
     assert small.chunk_rows == P.bucket_rows(small.chunk_rows)
 
 
@@ -250,7 +250,6 @@ def test_variant_env_gate(monkeypatch):
 
 def test_analytic_election_host_off_accelerator(monkeypatch):
     monkeypatch.delenv("LGBM_TPU_INGEST_KERNEL", raising=False)
-    monkeypatch.setenv("LGBM_TPU_AUTOTUNE", "0")
     off = P.plan_ingest(rows=1_000_000, features=28, num_groups=28,
                         accel=False)
     assert (off.variant, off.elected_by) == ("host", "analytic")
@@ -261,28 +260,6 @@ def test_analytic_election_host_off_accelerator(monkeypatch):
                          features=P.MAX_INGEST_KERNEL_FEATURES + 1,
                          num_groups=28, accel=True)
     assert wide.variant == "host"     # unrolled kernel stops paying
-
-
-def test_measured_election_and_counters(monkeypatch, tmp_path):
-    monkeypatch.setenv("LGBM_TPU_AUTOTUNE_DIR", str(tmp_path))
-    monkeypatch.delenv("LGBM_TPU_INGEST_KERNEL", raising=False)
-    kw = dict(rows=1_000_000, features=28, num_groups=28, item_bytes=1)
-    P.autotune_counters(reset=True)
-    cold = P.plan_ingest(accel=True, **kw)
-    assert cold.measured_variant == ""
-    assert cold.autotune_key.startswith("i-")
-    P.record_ingest_timing(variant="host", seconds=0.01, **kw)
-    P.record_ingest_timing(variant="kernel", seconds=0.5, **kw)
-    warm = P.plan_ingest(accel=True, **kw)
-    assert (warm.variant, warm.elected_by) == ("host", "measured")
-    c = P.autotune_counters()
-    assert c["hits"] >= 1 and c["misses"] >= 1 and c["flips"] >= 1
-    # the stopwatch flips back when the kernel wins
-    P.record_ingest_timing(variant="kernel", seconds=0.001, **kw)
-    assert P.plan_ingest(accel=True, **kw).variant == "kernel"
-    # unknown variant names in the store are skipped, not adopted
-    P.record_ingest_timing(variant="warp9", seconds=1e-9, **kw)
-    assert P.plan_ingest(accel=True, **kw).variant == "kernel"
 
 
 def test_ingest_vmem_model_monotone():

@@ -171,7 +171,7 @@ def test_traced_rule_covers_kwonly_and_posonly_params(tmp_path):
 
 def test_json_verdict_schema():
     """The last stdout line is machine-readable with the documented
-    keys/types (the bench lint stage and CI parse this)."""
+    keys/types (CI parses this)."""
     for args in ((), ("tests/lint_fixtures",)):
         r, verdict = run_cli(*args)
         assert verdict is not None, r.stdout
@@ -222,21 +222,19 @@ def test_env_registry_is_complete_and_documented():
         assert flag.name in doc, \
             f"{flag.name} missing from {flag.docfile}"
     # registry-backed accessor honors env + default
-    assert envflags.get("BENCH_SMOKE_TREES") == "3"
+    assert envflags.get("LGBM_TPU_FUSED") == "1"
     with pytest.raises(KeyError):
         envflags.get("LGBM_TPU_NOT_A_FLAG_EVER")
 
 
-def test_bench_lint_stage_shape():
-    """The bench 'lint' stage journals a clean verdict and raises (->
-    never journaled) on a dirty tree: the Python API the stage uses
-    agrees with the two cached CLI runs."""
+def test_run_lint_api_agrees_with_cli():
+    """The Python API (``load_project`` + ``run_lint``) holds the project
+    clean and agrees with the two cached CLI runs on the dirty corpus."""
     from tools.lint import load_project, run_lint
     project = load_project(root=REPO)
     violations = run_lint(project)
     assert violations == []
-    # dirty-tree path: the corpus is dirty through the same API the
-    # stage calls (CLI agreement already asserted above)
+    # dirty-tree path (CLI agreement already asserted above)
     _r, verdict = run_cli("tests/lint_fixtures")
     assert verdict["violations"] > 0
 

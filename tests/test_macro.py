@@ -436,16 +436,3 @@ def test_release_host_binned(monkeypatch):
     lgb.Booster(params={"objective": "binary", "num_leaves": 15,
                         "verbosity": -1}, train_set=ds2)
     assert ds2.binned is not None
-
-
-@pytest.mark.perf
-def test_dispatch_probe_json():
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "tools"))
-    from dispatch_probe import run_probe
-    out = run_probe(rows=4000, features=8, leaves=15, iters=4, chunks=(4,))
-    assert out["dispatch_ms"] > 0
-    assert out["per_iter"]["iters_per_sec"] > 0
-    assert out["fused"]["4"]["iters_per_sec"] > 0
-    assert "speedup_vs_per_iter" in out["fused"]["4"]

@@ -318,30 +318,6 @@ def test_refresh_many_matches_serial_candidates(tmp_path):
     assert [c.model_to_string() for c in cands] == solos
 
 
-def test_sweep_probe_reports():
-    import sys
-    sys.path.insert(0, str(__import__("pathlib").Path(
-        __file__).resolve().parents[1] / "tools"))
-    from sweep_probe import run_probe
-    out = run_probe(rows=2000, features=6, max_bin=15, leaves=7,
-                    chunk=2, reps=1, widths=(1, 2))
-    for B in (1, 2):
-        assert out[f"B{B}"]["iters_per_sec"] > 0
-    assert out["model_batch_plan"]["b_total"] == 2
-    assert out["aggregate_speedup_vs_b1"] > 0
-    assert "accel" in out
-
-
-@pytest.mark.obs
-def test_devprof_batched_row():
-    from lightgbm_tpu.obs.devprof import histogram_utilization_table
-    t = histogram_utilization_table(rows=1500, features=4, num_bins=8,
-                                    reps=1, quant=False)
-    row = t["f32/scatter_batched8/untiled"]
-    assert "error" not in row
-    assert row["seconds_per_call"] > 0
-
-
 @pytest.mark.fleet
 def test_fleet_swaps_sweep_winner():
     """The sweep winner hot-swaps into a serving Fleet through the

@@ -462,30 +462,6 @@ def test_elastic_shrink_resume_end_to_end(monkeypatch, tmp_path):
     assert bst2.model_to_string() == bst3.model_to_string()
 
 
-# ------------------------------------------------------------- probe
-
-
-def test_collective_probe_json():
-    import sys
-    sys.path.insert(0, os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "tools"))
-    from collective_probe import run_probe
-    out = run_probe(rows=4096, features=8, max_bin=31, trees=10,
-                    num_slices=2, top_k=4, reps=1)
-    assert out["mesh_shape"] == [2, 4]
-    for payload in ("f32", "quant"):
-        sec = out[payload]
-        assert sec["voting_dcn_below_data"]
-        assert sec["voting_parallel"]["dcn_bytes"] \
-            < sec["data_parallel"]["dcn_bytes"]
-        assert sec["data_parallel"]["dcn_bytes_total"] > 0
-    assert out["quant"]["payload_bytes"] < out["f32"]["payload_bytes"]
-    assert {"hierarchy_elected", "ici_bytes", "dcn_bytes",
-            "voting_k"} <= out.keys()
-    json.dumps(out)                      # journal-able
-
-
 # ------------------------------------------------------------- stress
 
 

@@ -387,57 +387,6 @@ def test_timer_publish_mirrors_registry():
     assert g["timer.Pub::X.total_s"] >= 0
 
 
-# ---------------------------------------------------------------- devprof
-
-
-def test_devprof_measures_a_program():
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.obs.devprof import measure_program, program_cost
-
-    a = jnp.ones((128, 128), jnp.float32)
-
-    def f(x):
-        return x @ x
-
-    m = measure_program(f, (a,), reps=1)
-    assert m["seconds_per_call"] > 0
-    # a CPU run is a share of no device: no peak, no utilization
-    assert not {"peak_flops", "peak_hbm_bw", "mfu", "hbm_util"} & set(m)
-    cost = program_cost(f, a)
-    if not cost:        # backend without a cost model: degrade, not fail
-        pytest.skip("cost_analysis unavailable on this backend")
-    assert cost["flops"] > 0
-    assert m["flops"] == cost["flops"]
-
-
-def test_devprof_histogram_table_small():
-    from lightgbm_tpu.obs.devprof import histogram_utilization_table
-
-    t = histogram_utilization_table(rows=2000, features=6, num_bins=16,
-                                    slots=4, reps=1, quant=True)
-    keys = [k for k in t if "/" in k]
-    # the full family x {f32, quant} x {untiled, tiled}, incl. the
-    # Pallas rows (bin-only VPU kernel + fused megakernel), the 8-lane
-    # model-axis row (f32/scatter_batched8) and the collective-seam
-    # rows (accumulate → {flat, hierarchical} reduce → sibling scan)
-    assert len(keys) == 34
-    for fam in ("f32/pallas", "f32/fused", "quant/fused",
-                "f32/scatter_batched8", "f32/fused_sharded_flat",
-                "f32/fused_sharded_hier", "quant/fused_sharded_flat",
-                "quant/fused_sharded_hier"):
-        assert f"{fam}/untiled" in t and f"{fam}/tiled" in t
-    for k in keys:
-        v = t[k]
-        assert "error" in v or v["seconds_per_call"] > 0, (k, v)
-    timed = [k for k in keys if "error" not in t[k]]
-    assert timed, "every variant errored"
-    # the fused rows must actually measure (interpret mode on CPU), not
-    # error out — they are the bench's acceptance figure
-    assert "seconds_per_call" in t["f32/fused/untiled"], t["f32/fused/untiled"]
-    assert "seconds_per_call" in t["quant/fused/tiled"], t["quant/fused/tiled"]
-
-
 def test_obs_dump_tool(tmp_path):
     import os
     import sys
@@ -464,17 +413,3 @@ def test_obs_dump_tool(tmp_path):
     # the dump restored the disabled-by-default state
     assert not global_tracer.enabled or os.environ.get(
         "LIGHTGBM_TPU_TRACE")
-
-
-def test_bench_mfu_estimate_guards_zero_peak():
-    """Satellite: bench.py's MFU estimate must not divide by an unknown
-    device's zero peak."""
-    import os
-    import sys
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, repo)
-    import bench
-
-    assert bench.mfu_estimate(1000, 28, 63, 255, 0.5, 0.0) == 0.0
-    assert bench.mfu_estimate(1000, 28, 63, 255, 0.5, -1.0) == 0.0
-    assert bench.mfu_estimate(1000, 28, 63, 255, 0.5, 197e12) > 0.0

@@ -5,7 +5,7 @@ train the same data through this package and through stock LightGBM
 (built from /root/reference by tools/build_reference.sh, staged at
 /tmp/refpkg) and compare metric trajectories and model-text cross-loading.
 
-Skipped wholesale when the reference lib is absent (CI/bench images build it
+Skipped wholesale when the reference lib is absent (CI images build it
 once; ~2 min).  The reference package is pure ctypes so importing it next to
 the JAX stack is safe.
 

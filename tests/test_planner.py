@@ -161,7 +161,7 @@ def test_booster_exposes_plan(monkeypatch):
 
 
 # ======================================================================
-# Measured-timings autotuner + shape-bucket ladder (the compile-time war)
+# Shape-bucket ladder (the compile-time war)
 
 def test_bucket_rows_ladder():
     """Rungs are {2^k, 1.5*2^k} with a 4096 floor: pad waste is bounded
@@ -176,136 +176,6 @@ def test_bucket_rows_ladder():
     for n in (4096, 6144, 8192, 12288, 1 << 20):
         assert bucket_rows(n) == n
         assert bucket_rows(bucket_rows(n + 1)) == bucket_rows(n + 1)
-
-
-def test_autotune_warm_election_flip_and_promotion(tmp_path, monkeypatch):
-    """Cold query = analytic + a miss; banked measurements flip the
-    election to the stopwatch's winner; apply_plan promotes a measured
-    point method into the grower config."""
-    from lightgbm_tpu.grower import GrowerConfig
-    from lightgbm_tpu.ops import planner as P
-    monkeypatch.setenv("LGBM_TPU_AUTOTUNE_DIR", str(tmp_path))
-    shape = (50_000, 12, 64, True, 8)   # rows, F, B, quant, round_width
-    P.autotune_counters(reset=True)
-    plan = P.plan_histograms(50_000, 12, 64, quant=True, method="auto",
-                             round_width=8)
-    assert plan.elected_by == "analytic"
-    assert P.autotune_counters()["misses"] == 1
-    # bank measurements: matmul_int8 fastest (differs from the CPU
-    # analytic scatter_int, so the adoption is also a FLIP)
-    assert P.record_timing(*shape, "scatter_int", 0.05) is not None
-    assert P.record_timing(*shape, "matmul_int8", 0.01) is not None
-    plan2 = P.plan_histograms(50_000, 12, 64, quant=True, method="auto",
-                              round_width=8)
-    assert plan2.elected_by == "measured"
-    assert plan2.variant == "matmul_int8"
-    assert plan2.measured_variant == "matmul_int8"
-    assert plan2.autotune_key == P.shape_bucket_key(*shape)
-    c = P.autotune_counters()
-    assert c["hits"] == 1 and c["misses"] == 1 and c["flips"] == 1
-    last = P.autotune_last()
-    assert last["elected_by"] == "measured"
-    assert last["elected_variant"] == "matmul_int8"
-    # a row count in the SAME bucket reuses the measurement (the whole
-    # point of bucketed keys: exact-shape keys would never warm up)
-    plan3 = P.plan_histograms(50_001, 12, 64, quant=True, method="auto",
-                              round_width=8)
-    assert plan3.elected_by == "measured"
-    # apply_plan promotes the measured POINT method into hist_method
-    cfg = GrowerConfig(num_leaves=15, num_bins=64, round_width=8,
-                       hist_method="auto", quant=True, quant_bins=8)
-    cfg2, _ = apply_plan(cfg, 50_000, 12)
-    assert cfg2.hist_method == "matmul_int8"
-    # an explicit method ignores the store entirely
-    plan4 = P.plan_histograms(50_000, 12, 64, quant=True,
-                              method="scatter_int", round_width=8)
-    assert plan4.elected_by == "analytic"
-
-
-def test_autotune_measured_staged_family_verdict(tmp_path, monkeypatch):
-    """A "staged" family verdict declines fused even when its arena
-    fits; a "fused" verdict only adopts when the VMEM election passed,
-    and measured kernel params override the analytic walk."""
-    from lightgbm_tpu.ops import planner as P
-    monkeypatch.setenv("LGBM_TPU_AUTOTUNE_DIR", str(tmp_path))
-    shape = (40_000, 8, 64, False, 8)
-    P.record_timing(*shape, "fused", 0.01,
-                    params={"feat_tile": 2, "block_rows": 128})
-    plan = P.plan_histograms(40_000, 8, 64, method="auto", round_width=8,
-                             fused_ok=True)
-    assert plan.fused and plan.elected_by == "measured"
-    assert plan.fused_feat_tile == 2 and plan.fused_block_rows == 128
-    # staged measured faster -> fused declined though the arena fits
-    P.record_timing(*shape, "staged", 0.001)
-    plan2 = P.plan_histograms(40_000, 8, 64, method="auto", round_width=8,
-                              fused_ok=True)
-    assert not plan2.fused and plan2.elected_by == "measured"
-    assert plan2.variant != "fused"
-    # without fused_ok the "fused" record cannot be adopted (no VMEM
-    # election ran) -> miss, analytic
-    (tmp_path / "hist_timings.json").unlink()
-    P.record_timing(*shape, "fused", 0.01)
-    plan3 = P.plan_histograms(40_000, 8, 64, method="auto", round_width=8)
-    assert plan3.elected_by == "analytic" and not plan3.fused
-
-
-def test_autotune_corrupt_store_is_a_miss(tmp_path, monkeypatch):
-    """Satellite: a corrupt, truncated, wrong-version or stale-named
-    store entry is a MISS, never a crash — and the next record_timing
-    rewrites a clean store through write_atomic."""
-    import json as _json
-    from lightgbm_tpu.ops import planner as P
-    monkeypatch.setenv("LGBM_TPU_AUTOTUNE_DIR", str(tmp_path))
-    store = tmp_path / "hist_timings.json"
-    shape = (50_000, 12, 64, True, 8)
-    for garbage in ("{not json", "", "[1, 2, 3]",
-                    _json.dumps({"version": 999, "entries": {
-                        P.shape_bucket_key(*shape): {
-                            "scatter_int": {"seconds": 0.01}}}}),
-                    _json.dumps({"version": 1, "entries": "nope"})):
-        store.write_text(garbage, encoding="utf-8")
-        assert P.measured_election(*shape) is None
-        P.autotune_counters(reset=True)
-        plan = P.plan_histograms(50_000, 12, 64, quant=True,
-                                 method="auto", round_width=8)
-        assert plan.elected_by == "analytic", garbage[:20]
-        assert P.autotune_counters()["misses"] == 1
-    # a stale variant NAME inside a well-formed store is also a miss
-    store.write_text(_json.dumps({"version": 1, "entries": {
-        P.shape_bucket_key(*shape): {
-            "kernel_deleted_in_pr9": {"seconds": 0.001}}}}),
-        encoding="utf-8")
-    P.autotune_counters(reset=True)
-    plan = P.plan_histograms(50_000, 12, 64, quant=True, method="auto",
-                             round_width=8)
-    assert plan.elected_by == "analytic"
-    assert P.autotune_counters() == {"hits": 0, "misses": 1, "flips": 0}
-    # recovery: record_timing read-merges {} from the bad store and
-    # lands a clean versioned document atomically
-    store.write_text("{torn", encoding="utf-8")
-    P.record_timing(*shape, "scatter_int", 0.02)
-    doc = _json.loads(store.read_text(encoding="utf-8"))
-    assert doc["version"] == 1
-    assert P.measured_election(*shape)["variant"] == "scatter_int"
-
-
-def test_autotune_disabled_and_no_store(monkeypatch):
-    """LGBM_TPU_AUTOTUNE=0 skips the election entirely; with the store
-    switched off (LGBM_TPU_AUTOTUNE_DIR=off) record_timing is a no-op and
-    elections are cold.  Unset, the store lives beside the compile cache."""
-    from lightgbm_tpu.ops import planner as P
-    from lightgbm_tpu.utils.platform import compile_cache_dir
-    monkeypatch.delenv("LGBM_TPU_AUTOTUNE_DIR", raising=False)
-    assert P.autotune_dir() == os.path.join(compile_cache_dir(), "autotune")
-    monkeypatch.setenv("LGBM_TPU_AUTOTUNE_DIR", "off")
-    assert P.autotune_dir() is None
-    assert P.record_timing(10_000, 8, 64, False, 8, "scatter", 0.01) is None
-    assert P.measured_election(10_000, 8, 64, False, 8) is None
-    monkeypatch.setenv("LGBM_TPU_AUTOTUNE", "0")
-    P.autotune_counters(reset=True)
-    plan = P.plan_histograms(10_000, 8, 64, method="auto", round_width=8)
-    assert plan.elected_by == "analytic"
-    assert P.autotune_counters() == {"hits": 0, "misses": 0, "flips": 0}
 
 
 def test_shape_bucket_quant_model_parity(monkeypatch):

@@ -26,14 +26,12 @@ def as_accelerator(monkeypatch):
 
 def test_cache_rule_env_set_program_sets_no_directory(tmp_path, monkeypatch):
     """JAX_COMPILATION_CACHE_DIR set => JAX's own handling is the whole
-    story: the program resolves that directory (and hangs the AOT and
-    autotune stores off it) but never sets one."""
+    story: the program resolves that directory (and hangs the AOT
+    store off it) but never sets one."""
     import jax
 
     from lightgbm_tpu.fleet.aot import aot_dir_from_env
-    from lightgbm_tpu.ops.planner import autotune_dir
     from lightgbm_tpu.utils import platform as PF
-    monkeypatch.delenv("LGBM_TPU_AUTOTUNE_DIR", raising=False)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     updates = []
     real_update = jax.config.update
@@ -45,7 +43,6 @@ def test_cache_rule_env_set_program_sets_no_directory(tmp_path, monkeypatch):
     assert "jax_compilation_cache_dir" not in updates
     assert jax.config.jax_compilation_cache_dir == prev
     assert aot_dir_from_env() == str(tmp_path / "serving")
-    assert autotune_dir() == str(tmp_path / "autotune")
 
 
 def test_cache_rule_env_unset_fixed_path_across_processes():
@@ -79,11 +76,9 @@ def test_cache_entries_exclude_reserved_subtrees(tmp_path):
     (tmp_path / "blob-a").write_text("x")
     (tmp_path / "serving").mkdir()
     (tmp_path / "serving" / "m-b8.bin").write_text("x")
-    (tmp_path / "autotune").mkdir()
-    (tmp_path / "autotune" / "hist_timings.json").write_text("{}")
     assert compile_cache_entries(str(tmp_path)) == 1
     assert compile_cache_entries_by_family(str(tmp_path)) == {
-        "jit": 1, "serving_aot": 1, "autotune": 1}
+        "jit": 1, "serving_aot": 1}
 
 
 def test_cpu_mesh_env_replaces_the_device_count():
@@ -103,18 +98,6 @@ class _Dev:
 
     def __init__(self, kind):
         self.device_kind = kind
-
-
-def test_peak_tables_raise_on_an_unknown_device_kind():
-    from lightgbm_tpu.obs import devprof
-    assert devprof.peak_flops_for(_Dev("TPU v5 lite")) == 197e12
-    assert devprof.peak_hbm_bw_for(_Dev("TPU v5 lite")) == 819e9
-    for fn in (devprof.peak_flops_for, devprof.peak_hbm_bw_for):
-        with pytest.raises(ValueError, match="no peak figure"):
-            fn(_Dev("TPU v99 imaginary"))
-        with pytest.raises(ValueError, match="no peak figure"):
-            fn()            # the live device here is a CPU
-    assert not hasattr(devprof, "DEFAULT_PEAK_FLOPS")
 
 
 def test_hbm_limit_is_not_guessed_on_an_accelerator(monkeypatch,

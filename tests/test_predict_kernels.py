@@ -202,7 +202,7 @@ def test_epilogue_env_pin(models, monkeypatch):
 
 
 # ----------------------------------------------------------------------
-# planner election: env gates, byte models, measured store
+# planner election: env gates, byte models
 # ----------------------------------------------------------------------
 
 
@@ -232,33 +232,6 @@ def test_chunk_respects_budget():
     big = P.elect_predict_chunk(64, 255, 256, 32, budget=1 << 40)
     assert small == P.MIN_BUCKET_ROWS
     assert small <= big <= P.MAX_PREDICT_CHUNK_ROWS
-
-
-def test_predict_bucket_key_namespace():
-    key = P.predict_bucket_key(100_000, 12, 40, 1, "f32")
-    assert key.startswith("p-")            # never collides with hist keys
-    assert key == P.predict_bucket_key(100_001, 12, 40, 1, "f32")  # rung
-
-
-def test_measured_predict_election_roundtrip(tmp_path):
-    store = str(tmp_path)                    # a store DIRECTORY
-    store_file = P._autotune_path(store)
-    shape = dict(rows=50_000, features=12, num_trees=40, num_class=1,
-                 precision="f32")
-    assert P.measured_predict_election(path=store, **shape) is None
-    P.record_predict_timing(variant="fori", seconds=0.5, path=store, **shape)
-    P.record_predict_timing(variant="fused", seconds=0.2, path=store, **shape)
-    P.record_predict_timing(variant="while", seconds=1.5, path=store, **shape)
-    best = P.measured_predict_election(path=store, **shape)
-    assert best["variant"] == "fused"
-    # a future store's unknown variant name is skipped, not adopted
-    with open(store_file) as fh:
-        d = json.load(fh)
-    d["entries"][best["key"]]["warp9"] = {"seconds": 0.01}
-    with open(store_file, "w") as fh:
-        json.dump(d, fh)
-    assert P.measured_predict_election(path=store, **shape)["variant"] == \
-        "fused"
 
 
 def test_fused_tile_ladder_fits_or_none():

@@ -435,25 +435,6 @@ def test_quant_rounds_grower_sorted_arena(monkeypatch):
     assert _auc(Y_BIN, pred) > 0.8
 
 
-# ----------------------------------------------------------- probe
-
-
-def test_hist_probe_json():
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(HERE), "tools"))
-    from hist_probe import run_probe
-    out = run_probe(rows=20000, features=8, max_bin=31, quant_bins=4,
-                    leaves=31, reps=1)
-    assert out["quant_method"] in ("matmul_int8", "scatter_int")
-    assert out["f32"]["ms_per_pass"] > 0
-    assert out["quant"]["ms_per_pass"] > 0
-    # the headline claim: quantized histogram psum payload is smaller
-    assert out["quant"]["psum_payload_bytes"] < \
-        out["f32"]["psum_payload_bytes"]
-    assert out["payload_shrink"] > 1.0
-    assert out["rescale_abs_err"]["ok"]
-
-
 def test_compacted_int_caps_ladder():
     """The bucketed-capacity integer gather path (lax.switch over the
     static cap ladder) matches the full masked pass for a sparse member

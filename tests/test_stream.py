@@ -425,20 +425,3 @@ def test_stream_plan_in_manifest_summary_roundtrips():
                     device_budget_bytes=1 << 24, host_budget_bytes=1 << 40)
     s = p.summary()
     assert json.loads(json.dumps(s)) == s
-
-
-# ----------------------------------------------------------- tooling
-
-@pytest.mark.perf
-def test_stream_probe_json():
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "tools"))
-    from stream_probe import run_probe
-    out = run_probe(rows=60_000, features=6, block_rows=8192, passes=1)
-    assert out["spill"]["rows_per_sec"] > 0
-    assert out["pump"]["blocks_per_sec"] > 0
-    assert out["pump"]["overlap_efficiency"] > 0
-    assert out["host_rss"]["predicted_stream_peak_bytes"] > 0
-    assert host_rss_bytes() > 0
-    json.dumps(out)

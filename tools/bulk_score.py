@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Bulk offline scoring smoke + bench: blockstore -> scores, with a
+"""Bulk offline scoring smoke: blockstore -> scores, with a
 crash-resume drill.
 
 Builds a synthetic float32 feature BlockStore (streamed to disk in
@@ -18,12 +18,10 @@ and drives ``data/score.BulkScorer`` through it twice:
    score bytes — the resume acceptance bar).
 
 Off-accelerator the row count is capped (interpret-mode fused kernels
-and a single host core make 10M rows pointless); the accelerator bench
-worker runs the real >= 10M-row shape via ``BENCH_BULK_ROWS``.
+and a single host core make 10M rows pointless); on an accelerator
+``--rows`` runs the real >= 10M-row shape.
 
-The LAST stdout line is a single JSON object so bench.py's worker can
-bank it as a stage (``stage: bulk_score``; ``BENCH_SKIP_BULK_SCORE=1``
-skips the stage).
+The LAST stdout line is a single JSON object (``stage: bulk_score``).
 
 Usage:
     JAX_PLATFORMS=cpu python tools/bulk_score.py \

@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Co-residency smoke: loadgen traffic AND continual refresh on the SAME
 device set, behind the shared residency ledger — the CLI twin of
-tests/test_coresident.py and the bench ``coresident`` stage (bench.py
-imports ``run_smoke``).  Stdout ends with one JSON summary object.
+tests/test_coresident.py.  Stdout ends with one JSON summary object.
 
 Phases (each banks its own sub-dict in the summary):
 

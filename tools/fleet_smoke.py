@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Fleet smoke: N-model serve, planner-driven eviction, AOT restart,
 and opt-in low-precision — the CLI twin of tests/test_fleet.py, for
-eyeballs, CI logs, and the bench `fleet` stage (bench.py imports
-``run_smoke``).  The LAST stdout line is a single JSON object.
+eyeballs and CI logs.  The LAST stdout line is a single JSON object.
 
 Phases (each banks its own sub-dict in the summary):
 
@@ -20,9 +19,8 @@ Phases (each banks its own sub-dict in the summary):
 * ``lowprec`` — register bf16 and int8 twins of a model under a
   declared accuracy budget; journal the measured deltas; demonstrate
   the quarantine by offering an int8 model a budget of 0.
-* ``failover`` (``--devices N``, N >= 2; the bench ``fleet_failover``
-  stage) — stand up a replicated ``PodFleet`` over N simulated
-  devices, fire a threaded traffic storm, KILL one device mid-run
+* ``failover`` (``--devices N``, N >= 2) — stand up a replicated
+  ``PodFleet`` over N simulated devices, fire a threaded traffic storm, KILL one device mid-run
   (chaos ``device`` site), and assert the acceptance bars: ZERO
   non-typed request failures, availability >= 0.999, every response
   bit-equal to ``Booster.predict(raw_score=True)``, and recovery

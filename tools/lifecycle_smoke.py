@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Lifecycle smoke: train -> continual refresh -> guarded promotion ->
-forced rollback — the CLI twin of tests/test_lifecycle.py, for eyeballs,
-CI logs, and the bench ``lifecycle`` stage (bench.py imports
-``run_smoke``).  The LAST stdout line is a single JSON object.
+forced rollback — the CLI twin of tests/test_lifecycle.py, for eyeballs
+and CI logs.  The LAST stdout line is a single JSON object.
 
 Phases (each banks its own sub-dict in the summary):
 

@@ -10,8 +10,8 @@ equivalent)::
     python tools/lint.py --list-rules
 
 Output: one human line per violation (``path:line: [rule] message``),
-then a LAST-LINE JSON verdict (the same contract tools/bench_diff.py
-and tools/obs_doctor.py follow)::
+then a LAST-LINE JSON verdict (the same contract tools/obs_doctor.py
+follows)::
 
     {"tool": "tpulint", "files": N, "violations": M,
      "by_rule": {"atomic-write": 2, ...}, "ok": false}

@@ -112,10 +112,10 @@ class Rule:
 
 # ------------------------------------------------------------ file walking
 
-# the default scan set: the library, the bench driver, the operator
-# tools and the graft entry; tests/ seed env vars and raw writes on
-# purpose and are excluded (pass paths explicitly to lint them)
-DEFAULT_ROOTS = ("lightgbm_tpu", "tools", "bench.py", "__graft_entry__.py")
+# the default scan set: the library, the operator tools and the graft
+# entry; tests/ seed env vars and raw writes on purpose and are
+# excluded (pass paths explicitly to lint them)
+DEFAULT_ROOTS = ("lightgbm_tpu", "tools", "__graft_entry__.py")
 _SKIP_DIRS = {"__pycache__", ".git", ".pytest_cache", "build", "dist"}
 
 

@@ -3,7 +3,7 @@
 Three failure modes, each named after the offending flag:
 
 1. a string literal matching the flag grammar
-   (``LGBM_TPU_*`` / ``LIGHTGBM_TPU_*`` / ``LGBT_*`` / ``BENCH_*``)
+   (``LGBM_TPU_*`` / ``LIGHTGBM_TPU_*`` / ``LGBT_*``)
    appears in scanned code but not in
    ``lightgbm_tpu/utils/envflags.FLAGS`` — an unregistered knob;
 2. a registered flag's name is absent from its declared doc file — an
@@ -30,7 +30,7 @@ from typing import Dict, List, Tuple
 from .core import Project, Rule, Violation
 
 _FLAG_RE = re.compile(
-    r"^(LGBM_TPU_|LIGHTGBM_TPU_|LGBT_|BENCH_)[A-Z0-9_]+$")
+    r"^(LGBM_TPU_|LIGHTGBM_TPU_|LGBT_)[A-Z0-9_]+$")
 
 # the registry itself spells every name; the lint package spells the
 # prefixes and fixture names in rule docs/tests
@@ -60,7 +60,7 @@ def load_registry(root: str) -> Dict[str, object]:
 
 class EnvFlagRegistryRule(Rule):
     name = "env-flag-registry"
-    doc = ("every LGBM_TPU_*/LIGHTGBM_TPU_*/BENCH_* literal must be "
+    doc = ("every LGBM_TPU_*/LIGHTGBM_TPU_*/LGBT_* literal must be "
            "registered in lightgbm_tpu/utils/envflags.py and documented "
            "in its declared doc file")
 
@@ -93,7 +93,7 @@ class EnvFlagRegistryRule(Rule):
                         "entry with default, consumer and doc anchor)"))
         # registered but undocumented / stale.  Word-boundary match: a
         # short flag must not pass because a longer flag it prefixes
-        # (BENCH_SKIP_STREAM vs BENCH_SKIP_STREAM_PROBE) is documented
+        # (LGBM_TPU_STREAM vs LGBM_TPU_STREAM_BLOCK_ROWS) is documented
         reg_file = "lightgbm_tpu/utils/envflags.py"
         doc_cache: Dict[str, str] = {}
         for name, flag in sorted(flags.items()):

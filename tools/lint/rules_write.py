@@ -1,7 +1,7 @@
 """atomic-write: durable writes route through ``file_io.write_atomic``.
 
 Every persistence claim in the tree (checkpoint bundles, blockstore
-manifests, AOT exports, the bench journal, flight bundles) rests on the
+manifests, AOT exports, flight bundles) rests on the
 temp-sibling + fsync + ``os.replace`` discipline in
 ``lightgbm_tpu/utils/file_io.write_atomic`` — a reader never observes a
 truncated file.  A raw ``open(path, "w")`` silently opts out of that

@@ -1,22 +1,24 @@
 #!/usr/bin/env python
-"""obs_doctor: automated bottleneck diagnosis over the banked bench
-journal + a metrics snapshot (lightgbm_tpu/obs/diagnose.py,
-docs/OBSERVABILITY.md verdict catalogue).
+"""obs_doctor: automated bottleneck diagnosis over a journal of banked
+stages + a metrics snapshot (lightgbm_tpu/obs/diagnose.py,
+docs/OBSERVABILITY.md verdict catalogue).  No program of this repository
+writes such a journal any more (ROADMAP.md C1); a registry snapshot
+(tools/obs_dump.py) or the live registry is the input that exists.
 
-Joins measured signals (devprof MFU tables, compile-cache warmth,
-stream-probe overlap efficiency, straggler skew) with
+Joins measured signals (MFU tables, compile-cache warmth,
+streaming overlap efficiency, straggler skew) with
 planner-predicted ones (per-tier ICI/DCN payload bytes, link models)
 and prints RANKED verdicts — "DCN-bound", "compile-bound",
 "input-bound", "straggler slice k", "contention" (co-resident train vs
 serve fighting over the same devices; evidence carries the residency
 ledger's lease table + brownout throttle/pause counts), and
-"kernel-underutilized" — each with the evidence behind it.  The LAST stdout line is one JSON summary (the
-shape the bench journals as the ``obs_doctor`` stage).
+"kernel-underutilized" — each with the evidence behind it.  The LAST
+stdout line is one JSON summary.
 
 Usage:
     python tools/obs_doctor.py \
-        [--journal bench_journal.json]   # banked bench stages
-        [--metrics bench_out/bench_obs_metrics.json]  # registry snapshot
+        [--journal journal.json]         # banked stages
+        [--metrics obs_metrics.json]     # registry snapshot (obs_dump)
         [--json-only]                    # machine consumers
 Exit codes: 0 = diagnosed (whatever the verdict), 2 = input unreadable.
 """
@@ -28,11 +30,9 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 def load_journal_stages(path):
-    """Banked stages from a bench journal ({} when absent); tolerant of
+    """Banked stages from a journal ({} when absent); tolerant of
     both the fingerprint-wrapped layout and a bare stage map."""
     if not path or not os.path.exists(path):
         return {}
@@ -60,8 +60,8 @@ def load_metrics_snapshot(path):
 
 
 def run_doctor(stages=None, registry=None):
-    """collect -> diagnose -> summary (the bench ``obs_doctor`` stage
-    entry point; falls back to the live process registry)."""
+    """collect -> diagnose -> summary (falls back to the live process
+    registry)."""
     from lightgbm_tpu.obs.diagnose import run_doctor as _run
     return _run(registry=registry, stages=stages)
 
@@ -80,13 +80,9 @@ def format_human(report):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--journal",
-                    default=os.environ.get(
-                        "BENCH_JOURNAL",
-                        os.path.join(REPO, "bench_journal.json")))
-    ap.add_argument("--metrics",
-                    default=os.path.join(REPO, "bench_out",
-                                         "bench_obs_metrics.json"))
+    ap.add_argument("--journal", default=None)
+    ap.add_argument("--metrics", default="obs_metrics.json",
+                    help="tools/obs_dump.py writes one into its --out-dir")
     ap.add_argument("--json-only", action="store_true")
     args = ap.parse_args()
     try:

@@ -14,8 +14,7 @@ process registry), then writes:
 - ``obs_metrics.prom``    — the same registry in Prometheus text format
 
 The LAST stdout line is one JSON summary (span names, coverage, artifact
-paths).  Smoke-invoked by bench.py as the ``obs_dump`` stage
-(``BENCH_SKIP_OBS=1`` skips; errors are never journaled so reruns retry).
+paths).
 
 Usage:
     JAX_PLATFORMS=cpu python tools/obs_dump.py \
