@@ -34,7 +34,8 @@ from ..obs.metrics import global_registry as _obs_registry
 from ..obs.trace import span as _span
 from ..ops.histogram import (on_accelerator, quantize_gradients,
                              take_from_table)
-from ..grower import GrowerConfig, TreeArrays, grow_tree, predict_tree_binned
+from ..grower import (GrowerConfig, TreeArrays, grow_tree,
+                      leaf_router_engages, predict_tree_binned)
 from ..objectives import ObjectiveFunction
 from ..ops.renew import leaf_percentile
 from ..tree import HostTree, tree_to_host
@@ -1313,6 +1314,12 @@ class GBDT:
         pred_key_tail = (len(mrp.num_bin), int(mrp.num_groups),
                          mrp.has_bundles, int(mrp.max_group_bin))
 
+        # which program finds a row's leaf (grower.predict_leaf_index_binned)
+        # is decided here, on the host, from the data set's own metadata;
+        # the shared programs are keyed on it
+        routed = self._leaf_routed = leaf_router_engages(self.meta)
+        pred_key_tail += (routed,)
+
         vkey = ("valid_update", K) + pred_key_tail
         vfn = _shared_program(vkey)
         if vfn is None:
@@ -1322,13 +1329,16 @@ class GBDT:
                                                     stacked_trees)
                     vscore = vscore.at[k].add(
                         predict_tree_binned(tree_k, binned, None,
-                                            meta_arrays=meta_a))
+                                            meta_arrays=meta_a,
+                                            routed=routed))
                 return vscore
             vfn = _shared_program(vkey, jax.jit(valid_update_full,
                                                 donate_argnums=(0,)))
-        self._valid_update = (
-            lambda vscore, trees, binned, _f=vfn:
-            _f(vscore, trees, binned, pred_meta_args))
+
+        def _valid_update(vscore, trees, binned):
+            self._count_valid_update(1)
+            return vfn(vscore, trees, binned, pred_meta_args)
+        self._valid_update = _valid_update
 
         # the TRAIN device matrix may have permuted group columns (sharded
         # EFB layout); history-tree traversal over it needs a meta whose
@@ -1351,10 +1361,13 @@ class GBDT:
             tfn = _shared_program(tkey, jax.jit(
                 lambda tree, binned, meta_a:
                 predict_tree_binned(tree, binned, None,
-                                    meta_arrays=meta_a)))
+                                    meta_arrays=meta_a, routed=routed)))
         self._tree_pred_jit = (lambda tree, binned, _f=tfn:
                                _f(tree, binned, pred_meta_args))
-        if self._col_perm is not None:
+        if self._col_perm is not None or (routed and self._mesh is not None):
+            # the walk, whatever the validation sets take: it partitions
+            # by rows as it stands, while the path form's row blocks would
+            # gather a matrix sharded over the mesh onto every device
             self._tree_pred_train_jit = jax.jit(
                 lambda tree, binned: predict_tree_binned(tree, binned,
                                                          meta_train))
@@ -1553,10 +1566,20 @@ class GBDT:
         z = jnp.zeros((1, 1), jnp.float32)
         return z, z
 
+    def _count_valid_update(self, iterations: int) -> None:
+        """Trees applied to one validation set, by the program that found
+        their rows' leaves (``_leaf_routed``, fixed when the programs were
+        built)."""
+        _obs_registry.counter(
+            "valid_update_trees_routed_total" if self._leaf_routed
+            else "valid_update_trees_walked_total").inc(
+                iterations * self.num_tree_per_iteration)
+
     def _chunk_valid_update(self, vscore, stacked_seq, binned, its):
         if self._macro_valid_jit is None:
             from .macro import build_chunk_valid
             self._macro_valid_jit = build_chunk_valid(self)
+        self._count_valid_update(its.shape[0])
         return self._macro_valid_jit(vscore, stacked_seq, binned, its,
                                      np.int32(its.shape[0]))
 

@@ -196,9 +196,17 @@ def build_chunk_valid(b):
     """Fused valid-score update: one program applies a whole ``[c, ...]``
     tree bundle to a valid set (vs. one dispatch per iteration).  Same
     runtime-trip-count loop as the chunk program so RF's running-mean
-    renormalization keeps identical codegen at every chunk size."""
+    renormalization keeps identical codegen at every chunk size.
+
+    A row's leaf is found by ``grower.predict_leaf_index_binned``: its path
+    form where ``b._leaf_routed`` (no categorical feature, on the
+    accelerator), else its walk over tree levels; the scores are the same
+    to the bit.  The jitted function keeps the name ``upd``: the benchmark
+    finds the evaluation layer's device time by the program name
+    ``jit_upd`` (``benchmark/metrics/_eval.py``)."""
     from ..grower import predict_tree_binned
     K = b.num_tree_per_iteration
+    routed = b._leaf_routed
     meta_args = b.meta.as_runtime_arrays()
     rf = b.boosting_type == "rf"
     init_col = (jnp.asarray(b.init_scores, jnp.float32)[:, None]
@@ -213,7 +221,8 @@ def build_chunk_valid(b):
             for k in range(K):
                 tree_k = jax.tree_util.tree_map(lambda a: a[k], st)
                 vs = vs.at[k].add(predict_tree_binned(
-                    tree_k, binned, None, meta_arrays=meta_args))
+                    tree_k, binned, None, meta_arrays=meta_args,
+                    routed=routed))
             if rf:
                 vs = (vs + init_col) / (itf + 1.0)
             return vs

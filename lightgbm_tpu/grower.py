@@ -36,8 +36,8 @@ from jax import lax
 from .dataset import FeatureMeta
 from .ops.histogram import (build_histogram, build_histogram_int,
                             capacity_schedule, compacted_histogram,
-                            compacted_histogram_int, psum_quant_hist,
-                            quant_levels, take_from_table)
+                            compacted_histogram_int, on_accelerator,
+                            psum_quant_hist, quant_levels, take_from_table)
 from .ops.split import (K_EPSILON, MAX_CAT_WORDS, PerFeatureBest,
                         SplitHyperparams, SplitResult, best_split_for_leaf,
                         feature_best_splits, leaf_gain, leaf_output,
@@ -1376,29 +1376,56 @@ def _grow_tree_traced(
     return tree, out.leaf_id
 
 
+# rows a block of ``route_leaf_index_binned`` resolves at once.  The v5e
+# reads the same ms a tree from 32k to 256k rows (PERF.md section 6, PR 33);
+# this end of it bounds the [L, block] int32 of leaf against row at 67 MB
+# for 255 leaves should a compiler not consume it where the matmul makes it
+ROUTE_BLOCK_ROWS = 65536
+
+
+def leaf_router_engages(meta: FeatureMeta) -> bool:
+    """Whether ``predict_leaf_index_binned`` should be traced in its path
+    form for a data set with this metadata: known on the host when the
+    programs are built, so it is also what the ``valid_update_trees_*``
+    counters follow.  A bitset test does not ride a table (categorical
+    splits keep the walk), and one-hot matmuls lose off the accelerator."""
+    return on_accelerator() and not meta.is_categorical.any()
+
+
+def _bin_layout(meta: FeatureMeta, meta_arrays: Optional[tuple]):
+    """(num_bin, missing_type, default_bin, feat_group, feat_start) from
+    the runtime tuple where there is one, else from the static ``meta``."""
+    (num_bin, missing_type, default_bin, _is_cat, feat_group,
+     feat_start) = (meta_arrays if meta_arrays is not None
+                    else meta.as_runtime_arrays())
+    return num_bin, missing_type, default_bin, feat_group, feat_start
+
+
 def predict_leaf_index_binned(tree: TreeArrays, binned_t: jax.Array,
                               meta: FeatureMeta,
-                              meta_arrays: Optional[tuple] = None) -> jax.Array:
-    """Route binned rows ([F, n] feature-major) to leaf indices by
-    iterative traversal.
+                              meta_arrays: Optional[tuple] = None,
+                              routed: bool = False) -> jax.Array:
+    """Route binned rows ([F, n] feature-major) to leaf indices.
 
     reference: Tree::Predict inline traversal (include/LightGBM/tree.h:190).
-    Vectorized: all rows advance one level per iteration; done when every
-    row has reached a leaf (child pointer < 0).  ``meta_arrays`` (same
-    tuple as grow_tree's) makes the bin layout a runtime input so one
-    compiled traversal serves every same-shaped dataset.
+    ``meta_arrays`` (same tuple as grow_tree's) makes the bin layout a
+    runtime input so one compiled traversal serves every same-shaped
+    dataset.
+
+    Two programs give the same indices.  ``routed=True`` (the caller's
+    trace-time ``leaf_router_engages(meta)``: no categorical feature, on
+    the accelerator) traces ``route_leaf_index_binned``, which reads whole
+    feature rows and resolves every leaf's root path by one matmul.
+    Otherwise the walk: all rows advance one level per iteration of a
+    ``lax.while_loop``, ten per-row gathers a level (one of them the
+    categorical bitset word), done when every row has reached a leaf
+    (child pointer < 0).
     """
+    if routed:
+        return route_leaf_index_binned(tree, binned_t, meta, meta_arrays)
     n = binned_t.shape[1]
-    if meta_arrays is not None:
-        (num_bin, missing_type, default_bin, _is_cat,
-         feat_group, feat_start) = meta_arrays
-    else:
-        meta = meta.resolved()
-        num_bin = jnp.asarray(meta.num_bin)
-        missing_type = jnp.asarray(meta.missing_type)
-        default_bin = jnp.asarray(meta.default_bin)
-        feat_group = jnp.asarray(meta.feat_group)
-        feat_start = jnp.asarray(meta.feat_start)
+    num_bin, missing_type, default_bin, feat_group, feat_start = \
+        _bin_layout(meta, meta_arrays)
 
     # node >= 0: internal; node < 0: leaf ~node
     def cond(state):
@@ -1426,8 +1453,127 @@ def predict_leaf_index_binned(tree: TreeArrays, binned_t: jax.Array,
     return ~node  # leaf index
 
 
+def _leaf_paths(tree: TreeArrays):
+    """The tree's root paths as a matrix: ``P[l, j]`` int8 is +1 where leaf
+    ``l``'s path goes left at internal node ``j``, -1 where right, 0 where
+    ``j`` is not on it; ``target[l]`` int32 is the path's length, or a
+    value no row can reach for a leaf ``>= num_leaves``.  Nodes
+    ``>= num_leaves - 1`` are on no path, and a stump's leaf 0 has the
+    empty path every row matches.  Read from ``left_child`` /
+    ``right_child`` / ``num_leaves`` alone (what the walk reads), with
+    arrays of L elements: every leaf climbs to the root at once, one
+    ancestor an iteration."""
+    L = tree.leaf_value.shape[0]
+    Ln = tree.left_child.shape[0]
+    iota_n = jnp.arange(Ln, dtype=jnp.int32)
+    iota_l = jnp.arange(L, dtype=jnp.int32)
+    live_n = iota_n < tree.num_leaves - 1
+    # Ln is no node's index and no leaf's code: a dead node is no parent
+    lc = jnp.where(live_n, tree.left_child, Ln)
+    rc = jnp.where(live_n, tree.right_child, Ln)
+
+    def parent_of(code):
+        """(parent node or -1, +1 / -1 the side) of each child code."""
+        is_l = lc[None, :] == code[:, None]
+        is_r = rc[None, :] == code[:, None]
+        par = jnp.sum(jnp.where(is_l | is_r, iota_n[None, :], 0), axis=1)
+        par = jnp.where(jnp.any(is_l | is_r, axis=1), par, -1)
+        return par, jnp.where(jnp.any(is_l, axis=1), 1, -1).astype(jnp.int8)
+
+    node_par, node_side = parent_of(iota_n)
+    leaf_par, leaf_side = parent_of(~iota_l)
+    live_l = iota_l < tree.num_leaves
+
+    def cond(state):
+        cur, _, _, it = state
+        return jnp.any(cur >= 0) & (it < Ln)
+
+    def body(state):
+        cur, side, paths, it = state
+        paths = jnp.where(iota_n[None, :] == cur[:, None], side[:, None],
+                          paths)
+        up = jnp.maximum(cur, 0)
+        return (jnp.where(cur >= 0, node_par[up], -1), node_side[up],
+                paths, it + 1)
+
+    _, _, paths, _ = lax.while_loop(
+        cond, body, (jnp.where(live_l, leaf_par, -1), leaf_side,
+                     jnp.zeros((L, Ln), jnp.int8), jnp.array(0)))
+    depth = jnp.sum(jnp.abs(paths.astype(jnp.int32)), axis=1)
+    return paths, jnp.where(live_l, depth, Ln + 1)
+
+
+def route_leaf_index_binned(tree: TreeArrays, binned_t: jax.Array,
+                            meta: FeatureMeta,
+                            meta_arrays: Optional[tuple] = None,
+                            block: int = ROUTE_BLOCK_ROWS) -> jax.Array:
+    """The leaf index of every binned row ([G, n] feature-major) of a tree
+    whose splits are all numeric, with no per-row gather and no loop over
+    levels; equal to ``predict_leaf_index_binned``'s walk to the bit, on
+    any backend.  For a block of rows:
+
+    1. node columns: row ``feat_group[split_feature[j]]`` of the matrix
+       for each of the L-1 internal nodes, whole and contiguous: a
+       [L-1, G] x [G, b] one-hot matmul where the bins are uint8 (< 256,
+       exact in bf16; 2.7x a leading-axis take of the same rows on the
+       v5e), the take for wider bins;
+    2. node decisions ``D[j, r]`` int8, +1 left / -1 right: the walk's bin
+       decode and ``row_goes_left``'s numeric rule, the node's parameters
+       broadcast as [L-1, 1] columns;
+    3. ``M = P @ D`` (int8 in, int32 out) against ``_leaf_paths``: row
+       ``r`` is in leaf ``l`` iff it agreed with every turn of ``l``'s
+       path, ``M[l, r] == depth[l]``; exactly one live leaf matches.
+
+    Blocks of ``block`` rows keep the [L, block] int32 transient small;
+    the last block starts at ``n - block`` and overlaps the one before
+    (the same rows get the same answer twice) so nothing is padded.
+    """
+    n = binned_t.shape[1]
+    num_bin, missing_type, default_bin, feat_group, feat_start = \
+        _bin_layout(meta, meta_arrays)
+    feat = tree.split_feature
+    grp = feat_group[feat]
+    fs, nb = feat_start[feat][:, None], num_bin[feat][:, None]
+    mt, db = missing_type[feat][:, None], default_bin[feat][:, None]
+    thr, dl = tree.threshold_bin[:, None], tree.default_left[:, None]
+    paths, target = _leaf_paths(tree)
+    iota_l = jnp.arange(paths.shape[0], dtype=jnp.int32)[:, None]
+    pick = None
+    if binned_t.dtype == jnp.uint8:
+        pick = (grp[:, None] == jnp.arange(binned_t.shape[0])[None, :]
+                ).astype(jnp.bfloat16)                       # [L-1, G]
+
+    def leaves_of(rows):
+        if pick is not None:
+            col = lax.dot(pick, rows.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+        else:
+            col = rows[grp]
+        col = col.astype(jnp.int32)                          # [L-1, b]
+        dec = col - fs + 1
+        binf = jnp.where((dec >= 1) & (dec < nb), dec, 0)
+        gl = row_goes_left(binf, thr, dl, None, None, mt, db, nb)
+        turns = jnp.where(gl, 1, -1).astype(jnp.int8)
+        agree = lax.dot(paths, turns, preferred_element_type=jnp.int32)
+        return jnp.sum(jnp.where(agree == target[:, None], iota_l, 0),
+                       axis=0)
+
+    if n <= block:
+        return leaves_of(binned_t)
+
+    def body(i, leaf):
+        start = jnp.minimum(i * block, n - block)
+        rows = lax.dynamic_slice_in_dim(binned_t, start, block, axis=1)
+        return lax.dynamic_update_slice_in_dim(leaf, leaves_of(rows), start,
+                                               axis=0)
+
+    return lax.fori_loop(0, -(-n // block), body, jnp.zeros(n, jnp.int32))
+
+
 def predict_tree_binned(tree: TreeArrays, binned_t: jax.Array,
                         meta: FeatureMeta,
-                        meta_arrays: Optional[tuple] = None) -> jax.Array:
-    leaf = predict_leaf_index_binned(tree, binned_t, meta, meta_arrays)
+                        meta_arrays: Optional[tuple] = None,
+                        routed: bool = False) -> jax.Array:
+    leaf = predict_leaf_index_binned(tree, binned_t, meta, meta_arrays,
+                                     routed)
     return take_from_table(tree.leaf_value, leaf)

@@ -339,3 +339,31 @@ def test_rounds_grower_compiles_with_the_offer(one_chip, as_accelerator):
         _shape(one_chip, (F, ROWS_1M), jnp.uint8), rows, rows, rows,
         q, q, scale, scale)
     assert _kernels(c) == 1 + len(slot_widths(K))
+
+
+def test_validation_update_compiles_in_its_path_form(one_chip,
+                                                     as_accelerator):
+    """One tree applied to ``criteo-quant.monitored``'s validation set
+    (2,796,202 x 67 uint8, 255 leaves) as the chip runs it: no Mosaic
+    kernel (the benchmark books every one as histogram time), and the
+    [L, block] int32 of leaf against row that the path matmul produces is
+    consumed where it is made: the program's temporaries stay under one
+    block of it."""
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.grower import (ROUTE_BLOCK_ROWS, TreeArrays,
+                                     predict_tree_binned)
+    G, n = 67, 2_796_202
+    tree = jax.tree_util.tree_map(
+        lambda a: _shape(one_chip, a.shape, a.dtype), TreeArrays.empty(LEAVES))
+    meta = tuple(_shape(one_chip, (G,), d)
+                 for d in (jnp.int32, jnp.int32, jnp.int32, jnp.bool_,
+                           jnp.int32, jnp.int32))
+    c = _compile(
+        lambda vs, t, b, m: vs.at[0].add(predict_tree_binned(
+            t, b, None, meta_arrays=m, routed=True)),
+        _shape(one_chip, (1, n), jnp.float32), tree,
+        _shape(one_chip, (G, n), jnp.uint8), meta)
+    assert _kernels(c) == 0
+    assert c.memory_analysis().temp_size_in_bytes < LEAVES * ROUTE_BLOCK_ROWS * 4
