@@ -465,7 +465,14 @@ class BinMapper:
                 used_cnt = 0
                 max_bin_c = min(len(dvi), max_bin)
                 cnt_in_bin = []
-                while cur_cat < len(dvi) and (used_cnt < cut_cnt or self.num_bin < max_bin_c):
+                # the reference's loop goes on past max_bin until 99% of
+                # the rows are covered, which gives a 10M-category id column
+                # thousands of bins: more than a node's category set
+                # (ops/split.MAX_CAT_WORDS) or the uint8 matrix can hold.
+                # Here max_bin binds; a column of no more than max_bin
+                # categories is binned as the reference bins it
+                while (cur_cat < len(dvi) and self.num_bin < max_bin
+                       and (used_cnt < cut_cnt or self.num_bin < max_bin_c)):
                     if cti[cur_cat] < min_data_in_bin and cur_cat > 1:
                         break
                     self.bin_2_categorical.append(dvi[cur_cat])

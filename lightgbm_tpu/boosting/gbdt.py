@@ -1738,12 +1738,19 @@ class GBDT:
         """One ``grower.tree`` flight-ring record a tree, where the host
         takes it: ``gstats`` is the iteration's ``[K, 5]`` (rounds,
         offered, applied, slots, clipped) of the grower's loop, pulled
-        beside the trees, or None (streamed executor)."""
+        beside the trees, or None (streamed executor).  The rounds also
+        count into ``grower_rounds_routed_total`` or ``_scanned_total``, by
+        the form their program routes rows in."""
         if gstats is None:
             return
+        from ..grower_rounds import router_engages
+        routed = _obs_registry.counter(
+            "grower_rounds_routed_total" if router_engages()
+            else "grower_rounds_scanned_total")
         for k in range(self.num_tree_per_iteration):
             rounds, offered, applied, slots, clipped = (
                 int(v) for v in gstats[k])
+            routed.inc(rounds)
             _flight_note("grower.tree", it=abs_it, k=k, rounds=rounds,
                          offered=offered, applied=applied, slots=slots,
                          clipped=clipped)

@@ -16,6 +16,7 @@ from __future__ import annotations
 import io
 import json
 import os
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Union
 
@@ -358,10 +359,14 @@ class Dataset:
         else:
             # bin boundaries and EFB groups from the row sample (host)
             with _span("ingest.edges", ring=True, rows=self.num_data,
-                       sample=sample_cnt):
+                       sample=sample_cnt) as edges:
                 sample_idx = _sample_indices(self.num_data, sample_cnt,
                                              seed)
-                self._fit_bin_mappers(raw, sp, sample_idx, categorical)
+                cat_s = self._fit_bin_mappers(raw, sp, sample_idx,
+                                              categorical)
+                if categorical:
+                    # the categorical columns' part (count-sorted codes)
+                    edges.set(categorical=len(categorical), cat_s=cat_s)
 
         # second pass: bin every row into the per-GROUP merged columns —
         # on device when plan_ingest elects the bucketize+pack kernel
@@ -381,8 +386,9 @@ class Dataset:
             self.raw_data = None
         return self
 
-    def _fit_bin_mappers(self, raw, sp, sample_idx, categorical) -> None:
-        """FindBin per feature over a row sample + EFB grouping.
+    def _fit_bin_mappers(self, raw, sp, sample_idx, categorical) -> float:
+        """FindBin per feature over a row sample + EFB grouping; returns
+        the seconds the categorical features' FindBin took.
 
         reference: DatasetLoader::ConstructBinMappersFromTextData
         (dataset_loader.cpp:823) + Dataset::Construct EFB
@@ -413,6 +419,7 @@ class Dataset:
         sraw = (np.ascontiguousarray(raw[sample_idx])
                 if raw is not None else None)
         self.bin_mappers = []
+        cat_s = 0.0
         for f in range(self.num_total_features):
             col = _get_col(sraw, sp, f,
                            None if sraw is not None else sample_idx)
@@ -422,6 +429,7 @@ class Dataset:
             m = BinMapper()
             btype = (BinType.CATEGORICAL if f in categorical
                      else BinType.NUMERICAL)
+            t_bin = time.perf_counter()
             m.find_bin(
                 vals, total_sample_cnt,
                 int(mbbf[f]) if mbbf else max_bin,
@@ -433,6 +441,8 @@ class Dataset:
                 zero_as_missing=zero_as_missing,
                 forced_upper_bounds=forced_bounds.get(f, ()),
             )
+            if btype == BinType.CATEGORICAL:
+                cat_s += time.perf_counter() - t_bin
             self.bin_mappers.append(m)
         self.used_features = [f for f, m in enumerate(self.bin_mappers)
                               if not m.is_trivial]
@@ -457,6 +467,7 @@ class Dataset:
             # sampled NaN values as non-zero entries)
             sample_nonzero[j] = np.isnan(col) | (np.abs(col) > 1e-35)
         self._build_groups(sample_nonzero, total_sample_cnt)
+        return cat_s
 
     def _bin_block(self, raw, sp, out: np.ndarray) -> None:
         """Bin a block of raw rows into ``out`` (a [rows, G] uint view).

@@ -78,7 +78,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from .dataset import FeatureMeta
-from .grower import GrowerConfig, TreeArrays, _LeafBest, _psum, row_goes_left
+from .grower import (GrowerConfig, TreeArrays, _LeafBest, _psum,
+                     bitset_halves, halves_hold, row_goes_left)
 from .ops.histogram import (build_histogram, build_histogram_int,
                             capacity_schedule, compacted_segment_histogram,
                             compacted_segment_histogram_int, pack_cols_u32,
@@ -120,6 +121,16 @@ def next_offer(rungs: jax.Array, m, m_before) -> jax.Array:
     therefore goes up a rung at least: no rung holds itself with room."""
     need = OFFER_SPARE_DEN * jnp.maximum(m, m_before)
     return rungs[jnp.sum(need > OFFER_SPARE_NUM * rungs[:-1])]
+
+
+def router_engages() -> bool:
+    """Whether a round routes rows by the router form (one table matmul
+    and one decision a row) or by the candidate scan (one pass over the
+    rows a candidate): fixed by the backend when the round program is
+    traced, so it is also what the ``grower_rounds_*_total`` counters
+    follow (``GBDT._note_trees``)."""
+    return (use_sorted_seghist()
+            and os.environ.get("LGBM_TPU_ROUTER") != "0")
 
 
 def grow_tree_rounds(binned_t, *args, **kwargs):
@@ -245,11 +256,12 @@ def _grow_tree_rounds_traced(
     else:
         packed = pack_cols_u32(binned_t, grad, hess, row_mask)
     # router-matmul candidate routing (see body): O(n)/round instead of
-    # the scan's O(k*n); numeric-only (categorical bitsets don't ride an
-    # f32 table) and accelerator-shaped.  LGBM_TPU_ROUTER=0 forces the
-    # scan (bisect/testing hook)
-    use_router = (use_sorted_seghist() and not meta.is_categorical.any()
-                  and os.environ.get("LGBM_TPU_ROUTER") != "0")
+    # the scan's O(k*n); accelerator-shaped.  A categorical candidate's
+    # set rides the same table as 16-bit halves of its words (exact in
+    # f32), as many as the widest feature's bins need.  LGBM_TPU_ROUTER=0
+    # forces the scan (bisect/testing hook)
+    use_router = router_engages()
+    cat_halves = 2 * min(MAX_CAT_WORDS, -(-B // 32)) if has_cat else 0
     # segment-histogram precision follows the resolved histogram method so
     # parent - smaller-child subtraction stays consistent: only the bf16
     # one-hot matmul is inexact; every other kernel accumulates f32-exact
@@ -578,15 +590,17 @@ def _grow_tree_rounds_traced(
             idl = jnp.clip(order[:KCAP], 0, L - 1)          # candidate leaves
 
             if use_router:
-                # ROUTER MATMUL (numeric features, accelerator path): ONE
-                # [9, n] take_from_table one-hot matmul hands every row its
+                # ROUTER MATMUL (accelerator path): ONE [9, n]
+                # take_from_table one-hot matmul hands every row its
                 # leaf's split params, then one fused [G, n] select-reduce
                 # reads the row's split-feature bin — O(G*n) total per round
                 # (~one binned-matrix stream, the cost the expanded segment
                 # histogram already pays) vs the scan's O(k*n) column passes:
                 # a clear win on the wide rounds (k up to 128) and a ~one-
                 # stream overhead on narrow ones.  All table values are
-                # integers < 2^16 or flags: exact in f32.
+                # integers < 2^16 or flags: exact in f32.  With categorical
+                # features the table gains the candidate's kind and its
+                # set's half-words (``cat_halves`` more rows).
                 feat_l = jnp.clip(b.feature, 0, F - 1)
                 live_l = pos & (rank < k)
                 tbl = jnp.stack([
@@ -600,7 +614,11 @@ def _grow_tree_rounds_traced(
                     feat_start[feat_l].astype(jnp.float32),
                     (b.left_count <= b.right_count).astype(jnp.float32),
                 ], axis=1)                                   # [L, 9]
-                prm = take_from_table(tbl, c.leaf_id, leading=True)  # [9, n]
+                if has_cat:
+                    tbl = jnp.concatenate([
+                        tbl, b.is_categorical.astype(jnp.float32)[:, None],
+                        bitset_halves(b.cat_bitset, cat_halves)], axis=1)
+                prm = take_from_table(tbl, c.leaf_id, leading=True)  # [9+, n]
                 crank = prm[0].astype(jnp.int32)
                 grp = prm[1].astype(jnp.int32)
                 thr_r = prm[2].astype(jnp.int32)
@@ -622,13 +640,19 @@ def _grow_tree_rounds_traced(
                 # mirror (DenseBin::SplitInner) — per-row params broadcast
                 gl = row_goes_left(binf, thr_r, dl_r, None, None,
                                    mt_r, db_r, nb_r)
+                if has_cat:
+                    # the same rule's categorical arm: bit ``bin`` of the
+                    # row's candidate's set, from the halves the table
+                    # carried — elementwise, no gather
+                    gl = jnp.where(prm[9] > 0.5,
+                                   halves_hold(prm[10:], binf), gl)
                 row_small = gl == sl_r
             else:
                 # candidate scan: one step per candidate reads its split
                 # feature as a CONTIGUOUS column of the transposed matrix and
-                # broadcasts scalar split params (kept for categorical splits
-                # — the per-row bitset test doesn't ride an f32 table — and
-                # for CPU, where one-hot matmuls lose)
+                # broadcasts scalar split params (kept for CPU, where one-hot
+                # matmuls lose, and as the router's oracle: the two forms
+                # grow the same trees bit for bit, tests/test_cat_router.py)
                 def cstep(carry, kk):
                     def live(carry):
                         gl_a, crank_a, small_a = carry

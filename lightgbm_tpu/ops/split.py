@@ -351,11 +351,13 @@ def feature_best_splits(
                               nf.left_count)
 
     # ---- categorical features ---------------------------------------------
-    cat = _best_categorical(
-        hist, sum_grad, sum_hess, num_data, num_bin, valid_bin, hp,
-        rand_u=(extra_rand_u[:, 1] if use_rand else None),
-        missing_type=missing_type,
-    ) if has_categorical else None
+    cat = None
+    if has_categorical:
+        with jax.named_scope("lgbm.cat_scan"):
+            cat = _best_categorical(
+                hist, sum_grad, sum_hess, num_data, num_bin, valid_bin, hp,
+                rand_u=(extra_rand_u[:, 1] if use_rand else None),
+                missing_type=missing_type)
 
     # each feature's gain is shifted by ITS OWN parent gain (categorical
     # uses l2+cat_l2, reference feature_histogram.hpp:268-276) so the

@@ -19,6 +19,7 @@ from typing import List, Optional
 import numpy as np
 
 from .binning import BinType, MissingType
+from .obs.metrics import global_registry as _obs_registry
 
 K_CATEGORICAL_MASK = 1
 K_DEFAULT_LEFT_MASK = 2
@@ -252,7 +253,7 @@ def tree_to_host(tree_arrays, train_set, shrinkage: float) -> HostTree:
     cat_boundaries = [0]
     cat_threshold: List[np.uint32] = []
     bin_cat_bitsets = {}
-    num_cat = 0
+    num_cat = cat_codes = 0
     for s in range(ns):
         m = mappers[used[split_feature_inner[s]]]
         dt = 0
@@ -276,6 +277,7 @@ def tree_to_host(tree_arrays, train_set, shrinkage: float) -> HostTree:
             cat_boundaries.append(cat_boundaries[-1] + nwords)
             cat_threshold.extend(words.tolist())
             num_cat += 1
+            cat_codes += len(cats)
             # missing type for categorical is NaN-ish; NaN goes right always
             dt |= (m.missing_type & 3) << 2
         else:
@@ -313,6 +315,11 @@ def tree_to_host(tree_arrays, train_set, shrinkage: float) -> HostTree:
         shrinkage=shrinkage,
         real_feature_index=real_feat,
     )
+    # what the forest stands on, as the host takes it
+    _obs_registry.counter("tree_splits_total").inc(ns)
+    if num_cat:
+        _obs_registry.counter("tree_splits_categorical_total").inc(num_cat)
+        _obs_registry.counter("tree_cat_set_codes_total").inc(cat_codes)
     ht._missing_bin = missing_bin
     ht._feat_num_bin = np.array(
         [mappers[used[f]].num_bin for f in split_feature_inner], np.int32)

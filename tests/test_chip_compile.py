@@ -273,6 +273,30 @@ def test_ingest_kernel_compiles_at_the_planned_tile(one_chip):
         _compile(refused._run, _shape(one_chip, (ROWS_1M, F), jnp.float32))
 
 
+def test_ingest_kernel_compiles_with_category_tables(one_chip):
+    """The click log's schema: 13 numeric columns and 26 categorical ones
+    of 63 kept codes each, at the tile the planner elects for it."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.ingest import (DeviceBinner, FeatureSpec,
+                                         IngestTables)
+    from lightgbm_tpu.ops.planner import plan_ingest_tile
+    f, codes = 39, 63
+    specs = tuple(FeatureSpec(j, j, 1, j >= 13, codes,
+                              j - 13 if j >= 13 else j, False)
+                  for j in range(f))
+    rs = np.random.RandomState(0)
+    bounds = np.sort(rs.rand(13, codes - 1).astype(np.float32), axis=1)
+    cats = np.stack([rs.permutation(10_000_000)[:codes]
+                     for _ in range(26)]).astype(np.int32)
+    tables = IngestTables(specs, bounds, cats, f, f, np.dtype(np.uint8))
+    tile = plan_ingest_tile(f, bounds.shape[1], codes, f)
+    assert tile is not None
+    binner = DeviceBinner(tables, tile["tile_rows"], interpret=False)
+    c = _compile(binner._run, _shape(one_chip, (ROWS_1M, f), jnp.float32))
+    assert _kernels(c) == 1
+
+
 @pytest.fixture(scope="module")
 def forest():
     """A 500-tree x 255-leaf forest (a few trained trees, tiled)."""
@@ -339,6 +363,47 @@ def test_rounds_grower_compiles_with_the_offer(one_chip, as_accelerator):
         _shape(one_chip, (F, ROWS_1M), jnp.uint8), rows, rows, rows,
         q, q, scale, scale)
     assert _kernels(c) == 1 + len(slot_widths(K))
+
+
+def test_rounds_grower_compiles_with_categorical_columns(one_chip,
+                                                         as_accelerator):
+    """The click log's schema (13 numeric + 26 categorical columns, 64
+    bins) through one whole tree as the chip runs it: the router form with
+    the candidates' sets on its table, and the categorical scan (a sort a
+    column) merged into the fused pick under int32 histograms."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.binning import MissingType
+    from lightgbm_tpu.dataset import FeatureMeta
+    from lightgbm_tpu.grower import GrowerConfig
+    from lightgbm_tpu.grower_rounds import grow_tree_rounds, router_engages
+    from lightgbm_tpu.ops.fused import slot_widths
+    from lightgbm_tpu.ops.split import SplitHyperparams
+    assert router_engages()
+    f, bins = 39, 64
+    is_cat = np.arange(f) >= 13
+    meta = FeatureMeta(
+        num_bin=np.where(is_cat, bins, bins - 1).astype(np.int32),
+        missing_type=np.where(is_cat, int(MissingType.NAN), 0
+                              ).astype(np.int32),
+        default_bin=np.zeros((f,), np.int32),
+        most_freq_bin=np.zeros((f,), np.int32),
+        is_categorical=is_cat, max_num_bin=bins)
+    cfg = GrowerConfig(num_leaves=LEAVES, num_bins=bins, quant=True,
+                       quant_bins=4, hist_method="fused",
+                       hp=SplitHyperparams(min_data_in_leaf=1,
+                                           min_sum_hessian_in_leaf=100.0))
+    rows = _shape(one_chip, (ROWS_1M,), jnp.float32)
+    q = _shape(one_chip, (ROWS_1M,), jnp.int8)
+    scale = _shape(one_chip, (), jnp.float32)
+    c = _compile(
+        lambda b, g, h, m, gq, hq, gs, hs: grow_tree_rounds(
+            b, g, h, m, meta, cfg, quant_vals=(gq, hq, gs, hs),
+            with_stats=True),
+        _shape(one_chip, (f, ROWS_1M), jnp.uint8), rows, rows, rows,
+        q, q, scale, scale)
+    assert _kernels(c) == 1 + len(slot_widths(K))
+    assert "lgbm.cat_scan" in c.as_text()
 
 
 def test_validation_update_compiles_in_its_path_form(one_chip,

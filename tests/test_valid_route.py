@@ -332,7 +332,8 @@ def test_eval_routed_share_reads_the_counters(monkeypatch):
     reg = metrics.MetricsRegistry()
     monkeypatch.setattr(metrics, "global_registry", reg)
     manifest = lookup.load_manifest()
-    entry = manifest["per_layer"][-1]
+    entry = next(m for m in manifest["per_layer"]
+                 if m["name"] == "eval_routed_share")
     assert entry == {
         "name": "eval_routed_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "eval",
