@@ -858,6 +858,18 @@ class GBDT:
                     self.num_data, self._binned_shape[1], self.num_bins))
         self.grower_cfg, self.hist_plan = apply_plan(
             self.grower_cfg, shard_rows, shard_feats, fused_ok=want_fused)
+        # which of the two pass counters this booster's trees feed
+        # (_note_trees): fixed here, with the programs, by the predicate
+        # the accumulate kernel itself reads; none without the kernel
+        self._hist_passes_counter = None
+        if self.grower_cfg.hist_method == "fused":
+            from ..ops.fused import pass_builds_packed
+            cfg = self.grower_cfg
+            self._hist_passes_counter = (
+                "hist_passes_packed_total" if pass_builds_packed(
+                    cfg.num_bins, cfg.fused_feat_tile, shard_feats,
+                    cfg.quant, self.train_set.binned_dtype())
+                else "hist_passes_compared_total")
         # unified-registry training gauges (the planner.plan trace event
         # itself is emitted inside apply_plan)
         _obs_registry.gauge("train_hist_method").set(
@@ -1740,17 +1752,24 @@ class GBDT:
         offered, applied, slots, clipped) of the grower's loop, pulled
         beside the trees, or None (streamed executor).  The rounds also
         count into ``grower_rounds_routed_total`` or ``_scanned_total``, by
-        the form their program routes rows in."""
+        the form their program routes rows in, and the tree's accumulate
+        passes (a round each, and the root) into ``hist_passes_packed_total``
+        or ``_compared_total``, by the form the kernel builds its one-hot
+        operands in (``ops/fused.packed_operands``)."""
         if gstats is None:
             return
         from ..grower_rounds import router_engages
         routed = _obs_registry.counter(
             "grower_rounds_routed_total" if router_engages()
             else "grower_rounds_scanned_total")
+        passes = (_obs_registry.counter(self._hist_passes_counter)
+                  if self._hist_passes_counter else None)
         for k in range(self.num_tree_per_iteration):
             rounds, offered, applied, slots, clipped = (
                 int(v) for v in gstats[k])
             routed.inc(rounds)
+            if passes is not None:
+                passes.inc(rounds + 1)
             _flight_note("grower.tree", it=abs_it, k=k, rounds=rounds,
                          offered=offered, applied=applied, slots=slots,
                          clipped=clipped)

@@ -52,8 +52,13 @@ data-parallel path too — only hists cross the wire.
 **The width the round needs** (the rounds grower, one chip and sharded
 alike).  The accumulate half is a one-hot matmul: a pass costs rows x F x
 (ch · slots) x B multiply-adds whatever the rows hold, and at 128 int8
-slots it runs at ~90% of a v5e's int8 peak (root PERF.md section 5) —
-compute-bound, so the slot axis is the lever.  ``frontier_accumulator``
+slots it runs at ~96% of a v5e's int8 peak (root PERF.md section 5) —
+compute-bound, so the slot axis is the lever.  The VPU's part, building
+the two one-hot operands, is packed four cells to a 32-bit word where
+``packed_operands`` says the shape allows (int8 values, one-byte bins,
+32 to 128 padded bins a feature: four int32 ops a word of four cells, no
+int32 intermediate); the compare form, one int32 compare a cell, stays
+for the f32 family and the other shapes.  ``frontier_accumulator``
 compiles the pass at ``NARROW_SLOT_WIDTHS`` and at the round cap, over
 ONE feature-blocked copy of the binned matrix built once a tree
 (``fused_blocked_bins``), and runs each round — and the root, whose one
@@ -470,6 +475,47 @@ def _fused_call(
     return hist, best
 
 
+# the four bytes of a 32-bit word, each 1 / each with bit 7 alone (int32)
+_BYTE_ONES = 0x01010101
+_BYTE_TOPS = 0x80808080 - (1 << 32)
+
+
+def packed_operands(quant: bool, bin_dtype, padded_bins: int) -> bool:
+    """Whether ``_accumulate_tile`` builds its one-hot operands PACKED,
+    four cells to a 32-bit word, and not by one int32 compare a cell:
+    where the program can see the packed form is exact and aligned — the
+    integer family (int8 operands), bins of one byte, every bin id below
+    128 (a byte's bit 7 is the packed compare's borrow guard), and a
+    feature's ``padded_bins`` (``_arena_dims``) whole int8 sublane tiles
+    of 32.  Elsewhere (the f32 family, 2-byte bins, 16 or 256 padded bins)
+    the compare form stays.  One predicate, fixed by the static shape;
+    ``GBDT._note_trees`` counts passes by it (``hist_passes_*_total``,
+    through ``pass_builds_packed``)."""
+    return (bool(quant) and jnp.dtype(bin_dtype).itemsize == 1
+            and padded_bins <= 128 and padded_bins % 32 == 0)
+
+
+def _packed_ids(n: int, C: int) -> jax.Array:
+    """int32 ``[n // 4, C]`` whose bytes are the row ids 0..n-1 of an
+    int8 ``[n, C]`` array, in the bitcast's own packing order: a packed
+    one-hot compared against it bitcasts back to int8 with row ``r`` at
+    row ``r`` whatever that order is."""
+    from jax.experimental.pallas import tpu as pltpu
+    ids = lax.broadcasted_iota(jnp.int32, (n, C), 0).astype(jnp.int8)
+    return pltpu.bitcast(ids, jnp.int32)
+
+
+def _packed_onehot(row: jax.Array, ids: jax.Array) -> jax.Array:
+    """Four one-hot cells to a word: ``row`` int32 ``[1, C]`` in [0, 128),
+    ``ids`` ``_packed_ids``; returns int32 ``[n // 4, C]`` whose bytes are
+    1 exactly where the byte of ``ids`` equals ``row``.  The XOR's bytes
+    are 0 there and at most 0x7F elsewhere, so ``0x80 - byte`` keeps bit 7
+    for the zero byte alone and no borrow crosses a byte: four int32 ops a
+    word in place of a compare, a select and a share of a pack a cell."""
+    x = (row * _BYTE_ONES) ^ ids
+    return ((_BYTE_TOPS - x) >> 7) & _BYTE_ONES
+
+
 def _accumulate_tile(acc, b_ref, v_ref, s_ref, K, Ft, B, ch, quant):
     """One row tile of the slot-expanded one-hot matmul, accumulated
     into the VMEM arena — the accumulate half of the megakernel, shared
@@ -481,33 +527,70 @@ def _accumulate_tile(acc, b_ref, v_ref, s_ref, K, Ft, B, ch, quant):
     ``[Ft*B, C]`` — the contraction runs over the lane axis of both
     operands (the q·kᵀ matmul form), so no in-kernel transpose is
     needed.  ``K`` and ``B`` arrive padded to the sublane/lane tiling
-    (``_arena_dims``)."""
+    (``_arena_dims``).
+
+    The int8 operands come packed where ``packed_operands`` says so: the
+    same bytes as the compare form's, hence the same int32 arena bit for
+    bit, at a quarter of the VPU's words."""
+    from jax.experimental.pallas import tpu as pltpu
     blk = b_ref[...].astype(jnp.int32)                 # [Ft, C]
     C = blk.shape[1]
-    oh_s = s_ref[...] == lax.broadcasted_iota(jnp.int32, (K, C), 0)
+    s = s_ref[...]                                     # [1, C]
     v = v_ref[...]                                     # [ch, C]
-    iota_b = lax.broadcasted_iota(jnp.int32, (B, C), 0)
     nt = (((1,), (1,)), ((), ()))                      # lhs · rhsᵀ
-    if quant:
-        lhs = jnp.concatenate(
-            [jnp.where(oh_s, v[c:c + 1, :].astype(jnp.int32), 0)
-             for c in range(ch)], axis=0).astype(jnp.int8)   # [ch*K, C]
+    packed = packed_operands(quant, b_ref.dtype, B)
+    if packed:
+        bin_ids = _packed_ids(B, C)
+        oh_bt = pltpu.bitcast(jnp.concatenate(
+            [_packed_onehot(blk[f:f + 1, :], bin_ids)
+             for f in range(Ft)], axis=0), jnp.int8)         # [Ft*B, C]
+    else:
+        iota_b = lax.broadcasted_iota(jnp.int32, (B, C), 0)
+        cell = jnp.int32 if quant else jnp.float32
         oh_bt = jnp.concatenate(
-            [(blk[f:f + 1, :] == iota_b).astype(jnp.int32)
-             for f in range(Ft)], axis=0).astype(jnp.int8)   # [Ft*B, C]
-        part = lax.dot_general(lhs, oh_bt, nt,
+            [(blk[f:f + 1, :] == iota_b).astype(cell)
+             for f in range(Ft)], axis=0)                    # [Ft*B, C]
+    if quant:
+        # the slot operand packed too where a channel's slots are whole
+        # int8 sublane tiles (at 16 slots the half-filled tiles cost more
+        # than the compare: 45.7 against 44.7 ms a pass, root PERF.md
+        # section 5) and a slot id fits a byte's seven bits
+        lhs = (_packed_slot_operand(s, v, K, ch)
+               if packed and K <= 128 and K % 32 == 0
+               else _compared_slot_operand(s, v, K, ch))
+        part = lax.dot_general(lhs, oh_bt.astype(jnp.int8), nt,
                                preferred_element_type=jnp.int32)
     else:
-        oh_sf = oh_s.astype(jnp.float32)
+        oh_sf = (s == lax.broadcasted_iota(jnp.int32, (K, C), 0)
+                 ).astype(jnp.float32)
         lhs = jnp.concatenate(
             [v[c:c + 1, :] * oh_sf for c in range(ch)], axis=0)
-        oh_bt = jnp.concatenate(
-            [(blk[f:f + 1, :] == iota_b).astype(jnp.float32)
-             for f in range(Ft)], axis=0)
         part = lax.dot_general(lhs, oh_bt, nt,
                                precision=lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
     acc[...] += part
+
+
+def _compared_slot_operand(s, v, K, ch):
+    """The int8 slot x value operand ``[ch*K, C]`` by one int32 compare
+    and select a cell."""
+    oh_s = s == lax.broadcasted_iota(jnp.int32, (K, s.shape[1]), 0)
+    return jnp.concatenate(
+        [jnp.where(oh_s, v[c:c + 1, :].astype(jnp.int32), 0)
+         for c in range(ch)], axis=0).astype(jnp.int8)
+
+
+def _packed_slot_operand(s, v, K, ch):
+    """The same operand four slots to a word.  A dropped row (slot == K;
+    128 would not fit the byte) takes slot 0 with value 0; 0/1 bytes
+    times a value byte <= 255 carry nothing, so one int32 multiply writes
+    four int8 cells."""
+    from jax.experimental.pallas import tpu as pltpu
+    live = s < K
+    oh_s = _packed_onehot(jnp.where(live, s, 0), _packed_ids(K, s.shape[1]))
+    vb = jnp.where(live, v.astype(jnp.int32) & 0xFF, 0)
+    return pltpu.bitcast(jnp.concatenate(
+        [oh_s * vb[c:c + 1, :] for c in range(ch)], axis=0), jnp.int8)
 
 
 def fused_frontier_accumulate(
@@ -546,11 +629,17 @@ def fused_frontier_accumulate(
 # round cap.  The one-hot matmul costs rows x F x (ch * slots) x B
 # multiply-adds whatever the rows hold, so a round of k live candidates
 # runs the narrowest of these that holds k.  Measured on one v5e at
-# 25.2M x 67, 64 bins, 2048-row tiles (root PERF.md section 5): int8 79 /
-# 87 / 106 / 180 ms a pass at 16 / 32 / 64 / 128 slots, f32 473 (16), 1439
-# (64, 512-row tiles), 2796 (128).  A width stays only where its pass is
-# at least 20% faster than the next wider kept one, so 32 goes; under 64
-# slots the int8 pass is bound by building the bin one-hot, not the MXU.
+# 25.2M x 67, 64 bins (root PERF.md section 5): int8 at 8192-row tiles
+# 44.7 / 81.3 / 157.1 ms a pass at 16 / 64 / 128 slots with the packed
+# operands (68.5 / 94.7 / 168.7 with the compared ones; 42.8 / 81.0 /
+# 156.6 with both operands handed over for nothing, so building them is
+# no longer what a pass waits for); f32 473 (16, 2048-row tiles), 1439
+# (64, 512-row tiles), 2796 (128).  When 32 went, at 2048-row tiles and
+# compared operands (79 / 87 / 106 / 180 ms at 16 / 32 / 64 / 128), a
+# width stayed only where its pass was at least 20% faster than the next
+# wider kept one; with the packed operands a 32-slot pass has not been
+# measured, and whether the offer should have that rung is the grower's
+# question (ROADMAP.md A3).
 NARROW_SLOT_WIDTHS = (16, 64)
 
 
@@ -559,6 +648,16 @@ def slot_widths(kcap: int) -> tuple:
     first: the rungs its passes run at and its offer moves on."""
     kcap = int(kcap)
     return tuple(w for w in NARROW_SLOT_WIDTHS if w < kcap) + (kcap,)
+
+
+def pass_builds_packed(num_bins: int, feat_tile: int, num_features: int,
+                       quant: bool, bin_dtype) -> bool:
+    """``packed_operands`` for the accumulate passes a booster runs at
+    these shapes (one answer for every slot width: the slot axis does not
+    enter it): what its pass counters follow."""
+    Ft = max(1, min(int(feat_tile), int(num_features)))
+    return packed_operands(
+        quant, bin_dtype, _arena_dims(1, int(num_bins), Ft, quant)[1])
 
 
 def frontier_accumulator(

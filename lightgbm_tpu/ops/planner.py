@@ -191,7 +191,10 @@ def fused_vmem_bytes(num_slots: int, num_bins: int, feat_tile: int,
     bins to whole 128-lane groups), the parent block, the double-buffered
     input tile windows and the two one-hot operands the dot consumes
     (bins ``[Ft·B, C]`` and slots x values ``[ch·K, C]``: they grow with
-    the row tile); the epilogue additionally materializes the 2K children
+    the row tile; a byte a cell in the integer family, which is all the
+    packed form of ``fused.packed_operands`` ever holds of them — the
+    compare form makes an int32 copy first, which the headroom has always
+    carried); the epilogue additionally materializes the 2K children
     (+ their rescale/prefix transients) and the tiny tuple blocks.
     Deliberately simple — the right ORDER for the fits/doesn't verdict,
     like ``predict_peak_bytes`` — and held to the chip's compiler at every
@@ -219,7 +222,8 @@ def fused_vmem_bytes(num_slots: int, num_bins: int, feat_tile: int,
 # ~0.2 us a grid step, 442,368 steps a pass at 25.2M x 67 in 512-row
 # tiles.  Measured on one v5e at 16 / 64 / 128 int8 slots, ms a pass: 143 /
 # 163 / 235 at 512 rows, 96 / 122 / 197 at 1024, 79 / 106 / 180 at 2048,
-# 72 / 99 / 172 at 4096, 68 / 95 / 168 at 8192 (root PERF.md section 5).
+# 72 / 99 / 172 at 4096, 68 / 95 / 168 at 8192 (root PERF.md section 5;
+# 45 / 81 / 157 at 8192 since the operands are built packed, ops/fused.py).
 # The VMEM model stops the f32 family earlier (the chip's compiler refuses
 # its 128-slot kernel at 4096 rows).
 FUSED_BLOCK_ROWS = (8192, 4096, 2048, 1024, 512, 256, 128)

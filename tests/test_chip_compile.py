@@ -1,8 +1,8 @@
 """The chip's compiler, held in tier-1: every Pallas kernel the main path
 can elect on a TPU is compiled here for a DESCRIBED v5e chip (the TPU
 compiler ships with jaxlib; no chip is attached), at HIGGS width —
-28 features, max_bin 63, 255 leaves, 1M and 10.5M-bucket rows — and
-every variant taken out of the on-chip election is shown to stay out
+28 features, max_bin 63, 255 leaves, 1M and 10.5M-bucket rows — the
+accumulate pass also at the benchmark's own shapes, and every variant taken out of the on-chip election is shown to stay out
 and to raise the compiler's own error when forced.
 
 Interpret mode (what the rest of tier-1 runs the kernels in) cannot see
@@ -152,6 +152,40 @@ def test_fused_accumulate_compiles_at_every_slot_width(one_chip, family,
         _shape(one_chip, (nf, ft, ROWS_10M), jnp.uint8),
         _shape(one_chip, (ch, ROWS_10M), dt),
         _shape(one_chip, (ROWS_10M,), jnp.int32))
+    assert _kernels(c) == 1
+
+
+@pytest.mark.parametrize("width", [16, 64, 128])
+@pytest.mark.parametrize("cols,rows", [
+    (67, 25_165_824),        # criteo-quant
+    (220, 8_388_608),        # istella-rank
+    (39, 33_554_432),        # criteo-cat
+])
+def test_packed_accumulate_compiles_at_the_benchmarks_shapes(one_chip, cols,
+                                                             rows, width):
+    """The pass every cell of the benchmark runs (64 bins, int8, the row
+    tile ``plan_fused`` elects) builds its operands four cells to a word:
+    Mosaic has to lower the int8 <-> int32 bitcasts, at 16 slots over
+    half-filled sublane tiles, and the packed bytes' int32 arithmetic."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.fused import (_arena_dims,
+                                        fused_frontier_accumulate,
+                                        packed_operands)
+    from lightgbm_tpu.ops.planner import plan_fused
+    bins = 64
+    ft = plan_fused(K, bins, True, num_features=cols)["feat_tile"]
+    tile = plan_fused(width, bins, True, feat_tile=ft)["block_rows"]
+    assert (ft, tile) == (8, 8192)
+    assert packed_operands(True, jnp.uint8,
+                           _arena_dims(width, bins, ft, True)[1])
+    c = _compile(
+        lambda b, v, s: fused_frontier_accumulate(
+            b, v, s, width, bins, block_rows=tile, num_features=cols,
+            interpret=False),
+        _shape(one_chip, (-(-cols // ft), ft, rows), jnp.uint8),
+        _shape(one_chip, (2, rows), jnp.int8),
+        _shape(one_chip, (rows,), jnp.int32))
     assert _kernels(c) == 1
 
 

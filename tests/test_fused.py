@@ -682,6 +682,142 @@ def test_histogram_pallas_tile_rows_parity():
     assert pal < sca
 
 
+def _numpy_arena(binned, vals, slot, K, B):
+    """[K, ch, F, B] slot histograms by ``np.add.at``, rows with
+    ``slot == K`` dropped; int64 inside, the values' family outside."""
+    F = binned.shape[0]
+    ch = vals.shape[0]
+    out = np.zeros((K + 1, ch, F, B),
+                   np.int64 if vals.dtype == np.int8 else np.float64)
+    for f in range(F):
+        for c in range(ch):
+            np.add.at(out[:, c, f, :], (slot, binned[f]), vals[c])
+    return out[:K]
+
+
+def _operand_case(B, F, K, n=700, bin_dtype=np.uint8, family="int8"):
+    rng = np.random.RandomState(B * 1000 + F * 10 + K)
+    binned = rng.randint(0, B, (F, n)).astype(bin_dtype)
+    binned[:, :9] = B - 1                         # the last bin, often
+    if family == "int8":
+        vals = rng.randint(-128, 128, (2, n)).astype(np.int8)
+        vals[:, :8] = np.array([-128, 127, -1, 1, 0, -127, 126, 2], np.int8)
+    else:
+        vals = rng.randint(-9, 10, (3, n)).astype(np.float32)
+    slot = rng.randint(0, K + 1, n).astype(np.int32)
+    slot[:4] = [K, 0, K - 1, K]                   # dropped rows and the ends
+    return binned, vals, slot
+
+
+def _count_calls(monkeypatch, name):
+    calls = []
+    real = getattr(FU, name)
+
+    def spy(*a, **k):
+        calls.append(1)
+        return real(*a, **k)
+
+    monkeypatch.setattr(FU, name, spy)
+    return calls
+
+
+# (num_bins, feat_tile, padded bins): _arena_dims pads a feature's bins to
+# whole lane groups of its tile, so tiles 1 and 3 only ever see 128
+_PACKED_SHAPES = [(30, 8, 32), (63, 8, 64), (128, 8, 128), (64, 1, 128),
+                  (100, 3, 128)]
+
+
+@pytest.mark.parametrize("K", [16, 64, 128])
+@pytest.mark.parametrize("B,feat_tile,padded", _PACKED_SHAPES)
+def test_packed_operands_give_the_numpy_arena(B, feat_tile, padded, K,
+                                              monkeypatch):
+    """Four bins to a word, four slots to a word: the int32 arena is the
+    NumPy reference's bit for bit, gradients at both signs and at the
+    int8 extremes, dropped rows, the last bin, a ragged last feature block
+    and a ragged last row tile."""
+    F = 2 * feat_tile + 1
+    assert FU._arena_dims(K, B, feat_tile, True) == (K, padded)
+    assert FU.packed_operands(True, np.uint8, padded)
+    binned, vals, slot = _operand_case(B, F, K)
+    calls = _count_calls(monkeypatch, "_packed_onehot")
+    got = FU.fused_frontier_accumulate(
+        jnp.asarray(binned), jnp.asarray(vals), jnp.asarray(slot), K, B,
+        feat_tile=feat_tile, block_rows=256, interpret=True)
+    assert calls, "the packed form did not engage"
+    assert got.dtype == jnp.int32
+    assert np.array_equal(np.asarray(got),
+                          _numpy_arena(binned, vals, slot, K, B))
+
+
+@pytest.mark.parametrize("case,B,feat_tile,padded,bin_dtype,family", [
+    ("f32_values", 63, 8, 64, np.uint8, "f32"),
+    ("two_byte_bins", 63, 8, 64, np.uint16, "int8"),
+    ("16_padded_bins", 16, 8, 16, np.uint8, "int8"),
+    ("256_padded_bins", 255, 2, 256, np.uint8, "int8"),
+])
+def test_compare_form_stays_off_the_packed_shapes(case, B, feat_tile, padded,
+                                                  bin_dtype, family,
+                                                  monkeypatch):
+    """``packed_operands`` says no for the f32 family, 2-byte bins and 16
+    or 256 padded bins; the kernel then never touches the packed builder
+    and its arena is what it was."""
+    K, F = 16, 2 * feat_tile + 1
+    quant = family == "int8"
+    assert FU._arena_dims(K, B, feat_tile, quant)[1] == padded
+    assert not FU.packed_operands(quant, bin_dtype, padded)
+
+    def refuse(*a, **k):
+        raise AssertionError("packed builder on a shape it is not exact for")
+
+    monkeypatch.setattr(FU, "_packed_onehot", refuse)
+    binned, vals, slot = _operand_case(B, F, K, bin_dtype=bin_dtype,
+                                       family=family)
+    got = np.asarray(FU.fused_frontier_accumulate(
+        jnp.asarray(binned), jnp.asarray(vals), jnp.asarray(slot), K, B,
+        feat_tile=feat_tile, block_rows=256, interpret=True))
+    # small integers in f32 sum exactly, so both families compare equal
+    assert np.array_equal(got, _numpy_arena(binned, vals, slot, K, B))
+
+
+@pytest.mark.parametrize("params,counter", [
+    (dict(tpu_hist_method="fused", use_quantized_grad=True, max_bin=63),
+     "hist_passes_packed_total"),
+    (dict(tpu_hist_method="fused", use_quantized_grad=True, max_bin=255),
+     "hist_passes_compared_total"),
+    (dict(tpu_hist_method="fused", max_bin=63), "hist_passes_compared_total"),
+    (dict(tpu_hist_method="scatter", use_quantized_grad=True, max_bin=63),
+     None),
+])
+def test_accumulate_passes_are_counted_by_their_form(params, counter):
+    """A tree's rounds + 1 passes count into the one counter the booster's
+    shape elects when its programs are built; neither without the kernel."""
+    from lightgbm_tpu.obs.flight import global_flight
+    from lightgbm_tpu.obs.metrics import global_registry
+    names = ("hist_passes_packed_total", "hist_passes_compared_total")
+
+    def counters():
+        c = global_registry.to_dict().get("counters", {})
+        return {n: c.get(n, 0) for n in names}
+
+    rng = np.random.RandomState(5)
+    X = rng.randn(2000, 9).astype(np.float32)
+    y = (X[:, 0] * X[:, 1] + X[:, 2] > 0).astype(np.float32)
+    before = counters()
+    bst = lgb.train(dict(objective="binary", num_leaves=15, verbose=-1,
+                         min_data_in_leaf=5, tpu_tree_growth="rounds",
+                         **params),
+                    lgb.Dataset(X, label=y), num_boost_round=3)
+    trees = [e["args"] for e in global_flight.ring_events()
+             if e.get("name") == "grower.tree"][-3:]
+    assert len(trees) == 3 == len(bst.models)
+    assert [t["it"] for t in trees] == [0, 1, 2]
+    grew = {n: v - before[n] for n, v in counters().items()}
+    want = dict.fromkeys(names, 0)
+    if counter is not None:
+        want[counter] = sum(t["rounds"] + 1 for t in trees)
+    assert grew == want
+
+
 @pytest.mark.slow
 def test_fused_stress_wide_frontier():
     """Accelerator-shaped stress: full round_width=64 frontier, B=64,
