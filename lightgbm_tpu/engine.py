@@ -181,11 +181,14 @@ def train(params: dict, train_set: Dataset, num_boost_round: int = 100,
     if resume_from is not None:
         from .resilience.checkpoint import (resolve_resume_point,
                                             restore_booster)
-        ck = resolve_resume_point(resume_from)
-        restore_booster(booster, ck)
-        _restore_callback_states(cbs_before + cbs_after,
-                                 ck.engine_state.get("callbacks", {}))
-        start_iter = ck.iteration
+        with _span("engine.resume", ring=True) as resume:
+            ck = resolve_resume_point(resume_from)
+            restore_booster(booster, ck)
+            _restore_callback_states(cbs_before + cbs_after,
+                                     ck.engine_state.get("callbacks", {}))
+            start_iter = ck.iteration
+            resume.set(it=start_iter, bytes=ck.nbytes)
+        _obs_registry.counter("checkpoint_resumes_total").inc()
         from .utils.log import log_info
         log_info(f"resume: restored iteration {start_iter} from "
                  f"{ck.path or resume_from}")
