@@ -145,9 +145,9 @@ def make_chunk_fn(b):
         # exactly as per-iteration training does (the stochastic-rounding
         # keys derive from the stacked per-round key stream `keys`)
         qss0 = jnp.zeros((c, K, 2), jnp.float32)
-        # the grower loop's (rounds, offered, applied, slots, clipped) per
-        # tree ride out the same way, beside the trees and not in them
-        gss0 = jnp.zeros((c, K, 5), jnp.int32)
+        # the grower loop's (rounds, offered, applied, slots, clipped,
+        # lanes) per tree ride out the same way, beside the trees
+        gss0 = jnp.zeros((c, K, 6), jnp.int32)
 
         def body(j, state):
             score, cu, cr, ys, qss, gss = state

@@ -305,30 +305,6 @@ def row_goes_left(col: jax.Array, node_thr: jax.Array, node_dl: jax.Array,
     return jnp.where(node_cat, cat_left, num_left)
 
 
-def bitset_halves(bitset: jax.Array, halves: int) -> jax.Array:
-    """[..., W] u32 category sets -> [..., halves] f32: the 16-bit halves
-    of their first ``halves / 2`` words, low half first.  Each is an integer
-    below 2^16, so a set rides an f32 table (``take_from_table``) exactly."""
-    words = bitset[..., :halves // 2]
-    both = jnp.stack([words & jnp.uint32(0xFFFF), words >> jnp.uint32(16)],
-                     axis=-1)
-    return both.reshape(bitset.shape[:-1] + (halves,)).astype(jnp.float32)
-
-
-def halves_hold(halves: jax.Array, col: jax.Array) -> jax.Array:
-    """``row_goes_left``'s bitset test on per-row sets that came as
-    ``bitset_halves`` rows ([halves, n] f32, component-leading): whether
-    bit ``col`` of each row's set is set.  A select over the halves and one
-    shift, elementwise: no per-row gather.  Bins past the halves carried
-    are in no set."""
-    col = col.astype(jnp.int32)
-    which = col >> 4
-    half = jnp.zeros(col.shape, jnp.int32)
-    for i in range(halves.shape[0]):
-        half = jnp.where(which == i, halves[i].astype(jnp.int32), half)
-    return ((half >> (col & 15)) & 1) == 1
-
-
 def grow_tree(binned_t, *args, **kwargs):
     """Grow one tree (full signature/contract: ``_grow_tree_traced``).
 
@@ -1413,8 +1389,8 @@ def leaf_router_engages(meta: FeatureMeta) -> bool:
     programs are built, so it is also what the ``valid_update_trees_*``
     counters follow.  The path form compares a node's threshold with the
     row's bin and has no arm for a category set yet (the rounds grower's
-    router carries sets as 16-bit halves, ``bitset_halves``; doing the same
-    here waits for a benchmark cell that would guard it), so categorical
+    router carries sets as bytes, ``grower_rounds.set_bytes_hold``; doing
+    the same here waits for a benchmark cell that would guard it), so categorical
     splits keep the walk; and one-hot matmuls lose off the accelerator."""
     return on_accelerator() and not meta.is_categorical.any()
 

@@ -60,6 +60,14 @@ gains and commits in the same order, so the tree does not depend on it.
 The fifth counter, ``clipped``, counts the rounds that the offer (not a
 child) ended: the passes it may have cost.
 
+Routing.  A round hands every row its candidate's rank (``crank``) and
+its goes-left bit.  On the accelerator the router form does it a block of
+rows at a time against the round's lanes (``route_lanes``): as many as
+the pass is wide on the fused arm, in the same branch of the width switch
+as the pass, so a 16-slot round compares a row with 16 lanes and not with
+every leaf.  The sixth counter, ``lanes``, sums them.  The candidate scan
+(``route_scan``) is the CPU form and the router's oracle.
+
 Support matrix: EFB bundles, bagging/GOSS weights, per-tree and per-node
 column sampling, extra_trees, monotone constraints, max_depth, and
 data-parallel row sharding (``axis_name`` -> histogram/scalar psums).
@@ -79,13 +87,13 @@ from jax import lax
 
 from .dataset import FeatureMeta
 from .grower import (GrowerConfig, TreeArrays, _LeafBest, _psum,
-                     bitset_halves, halves_hold, row_goes_left)
+                     row_goes_left)
 from .ops.histogram import (build_histogram, build_histogram_int,
                             capacity_schedule, compacted_segment_histogram,
                             compacted_segment_histogram_int, pack_cols_u32,
                             pack_cols_u32_quant, psum_quant_hist,
                             quant_levels, resolve_hist_method,
-                            take_from_table, use_sorted_seghist)
+                            use_sorted_seghist)
 from .ops.split import (MAX_CAT_WORDS, SplitResult, best_split_for_leaf,
                         leaf_output, quant_rescale_hist)
 
@@ -124,13 +132,177 @@ def next_offer(rungs: jax.Array, m, m_before) -> jax.Array:
 
 
 def router_engages() -> bool:
-    """Whether a round routes rows by the router form (one table matmul
-    and one decision a row) or by the candidate scan (one pass over the
-    rows a candidate): fixed by the backend when the round program is
-    traced, so it is also what the ``grower_rounds_*_total`` counters
-    follow (``GBDT._note_trees``)."""
+    """Whether a round routes rows by the router form (``route_lanes``:
+    one decision a row against the round's lanes) or by the candidate scan
+    (one pass over the rows a candidate): fixed by the backend when the
+    round program is traced, so it is also what the
+    ``grower_rounds_*_total`` counters follow (``GBDT._note_trees``)."""
     return (use_sorted_seghist()
             and os.environ.get("LGBM_TPU_ROUTER") != "0")
+
+
+# The router decides rows a block at a time: a step of its loop reads a
+# block's leaf ids and binned columns and writes only the block's
+# (crank, gl, slot); the last block starts at n - block and rewrites rows
+# the one before decided the same way, so nothing is padded.
+ROUTE_BLOCK = 1 << 17
+
+
+def _byte_count(bound: int) -> int:
+    """Bytes that hold every integer in [0, bound)."""
+    return max(1, (max(int(bound) - 1, 1).bit_length() + 7) // 8)
+
+
+def set_bytes_hold(set_bytes, col):
+    """``row_goes_left``'s bitset test on per-row sets that came as their
+    little-endian bytes (a sequence of [C] i32 in [0, 256)): whether bit
+    ``col`` of each row's set is set.  A select over the bytes and one
+    shift, elementwise: no per-row gather.  Bins past the bytes carried are
+    in no set."""
+    col = col.astype(jnp.int32)
+    which = col >> 3
+    byte = jnp.zeros(col.shape, jnp.int32)
+    for i, b in enumerate(set_bytes):
+        byte = jnp.where(which == i, b, byte)
+    return ((byte >> (col & 7)) & 1) == 1
+
+
+def route_lanes(binned_t, leaf_id, idl, k, W: int, best, kcap: int,
+                bin_layout, num_bins: int, set_words: int):
+    """The router form of a round's routing against its ``W`` lanes.
+
+    Lane ``r`` is the round's ``r``-th candidate ``idl[r]``, live where
+    ``r < k`` (``k <= W``).  Per lane its split's parameters become bytes
+    (an integer under 256 each); a block of rows compares its leaf ids with
+    the lanes, takes its lane's bytes by one bf16 one-hot matmul (one
+    nonzero a column and every value under 256, so exact; on one v5e it
+    beat a select over the lanes at 16 lanes too, PERF.md section 5),
+    reads the bin of its split's feature by a select-reduce over the
+    block's columns, and decides by ``row_goes_left``'s rule.
+
+    Returns ``crank`` ([n] i32: the row's lane, ``kcap`` where none is
+    live), ``gl`` ([n] bool, meaningful where ``crank < kcap``) and
+    ``slot`` ([n] i32: ``crank`` where the row goes to the smaller child,
+    else ``kcap``): what the candidate scan gives, with no per-row
+    parameter array outside a block.  ``num_bins`` bounds every bin-valued
+    parameter; ``set_words``: how many words of a categorical lane's set
+    ride along (0 = no categorical column)."""
+    num_bin, missing_type, default_bin, feat_group, feat_start = bin_layout
+    G, n = binned_t.shape
+    F = num_bin.shape[0]
+    leaf = idl[:W]
+    r = jnp.arange(W, dtype=jnp.int32)
+    lane_leaf = jnp.where(r < k, leaf, -1)
+    feat = jnp.clip(best.feature[leaf], 0, F - 1)
+    flags = (best.default_left[leaf].astype(jnp.int32)
+             | ((best.left_count[leaf] <= best.right_count[leaf])
+                .astype(jnp.int32) << 1)
+             | (missing_type[feat] << 2))
+    if set_words:
+        flags = flags | (best.is_categorical[leaf].astype(jnp.int32) << 4)
+    cols = [(jnp.where(r < k, r + 1, 0), W + 1),    # crank + 1, 0 = none
+            (feat_group[feat], G),
+            (best.threshold[leaf], num_bins),
+            (flags, 32),
+            (default_bin[feat], num_bins),
+            (num_bin[feat], num_bins + 1),
+            (feat_start[feat], num_bins + 1)]
+    lane_bytes, spans = [], []
+    for v, bound in cols:
+        nb_ = _byte_count(bound)
+        spans.append((len(lane_bytes), nb_))
+        lane_bytes += [(v >> (8 * i)) & 255 for i in range(nb_)]
+    set_at = len(lane_bytes)
+    if set_words:
+        words = best.cat_bitset[leaf][:, :set_words]
+        lane_bytes += [((words[:, w] >> jnp.uint32(8 * i)) & jnp.uint32(255))
+                       .astype(jnp.int32)
+                       for w in range(set_words) for i in range(4)]
+    lane_t = jnp.stack(lane_bytes).astype(jnp.bfloat16)    # [P, W]
+    iota_G = jnp.arange(G, dtype=jnp.int32)
+
+    def decide(lid, bt):
+        eq = (lane_leaf[:, None] == lid[None, :]).astype(jnp.bfloat16)
+        rb = lax.dot(lane_t, eq)                          # [P, C], exact
+        byte = [rb[p].astype(jnp.int32) for p in range(len(lane_bytes))]
+
+        def col(j):
+            at, nb_ = spans[j]
+            v = byte[at]
+            for i in range(1, nb_):
+                v = v | (byte[at + i] << (8 * i))
+            return v
+        crank1, grp, thr, fl, db, nbr, fs = (col(j) for j in range(7))
+        crank = jnp.where(crank1 == 0, kcap, crank1 - 1)
+        bin_ = jnp.sum(jnp.where(iota_G[:, None] == grp[None, :],
+                                 bt.astype(jnp.int32), 0), axis=0)
+        dec = bin_ - fs + 1
+        binf = jnp.where((dec >= 1) & (dec < nbr), dec, 0)
+        gl = row_goes_left(binf, thr, (fl & 1) == 1, None, None,
+                           (fl >> 2) & 3, db, nbr)
+        if set_words:
+            gl = jnp.where(((fl >> 4) & 1) == 1,
+                           set_bytes_hold(byte[set_at:], binf), gl)
+        slot = jnp.where(gl == (((fl >> 1) & 1) == 1), crank, kcap)
+        return crank, gl, slot
+
+    C = min(ROUTE_BLOCK, n)
+
+    def step(i, outs):
+        s = jnp.minimum(i * C, n - C)
+        got = decide(lax.dynamic_slice_in_dim(leaf_id, s, C),
+                     lax.dynamic_slice_in_dim(binned_t, s, C, axis=1))
+        return tuple(lax.dynamic_update_slice_in_dim(o, v, s, 0)
+                     for o, v in zip(outs, got))
+
+    return lax.fori_loop(0, -(-n // C), step,
+                         (jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.bool_),
+                          jnp.zeros(n, jnp.int32)))
+
+
+def route_scan(binned_t, leaf_id, idl, k, best, kcap: int, bin_layout,
+               has_cat: bool):
+    """The candidate scan form of a round's routing: one step per
+    candidate reads its split feature as a CONTIGUOUS column of the
+    transposed matrix and broadcasts scalar split params (kept for CPU,
+    where one-hot matmuls lose, and as the router's oracle: the two forms
+    grow the same trees bit for bit, tests/test_cat_router.py).  Returns
+    ``route_lanes``'s (crank, gl, slot)."""
+    num_bin, missing_type, default_bin, feat_group, feat_start = bin_layout
+    n = binned_t.shape[1]
+    F = num_bin.shape[0]
+    b = best
+
+    def cstep(carry, kk):
+        def live(carry):
+            gl_a, crank_a, small_a = carry
+            leaf = idl[kk]
+            feat = jnp.clip(b.feature[leaf], 0, F - 1)
+            col = lax.dynamic_index_in_dim(binned_t, feat_group[feat], 0,
+                                           keepdims=False)   # [n]
+            nb = num_bin[feat]
+            dec = col.astype(jnp.int32) - feat_start[feat] + 1
+            binf = jnp.where((dec >= 1) & (dec < nb), dec, 0)
+            glk = row_goes_left(
+                binf, b.threshold[leaf], b.default_left[leaf],
+                b.is_categorical[leaf] if has_cat else None,
+                b.cat_bitset[leaf] if has_cat else None,
+                missing_type[feat], default_bin[feat], nb)
+            mk = leaf_id == leaf
+            sl = b.left_count[leaf] <= b.right_count[leaf]
+            return (jnp.where(mk, glk, gl_a),
+                    jnp.where(mk, kk, crank_a),
+                    jnp.where(mk, glk == sl, small_a))
+        # skip the O(n) column read + masking for dead candidate
+        # lanes (late-tree rounds often have k of 1-2 of KCAP)
+        return lax.cond(kk < k, live, lambda c_: c_, carry), None
+
+    (gl, crank, row_small), _ = lax.scan(
+        cstep,
+        (jnp.zeros(n, jnp.bool_), jnp.full(n, kcap, jnp.int32),
+         jnp.zeros(n, jnp.bool_)),
+        jnp.arange(kcap, dtype=jnp.int32))
+    return crank, gl, jnp.where(row_small, crank, kcap)
 
 
 def grow_tree_rounds(binned_t, *args, **kwargs):
@@ -165,12 +337,15 @@ def _grow_tree_rounds_traced(
     with_stats: bool = False,
 ):
     """Grow one tree; returns (TreeArrays, leaf_id [n] i32), and with
-    ``with_stats`` a third [5] i32: the loop's trips, the candidates it
+    ``with_stats`` a third [6] i32: the loop's trips, the candidates it
     offered (a round builds that many smaller-child histograms), the
     splits it committed, the slot widths its histogram passes ran at,
     summed (the fused arm's root pass included; a staged pass runs at the
-    round cap), and the trips the offer clipped (it bound ``k`` and all
-    ``k`` committed: the trips it may have cost a pass)."""
+    round cap), the trips the offer clipped (it bound ``k`` and all ``k``
+    committed: the trips it may have cost a pass), and the lanes its
+    route compared rows with, summed (the router's ``W`` a round, the
+    width of its pass on the fused arm and the cap staged; the scan's
+    ``k``)."""
     meta = meta.resolved()
     G, n = binned_t.shape
     L = cfg.num_leaves
@@ -255,13 +430,14 @@ def _grow_tree_rounds_traced(
         packed = pack_cols_u32_quant(binned_t, q_grad, q_hess, row_mask > 0)
     else:
         packed = pack_cols_u32(binned_t, grad, hess, row_mask)
-    # router-matmul candidate routing (see body): O(n)/round instead of
-    # the scan's O(k*n); accelerator-shaped.  A categorical candidate's
-    # set rides the same table as 16-bit halves of its words (exact in
-    # f32), as many as the widest feature's bins need.  LGBM_TPU_ROUTER=0
-    # forces the scan (bisect/testing hook)
+    # router candidate routing (route_lanes): O(n)/round instead of the
+    # scan's O(k*n); accelerator-shaped.  A categorical candidate's set
+    # rides the lane's bytes, as many words as the widest feature's bins
+    # need.  LGBM_TPU_ROUTER=0 forces the scan (bisect/testing hook)
     use_router = router_engages()
-    cat_halves = 2 * min(MAX_CAT_WORDS, -(-B // 32)) if has_cat else 0
+    set_words = min(MAX_CAT_WORDS, -(-B // 32)) if has_cat else 0
+    bin_layout = tuple(a.astype(jnp.int32) for a in (
+        num_bin, missing_type, default_bin, feat_group, feat_start))
     # segment-histogram precision follows the resolved histogram method so
     # parent - smaller-child subtraction stays consistent: only the bf16
     # one-hot matmul is inexact; every other kernel accumulates f32-exact
@@ -379,8 +555,8 @@ def _grow_tree_rounds_traced(
         if quant:
             member = row_mask > 0
             if use_fused:
-                root_arena, root_width = fused_accumulate(
-                    jnp.where(member, 0, KCAP), 1)
+                root_arena, root_width, _ = fused_accumulate(
+                    lambda W: (jnp.where(member, 0, KCAP), None), 1)
                 root_local = root_arena[0]
             else:
                 root_local = build_histogram_int(
@@ -395,8 +571,8 @@ def _grow_tree_rounds_traced(
             root_cnt = psum_(jnp.sum(member.astype(jnp.float32)))
         else:
             if use_fused:
-                root_arena, root_width = fused_accumulate(
-                    jnp.where(row_mask > 0, 0, KCAP), 1)
+                root_arena, root_width, _ = fused_accumulate(
+                    lambda W: (jnp.where(row_mask > 0, 0, KCAP), None), 1)
                 root_local = root_arena[0]
             else:
                 root_local = hist_fn(binned_t, grad, hess, row_mask)
@@ -441,6 +617,7 @@ def _grow_tree_rounds_traced(
         offer: jax.Array        # most candidates the next round may offer
         last_m: jax.Array       # splits the last round committed
         clipped: jax.Array      # trips the offer bound and wholly committed
+        lanes: jax.Array        # sum of the lanes the route compared rows with
 
     iota_L = jnp.arange(L, dtype=jnp.int32)
 
@@ -541,7 +718,7 @@ def _grow_tree_rounds_traced(
                      leaf_parent_side, new_leaf_id, c.split_idx + k,
                      leaf_min, leaf_max, c.rounds, c.offered,
                      c.applied + k, c.slots, c.offer, c.last_m,
-                     c.clipped)
+                     c.clipped, c.lanes)
 
     def child_bounds(c: Carry):
         """Per-leaf monotone bounds the two children of each leaf's cached
@@ -585,116 +762,41 @@ def _grow_tree_rounds_traced(
             rank = jnp.zeros(L, jnp.int32).at[order].set(iota_L)
 
             # -- candidate routing: per-row goes-left bit, candidate rank, and
-            # smaller-child membership for the whole batch.
+            # smaller-child slot for the whole batch.
             b = c.best
             idl = jnp.clip(order[:KCAP], 0, L - 1)          # candidate leaves
-
-            if use_router:
-                # ROUTER MATMUL (accelerator path): ONE [9, n]
-                # take_from_table one-hot matmul hands every row its
-                # leaf's split params, then one fused [G, n] select-reduce
-                # reads the row's split-feature bin — O(G*n) total per round
-                # (~one binned-matrix stream, the cost the expanded segment
-                # histogram already pays) vs the scan's O(k*n) column passes:
-                # a clear win on the wide rounds (k up to 128) and a ~one-
-                # stream overhead on narrow ones.  All table values are
-                # integers < 2^16 or flags: exact in f32.  With categorical
-                # features the table gains the candidate's kind and its
-                # set's half-words (``cat_halves`` more rows).
-                feat_l = jnp.clip(b.feature, 0, F - 1)
-                live_l = pos & (rank < k)
-                tbl = jnp.stack([
-                    jnp.where(live_l, rank, KCAP).astype(jnp.float32),  # crank
-                    feat_group[feat_l].astype(jnp.float32),             # group
-                    b.threshold.astype(jnp.float32),
-                    b.default_left.astype(jnp.float32),
-                    missing_type[feat_l].astype(jnp.float32),
-                    default_bin[feat_l].astype(jnp.float32),
-                    num_bin[feat_l].astype(jnp.float32),
-                    feat_start[feat_l].astype(jnp.float32),
-                    (b.left_count <= b.right_count).astype(jnp.float32),
-                ], axis=1)                                   # [L, 9]
-                if has_cat:
-                    tbl = jnp.concatenate([
-                        tbl, b.is_categorical.astype(jnp.float32)[:, None],
-                        bitset_halves(b.cat_bitset, cat_halves)], axis=1)
-                prm = take_from_table(tbl, c.leaf_id, leading=True)  # [9+, n]
-                crank = prm[0].astype(jnp.int32)
-                grp = prm[1].astype(jnp.int32)
-                thr_r = prm[2].astype(jnp.int32)
-                dl_r = prm[3] > 0.5
-                mt_r = prm[4].astype(jnp.int32)
-                db_r = prm[5].astype(jnp.int32)
-                nb_r = prm[6].astype(jnp.int32)
-                fs_r = prm[7].astype(jnp.int32)
-                sl_r = prm[8] > 0.5
-                # row's bin of its leaf's split feature: a select-reduce over
-                # the feature-major matrix (exactly one group matches; fused —
-                # no [n, G] intermediate, no serialized gather)
-                iota_G = jnp.arange(G, dtype=jnp.int32)
-                col = jnp.sum(jnp.where(iota_G[:, None] == grp[None, :],
-                                        binned_t.astype(jnp.int32), 0), axis=0)
-                dec = col - fs_r + 1
-                binf = jnp.where((dec >= 1) & (dec < nb_r), dec, 0)
-                # the numeric fast path of the one documented decision-rule
-                # mirror (DenseBin::SplitInner) — per-row params broadcast
-                gl = row_goes_left(binf, thr_r, dl_r, None, None,
-                                   mt_r, db_r, nb_r)
-                if has_cat:
-                    # the same rule's categorical arm: bit ``bin`` of the
-                    # row's candidate's set, from the halves the table
-                    # carried — elementwise, no gather
-                    gl = jnp.where(prm[9] > 0.5,
-                                   halves_hold(prm[10:], binf), gl)
-                row_small = gl == sl_r
-            else:
-                # candidate scan: one step per candidate reads its split
-                # feature as a CONTIGUOUS column of the transposed matrix and
-                # broadcasts scalar split params (kept for CPU, where one-hot
-                # matmuls lose, and as the router's oracle: the two forms
-                # grow the same trees bit for bit, tests/test_cat_router.py)
-                def cstep(carry, kk):
-                    def live(carry):
-                        gl_a, crank_a, small_a = carry
-                        leaf = idl[kk]
-                        feat = jnp.clip(b.feature[leaf], 0, F - 1)
-                        col = lax.dynamic_index_in_dim(binned_t,
-                                                       feat_group[feat], 0,
-                                                       keepdims=False)   # [n]
-                        nb = num_bin[feat]
-                        dec = col.astype(jnp.int32) - feat_start[feat] + 1
-                        binf = jnp.where((dec >= 1) & (dec < nb), dec, 0)
-                        glk = row_goes_left(
-                            binf, b.threshold[leaf], b.default_left[leaf],
-                            b.is_categorical[leaf] if has_cat else None,
-                            b.cat_bitset[leaf] if has_cat else None,
-                            missing_type[feat], default_bin[feat], nb)
-                        mk = c.leaf_id == leaf
-                        sl = b.left_count[leaf] <= b.right_count[leaf]
-                        return (jnp.where(mk, glk, gl_a),
-                                jnp.where(mk, kk, crank_a),
-                                jnp.where(mk, glk == sl, small_a))
-                    # skip the O(n) column read + masking for dead candidate
-                    # lanes (late-tree rounds often have k of 1-2 of KCAP)
-                    return lax.cond(kk < k, live, lambda c_: c_, carry), None
-
-                (gl, crank, row_small), _ = lax.scan(
-                    cstep,
-                    (jnp.zeros(n, jnp.bool_), jnp.full(n, KCAP, jnp.int32),
-                     jnp.zeros(n, jnp.bool_)),
-                    jnp.arange(KCAP, dtype=jnp.int32))
-
-            # smaller-child segment histograms: one pass for the whole
-            # candidate batch (slot r = the round's r-th candidate)
             small_left = b.left_count <= b.right_count
-            slot = jnp.where(row_small, crank, KCAP)
+            if use_router:
+                # the router form (accelerator path): the rows are decided
+                # a block at a time against the round's W lanes
+                # (route_lanes): O(n) a round at W compares a row instead
+                # of the scan's k column passes, and no per-row parameter
+                # array.  On the fused arm W is the width of the round's
+                # accumulate pass and the route runs in that pass's branch
+                # of the width switch; staged, W is the cap
+                def route_at(W):
+                    with jax.named_scope("lgbm.route"):
+                        crank_, gl_, slot_ = route_lanes(
+                            binned_t, c.leaf_id, idl, k, W, b, KCAP,
+                            bin_layout, max(B, Bg), set_words)
+                    return slot_, (crank_, gl_)
+            else:
+                crank_s, gl_s, slot_s = route_scan(
+                    binned_t, c.leaf_id, idl, k, b, KCAP, bin_layout,
+                    has_cat)
+
+                def route_at(W):
+                    return slot_s, (crank_s, gl_s)
+            if not use_fused:
+                slot, (crank, gl) = route_at(KCAP)
         width = jnp.int32(KCAP)
         with jax.named_scope("lgbm.hist"):
             if use_fused:
                 # the accumulate half of the fused megakernel, at the
-                # narrowest compiled width that holds the k candidates;
-                # sharded, exactly these (padded) hists cross the wire
-                seg, width = fused_accumulate(slot, k)
+                # narrowest compiled width that holds the k candidates, in
+                # one switch with the route; sharded, exactly these
+                # (padded) hists cross the wire
+                seg, width, (crank, gl) = fused_accumulate(route_at, k)
                 seg = (psum_quant_hist(seg, axis_name, rows_global,
                                        cfg.quant_bins, hierarchical=hier_rd)
                        if quant else psum_(seg))
@@ -710,6 +812,9 @@ def _grow_tree_rounds_traced(
                     binned_t, grad, hess, row_mask, slot, KCAP, Bg, caps,
                     f32_vals=seg_f32, num_live=k, packed=packed,
                     tile_rows=tile), axis_name, hier_rd, pinned_rd)
+        # the lanes the route compared rows with: the router's W, the
+        # scan's live candidates
+        lanes = width if use_router else k
 
         # -- candidate children's best splits, BEFORE committing anything:
         # per-leaf candidates are independent, so lane i's results are
@@ -840,13 +945,14 @@ def _grow_tree_rounds_traced(
                 rounds=c.rounds + 1, offered=c.offered + k,
                 slots=c.slots + width,
                 offer=next_offer(rungs, m, c.last_m), last_m=m,
-                clipped=c.clipped + ((c.offer < room) & (m == k)))
+                clipped=c.clipped + ((c.offer < room) & (m == k)),
+                lanes=c.lanes + lanes)
 
     zero = jnp.array(0, jnp.int32)
     init = Carry(tree, best, hist_cache, leaf_sg, leaf_sh, leaf_cnt,
                  leaf_parent_side, leaf_id, zero, leaf_min, leaf_max,
                  zero, zero, zero, root_width if use_fused else zero,
-                 rungs[0], zero, zero)
+                 rungs[0], zero, zero, zero)
     out = lax.while_loop(cond, body, init)
 
     # finalize leaf values (reference: CalculateSplittedLeafOutput; clamped
@@ -877,5 +983,6 @@ def _grow_tree_rounds_traced(
         )
     if with_stats:
         return tree, out.leaf_id, jnp.stack(
-            [out.rounds, out.offered, out.applied, out.slots, out.clipped])
+            [out.rounds, out.offered, out.applied, out.slots, out.clipped,
+             out.lanes])
     return tree, out.leaf_id

@@ -669,13 +669,16 @@ def frontier_accumulator(
     block_rows: Optional[int] = None,   # row tile AT ``kcap`` slots
     tile_rows: Optional[int] = None,
 ):
-    """``accumulate(slot, k) -> (hist [kcap, ch, F, B], width)``: one
-    ``fused_frontier_accumulate`` pass at the narrowest compiled slot
-    width that holds the ``k`` live slots of ``slot`` ([n] i32 in
-    [0, k) or >= k = dropped), zero-padded to ``kcap`` slots so whatever
-    follows (the collective, the scan) keeps one shape.  ``k`` is a
-    Python int (the root: one slot) or a traced i32 (a round: the pass
-    runs under ``lax.switch``); ``width`` is the slot width that ran.
+    """``accumulate(route, k) -> (hist [kcap, ch, F, B], width, aux)``:
+    one ``fused_frontier_accumulate`` pass at the narrowest compiled slot
+    width ``W`` that holds the ``k`` live slots, zero-padded to ``kcap``
+    slots so whatever follows (the collective, the scan) keeps one shape.
+    ``route(W) -> (slot, aux)`` makes the pass's ``slot`` ([n] i32 in
+    [0, k) or >= k = dropped) inside the branch that runs at ``W``, so a
+    router that works at the pass's width shares its switch; ``aux`` is
+    whatever else it returns.  ``k`` is a Python int (the root: one slot)
+    or a traced i32 (a round: the pass runs under ``lax.switch``);
+    ``width`` is the slot width that ran.
 
     Built once a tree, outside the grower's loop: every width reads ONE
     feature-blocked operand (``fused_blocked_bins``), at the feature
@@ -702,22 +705,23 @@ def frontier_accumulator(
     tiles = tuple(_row_tile(planned_rows(W), tile_rows, n) for W in widths)
     bins = fused_blocked_bins(binned_t, feat_tile, math.lcm(*tiles))
 
-    def at(W, C):
-        def run(slot):
+    def at(W, C, route):
+        def run():
+            slot, aux = route(W)
             hist = fused_frontier_accumulate(
                 bins, vals_t, jnp.minimum(slot, W), W, num_bins,
                 block_rows=C, num_features=F)
-            return jnp.pad(hist, ((0, kcap - W),) + ((0, 0),) * 3)
+            return jnp.pad(hist, ((0, kcap - W),) + ((0, 0),) * 3), aux
         return run
 
-    branches = [at(W, C) for W, C in zip(widths, tiles)]
-
-    def accumulate(slot, k):
+    def accumulate(route, k):
+        branches = [at(W, C, route) for W, C in zip(widths, tiles)]
         i = sum(k > W for W in widths[:-1])     # the narrowest width >= k
         if isinstance(i, int):                  # static k, or one width
-            return branches[i](slot), jnp.int32(widths[i])
-        return (lax.switch(i, branches, slot),
-                jnp.asarray(widths, jnp.int32)[i])
+            hist, aux = branches[i]()
+            return hist, jnp.int32(widths[i]), aux
+        hist, aux = lax.switch(i, branches)
+        return hist, jnp.asarray(widths, jnp.int32)[i], aux
 
     return accumulate
 

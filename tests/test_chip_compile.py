@@ -368,43 +368,65 @@ def test_fused_traversal_is_out_of_the_election(one_chip, forest):
         _compile(lambda x: PK.fused_traverse(dev, x, 512, interpret=False), X)
 
 
-def test_rounds_grower_compiles_with_the_offer(one_chip, as_accelerator):
+def _no_row_parameter_array(compiled, n):
+    """The router decides rows a block at a time: no f32 array of the
+    program holds two or more values a row (a per-row parameter table, or
+    its transpose; the gradients are one a row)."""
+    import re
+    sizes = [int(np.prod([int(d) for d in m.split(",")]))
+             for m in re.findall(r"\bf32\[([\d,]+)\]", compiled.as_text())]
+    return max(sizes) < 2 * n
+
+
+@pytest.mark.parametrize("f,n", [(F, ROWS_1M), (67, 25_165_824),
+                                 (220, 8_388_608)],
+                         ids=["higgs", "criteo-quant", "istella-rank"])
+def test_rounds_grower_compiles_with_the_offer(one_chip, as_accelerator, f,
+                                               n):
     """One whole tree of the rounds grower, int8 gradients, as the chip
-    runs it: the root pass, then a loop whose round elects the width of
-    its accumulate pass from the ``k`` its offer allowed — one Mosaic
-    kernel a width and the root's, and the whole program compiles."""
+    runs it, at HIGGS width and at the benchmark cells' shapes: the root
+    pass, then a loop whose round elects the width of its accumulate pass
+    from the ``k`` its offer allowed, the route in the same branch — one
+    Mosaic kernel a width and the root's, the whole program compiles, and
+    no per-row parameter array of all the rows exists."""
     import jax.numpy as jnp
 
     from lightgbm_tpu.dataset import FeatureMeta
     from lightgbm_tpu.grower import GrowerConfig
-    from lightgbm_tpu.grower_rounds import grow_tree_rounds
+    from lightgbm_tpu.grower_rounds import grow_tree_rounds, router_engages
     from lightgbm_tpu.ops.fused import slot_widths
     from lightgbm_tpu.ops.split import SplitHyperparams
-    nb, mt, db = _meta_vectors()
-    meta = FeatureMeta(num_bin=nb, missing_type=mt, default_bin=db,
-                       most_freq_bin=np.zeros((F,), np.int32),
-                       is_categorical=np.zeros((F,), bool), max_num_bin=B)
+    assert router_engages()
+    meta = FeatureMeta(num_bin=np.full((f,), B, np.int32),
+                       missing_type=np.zeros((f,), np.int32),
+                       default_bin=np.zeros((f,), np.int32),
+                       most_freq_bin=np.zeros((f,), np.int32),
+                       is_categorical=np.zeros((f,), bool), max_num_bin=B)
     cfg = GrowerConfig(num_leaves=LEAVES, num_bins=B + 1, quant=True,
                        quant_bins=4, hist_method="fused",
                        hp=SplitHyperparams(min_data_in_leaf=20))
-    rows = _shape(one_chip, (ROWS_1M,), jnp.float32)
-    q = _shape(one_chip, (ROWS_1M,), jnp.int8)
+    rows = _shape(one_chip, (n,), jnp.float32)
+    q = _shape(one_chip, (n,), jnp.int8)
     scale = _shape(one_chip, (), jnp.float32)
     c = _compile(
         lambda b, g, h, m, gq, hq, gs, hs: grow_tree_rounds(
             b, g, h, m, meta, cfg, quant_vals=(gq, hq, gs, hs),
             with_stats=True),
-        _shape(one_chip, (F, ROWS_1M), jnp.uint8), rows, rows, rows,
+        _shape(one_chip, (f, n), jnp.uint8), rows, rows, rows,
         q, q, scale, scale)
     assert _kernels(c) == 1 + len(slot_widths(K))
+    assert _no_row_parameter_array(c, n)
 
 
+@pytest.mark.parametrize("n", [ROWS_1M, 33_554_432],
+                         ids=["1m", "criteo-cat"])
 def test_rounds_grower_compiles_with_categorical_columns(one_chip,
-                                                         as_accelerator):
+                                                         as_accelerator, n):
     """The click log's schema (13 numeric + 26 categorical columns, 64
-    bins) through one whole tree as the chip runs it: the router form with
-    the candidates' sets on its table, and the categorical scan (a sort a
-    column) merged into the fused pick under int32 histograms."""
+    bins) through one whole tree as the chip runs it, at 1M rows and at
+    the cell's: the router form with the candidates' sets on its lanes,
+    and the categorical scan (a sort a column) merged into the fused pick
+    under int32 histograms."""
     import jax.numpy as jnp
 
     from lightgbm_tpu.binning import MissingType
@@ -427,17 +449,18 @@ def test_rounds_grower_compiles_with_categorical_columns(one_chip,
                        quant_bins=4, hist_method="fused",
                        hp=SplitHyperparams(min_data_in_leaf=1,
                                            min_sum_hessian_in_leaf=100.0))
-    rows = _shape(one_chip, (ROWS_1M,), jnp.float32)
-    q = _shape(one_chip, (ROWS_1M,), jnp.int8)
+    rows = _shape(one_chip, (n,), jnp.float32)
+    q = _shape(one_chip, (n,), jnp.int8)
     scale = _shape(one_chip, (), jnp.float32)
     c = _compile(
         lambda b, g, h, m, gq, hq, gs, hs: grow_tree_rounds(
             b, g, h, m, meta, cfg, quant_vals=(gq, hq, gs, hs),
             with_stats=True),
-        _shape(one_chip, (f, ROWS_1M), jnp.uint8), rows, rows, rows,
+        _shape(one_chip, (f, n), jnp.uint8), rows, rows, rows,
         q, q, scale, scale)
     assert _kernels(c) == 1 + len(slot_widths(K))
     assert "lgbm.cat_scan" in c.as_text()
+    assert _no_row_parameter_array(c, n)
 
 
 def test_validation_update_compiles_in_its_path_form(one_chip,

@@ -306,9 +306,11 @@ def test_width_election_matches_the_fixed_width(family, monkeypatch):
     np.testing.assert_allclose(np.asarray(t_el.leaf_value),
                                np.asarray(t_fx.leaf_value),
                                rtol=3e-5, atol=1e-7)
-    rounds, offered, applied, slots, clipped = (int(v) for v in st_el)
-    rounds_fx, offered_fx, applied_fx, slots_fx, clipped_fx = (
+    rounds, offered, applied, slots, clipped, lanes = (int(v) for v in st_el)
+    rounds_fx, offered_fx, applied_fx, slots_fx, clipped_fx, lanes_fx = (
         int(v) for v in st_fx)
+    # the candidate scan (the CPU's form) passes over the live lanes alone
+    assert (lanes, lanes_fx) == (offered, offered_fx)
     assert applied == applied_fx == 254
     assert slots_fx == 128 * (rounds_fx + 1)        # the root, then rounds
     assert clipped_fx == 0                          # one rung: no offer binds
@@ -336,7 +338,8 @@ def test_root_through_the_kernel_equals_the_staged_root():
     bt = jnp.asarray(binned.T)
     accumulate = FU.frontier_accumulator(
         bt, H._vals_t_int(gq, hq, jnp.asarray(member)), KCAP, B)
-    arena, width = accumulate(jnp.where(jnp.asarray(member), 0, KCAP), 1)
+    arena, width, _ = accumulate(
+        lambda W: (jnp.where(jnp.asarray(member), 0, KCAP), None), 1)
     assert int(width) == 16 and arena.shape == (KCAP, 2, F, B)
     ref = H.build_histogram_int(bt, gq, hq, jnp.asarray(member), B,
                                 levels=H.quant_levels(8))

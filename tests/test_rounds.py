@@ -376,27 +376,115 @@ def test_rounds_data_parallel_sorted_dispatch(problem, monkeypatch):
     np.testing.assert_array_equal(np.asarray(leaf_id), np.asarray(ref_leaf))
 
 
-def test_router_matmul_matches_scan(problem, monkeypatch):
-    """The router-matmul candidate routing (one-hot table lookup +
-    select-reduce bin read) must produce the identical tree to the
-    candidate scan it replaces."""
+def _router_case(case, problem):
+    """(grow, lanes_of) for one case of the router-against-scan test:
+    ``grow()`` grows one tree and returns (tree, leaf ids, stats)."""
+    import jax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from lightgbm_tpu.binning import MissingType
     binned, grad, hess, B, F = problem
+    mask = np.ones(len(grad), np.float32)
     meta = _meta(B, F)
     cfg = GrowerConfig(num_leaves=31, num_bins=B,
                        hp=SplitHyperparams(min_data_in_leaf=10),
                        hist_method="matmul_f32")
-    mask = np.ones(len(grad), np.float32)
+    kw = {}
+    if case == "narrow":
+        # a cap of 16: every round at the narrowest rung's lanes
+        cfg = cfg._replace(num_leaves=17)
+    elif case == "missing":
+        # every missing type, with default bins off the first: NaN rows in
+        # the last bin, zero rows at the default bin
+        mt = np.array([int(MissingType.NAN), int(MissingType.ZERO),
+                       int(MissingType.NONE)] * 4, np.int32)[:F]
+        db = np.arange(F, dtype=np.int32) % 7 + 3
+        meta = FeatureMeta(
+            num_bin=np.full(F, B, np.int32), missing_type=mt,
+            default_bin=db, most_freq_bin=np.zeros(F, np.int32),
+            is_categorical=np.zeros(F, bool), max_num_bin=B)
+        binned = binned.copy()
+        binned[::5, 1] = B - 1
+        binned[1::6, 4] = db[4]
+        grad = (grad + 0.9 * (binned[:, 1] == B - 1)
+                - 0.8 * (binned[:, 4] == db[4])).astype(np.float32)
+    elif case == "rungs":
+        # the fused arm at 255 leaves on int8 gradients: rounds at 16, 64
+        # and the cap, the route in the pass's branch at its width
+        from lightgbm_tpu.ops import histogram as H
+        cfg = GrowerConfig(num_leaves=255, num_bins=B, quant=True,
+                           quant_bins=8, hist_method="fused",
+                           hp=SplitHyperparams(min_data_in_leaf=2))
+        kw["quant_vals"] = H.quantize_gradients(
+            jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(mask), 8,
+            jax.random.PRNGKey(3))
+    elif case == "efb":
+        # sparse exclusive columns bundled into shared groups: the route
+        # decodes a row's bin by its feature's group and start
+        rng = np.random.RandomState(0)
+        n = len(grad)
+        groups = rng.randint(0, 8, size=n)
+        X = np.zeros((n, 8), np.float32)
+        X[np.arange(n), groups] = rng.randint(1, 9, n)
+        X = np.concatenate([X, rng.rand(n, 4).astype(np.float32)], axis=1)
+        ds = lgb.Dataset(X, label=np.zeros(n), free_raw_data=False,
+                         params={"max_bin": 31, "verbosity": -1})
+        ds.construct()
+        meta = ds.feature_meta()
+        assert meta.resolved().has_bundles, "test premise: EFB fires"
+        binned = np.asarray(ds.host_binned())
+        grad = (0.6 * (groups % 3 == 1) - X[:, 2] + 0.8 * (X[:, 9] > 0.4)
+                + 0.2 * rng.randn(n)).astype(np.float32)
+        cfg = cfg._replace(num_bins=int(meta.max_num_bin))
+    args = (jnp.asarray(binned.T), jnp.asarray(grad), jnp.asarray(hess),
+            jnp.asarray(mask), meta, cfg)
+    if case == "data_parallel":
+        mesh = Mesh(np.array(jax.devices()[:4]), ("d",))
+        # a new program a call: the form is fixed when it is traced
+        return lambda: jax.jit(jax.shard_map(
+            lambda b, g, h, m: grow_tree_rounds(
+                b, g, h, m, meta, cfg._replace(num_machines=4),
+                axis_name="d", with_stats=True),
+            mesh=mesh, in_specs=(P(None, "d"), P("d"), P("d"), P("d")),
+            out_specs=(P(), P("d"), P()), check_vma=False))(*args[:4])
+    return lambda: grow_tree_rounds(*args, with_stats=True, **kw)
+
+
+@pytest.mark.parametrize("case", ["cap", "narrow", "missing", "rungs",
+                                  "efb", "data_parallel"])
+def test_router_matmul_matches_scan(problem, monkeypatch, case):
+    """The router form (rows decided a block at a time against the round's
+    lanes, ``route_lanes``) must produce the identical tree and rows to
+    the candidate scan it replaces: at the cap's lanes, at 16, with every
+    missing type and default bins, through the fused arm's rungs (16 / 64
+    / cap, the lanes following the pass's width), on EFB bundles and on
+    four data-parallel shards.  Blocks of 1,000 rows, so every tree
+    decides its rows in several and rewrites a last one."""
+    from lightgbm_tpu import grower_rounds as GR
+    monkeypatch.setattr(GR, "ROUTE_BLOCK", 1000)
+    grow = _router_case(case, problem)
     monkeypatch.setenv("LGBM_TPU_SEGHIST", "sorted")
     monkeypatch.setenv("LGBM_TPU_ROUTER", "0")
-    t_scan, lid_scan = grow_tree_rounds(
-        jnp.asarray(binned.T), jnp.asarray(grad), jnp.asarray(hess),
-        jnp.asarray(mask), meta, cfg)
+    t_scan, lid_scan, st_scan = grow()
     monkeypatch.setenv("LGBM_TPU_ROUTER", "1")
-    t_rt, lid_rt = grow_tree_rounds(
-        jnp.asarray(binned.T), jnp.asarray(grad), jnp.asarray(hess),
-        jnp.asarray(mask), meta, cfg)
+    t_rt, lid_rt, st_rt = grow()
     _assert_trees_equal(t_scan, t_rt)
+    for name in t_scan._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(t_scan, name)),
+                                      np.asarray(getattr(t_rt, name)),
+                                      err_msg=name)
     np.testing.assert_array_equal(np.asarray(lid_scan), np.asarray(lid_rt))
+    rounds, offered, applied, slots, clipped, lanes = (
+        int(v) for v in st_rt)
+    np.testing.assert_array_equal(np.asarray(st_scan)[:5],
+                                  np.asarray(st_rt)[:5])
+    assert int(st_scan[5]) == offered            # the scan: live lanes
+    if case == "rungs":
+        # the router's lanes are the pass's widths, the root's aside
+        assert lanes == slots - 16 and lanes < 128 * rounds
+        assert rounds > 8
+    else:                                        # staged: the cap
+        assert lanes == (16 if case == "narrow" else 30) * rounds
 
 
 @pytest.mark.parametrize("sharded", [False, True],
@@ -455,7 +543,8 @@ def test_rounds_width_election_equals_serial_quantized(sharded):
                                       np.asarray(getattr(t_r, name)),
                                       err_msg=name)
     np.testing.assert_array_equal(np.asarray(lid_s), np.asarray(lid_r))
-    rounds, offered, _, slots, clipped = (int(v) for v in stats)
+    rounds, offered, _, slots, clipped, lanes = (int(v) for v in stats)
+    assert lanes == offered             # the scan: the live lanes alone
     # the root at 16, then every round at the rung its offer named: with
     # the offer following the commits, under 64 slots a round on average
     assert offered <= slots - 16 < 64 * rounds
@@ -518,8 +607,9 @@ def test_offer_equals_serial_quantized(gains):
                                       np.asarray(getattr(t_r, name)),
                                       err_msg=name)
     np.testing.assert_array_equal(np.asarray(lid_s), np.asarray(lid_r))
-    rounds, offered, applied, slots, clipped = (int(v) for v in stats)
+    rounds, offered, applied, slots, clipped, lanes = (int(v) for v in stats)
     assert applied == int(t_r.num_leaves) - 1
+    assert lanes == offered
     narrow, cap = FU.NARROW_SLOT_WIDTHS[0], 128
     wider = slots - narrow * (rounds + 1)       # what passes over 16 added
     if gains == "noise":
@@ -560,7 +650,8 @@ def test_offer_climbs_when_the_rounds_fill_the_rung(monkeypatch):
     assert int(t_of.num_leaves) == int(t_fx.num_leaves) == 255
     np.testing.assert_array_equal(np.sort(np.asarray(t_of.leaf_count)),
                                   np.sort(np.asarray(t_fx.leaf_count)))
-    rounds, offered, applied, slots, clipped = (int(v) for v in st_of)
+    rounds, offered, applied, slots, clipped, lanes = (int(v) for v in st_of)
+    assert lanes == offered
     assert (rounds, offered, applied) == tuple(int(v) for v in st_fx[:3])
     assert (rounds, offered, applied, clipped) == (8, 254, 254, 0)
     # root, 1, 2, 4, 8, 16 at 16 slots; 32 and 64 at 64; 127 at the cap

@@ -217,6 +217,7 @@ def test_serial_grower_counts_one_split_a_trip():
     assert (t["rounds"], t["offered"], t["applied"]) == (splits,) * 3
     assert t["slots"] == splits          # a pass one slot wide a split
     assert t["clipped"] == 0             # and no offer to clip
+    assert t["lanes"] == splits          # rows routed against one split
 
 
 @pytest.mark.parametrize("method", ["scatter", "fused"])
@@ -244,6 +245,9 @@ def test_grower_tree_carries_slots(method):
         # the fifth counter: trips in which the offer bound and all of it
         # committed.  The offer moves on the same rungs in both families
         assert 0 <= t["clipped"] <= t["rounds"]
+        # the sixth: the lanes the route compared rows with (the CPU's
+        # candidate scan passes over the live ones alone)
+        assert t["lanes"] == t["offered"]
         if method == "fused":
             # root at 16, then each round at the rung its offer named:
             # 16 or the cap (62 < 64)
@@ -284,7 +288,8 @@ def test_counters_leave_the_tree_as_it_was():
                     jax.tree_util.tree_leaves(t1)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_array_equal(np.asarray(lid0), np.asarray(lid1))
-    rounds, offered, applied, slots, clipped = (int(v) for v in stats)
+    rounds, offered, applied, slots, clipped, lanes = (int(v) for v in stats)
+    assert lanes == offered             # the scan: the live lanes alone
     assert applied == int(t1.num_leaves) - 1
     assert 1 <= rounds <= applied <= offered
     assert 0 <= clipped <= rounds
