@@ -2189,7 +2189,10 @@ class GBDT:
             return self._eval_inner(dataname, score, metrics, objective)
 
     def _eval_inner(self, dataname, score, metrics, objective):
-        score_np = np.asarray(score)
+        # annotations only: a device-idle gap inside ``engine.eval`` splits
+        # on the trace into the score's pull and each metric
+        with _span("eval.pull"):
+            score_np = np.asarray(score)
         if dataname == "training":
             if self._inv_perm is not None:
                 score_np = score_np[:, self._inv_perm]  # undo query layout
@@ -2198,8 +2201,9 @@ class GBDT:
         s = score_np if self.num_tree_per_iteration > 1 else score_np[0]
         out = []
         for m in metrics:
-            for (mname, val, hib) in m.eval(s, objective):
-                out.append((dataname, mname, val, hib))
+            with _span("metric." + getattr(m, "name", type(m).__name__)):
+                for (mname, val, hib) in m.eval(s, objective):
+                    out.append((dataname, mname, val, hib))
         return out
 
     # -------------------------------------------------------------- inference

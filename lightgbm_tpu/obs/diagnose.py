@@ -34,7 +34,10 @@ Verdict catalogue (docs/OBSERVABILITY.md):
 Each verdict carries ``score`` in [0, 1] (comparable across verdicts:
 the ranking IS the diagnosis), a one-line human summary, and the raw
 numbers as ``evidence``.  ``collect_signals`` assembles the dict from
-the live registry and/or a journal of banked stages; pure stdlib.
+the live registry or a snapshot of it; pure stdlib.  The rules that read
+``compile_seconds`` / ``train_seconds``, ``overlap_efficiency``,
+``bin_seconds`` and ``mfu_measured_best`` have no input there: a journal
+of banked stages fed them, and no program writes one (ROADMAP.md C7).
 """
 
 from __future__ import annotations
@@ -73,10 +76,10 @@ def _num(v, default=0.0):
         return default
 
 
-def collect_signals(registry=None, stages: Optional[dict] = None) -> dict:
+def collect_signals(registry=None) -> dict:
     """Assemble the diagnoser's flat signal dict from the live process
-    registry and/or a journal's banked stages (either may be
-    None/empty; absent signals simply don't fire their rules)."""
+    registry (or a snapshot of one: only ``to_dict`` is read); absent
+    signals simply don't fire their rules."""
     sig: dict = {}
     if registry is None:
         from .metrics import global_registry as registry
@@ -119,44 +122,6 @@ def collect_signals(registry=None, stages: Optional[dict] = None) -> dict:
             sig["ledger_lease_table"] = lg.table()
     except Exception:  # noqa: BLE001 — forensics only
         pass
-    # journal stages refine / supply the workload-scale numbers
-    stages = stages or {}
-    full = None
-    for key, st in stages.items():
-        if key == "full" or str(key).startswith("full@"):
-            full = st
-    full = full or stages.get("smoke")
-    if isinstance(full, dict):
-        sig.setdefault("sec_per_tree", _num(full.get("sec_per_tree")))
-        sig.setdefault("trees", _num(full.get("trees")))
-        sig.setdefault("compile_seconds",
-                       _num(full.get("compile_seconds")))
-        sig.setdefault("train_seconds", _num(full.get("value")))
-        cc = full.get("compile_cache")
-        if isinstance(cc, dict):
-            sig.setdefault("compile_cache_warm",
-                           1.0 if cc.get("warm_start") else 0.0)
-        mm = full.get("mfu_measured")
-        if isinstance(mm, dict):
-            best = max((v.get("mfu", 0.0) for v in mm.values()
-                        if isinstance(v, dict)), default=0.0)
-            if best:
-                sig.setdefault("mfu_measured_best", best)
-        sig.setdefault("bin_seconds", _num(full.get("bin_seconds")))
-        sig.setdefault("bin_rows_per_sec",
-                       _num(full.get("bin_rows_per_sec")))
-    ip = stages.get("ingest_probe")
-    if isinstance(ip, dict):
-        sig.setdefault("ingest_kernel_speedup",
-                       _num(ip.get("kernel_speedup_vs_host")))
-    sp = stages.get("stream_probe")
-    if isinstance(sp, dict):
-        sig.setdefault("overlap_efficiency",
-                       _num(sp.get("overlap_efficiency"), 1.0))
-    cp = stages.get("collective_probe")
-    if isinstance(cp, dict):
-        sig.setdefault("train_ici_payload_bytes", _num(cp.get("ici_bytes")))
-        sig.setdefault("train_dcn_payload_bytes", _num(cp.get("dcn_bytes")))
     # planner link speeds (the model the DCN rule prices bytes with)
     try:
         from ..ops.planner import (DEFAULT_DCN_GBPS, DEFAULT_ICI_GBPS,
@@ -356,8 +321,8 @@ def diagnosis_summary(verdicts: List[Verdict],
     return out
 
 
-def run_doctor(registry=None, stages: Optional[dict] = None) -> dict:
+def run_doctor(registry=None) -> dict:
     """collect -> diagnose -> summarize in one call
     (tools/obs_doctor.py entry point)."""
-    signals = collect_signals(registry=registry, stages=stages)
+    signals = collect_signals(registry=registry)
     return diagnosis_summary(diagnose(signals), signals)

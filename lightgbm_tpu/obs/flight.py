@@ -215,6 +215,9 @@ class FlightRecorder:
             "env": {k: os.environ[k] for k in sorted(os.environ)
                     if k.startswith(_ENV_PREFIXES)},
             "context": dict(self._context),
+            # the ring's ``ts`` count from this absolute perf_counter_ns
+            # (obs/trace.ring_offset_ns lays them on a profiler trace)
+            "trace_epoch_ns": _trace.global_tracer.epoch_ns,
         }
         # jax.devices() INITIALIZES the default backend when none exists
         # — multi-second TPU init from a crash path — so the device facts
@@ -242,7 +245,9 @@ class FlightRecorder:
             {"name": "process_name", "ph": "M", "pid": self._pid,
              "tid": 0, "ts": 0.0,
              "args": {"name": f"lightgbm-tpu flight [{trigger}]"}}] + evs,
-            "displayTimeUnit": "ms"}
+            "displayTimeUnit": "ms",
+            "otherData": {
+                "epoch_perf_counter_ns": _trace.global_tracer.epoch_ns}}
         out = {
             "flight_bundle": BUNDLE_VERSION,
             "trigger": trigger,

@@ -36,6 +36,14 @@ Event format (Chrome trace-event "JSON object format"): complete events
 (``"ph": "i"``) for point-in-time facts (planner verdicts, measured HBM
 peaks, request admissions).  Events are timestamp-sorted at dump time.
 
+One clock: the epoch is ``Tracer.epoch_ns``, an absolute
+``time.perf_counter_ns()`` value, in the dump's ``otherData`` and in
+every flight bundle's fingerprint (``trace_epoch_ns``).  A seam takes its
+start and end inside its annotation, so a ring record and its ``lgbm.``
+annotation differ by the annotation's own entry and exit, and
+``ring_offset_ns`` finds the one constant that lays the ring on a
+profiler trace's host clock.
+
 Because device work is asynchronous under jit, spans measure HOST time:
 dispatch cost lands in the dispatch span and device time surfaces in
 whichever span first blocks on a result (the same decomposition
@@ -48,6 +56,7 @@ is tolerated.
 from __future__ import annotations
 
 import atexit
+import bisect
 import json
 import os
 import threading
@@ -102,7 +111,12 @@ class Tracer:
         self._events: List[dict] = []
         self._lock = threading.Lock()
         self._pid = os.getpid()
-        self._epoch = time.perf_counter()
+        # the epoch every ``ts`` counts from, as an absolute
+        # ``time.perf_counter_ns()`` value: with it a ring record (or a
+        # dumped event) lands on any clock the process also read
+        # (``ring_offset_ns`` finds the profiler's)
+        self.epoch_ns = time.perf_counter_ns()
+        self._epoch = self.epoch_ns / 1e9
         # only the process tracer tees into the flight ring (scratch
         # tracers in tests must not pollute the process forensics)
         self._flight_tee = False
@@ -191,7 +205,8 @@ class Tracer:
                 "ts": (evs[-1]["ts"] if evs else 0.0),
                 "args": {"dropped": self.dropped,
                          "max_events": self.max_events}}]
-        return {"traceEvents": meta + evs, "displayTimeUnit": "ms"}
+        return {"traceEvents": meta + evs, "displayTimeUnit": "ms",
+                "otherData": {"epoch_perf_counter_ns": self.epoch_ns}}
 
     def dump(self, path: str, events: Optional[List[dict]] = None) -> str:
         """Write the Chrome-trace JSON to ``path`` (atomic); returns it."""
@@ -355,6 +370,79 @@ def span_coverage(events: List[dict], root_name: str) -> Optional[float]:
     if cur_t is not None:
         covered += cur_t - cur_s
     return covered / (hi - lo)
+
+
+def ring_offset_ns(ring_events: List[dict], host_events,
+                   epoch_ns: Optional[int] = None,
+                   tol_ns: float = 1e6) -> Optional[dict]:
+    """The constant that lays ring records on a profiler trace:
+    ``trace_ns = epoch_ns + ts * 1e3 + offset_ns`` for a record's start.
+
+    ``ring_events`` are complete records as the flight ring (or a bundle's
+    ``ring``, or a Chrome dump) holds them, ``ts`` in us since the tracer's
+    epoch (``epoch_ns``: ``global_tracer.epoch_ns`` for this process; a
+    bundle's ``fingerprint["trace_epoch_ns"]``).  ``host_events`` are
+    ``(name, start_ns, dur_ns)`` of any trace's host plane.  A record is
+    matched to an ``"lgbm." + name`` annotation by name and order: the
+    offset shared by the most (record, annotation) pairs of one name within
+    ``tol_ns`` fixes the alignment, then each record takes the annotation
+    nearest to it.  Returns ``{"offset_ns", "worst_ns", "matched",
+    "unmatched", "no_annotation"}``: the median offset over matched pairs,
+    the largest deviation from it, the records inside the trace's span that
+    found no annotation of their name, and the names of records that have
+    none in the trace at all (``grower.tree`` is a record, not a seam);
+    ``None`` when no record matched."""
+    if epoch_ns is None:
+        epoch_ns = global_tracer.epoch_ns
+    starts = {}
+    lo = hi = None
+    for name, s, d in host_events:
+        if name.startswith("lgbm."):
+            starts.setdefault(name[5:], []).append(float(s))
+            lo = s if lo is None else min(lo, s)
+            hi = s + d if hi is None else max(hi, s + d)
+    recs = {}
+    missing = set()
+    for e in ring_events:
+        if e.get("ph") != "X":
+            continue
+        if e["name"] in starts:
+            recs.setdefault(e["name"], []).append(
+                epoch_ns + float(e["ts"]) * 1e3)
+        else:
+            missing.add(e["name"])
+    diffs = sorted(a - r for name, rs in recs.items()
+                   for r in rs for a in starts[name])
+    if not diffs:
+        return None
+    best, j = (0, 0.0), 0
+    for i, d in enumerate(diffs):        # densest window of width tol_ns
+        while diffs[j] < d - tol_ns:
+            j += 1
+        if i - j + 1 > best[0]:
+            best = (i - j + 1, diffs[(i + j) // 2])
+    guess = best[1]
+    offsets, unmatched = [], 0
+    for name, rs in recs.items():
+        free = sorted(starts[name])
+        for r in sorted(rs):
+            k = bisect.bisect_left(free, r + guess)
+            near = [x for x in free[max(k - 1, 0):k + 1]
+                    if abs(x - r - guess) <= tol_ns]
+            if near:
+                a = min(near, key=lambda x: abs(x - r - guess))
+                free.remove(a)
+                offsets.append(a - r)
+            elif lo <= r + guess <= hi:
+                unmatched += 1
+    if not offsets:
+        return None
+    offsets.sort()
+    mid = offsets[len(offsets) // 2]
+    return {"offset_ns": mid,
+            "worst_ns": max(abs(x - mid) for x in offsets),
+            "matched": len(offsets), "unmatched": unmatched,
+            "no_annotation": sorted(missing)}
 
 
 @atexit.register

@@ -496,48 +496,47 @@ def test_doctor_ranks_verdicts():
         (v.score for v in vs), reverse=True)
 
 
-def test_doctor_collects_from_journal_stages():
-    """collect_signals joins banked bench stages (full/stream_probe/
-    collective_probe) with registry gauges; run_doctor produces the
-    journal-ready report naming the injected bottleneck."""
-    from lightgbm_tpu.obs.diagnose import run_doctor
-
-    stages = {
-        "full@200000": {"sec_per_tree": 0.5, "value": 25.0,
-                        "compile_seconds": 130.0, "trees": 50,
-                        "compile_cache": {"warm_start": False},
-                        "mfu_measured": {"f32/matmul/untiled":
-                                         {"mfu": 0.002}}},
-        "stream_probe": {"overlap_efficiency": 1.0},
-    }
-    report = run_doctor(registry=MetricsRegistry(), stages=stages)
-    assert report["top_verdict"] == "compile-bound"
-    names = [v["name"] for v in report["verdicts"]]
-    assert "input-bound" in names
-    assert report["signals"]["mfu_measured_best"] == 0.002
-    json.dumps(report)          # journal-ready
-
-
 def test_obs_doctor_tool(tmp_path):
-    """The CLI: journal in, human table + machine-readable last line
-    out."""
+    """The CLI: a registry snapshot in (``tools/obs_dump.py`` writes one),
+    human table + machine-readable last line out."""
     import subprocess
     import sys
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    journal = tmp_path / "j.json"
-    journal.write_text(json.dumps({
-        "fingerprint": "t", "stages": {
-            "full": {"compile_seconds": 130.0, "value": 25.0,
-                     "compile_cache": {"warm_start": False}}}}))
+    snap = MetricsRegistry()
+    snap.gauge("train_dcn_payload_bytes").set(2e9)
+    snap.gauge("train_num_slices").set(4)
+    snap.gauge("train_hier_reduce").set(1)
+    snap.gauge("train_iter_seconds").set(1.0)
+    metrics = tmp_path / "obs_metrics.json"
+    metrics.write_text(json.dumps(snap.to_dict()))
     proc = subprocess.run(
         [sys.executable, os.path.join(repo, "tools", "obs_doctor.py"),
-         "--journal", str(journal), "--metrics", str(tmp_path / "no")],
+         "--metrics", str(metrics)],
         capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stderr[-1000:]
     lines = proc.stdout.strip().splitlines()
     report = json.loads(lines[-1])
-    assert report["top_verdict"] == "compile-bound"
-    assert "compile-bound" in proc.stdout
+    assert report["top_verdict"] == "dcn-bound"
+    assert report["signals"]["train_num_slices"] == 4
+    assert "dcn-bound" in proc.stdout
+
+
+def test_bundle_fingerprint_carries_the_epoch(tmp_path):
+    """A bundle names the absolute ``perf_counter_ns`` its ring's ``ts``
+    count from (in the fingerprint and the ring's own metadata), so a
+    bundle and a device trace of the same process make one timeline
+    (``obs.trace.ring_offset_ns``)."""
+    from lightgbm_tpu.obs.trace import global_tracer
+    fr = FlightRecorder(max_events=8, out_dir=str(tmp_path))
+    t_ns = time.perf_counter_ns()
+    fr.note("probe")
+    with open(fr.dump("epoch")) as fh:
+        b = json.load(fh)
+    epoch = b["fingerprint"]["trace_epoch_ns"]
+    assert epoch == global_tracer.epoch_ns
+    assert b["ring"]["otherData"]["epoch_perf_counter_ns"] == epoch
+    (ev,) = [e for e in b["ring"]["traceEvents"] if e["name"] == "probe"]
+    assert 0 <= epoch + ev["ts"] * 1e3 - t_ns < 5e6
 
 
 # -------------------------------------------------------- HTTP endpoint

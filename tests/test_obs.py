@@ -98,6 +98,52 @@ def test_span_cost_with_tracing_off_is_bounded():
         global_tracer.enabled = was
 
 
+@pytest.mark.parametrize("name, ring", [
+    ("engine.callbacks", False), ("eval.pull", False),
+    ("metric.auc", False), ("engine.step", True), ("engine.eval", True)])
+def test_turn_seams_cost_with_tracing_off_is_bounded(name, ring):
+    """The seams of a loop turn with tracing off: an annotation each for
+    the callbacks, the evaluation's pull and each metric, and one ring
+    append a turn for ``engine.step`` and ``engine.eval``; under the same
+    loose bound as any span, on rounds of seconds."""
+    import time
+    from lightgbm_tpu.obs.flight import FlightRecorder
+    from lightgbm_tpu.obs import trace
+    was, sink = global_tracer.enabled, trace._flight_sink
+    global_tracer.disable()
+    trace.set_flight_sink(FlightRecorder(max_events=64, enabled=True))
+    try:
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for i in range(2000):
+                with span(name, ring=ring, it=i):
+                    pass
+            best = min(best, (time.perf_counter() - t0) / 2000)
+        assert best < 50e-6, f"{best * 1e6:.1f} us a {name} span"
+        assert len(trace._flight_sink.ring_events()) == (64 if ring else 0)
+    finally:
+        global_tracer.enabled = was
+        trace.set_flight_sink(sink)
+
+
+def test_chrome_dump_carries_the_epoch(tmp_path):
+    """The dump names the absolute ``perf_counter_ns`` its ``ts`` count
+    from, so its events land on any clock the process read."""
+    import time
+    t = Tracer(enabled=True)
+    assert abs(t.epoch_ns - time.perf_counter_ns()) < 60e9
+    t_ns = time.perf_counter_ns()
+    with t.span("probe"):
+        pass
+    with open(t.dump(str(tmp_path / "t.json"))) as fh:
+        doc = json.load(fh)
+    epoch = doc["otherData"]["epoch_perf_counter_ns"]
+    assert epoch == t.epoch_ns
+    (ev,) = [e for e in doc["traceEvents"] if e["name"] == "probe"]
+    assert 0 <= epoch + ev["ts"] * 1e3 - t_ns < 5e6
+
+
 def test_chrome_trace_json_validates():
     t = Tracer(enabled=True)
 

@@ -323,3 +323,178 @@ def test_compile_counters_rise_once():
     c2 = _counters("compile_")
     bst.update()
     assert _counters("compile_") == c2
+
+
+# ------------------------------------ (e) the loop's whole turn, one clock
+
+
+def _ring_spans():
+    return [e for e in global_flight.ring_events() if e.get("ph") == "X"]
+
+
+def _on_ring_clock(t_ns):
+    """A ``perf_counter_ns`` reading in the ring's microseconds."""
+    return (t_ns - global_tracer.epoch_ns) / 1e3
+
+
+def test_engine_step_is_the_whole_turn(tmp_path):
+    """One ``engine.step`` record a turn holds the before-round callbacks,
+    the update's seams, the evaluation, the after-round callbacks and the
+    snapshot save; the watchdog's beat and ``train_iter_seconds`` still
+    read the update."""
+    import time
+    X, y = _data(16)
+    ds = lgb.Dataset(X, label=y)
+    dv = lgb.Dataset(X[:600], label=y[:600], reference=ds)
+    seen = {"before": [], "after": []}
+
+    def before(env):
+        seen["before"].append((env.iteration, time.perf_counter_ns()))
+    before.before_iteration = True
+
+    def after(env):
+        time.sleep(0.01)
+        seen["after"].append((env.iteration, time.perf_counter_ns()))
+
+    global_flight._ring.clear()
+    lgb.train({"objective": "binary", "num_leaves": 7, "verbosity": -1,
+               "metric": "auc"}, ds, num_boost_round=4, valid_sets=[dv],
+              callbacks=[before, after], snapshot_freq=2,
+              snapshot_out=str(tmp_path / "m.txt"))
+    recs = _ring_spans()
+    steps = [e for e in recs if e["name"] == "engine.step"]
+    assert [e["args"]["it"] for e in steps] == [0, 1, 2, 3]
+    assert all(e["args"]["parent"] == "engine.train" for e in steps)
+    for step in steps:
+        lo, hi = step["ts"], step["ts"] + step["dur"]
+        it = step["args"]["it"]
+        for when in ("before", "after"):
+            (t_ns,) = [t for i, t in seen[when] if i == it]
+            assert lo < _on_ring_clock(t_ns) < hi, (when, it)
+        kids = {e["name"] for e in recs if e is not step
+                and lo <= e["ts"] and e["ts"] + e["dur"] <= hi}
+        assert {"engine.eval", "macro.dispatch"} <= kids, kids
+        assert ("checkpoint.save" in kids) == (it % 2 == 1), (it, kids)
+    for e in recs:
+        if e["name"] in ("engine.eval", "checkpoint.save", "macro.dispatch"):
+            assert e["args"]["parent"] == "engine.step", e
+    # the update's own accounting: not the turn's 10 ms callback sleep
+    g = global_registry.to_dict()["gauges"]
+    assert g["train_iter_seconds"] < steps[-1]["dur"] / 1e6
+
+
+@pytest.mark.parametrize("metric_freq", [1, 2])
+def test_engine_eval_is_on_the_ring(metric_freq):
+    """One ``engine.eval`` record an evaluation boundary, with ``it`` and
+    ``parent`` ``engine.step``; ``gbdt.eval`` stays an annotation."""
+    X, y = _data(17)
+    ds = lgb.Dataset(X, label=y)
+    dv = lgb.Dataset(X[:600], label=y[:600], reference=ds)
+    global_flight._ring.clear()
+    lgb.train({"objective": "binary", "num_leaves": 7, "verbosity": -1,
+               "metric": ["auc", "binary_logloss"],
+               "metric_freq": metric_freq},
+              ds, num_boost_round=4, valid_sets=[dv])
+    recs = _ring_spans()
+    evals = [e for e in recs if e["name"] == "engine.eval"]
+    assert [e["args"]["iteration"] for e in evals] \
+        == [j for j in range(4) if (j + 1) % metric_freq == 0]
+    steps = {e["args"]["it"]: e for e in recs if e["name"] == "engine.step"}
+    for e in evals:
+        assert e["args"]["parent"] == "engine.step"
+        step = steps[e["args"]["it"]]
+        assert step["ts"] <= e["ts"] \
+            and e["ts"] + e["dur"] <= step["ts"] + step["dur"]
+    names = {e["name"] for e in recs}
+    assert not {"gbdt.eval", "eval.pull", "metric.auc",
+                "engine.callbacks"} & names
+
+
+def test_ring_offset_lays_the_ring_on_a_profiler_trace(tmp_path):
+    """A real profiler session on XLA:CPU around a short ``lgb.train``:
+    every ring record of the session lands on its ``lgbm.`` annotation by
+    one offset; the evaluation's pull and metrics are annotations."""
+    from benchmark.lib import trace_reduce as tr
+    from lightgbm_tpu.obs.trace import ring_offset_ns
+    X, y = _data(18)
+    ds = lgb.Dataset(X, label=y)
+    dv = lgb.Dataset(X[:600], label=y[:600], reference=ds)
+    global_flight._ring.clear()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        lgb.train({"objective": "binary", "num_leaves": 7, "verbosity": -1,
+                   "metric": ["auc", "binary_logloss"]}, ds,
+                  num_boost_round=3, valid_sets=[dv],
+                  snapshot_freq=2, snapshot_out=str(tmp_path / "m.txt"))
+    finally:
+        jax.profiler.stop_trace()
+    planes = tr.read_planes(glob.glob(
+        str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[-1])
+    host = [(n, s, d) for p, lines in planes.items() if p.startswith("/host")
+            for events in lines.values() for n, s, d in events
+            if n.startswith("lgbm.")]
+    names = {n for n, _, _ in host}
+    assert {"lgbm.engine.step", "lgbm.engine.eval", "lgbm.engine.callbacks",
+            "lgbm.eval.pull", "lgbm.metric.auc",
+            "lgbm.metric.binary_logloss"} <= names
+    spans = _ring_spans()
+    got = ring_offset_ns(spans, host)
+    assert got is not None
+    assert got["unmatched"] == 0
+    assert got["no_annotation"] == ["grower.tree"]
+    assert got["matched"] == sum(e["name"] != "grower.tree" for e in spans)
+    assert got["worst_ns"] < 50e3, got
+
+
+def test_ring_offset_on_planted_records():
+    """Records before the session match nothing and count for nothing;
+    a record inside it with no annotation of its name is unmatched."""
+    from lightgbm_tpu.obs.trace import ring_offset_ns
+    epoch, off = 10**12, 777_000
+    ring = [{"name": "a", "ph": "X", "ts": float(t), "dur": 5.0}
+            for t in (0, 1e6, 2e6, 3e6, 4e6)]
+    # the session saw the last three, each 1-3 us late
+    host = [("lgbm.a", epoch + t * 1e3 + off + d, 5e3)
+            for t, d in ((2e6, 1000), (3e6, 3000), (4e6, 2000))]
+    got = ring_offset_ns(ring, host, epoch_ns=epoch)
+    assert got["matched"] == 3 and got["unmatched"] == 0
+    assert got["offset_ns"] == off + 2000 and got["worst_ns"] == 1000
+    ring.append({"name": "a", "ph": "X", "ts": 3.5e6, "dur": 1.0})
+    ring.append({"name": "b", "ph": "X", "ts": 3.6e6, "dur": 1.0})
+    got = ring_offset_ns(ring, host, epoch_ns=epoch)
+    assert got["unmatched"] == 1 and got["no_annotation"] == ["b"]
+    assert ring_offset_ns(ring, [("lgbm.c", 0, 1)], epoch_ns=epoch) is None
+
+
+def test_idle_split_of_a_kept_trace():
+    """``tools/scope_table.py --idle``: the idle pieces add up to the
+    window less the busy time, and match the benchmark reducer's
+    ``idle_gaps`` on a trace whose only annotations are the harness's."""
+    from benchmark.lib import trace_reduce as tr
+    from tools import scope_table
+    path = REPO / "benchmark/tests/data/trace_small.xplane.pb.gz"
+    got = scope_table.idle(path)
+    assert got["idle_s"] == pytest.approx(got["window_s"] - got["busy_s"])
+    assert sum(got["by_innermost_s"].values()) \
+        == pytest.approx(got["idle_s"], rel=1e-9)
+    reduced = tr.reduce_trace(path)
+    assert got["window_s"] == pytest.approx(reduced["window_s"])
+    assert got["busy_s"] == pytest.approx(reduced["busy_s"], rel=1e-6)
+    expect = {("outside_spans" if n == "outside_harness_spans" else n): s
+              for n, s in reduced["idle_gaps"]}
+    assert got["by_innermost_s"] == pytest.approx(expect)
+    # with one span nested in another, the outer one holds both under_s
+    planes = tr.read_planes(path)
+    planes["/host:CPU"]["probe"] = [("lgbm.probe", 0.0, 1e30)]
+    inner = {"/host:CPU": planes["/host:CPU"],
+             "/device:TPU:0": planes["/device:TPU:0"]}
+    import unittest.mock as mock
+    with mock.patch.object(scope_table.tr, "read_planes",
+                           return_value=inner):
+        nested = scope_table.idle(path)
+    # the harness's spans stay innermost; what they left now reads probe
+    expect["lgbm.probe"] = expect.pop("outside_spans")
+    assert nested["by_innermost_s"] == pytest.approx(expect)
+    assert nested["under_s"]["lgbm.probe"] == pytest.approx(got["idle_s"])

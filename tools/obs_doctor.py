@@ -1,23 +1,19 @@
 #!/usr/bin/env python
-"""obs_doctor: automated bottleneck diagnosis over a journal of banked
-stages + a metrics snapshot (lightgbm_tpu/obs/diagnose.py,
-docs/OBSERVABILITY.md verdict catalogue).  No program of this repository
-writes such a journal any more (ROADMAP.md C1); a registry snapshot
-(tools/obs_dump.py) or the live registry is the input that exists.
+"""obs_doctor: automated bottleneck diagnosis over a metrics snapshot
+(lightgbm_tpu/obs/diagnose.py, docs/OBSERVABILITY.md verdict catalogue):
+a registry snapshot (tools/obs_dump.py writes one), or the live registry.
 
-Joins measured signals (MFU tables, compile-cache warmth,
-streaming overlap efficiency, straggler skew) with
-planner-predicted ones (per-tier ICI/DCN payload bytes, link models)
-and prints RANKED verdicts — "DCN-bound", "compile-bound",
-"input-bound", "straggler slice k", "contention" (co-resident train vs
-serve fighting over the same devices; evidence carries the residency
-ledger's lease table + brownout throttle/pause counts), and
-"kernel-underutilized" — each with the evidence behind it.  The LAST
-stdout line is one JSON summary.
+Joins measured signals (iteration seconds, straggler skew, brownout
+counts) with planner-predicted ones (per-tier ICI/DCN payload bytes,
+link models) and prints RANKED verdicts — "DCN-bound", "straggler slice
+k", "contention" (co-resident train vs serve fighting over the same
+devices; evidence carries the residency ledger's lease table + brownout
+throttle/pause counts) — each with the evidence behind it; the rules
+whose inputs no program records are listed in docs/OBSERVABILITY.md.
+The LAST stdout line is one JSON summary.
 
 Usage:
     python tools/obs_doctor.py \
-        [--journal journal.json]         # banked stages
         [--metrics obs_metrics.json]     # registry snapshot (obs_dump)
         [--json-only]                    # machine consumers
 Exit codes: 0 = diagnosed (whatever the verdict), 2 = input unreadable.
@@ -29,18 +25,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-
-def load_journal_stages(path):
-    """Banked stages from a journal ({} when absent); tolerant of
-    both the fingerprint-wrapped layout and a bare stage map."""
-    if not path or not os.path.exists(path):
-        return {}
-    with open(path) as fh:
-        d = json.load(fh)
-    if isinstance(d, dict) and isinstance(d.get("stages"), dict):
-        return d["stages"]
-    return d if isinstance(d, dict) else {}
 
 
 def load_metrics_snapshot(path):
@@ -59,11 +43,11 @@ def load_metrics_snapshot(path):
     return _Snap()
 
 
-def run_doctor(stages=None, registry=None):
+def run_doctor(registry=None):
     """collect -> diagnose -> summary (falls back to the live process
     registry)."""
     from lightgbm_tpu.obs.diagnose import run_doctor as _run
-    return _run(registry=registry, stages=stages)
+    return _run(registry=registry)
 
 
 def format_human(report):
@@ -80,18 +64,16 @@ def format_human(report):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--journal", default=None)
     ap.add_argument("--metrics", default="obs_metrics.json",
                     help="tools/obs_dump.py writes one into its --out-dir")
     ap.add_argument("--json-only", action="store_true")
     args = ap.parse_args()
     try:
-        stages = load_journal_stages(args.journal)
         registry = load_metrics_snapshot(args.metrics)
     except (OSError, ValueError) as e:
         print(json.dumps({"error": f"unreadable input: {e}"}))
         return 2
-    report = run_doctor(stages=stages, registry=registry)
+    report = run_doctor(registry=registry)
     if not args.json_only:
         print(format_human(report))
     print(json.dumps(report, sort_keys=True))
