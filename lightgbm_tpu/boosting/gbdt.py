@@ -224,6 +224,9 @@ class GBDT:
         self.valid_scores: List[jax.Array] = []
         self.train_metrics = []
         self.valid_metrics: List[list] = []
+        # set i -> (the score upd returned, its metrics' names, their device
+        # partials): used only while the set's score is that same array
+        self._valid_partials: Dict[int, tuple] = {}
 
         self._rng = np.random.RandomState(config.bagging_seed)
         self._goss_rng_key = jax.random.PRNGKey(config.bagging_seed)
@@ -1312,7 +1315,8 @@ class GBDT:
         self._macro_ctx = {"label": label_a, "weight": weight_a,
                            "obj_tables": obj_tables}
         self._macro_chunk_jit = None
-        self._macro_valid_jit = None
+        self._macro_valid_jit = {}
+        self._partials_jit = {}
         self._has_forced_plan = forced_plan is not None
         if self._stream is not None:
             # (re)built with the programs so reset_parameter rebuilds
@@ -1589,12 +1593,26 @@ class GBDT:
                 iterations * self.num_tree_per_iteration)
 
     def _chunk_valid_update(self, vscore, stacked_seq, binned, its):
-        if self._macro_valid_jit is None:
-            from .macro import build_chunk_valid
-            self._macro_valid_jit = build_chunk_valid(self)
+        """The set whose ``binned`` this is, updated by ``upd``; where its
+        evaluation takes device forms, ``upd`` also returns their partials
+        of the updated score, kept beside it for ``_eval_inner``."""
         self._count_valid_update(its.shape[0])
-        return self._macro_valid_jit(vscore, stacked_seq, binned, its,
-                                     np.int32(its.shape[0]))
+        i = next((j for j, vb in enumerate(self.valid_binned)
+                  if vb is binned), None)
+        forms = (self._device_forms(self.valid_metrics[i], self.objective)
+                 if i is not None and i < len(self.valid_metrics) else ())
+        names = [m.name for m in forms]
+        key = tuple(names)
+        if key not in self._macro_valid_jit:
+            from .macro import build_chunk_valid
+            self._macro_valid_jit[key] = build_chunk_valid(self, forms)
+        args = (vscore, stacked_seq, binned, its, np.int32(its.shape[0]))
+        if not forms:
+            return self._macro_valid_jit[key](*args)
+        vs, parts = self._macro_valid_jit[key](*args,
+                                               forms[0].device_label())
+        self._valid_partials[i] = (vs, names, parts)
+        return vs
 
     def _finish_chunk(self, stacked_seq, c: int, shrinks, it0: int,
                       gstats_seq=None) -> bool:
@@ -2188,22 +2206,66 @@ class GBDT:
                    timer="GBDT::EvalMetrics"):
             return self._eval_inner(dataname, score, metrics, objective)
 
+    def _device_forms(self, metrics, objective) -> tuple:
+        """The metrics of one set that its evaluation reduces on the
+        device (``metrics.py``): one output a row, an objective, and the
+        metric's own ``device_ready`` (no weights, among others)."""
+        if self.num_tree_per_iteration != 1 or objective is None:
+            return ()
+        return tuple(m for m in metrics if m.device_ready())
+
+    def _metric_partials(self, score, forms, rows: int):
+        """``forms``' partials over the first ``rows`` rows of ``score``:
+        the ones ``upd`` returned with this very array, else the same
+        reduction jitted alone (the training score, a score changed after
+        ``upd``: rollback, DART, an init score added)."""
+        names = [m.name for m in forms]
+        for kept, kept_names, parts in self._valid_partials.values():
+            if kept is score and kept_names == names:
+                return parts
+        key = (tuple(names), rows)
+        if key not in self._partials_jit:
+            from ..metrics import device_partials
+            objective = self.objective
+
+            def metric_partials(score, label):
+                return device_partials(forms, score[0, :rows], label,
+                                       objective)
+            self._partials_jit[key] = jax.jit(metric_partials)
+        return self._partials_jit[key](score, forms[0].device_label())
+
     def _eval_inner(self, dataname, score, metrics, objective):
         # annotations only: a device-idle gap inside ``engine.eval`` splits
-        # on the trace into the score's pull and each metric
+        # on the trace into the pull (the device forms' partials, the score
+        # where a metric has none) and each metric
+        training = dataname == "training"
+        forms = (() if training and self._inv_perm is not None
+                 else self._device_forms(metrics, objective))
+        partials, score_np = {}, None
         with _span("eval.pull"):
-            score_np = np.asarray(score)
-        if dataname == "training":
-            if self._inv_perm is not None:
-                score_np = score_np[:, self._inv_perm]  # undo query layout
-            elif score_np.shape[-1] > self.num_data:
-                score_np = score_np[:, :self.num_data]  # drop pad rows
-        s = score_np if self.num_tree_per_iteration > 1 else score_np[0]
+            if forms:
+                rows = self.num_data if training else score.shape[-1]
+                partials = dict(zip(forms, jax.device_get(
+                    self._metric_partials(score, forms, rows))))
+            if len(partials) < len(metrics):
+                score_np = np.asarray(score)
+        if score_np is not None:
+            if training:
+                if self._inv_perm is not None:
+                    score_np = score_np[:, self._inv_perm]  # query layout
+                elif score_np.shape[-1] > self.num_data:
+                    score_np = score_np[:, :self.num_data]  # drop pad rows
+            s = score_np if self.num_tree_per_iteration > 1 else score_np[0]
         out = []
         for m in metrics:
+            on_device = m in partials
             with _span("metric." + getattr(m, "name", type(m).__name__)):
-                for (mname, val, hib) in m.eval(s, objective):
-                    out.append((dataname, mname, val, hib))
+                res = (m.finish(partials[m]) if on_device
+                       else m.eval(s, objective))
+            _obs_registry.counter("eval_metrics_device_total" if on_device
+                                  else "eval_metrics_host_total").inc()
+            out.extend((dataname, mname, val, hib)
+                       for (mname, val, hib) in res)
         return out
 
     # -------------------------------------------------------------- inference

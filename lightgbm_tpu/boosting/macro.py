@@ -192,7 +192,7 @@ def build_chunk_program(b):
     return jax.jit(make_chunk_fn(b), donate_argnums=(1,))
 
 
-def build_chunk_valid(b):
+def build_chunk_valid(b, forms=()):
     """Fused valid-score update: one program applies a whole ``[c, ...]``
     tree bundle to a valid set (vs. one dispatch per iteration).  Same
     runtime-trip-count loop as the chunk program so RF's running-mean
@@ -203,16 +203,26 @@ def build_chunk_valid(b):
     accelerator), else its walk over tree levels; the scores are the same
     to the bit.  The jitted function keeps the name ``upd``: the benchmark
     finds the evaluation layer's device time by the program name
-    ``jit_upd`` (``benchmark/metrics/_eval.py``)."""
+    ``jit_upd`` (``benchmark/metrics/_eval.py``).
+
+    ``forms``: the set's metrics whose device form its evaluation takes
+    (``metrics.py``).  With any, ``upd`` also takes the set's float32
+    labels, a runtime argument and never a constant of the program, and
+    returns ``(vscore, partials)``: the metrics' partials of the updated
+    score.  The reduction runs at every update, so a chunk that ends
+    between two evaluations (``metric_freq`` > 1 at a snapshot or pause
+    boundary) pays it unused."""
     from ..grower import predict_tree_binned
+    from ..metrics import device_partials
     K = b.num_tree_per_iteration
     routed = b._leaf_routed
     meta_args = b.meta.as_runtime_arrays()
     rf = b.boosting_type == "rf"
     init_col = (jnp.asarray(b.init_scores, jnp.float32)[:, None]
                 if rf else None)
+    objective = b.objective
 
-    def upd(vscore, stacked_seq, binned, its, n_steps):
+    def upd(vscore, stacked_seq, binned, its, n_steps, label=None):
         def body(j, vs):
             st = jax.tree_util.tree_map(lambda a: _ix(a, j), stacked_seq)
             if rf:
@@ -227,7 +237,10 @@ def build_chunk_valid(b):
                 vs = (vs + init_col) / (itf + 1.0)
             return vs
 
-        return lax.fori_loop(0, n_steps, body, vscore)
+        vs = lax.fori_loop(0, n_steps, body, vscore)
+        if not forms:
+            return vs
+        return vs, device_partials(forms, vs[0], label, objective)
 
     return jax.jit(upd, donate_argnums=(0,))
 

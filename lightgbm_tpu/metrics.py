@@ -5,9 +5,17 @@ factory (src/metric/metric.cpp:17-56), regression_metric.hpp,
 binary_metric.hpp, multiclass_metric.hpp, rank_metric.hpp, map_metric.hpp,
 xentropy_metric.hpp, dcg_calculator.cpp.
 
-Metrics run on host NumPy: they are O(n) or O(n log n) once per iteration,
-off the device critical path (scores are fetched once per eval).  Each
-metric returns (name, value, higher_better).
+Every metric has a host form, ``eval``: NumPy in float64 over the score
+pulled to the host.  ``auc`` and ``binary_logloss`` also have a device form:
+``device_partials`` (jnp, traceable) reduces the score the device already
+holds to int32 / float32 sums over blocks of rows, and ``finish`` turns those
+partials, pulled to the host, into the value in float64.  The booster takes
+the device form where the metric's ``device_ready`` holds, the model has one
+output a row and there is an objective (``GBDT._device_forms``): traced into
+the program that updates a validation score (``boosting/macro.
+build_chunk_valid``), or jitted alone for any other score.  Unweighted AUC is
+then the host form's value to the last bit, logloss the host's within f32
+rounding.  Each metric returns (name, value, higher_better).
 """
 
 from __future__ import annotations
@@ -15,10 +23,46 @@ from __future__ import annotations
 import math
 from typing import List, Optional, Sequence, Tuple
 
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from .config import Config
 from .dataset import Metadata
+
+
+def _block_sums(x, rows: int):
+    """Sums of ``x`` over consecutive blocks of ``rows`` rows, the last one
+    padded with zeros, in ``x``'s dtype."""
+    nb = -(-x.shape[0] // rows)
+    x = jnp.pad(x, (0, nb * rows - x.shape[0]))
+    return x.reshape(nb, rows).sum(axis=1, dtype=x.dtype)
+
+
+def _log(x):
+    """Natural log of a positive f32 ``x`` to within 2 ulp, from exact bit
+    operations, adds, multiplies and one divide: ``x = m * 2^e`` with ``m``
+    in [sqrt(1/2), sqrt(2)), ``log m = 2 atanh(z)``, ``z = (m - 1) / (m +
+    1)``, ``|z| < 0.172``, by its odd series to ``z^13``.  With the
+    backend's own f32 log the device logloss of 2,796,202 rows on a TPU v5e
+    read up to 4.7e-5 relative off the host's float64 one."""
+    m, e = jnp.frexp(x)                                 # m in [0.5, 1)
+    low = m < np.float32(np.sqrt(0.5))
+    m = jnp.where(low, m * 2, m)
+    e = (e - low).astype(jnp.float32)
+    z = (m - 1) / (m + 1)
+    z2 = z * z
+    odd = z2 * (1 / 3 + z2 * (1 / 5 + z2 * (1 / 7 + z2 * (1 / 9 + z2 * (
+        1 / 11 + z2 / 13)))))
+    ln2_hi = np.float32(0.693359375)                    # 10 bits: e * it exact
+    return e * ln2_hi + (e * np.float32(np.log(2) - 0.693359375)
+                         + (2 * z + 2 * z * odd))
+
+
+def device_partials(metrics, score, label, objective):
+    """Each metric's ``device_partials`` over one output's score row
+    ``score`` [n] and the set's float32 ``label`` [n]; traceable."""
+    return tuple(m.device_partials(score, label, objective) for m in metrics)
 
 
 class Metric:
@@ -44,6 +88,19 @@ class Metric:
         """Names this metric will emit from :meth:`eval`, derivable without
         an evaluation pass (reference: Metric::GetName, metric.h:40)."""
         return [self.name]
+
+    def device_ready(self) -> bool:
+        """Whether ``device_partials`` and ``finish`` give this metric's
+        value for its data set: False for a metric without a device form,
+        and for an input its device form cannot reduce as the host does."""
+        return False
+
+    def device_label(self):
+        """The label as a float32 device array, made at the first call:
+        the ``label`` argument of ``device_partials``."""
+        if getattr(self, "_label_dev", None) is None:
+            self._label_dev = jnp.asarray(self.label.astype(np.float32))
+        return self._label_dev
 
     def _avg(self, pointwise: np.ndarray) -> float:
         if self.weight is not None:
@@ -175,11 +232,43 @@ class BinaryLoglossMetric(_PointwiseRegressionMetric):
     """reference: binary_metric.hpp:115 (prob via objective ConvertOutput)."""
 
     name = "binary_logloss"
+    block_rows = 4096           # rows a partial sums
+    unit = 4096.0               # a row's loss in whole 1/4096ths and the rest
 
     def point_loss(self, p):
         eps = 1e-15
         p = np.clip(p, eps, 1 - eps)
         return -(self.label * np.log(p) + (1 - self.label) * np.log(1 - p))
+
+    def init(self, metadata, num_data):
+        super().init(metadata, num_data)
+        self._labels_in_01 = bool(((self.label >= 0) & (self.label <= 1)).all())
+
+    def device_ready(self):
+        return self.weight is None and self.num_data > 0 and self._labels_in_01
+
+    def device_partials(self, score, label, objective):
+        """The host's per-row loss in f32 (the same ``convert_output``, the
+        same clip), summed by blocks of rows as good as exactly.  ``1 - p``
+        is exact for ``p >= 0.5``; the host clips ``p`` at ``1 - 1e-15`` in
+        float64, which leaves ``1 - p`` at least 9.992e-16, so ``p == 1``
+        reads that and not ``log(0)``.  A row's loss, at most 34.54 for a
+        label in [0, 1], splits into whole 1/4096ths, summed exactly in
+        int32 (a block's at most 4096 x 141,476 < 2^31), and the f32 rest
+        (at most 2^-13), summed in f32."""
+        p = jnp.clip(objective.convert_output(score), jnp.float32(1e-15), 1.0)
+        q = jnp.maximum(1.0 - p, jnp.float32(1.0 - (1.0 - 1e-15)))
+        loss = -(label * _log(p) + (1.0 - label) * _log(q))
+        whole = jnp.round(loss * self.unit)
+        return (_block_sums(whole.astype(jnp.int32), self.block_rows),
+                _block_sums(loss - whole / self.unit, self.block_rows))
+
+    def finish(self, partials):
+        whole, rest = partials
+        total = (int(np.asarray(whole, np.int64).sum()) / self.unit
+                 + float(np.asarray(rest, np.float64).sum()))
+        return [(self.name, self.transform(total / self.num_data),
+                 self.higher_better)]
 
 
 class BinaryErrorMetric(_PointwiseRegressionMetric):
@@ -195,6 +284,57 @@ class AUCMetric(Metric):
 
     name = "auc"
     higher_better = True
+
+    def init(self, metadata, num_data):
+        super().init(metadata, num_data)
+        pos, neg = self.label > 0, self.label <= 0
+        self._counts = (int(pos.sum()), int(neg.sum()))
+        # exact on the device: no weights, a row's class the same from the
+        # float32 label, and counts whose products the host sums exactly
+        # in float64 (n^2 / 4 < 2^52)
+        lab32 = self.label.astype(np.float32)
+        self._exact_on_device = (
+            self.weight is None and 0 < num_data < 2 ** 26
+            and np.array_equal(pos, lab32 > 0)
+            and np.array_equal(neg, lab32 <= 0))
+
+    def device_ready(self):
+        return self._exact_on_device
+
+    @staticmethod
+    def block_rows(n: int) -> int:
+        """Rows an int32 partial sums: a row adds at most ``2n``, so the
+        largest power of two that keeps ``rows * 2n`` under 2^31."""
+        return 1 << (((2 ** 31 - 1) // (2 * n)).bit_length() - 1)
+
+    def device_partials(self, score, label, objective=None):
+        """Twice the host's ``auc_sum`` as exact int32 block sums.  Scores
+        sorted in descending order, stable, with each row's class (0
+        negative, 1 positive, 2 neither) as payload; a group is a run of
+        equal f32 scores, as ``np.diff`` finds it on their float64 values.
+        A positive row adds the negatives above its group plus those above
+        and in it: summed over a group, ``pos_g * (2 * cum_neg + neg_g)``."""
+        cls = jnp.where(label > 0, 1, jnp.where(label <= 0, 0, 2))
+        key, cls = lax.sort((-score, cls.astype(jnp.int32)), num_keys=1,
+                            is_stable=True)
+        neg = (cls == 0).astype(jnp.int32)
+        new = key[1:] != key[:-1]
+        first = jnp.concatenate([jnp.ones(1, bool), new])
+        last = jnp.concatenate([new, jnp.ones(1, bool)])
+        cum = jnp.cumsum(neg, dtype=jnp.int32)     # negatives up to the row
+        above_group = lax.cummax(jnp.where(first, cum - neg, 0))
+        through_group = lax.cummin(
+            jnp.where(last, cum, jnp.int32(2 ** 31 - 1)), reverse=True)
+        per_row = jnp.where(cls == 1, above_group + through_group, 0)
+        return _block_sums(per_row, self.block_rows(score.shape[0]))
+
+    def finish(self, partials):
+        pos, neg = self._counts
+        if pos == 0 or neg == 0:
+            return [(self.name, 1.0, True)]
+        twice = int(np.asarray(partials, np.int64).sum())
+        return [(self.name, 1.0 - (twice / 2) / (float(pos) * float(neg)),
+                 True)]
 
     def eval(self, score, objective):
         score = np.asarray(score, np.float64).reshape(-1)

@@ -489,3 +489,48 @@ def test_validation_update_compiles_in_its_path_form(one_chip,
         _shape(one_chip, (G, n), jnp.uint8), meta)
     assert _kernels(c) == 0
     assert c.memory_analysis().temp_size_in_bytes < LEAVES * ROUTE_BLOCK_ROWS * 4
+
+
+def test_validation_update_compiles_with_the_metrics(one_chip):
+    """``criteo-quant.monitored``'s ``upd`` as the chip runs it: one tree
+    applied to 2,796,202 x 67 one-byte bins, then AUC (a stable sort of
+    the scores with the classes, cumulative max and min, int32 block sums)
+    and logloss (int32 and f32 block sums) reduced from the updated score,
+    the labels a parameter of the program."""
+    from types import SimpleNamespace
+
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.boosting.macro import build_chunk_valid
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.dataset import FeatureMeta
+    from lightgbm_tpu.grower import TreeArrays
+    from lightgbm_tpu.metrics import AUCMetric, create_metric
+    from lightgbm_tpu.objectives import BinaryLogloss
+    G, n = 67, 2_796_202
+    meta = FeatureMeta(num_bin=np.full((G,), B + 1, np.int32),
+                       missing_type=np.zeros((G,), np.int32),
+                       default_bin=np.zeros((G,), np.int32),
+                       most_freq_bin=np.zeros((G,), np.int32),
+                       is_categorical=np.zeros((G,), bool), max_num_bin=B + 1)
+    objective = BinaryLogloss.__new__(BinaryLogloss)
+    objective.config = Config()
+    booster = SimpleNamespace(num_tree_per_iteration=1, _leaf_routed=True,
+                              meta=meta, boosting_type="gbdt",
+                              init_scores=[0.0], objective=objective)
+    forms = tuple(create_metric(name, Config())
+                  for name in ("auc", "binary_logloss"))
+    seq = jax.tree_util.tree_map(
+        lambda a: _shape(one_chip, (1, 1) + a.shape, a.dtype),
+        TreeArrays.empty(LEAVES))
+    c = build_chunk_valid(booster, forms).lower(
+        _shape(one_chip, (1, n), jnp.float32), seq,
+        _shape(one_chip, (G, n), jnp.uint8),
+        _shape(one_chip, (1,), jnp.int32), _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (n,), jnp.float32)).compile()
+    assert _kernels(c) == 0
+    text = c.as_text()
+    blocks = -(-n // forms[1].block_rows)
+    assert f"s32[{-(-n // AUCMetric.block_rows(n))}]" in text
+    assert f"s32[{blocks}]" in text and f"f32[{blocks}]" in text
