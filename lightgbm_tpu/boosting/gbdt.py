@@ -1042,11 +1042,12 @@ class GBDT:
             mode) — default to the closed-over constants otherwise.
             Returns (new_score, stacked trees, leaf_ids, cegb_used,
             cegb_rows, qscales [K, 2] — per-class quantization scales,
-            zeros when quantized training is off — and gstats [K, 6] i32:
+            zeros when quantized training is off — and gstats [K, 8] i32:
             the grower loop's (rounds, candidates offered, splits
             applied, slot widths run, rounds the offer clipped, lanes
-            the route compared rows with) per tree,
-            beside the tree and not in it)."""
+            the route compared rows with) per tree, beside the tree and
+            not in it, and two zeros where GOSS puts its (kept, top)
+            rows of the round: ``boosting/macro.py``)."""
             mc_in = mc if mc_arr is None else mc_arr
             trees = []
             leaf_ids = []
@@ -1116,7 +1117,8 @@ class GBDT:
                     # rows against that one split, and has no offer to clip
                     gstat = jnp.broadcast_to(tree.num_leaves - 1,
                                              (6,)).at[4].set(0)
-                gstat_rows.append(gstat.astype(jnp.int32))
+                gstat_rows.append(jnp.concatenate(
+                    [gstat.astype(jnp.int32), jnp.zeros(2, jnp.int32)]))
                 if feat_perm_j is not None:
                     tree = tree._replace(
                         split_feature=feat_perm_j[tree.split_feature])
@@ -1619,7 +1621,7 @@ class GBDT:
         """Chunk counterpart of _finish_iter: per-iteration bookkeeping
         from ONE stacked ``[c, ...]`` device tree bundle.  Same timer tag
         as _finish_iter — it is the same role, amortized over c.
-        ``gstats_seq``: the chunk's ``[c, K, 6]`` grower counters.  The
+        ``gstats_seq``: the chunk's ``[c, K, 8]`` grower counters.  The
         seam is host until a block on BOTH paths: the eager path's
         ``device_get`` waits for the chunk, and on the deferred path the
         eager ``x[j]`` slices of ``_chunk_slice`` are dispatches that wait
@@ -1767,9 +1769,11 @@ class GBDT:
 
     def _note_trees(self, abs_it: int, gstats) -> None:
         """One ``grower.tree`` flight-ring record a tree, where the host
-        takes it: ``gstats`` is the iteration's ``[K, 6]`` (rounds,
-        offered, applied, slots, clipped, lanes) of the grower's loop, pulled
-        beside the trees, or None (streamed executor).  The rounds also
+        takes it: ``gstats`` is the iteration's ``[K, 8]`` (rounds,
+        offered, applied, slots, clipped, lanes) of the grower's loop and
+        GOSS's (kept, top) rows of the round (0 for a tree grown on no
+        sample), pulled beside the trees, or None (streamed executor).
+        The rounds also
         count into ``grower_rounds_routed_total`` or ``_scanned_total``, by
         the form their program routes rows in, and the tree's accumulate
         passes (a round each, and the root) into ``hist_passes_packed_total``
@@ -1784,14 +1788,15 @@ class GBDT:
         passes = (_obs_registry.counter(self._hist_passes_counter)
                   if self._hist_passes_counter else None)
         for k in range(self.num_tree_per_iteration):
-            rounds, offered, applied, slots, clipped, lanes = (
-                int(v) for v in gstats[k])
+            (rounds, offered, applied, slots, clipped, lanes, goss_kept,
+             goss_top) = (int(v) for v in gstats[k])
             routed.inc(rounds)
             if passes is not None:
                 passes.inc(rounds + 1)
             _flight_note("grower.tree", it=abs_it, k=k, rounds=rounds,
                          offered=offered, applied=applied, slots=slots,
-                         clipped=clipped, lanes=lanes)
+                         clipped=clipped, lanes=lanes, goss_kept=goss_kept,
+                         goss_top=goss_top)
 
     def _drain_pending_inner(self) -> None:
         K = self.num_tree_per_iteration
@@ -1845,7 +1850,7 @@ class GBDT:
         """Post-step bookkeeping shared by GBDT/GOSS/DART/RF: host copies of
         the (tiny) tree arrays, first-iteration bias folding, valid-score
         updates.  Returns True when training should stop.  ``gstats``: the
-        iteration's ``[K, 6]`` grower counters (device).  Host until a
+        iteration's ``[K, 8]`` grower counters (device).  Host until a
         block, like ``macro.host_fetch`` (``_finish_chunk``): the eager
         path's host copies wait for the device, and the deferred path's
         eager device ops may."""

@@ -1,12 +1,36 @@
 """GOSS: gradient-based one-side sampling.
 
-reference: src/boosting/goss.hpp:24-132 — keep the top ``top_rate`` fraction
-of rows by |grad*hess|, sample ``other_rate`` of the rest and amplify their
-weight by (1-top_rate)/other_rate; no sampling during the first
-1/learning_rate warm-up iterations (goss.hpp:126-131).
+reference: Ke et al. 2017 (NIPS), Algorithm 2; src/boosting/goss.hpp:24-132.
+Each sampled round keeps the ``top_k = max(1, floor(top_rate * n))`` rows
+of largest |grad*hess| (summed over classes), draws exactly ``other_k =
+floor(other_rate * n)`` rows from the rest and multiplies their gradients
+and hessians by ``(n - top_k) / other_k`` (f32); every other row weighs 0.
+No sampling during the first 1/learning_rate rounds (goss.hpp:126-131).
 
-TPU form: pure weight mask (1 / amplified / 0) computed on device from the
-current gradients — no index compaction, shapes stay static.
+TPU form: a weight mask (1 / amplified / 0) computed on the device from the
+round's gradients, inside the round program; no index compaction, shapes
+stay static.  Nothing sorts the rows:
+
+- the top set is every real row whose f32 score is ``>=`` the k-th largest,
+  found by a 31-step bisection on the int32 bit pattern (non-negative f32
+  values order like their bits): a count of the rows at or below each
+  step's bits decides it, so the threshold equals ``lax.top_k(score,
+  top_k)[0][-1]`` to the bit.  Ties at the threshold are all kept, as
+  goss.hpp keeps them; padding rows never enter;
+- the rest is the ``other_k`` rest rows with the smallest 32-bit random keys
+  (``jax.random.bits`` from the round's GOSS subkey), the cut found by a
+  32-step bisection on the keys, ties at the cut taken in row order by a
+  prefix count (a bisection on the row index, run only in a round whose cut
+  holds more rows than it needs).
+
+Every count is a sum over all rows of the global array, so under a data
+mesh it is summed over the data axis.
+
+Where this departs from goss.hpp (the count and the multiplier are equal):
+goss.hpp selects per thread block, this program over all rows at once, as
+the paper's Algorithm 2 does; goss.hpp draws its rest row by row with
+adaptive probabilities, this program takes the smallest keys; the paper
+ranks by |g|, goss.hpp and this program by |g*h|.
 """
 
 from __future__ import annotations
@@ -14,8 +38,82 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from .gbdt import GBDT
+
+
+def _count(mask):
+    return jnp.sum(mask.astype(jnp.int32))
+
+
+def _smallest_at_least(count_upto, hi, k, steps):
+    """Smallest ``t`` in [0, hi] with ``count_upto(t) >= k`` (``hi`` where
+    none is), by bisection; ``count_upto`` does not decrease in ``t``."""
+    def step(_, lohi):
+        lo, hi = lohi
+        mid = lo + (hi - lo) // 2
+        ok = count_upto(mid) >= k
+        return jnp.where(ok, lo, mid + 1), jnp.where(ok, mid, hi)
+    lo, _ = lax.fori_loop(0, steps, step, (jnp.zeros_like(hi), hi))
+    return lo
+
+
+def kth_largest_bits(bits, k):
+    """Bit pattern of the k-th largest of the non-negative f32 values whose
+    int32 bits are ``bits`` (rows to leave out hold -1): the (m - k + 1)-th
+    smallest of the m held, by bisection over [0, 2**31 - 1]."""
+    held = bits >= 0
+    return _smallest_at_least(lambda t: _count(held & (bits <= t)),
+                              jnp.int32(0x7FFFFFFF), _count(held) - k + 1, 31)
+
+
+def smallest_keys(keys, pool, k):
+    """Rows of ``pool`` holding the ``k`` smallest uint32 ``keys``, ties at
+    the cut taken in row order (a prefix count, bisected over the row
+    index where the cut holds more rows than are wanted); all of ``pool``
+    where it has fewer."""
+    cut = _smallest_at_least(lambda t: _count(pool & (keys <= t)),
+                             jnp.uint32(0xFFFFFFFF), k, 32)
+    below = pool & (keys < cut)
+    at = pool & (keys == cut)
+    need = k - _count(below)
+    row = lax.iota(jnp.int32, keys.shape[0])
+
+    def first_in_row_order(at):
+        last = _smallest_at_least(lambda r: _count(at & (row <= r)),
+                                  jnp.int32(keys.shape[0] - 1), need,
+                                  max(1, (keys.shape[0] - 1).bit_length()))
+        return at & (row <= last)
+    return below | lax.cond(_count(at) > need, first_in_row_order,
+                            lambda at: at, at)
+
+
+def make_goss_weights(n, top_rate, other_rate):
+    """The round's selection for ``n`` real rows: ``fn(grad, hess, key,
+    row_valid) -> (weights [n_pad] f32, [kept, top] int32)``.  ``grad`` and
+    ``hess`` are [K, n_pad]; ``row_valid`` is 0 on padding rows."""
+    top_k = max(1, int(top_rate * n))
+    other_k = int(other_rate * n)
+    amp = np.float32(n - top_k) / np.float32(max(other_k, 1))
+
+    def goss_weights(grad, hess, key, row_valid):
+        with jax.named_scope("lgbm.goss"):
+            real = row_valid > 0
+            score = jnp.sum(jnp.abs(grad * hess), axis=0)
+            bits = jnp.where(real, lax.bitcast_convert_type(score, jnp.int32),
+                             -1)
+            is_top = bits >= kth_largest_bits(bits, top_k)
+            if other_k > 0:
+                keys = jax.random.bits(key, score.shape, jnp.uint32)
+                rest = smallest_keys(keys, real & ~is_top, other_k)
+            else:
+                rest = jnp.zeros_like(is_top)
+            weights = jnp.where(is_top, jnp.float32(1.0),
+                                jnp.where(rest, amp, jnp.float32(0.0)))
+            top = _count(is_top)
+            return weights, jnp.stack([top + _count(rest), top])
+    return goss_weights
 
 
 class GOSS(GBDT):
@@ -27,33 +125,13 @@ class GOSS(GBDT):
             raise ValueError("cannot use bagging in GOSS")
         if config.top_rate + config.other_rate > 1.0:
             raise ValueError("top_rate + other_rate cannot be larger than 1.0")
-
-        top_rate = config.top_rate
-        other_rate = config.other_rate
-        n = self.num_data
-        n_pad = self._n_pad
-        row_valid = self._row_valid
-
-        def goss_mask_raw(grad, hess, key, row_valid):
-            # grad/hess: [K, n_pad]; sharding-pad rows (row_valid == 0) are
-            # pushed below any real score so they can never enter the top set
-            score = jnp.sum(jnp.abs(grad * hess), axis=0)
-            score = score * row_valid - (1.0 - row_valid)
-            top_k = max(1, int(top_rate * n))
-            thresh = jax.lax.top_k(score, top_k)[0][-1]
-            is_top = score >= thresh
-            rest_p = other_rate / max(1e-12, 1.0 - top_rate)
-            keep_rest = jax.random.uniform(key, (n_pad,)) < rest_p
-            amp = (1.0 - top_rate) / max(other_rate, 1e-12)
-            return jnp.where(is_top, 1.0,
-                             jnp.where(keep_rest, amp, 0.0)) * row_valid
-
-        # the macro-step scan body (boosting/macro.py) traces the SAME
-        # function with the row mask riding as the scan input
-        self._macro_goss_mask = goss_mask_raw
-        self._goss_mask_fn = jax.jit(
-            lambda grad, hess, key: goss_mask_raw(grad, hess, key,
-                                                  row_valid))
+        # the weights the newest tree was grown on (device [n_pad] f32): a
+        # round's sample, or the row mask for an unsampled round
+        self.last_row_weights = None
+        # the chunk program (boosting/macro.py) traces the SAME function
+        self._macro_goss_mask = make_goss_weights(
+            self.num_data, config.top_rate, config.other_rate)
+        self._goss_mask_fn = jax.jit(self._macro_goss_mask)
 
     def _bagging_mask(self, it):
         return self._row_valid
@@ -72,8 +150,10 @@ class GOSS(GBDT):
             self.boost_from_average()
             g, h = self._boost(self.train_score)
             self._goss_rng_key, sub = jax.random.split(self._goss_rng_key)
-            mask = self._goss_mask_fn(g, h, sub)
-            return self._train_with(g, h, mask)
+            mask, counts = self._goss_mask_fn(g, h, sub, self._row_valid)
+            self.last_row_weights = mask
+            return self._train_with(g, h, mask, counts)
+        self.last_row_weights = self._row_valid
         return super().train_one_iter(grad, hess)
 
     def _macro_goss_inputs(self, c, it0, lrs):
@@ -94,7 +174,7 @@ class GOSS(GBDT):
                 flags.append(False)
         return jnp.stack(keys), jnp.asarray(np.asarray(flags))
 
-    def _train_with(self, grad, hess, mask):
+    def _train_with(self, grad, hess, mask, counts):
         if self._stream is not None:
             # out-of-core streamed executor (data/stream.py): same mask,
             # same RNG order, streamed tree growth
@@ -105,4 +185,4 @@ class GOSS(GBDT):
             self._feature_masks(), jnp.float32(self.shrinkage_rate),
             self._node_key(), *self._cegb_state)
         self._cegb_state = (cu, cr)
-        return self._finish_iter(stacked, gstats)
+        return self._finish_iter(stacked, gstats.at[:, 6:].set(counts))

@@ -17,7 +17,8 @@ Everything the scan needs is device-resident or precomputable per chunk:
 - per-tree feature masks -> stacked ``[c, K, F]`` input;
 - learning-rate schedules (reset_parameter) -> ``[c]`` array;
 - per-iteration node keys -> stacked PRNG keys;
-- GOSS masks derive from the in-scan gradients + precomputed subkeys;
+- GOSS masks derive from the in-scan gradients + precomputed subkeys,
+  and the last round's row weights ride out beside the trees;
 - RF's running-mean renormalization rides on a ``[c]`` iteration-index
   array (``score*it`` pre / ``(score+init)/(it+1)`` post, as in rf.py).
 
@@ -146,11 +147,15 @@ def make_chunk_fn(b):
         # keys derive from the stacked per-round key stream `keys`)
         qss0 = jnp.zeros((c, K, 2), jnp.float32)
         # the grower loop's (rounds, offered, applied, slots, clipped,
-        # lanes) per tree ride out the same way, beside the trees
-        gss0 = jnp.zeros((c, K, 6), jnp.int32)
+        # lanes) per tree ride out the same way, beside the trees, with
+        # GOSS's (kept, top) rows of the round: 0 for an unsampled one
+        gss0 = jnp.zeros((c, K, 8), jnp.int32)
+        # GOSS: the weights the round's trees were grown on, one [n_pad]
+        # buffer carried through the loop; the last round's ride out
+        wl0 = _ix(masks, 0) if kind == "goss" else None
 
         def body(j, state):
-            score, cu, cr, ys, qss, gss = state
+            score, cu, cr, ys, qss, gss, wl = state
             mask = _ix(masks, j)
             it = _ix(its, j)
             if kind == "rf":
@@ -162,11 +167,14 @@ def make_chunk_fn(b):
                 g, h = grad_fn(score, obj_tables)
                 score_in = score
             if kind == "goss":
-                gm = goss_mask(g, h, _ix(gkeys, j), mask)
-                mask = jnp.where(_ix(gons, j), gm, mask)
+                on = _ix(gons, j)
+                gm, gcount = goss_mask(g, h, _ix(gkeys, j), mask)
+                mask = wl = jnp.where(on, gm, mask)
             new_score, stacked, _leaf_ids, cu, cr, qsc, gst = core(
                 binned, score_in, mask, g, h, _ix(fmasks, j), _ix(lrs, j),
                 _ix(keys, j), cu, cr, label_r, weight_r)
+            if kind == "goss":
+                gst = gst.at[:, 6:].set(jnp.where(on, gcount, 0))
             if kind == "rf":
                 new_score = (new_score + init_col) / (
                     it.astype(jnp.float32) + 1.0)
@@ -175,12 +183,11 @@ def make_chunk_fn(b):
                 ys, stacked)
             qss = lax.dynamic_update_index_in_dim(qss, qsc, j, 0)
             gss = lax.dynamic_update_index_in_dim(gss, gst, j, 0)
-            return new_score, cu, cr, ys, qss, gss
+            return new_score, cu, cr, ys, qss, gss, wl
 
-        score, cegb_used, cegb_rows, ys, qss, gss = lax.fori_loop(
+        return lax.fori_loop(
             0, n_steps, body,
-            (score, cegb_used, cegb_rows, ys0, qss0, gss0))
-        return score, cegb_used, cegb_rows, ys, qss, gss
+            (score, cegb_used, cegb_rows, ys0, qss0, gss0, wl0))
 
     return chunk
 
@@ -328,11 +335,14 @@ def run_chunk(b, c: int, lrs: Optional[Sequence[float]] = None) -> bool:
     # (the first one of a shape also traces, lowers and compiles)
     with _span("macro.dispatch", ring=True, it=it0, c=c, it0=it0,
                timer="TreeLearner::Train(dispatch)"):
-        (b.train_score, cu, cr, stacked_seq, qss, gss) = b._macro_chunk_jit(
+        (b.train_score, cu, cr, stacked_seq, qss, gss,
+         wl) = b._macro_chunk_jit(
             b.binned, b.train_score, cu, cr, np.int32(c), xs,
             b._macro_ctx["label"], b._macro_ctx["weight"], grad_c, hess_c,
             b._macro_ctx["obj_tables"])
     b._cegb_state = (cu, cr)
+    if wl is not None:
+        b.last_row_weights = wl
     if getattr(b, "_quant_on", False):
         b._quant_scales = qss[c - 1]   # last round's per-class scales
     return b._finish_chunk(stacked_seq, c, lr_list, it0, gss)

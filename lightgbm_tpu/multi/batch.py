@@ -185,7 +185,7 @@ class BatchedChunkProgram:
         with _span("multi.dispatch", lanes=len(bs), c=c,
                    live=sum(map(bool, live)),
                    timer="TreeLearner::Train(dispatch)"):
-            score_B, cu_B, cr_B, ys_B, qss_B, gss_B = self._fn(
+            score_B, cu_B, cr_B, ys_B, qss_B, gss_B, wl_B = self._fn(
                 self._binned_B, score_B, cu_B, cr_B, np.int32(c), xs_B,
                 self._label_B, self._weight_B, grad_c, hess_c,
                 self.b0._macro_ctx["obj_tables"], self._obj_arrs_B)
@@ -198,6 +198,8 @@ class BatchedChunkProgram:
             b._cegb_state = (cu_B[i], cr_B[i])
             if getattr(b, "_quant_on", False):
                 b._quant_scales = qss_B[i][c - 1]
+            if wl_B is not None:
+                b.last_row_weights = wl_B[i]
             seq_i = jax.tree_util.tree_map(lambda a, _i=i: a[_i], ys_B)
             stopped[i] = b._finish_chunk(seq_i, c, lane_lrs[i], it0s[i],
                                          gss_B[i])
