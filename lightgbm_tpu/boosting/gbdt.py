@@ -80,6 +80,10 @@ class GBDT:
     # outputs came from round-local quantization scales, compounding the
     # discretization error in a way the reference never ships
     _quant_ok = True
+    # GOSS: most rows weigh 0 in a sampled round, so the tree grows on the
+    # kept rows gathered to the front where that gives the same tree
+    # (``gather_kept`` in _build_jit_fns_inner)
+    _rows_sampled = False
     # out-of-core streamed execution (lightgbm_tpu/data/): DART needs
     # device re-evaluation of dropped trees over the full matrix and RF
     # renews against running means per iteration — both stay resident
@@ -1028,6 +1032,21 @@ class GBDT:
 
         stoch_round = bool(cc.stochastic_rounding)
         quant_bins = int(cc.num_grad_quant_bins)
+        # a sampled booster's tree grows on its kept rows gathered to the
+        # front of a row buffer, its passes and routes stopping at the
+        # last of them (boosting/goss.kept_rows_first): where the rounds
+        # grower's fused arm runs on one chip over the integer family, so
+        # that the sums, and the tree, are what the weight mask gives.
+        # Quantized values come from the full rows first (stochastic
+        # rounding draws by row position); renewal from true f32 sums
+        # keeps the mask
+        meta_r = meta.resolved()
+        gather_kept = (type(self)._rows_sampled and use_rounds
+                       and quant_on and not cfg.quant_renew
+                       and cfg.hist_method == "fused"
+                       and not meta_r.has_bundles and not cc.extra_trees
+                       and bynode_cnt == 0 and self._mesh is None)
+        kept_routed = not bool(meta_r.is_categorical.any())
 
         def iter_body(binned, score, row_mask, grad, hess, fmask, lr, rng,
                       label_r, weight_r, cegb_used, cegb_rows,
@@ -1042,19 +1061,21 @@ class GBDT:
             mode) — default to the closed-over constants otherwise.
             Returns (new_score, stacked trees, leaf_ids, cegb_used,
             cegb_rows, qscales [K, 2] — per-class quantization scales,
-            zeros when quantized training is off — and gstats [K, 8] i32:
+            zeros when quantized training is off — and gstats [K, 9] i32:
             the grower loop's (rounds, candidates offered, splits
             applied, slot widths run, rounds the offer clipped, lanes
             the route compared rows with) per tree, beside the tree and
-            not in it, and two zeros where GOSS puts its (kept, top)
-            rows of the round: ``boosting/macro.py``)."""
+            not in it, two zeros where GOSS puts its (kept, top) rows of
+            the round (``boosting/macro.py``), and the rows its
+            histogram passes read, summed)."""
             mc_in = mc if mc_arr is None else mc_arr
             trees = []
             leaf_ids = []
             qscale_rows = []
             gstat_rows = []
             new_score = score
-            for k in range(K):
+
+            def quantize(k):
                 # quantized-gradient mode: per-round discretization with
                 # stochastic rounding seeded from the SAME per-round key
                 # stream the node randomness rides (so chunked and
@@ -1062,21 +1083,41 @@ class GBDT:
                 # data sharding each shard folds its axis index in so the
                 # rounding noise is i.i.d. across shards while the scales
                 # (pmax inside quantize_gradients) stay replicated
-                if quant_on:
+                qkey = jax.random.fold_in(
+                    jax.random.fold_in(rng, 0x51475442), k)
+                if axis_name is not None:
+                    from ..parallel.collectives import axis_index_flat
                     qkey = jax.random.fold_in(
-                        jax.random.fold_in(rng, 0x51475442), k)
-                    if axis_name is not None:
-                        from ..parallel.collectives import axis_index_flat
-                        qkey = jax.random.fold_in(
-                            qkey, axis_index_flat(axis_name))
-                    with jax.named_scope("lgbm.quantize"):
-                        quant_vals = quantize_gradients(
-                            grad[k], hess[k], row_mask, quant_bins, qkey,
-                            stochastic=stoch_round, axis_name=axis_name)
+                        qkey, axis_index_flat(axis_name))
+                with jax.named_scope("lgbm.quantize"):
+                    return quantize_gradients(
+                        grad[k], hess[k], row_mask, quant_bins, qkey,
+                        stochastic=stoch_round, axis_name=axis_name)
+            quants = [quantize(k) if quant_on else None for k in range(K)]
+            if gather_kept:
+                # the matrix and every class's two int8 rows, in one
+                # gather: its cost is per column
+                from .goss import kept_rows_first, take_kept
+                G = binned.shape[0]
+                with jax.named_scope("lgbm.goss"):
+                    kept, live = kept_rows_first(row_mask)
+                    buf = take_kept(jnp.concatenate([binned] + [
+                        jax.lax.bitcast_convert_type(v, jnp.uint8).astype(
+                            binned.dtype)[None]
+                        for q in quants for v in q[:2]]), kept, live)
+                    binned_kept = buf[:G]
+                    q_kept = [tuple(
+                        jax.lax.bitcast_convert_type(
+                            buf[G + 2 * k + c].astype(jnp.uint8), jnp.int8)
+                        for c in (0, 1)) + tuple(q[2:])
+                        for k, q in enumerate(quants)]
+                    mask_kept = (jax.lax.iota(jnp.int32, n_pad)
+                                 < live).astype(jnp.float32)
+            for k in range(K):
+                quant_vals = quants[k]
+                if quant_on:
                     qscale_rows.append(jnp.stack([quant_vals[2],
                                                   quant_vals[3]]))
-                else:
-                    quant_vals = None
                 gstat = None
                 if cegb_on:
                     tree, leaf_id, (cegb_used, cegb_rows) = grow_tree(
@@ -1091,6 +1132,22 @@ class GBDT:
                         cegb_used_rows=cegb_rows,
                         forced_plan=forced_plan,
                         meta_arrays=meta_args)
+                elif gather_kept:
+                    # the grower reads the integer values and the
+                    # membership of the buffer's rows (no renewal): every
+                    # row's leaf then comes from the finished tree
+                    from ..grower import predict_leaf_index_binned
+                    from ..grower_rounds import grow_tree_rounds
+                    tree, _, gstat = grow_tree_rounds(
+                        binned_kept, grad[k], hess[k], mask_kept, meta, cfg,
+                        feature_mask=fmask[k], monotone_constraints=mc_in,
+                        rng_key=jax.random.fold_in(rng, k),
+                        meta_arrays=meta_args, quant_vals=q_kept[k],
+                        with_stats=True, live_rows=live)
+                    with jax.named_scope("lgbm.score_update"):
+                        leaf_id = predict_leaf_index_binned(
+                            tree, binned, meta, meta_args,
+                            routed=kept_routed)
                 elif use_rounds:
                     from ..grower_rounds import grow_tree_rounds
                     tree, leaf_id, gstat = grow_tree_rounds(
@@ -1113,12 +1170,15 @@ class GBDT:
                                               quant_vals=quant_vals)
                 if gstat is None:
                     # the serial grower offers and commits one split a
-                    # trip of its loop, in a pass one slot wide, routes its
-                    # rows against that one split, and has no offer to clip
+                    # trip of its loop, in a pass one slot wide over every
+                    # row, routes its rows against that one split, and has
+                    # no offer to clip
                     gstat = jnp.broadcast_to(tree.num_leaves - 1,
-                                             (6,)).at[4].set(0)
+                                             (7,)).at[4].set(0).at[6].set(
+                        tree.num_leaves * n_pad)
+                gstat = gstat.astype(jnp.int32)
                 gstat_rows.append(jnp.concatenate(
-                    [gstat.astype(jnp.int32), jnp.zeros(2, jnp.int32)]))
+                    [gstat[:6], jnp.zeros(2, jnp.int32), gstat[6:]]))
                 if feat_perm_j is not None:
                     tree = tree._replace(
                         split_feature=feat_perm_j[tree.split_feature])
@@ -1212,7 +1272,7 @@ class GBDT:
                     mr.has_bundles, int(mr.max_group_bin),
                     len(mr.num_bin), int(mr.num_groups),
                     bool(mr.is_categorical.any()), env_gates,
-                    stoch_round)
+                    stoch_round, gather_kept)
             shared = _shared_program(cache_key)
             if shared is None:
                 def one_iter_full(binned, score, row_mask, grad, hess,
@@ -1621,7 +1681,7 @@ class GBDT:
         """Chunk counterpart of _finish_iter: per-iteration bookkeeping
         from ONE stacked ``[c, ...]`` device tree bundle.  Same timer tag
         as _finish_iter — it is the same role, amortized over c.
-        ``gstats_seq``: the chunk's ``[c, K, 8]`` grower counters.  The
+        ``gstats_seq``: the chunk's ``[c, K, 9]`` grower counters.  The
         seam is host until a block on BOTH paths: the eager path's
         ``device_get`` waits for the chunk, and on the deferred path the
         eager ``x[j]`` slices of ``_chunk_slice`` are dispatches that wait
@@ -1769,10 +1829,12 @@ class GBDT:
 
     def _note_trees(self, abs_it: int, gstats) -> None:
         """One ``grower.tree`` flight-ring record a tree, where the host
-        takes it: ``gstats`` is the iteration's ``[K, 8]`` (rounds,
-        offered, applied, slots, clipped, lanes) of the grower's loop and
+        takes it: ``gstats`` is the iteration's ``[K, 9]`` (rounds,
+        offered, applied, slots, clipped, lanes) of the grower's loop,
         GOSS's (kept, top) rows of the round (0 for a tree grown on no
-        sample), pulled beside the trees, or None (streamed executor).
+        sample) and the rows the tree's histogram passes read (``n_pad``
+        a pass, fewer where a pass stopped at the last kept row), pulled
+        beside the trees, or None (streamed executor).
         The rounds also
         count into ``grower_rounds_routed_total`` or ``_scanned_total``, by
         the form their program routes rows in, and the tree's accumulate
@@ -1789,14 +1851,14 @@ class GBDT:
                   if self._hist_passes_counter else None)
         for k in range(self.num_tree_per_iteration):
             (rounds, offered, applied, slots, clipped, lanes, goss_kept,
-             goss_top) = (int(v) for v in gstats[k])
+             goss_top, pass_rows) = (int(v) for v in gstats[k])
             routed.inc(rounds)
             if passes is not None:
                 passes.inc(rounds + 1)
             _flight_note("grower.tree", it=abs_it, k=k, rounds=rounds,
                          offered=offered, applied=applied, slots=slots,
                          clipped=clipped, lanes=lanes, goss_kept=goss_kept,
-                         goss_top=goss_top)
+                         goss_top=goss_top, pass_rows=pass_rows)
 
     def _drain_pending_inner(self) -> None:
         K = self.num_tree_per_iteration
@@ -1850,7 +1912,7 @@ class GBDT:
         """Post-step bookkeeping shared by GBDT/GOSS/DART/RF: host copies of
         the (tiny) tree arrays, first-iteration bias folding, valid-score
         updates.  Returns True when training should stop.  ``gstats``: the
-        iteration's ``[K, 8]`` grower counters (device).  Host until a
+        iteration's ``[K, 9]`` grower counters (device).  Host until a
         block, like ``macro.host_fetch`` (``_finish_chunk``): the eager
         path's host copies wait for the device, and the deferred path's
         eager device ops may."""

@@ -8,8 +8,8 @@ and hessians by ``(n - top_k) / other_k`` (f32); every other row weighs 0.
 No sampling during the first 1/learning_rate rounds (goss.hpp:126-131).
 
 TPU form: a weight mask (1 / amplified / 0) computed on the device from the
-round's gradients, inside the round program; no index compaction, shapes
-stay static.  Nothing sorts the rows:
+round's gradients, inside the round program; shapes stay static.  Nothing
+sorts the rows:
 
 - the top set is every real row whose f32 score is ``>=`` the k-th largest,
   found by a 31-step bisection on the int32 bit pattern (non-negative f32
@@ -25,6 +25,17 @@ stay static.  Nothing sorts the rows:
 
 Every count is a sum over all rows of the global array, so under a data
 mesh it is summed over the data axis.
+
+Where the rounds grower's fused arm runs on one chip over quantized
+gradients, the tree is grown on the kept rows alone (``kept_rows_first``,
+``GBDT._build_jit_fns_inner``): once a round, after the values are
+quantized on the full rows, the kept rows are gathered, in row order, to
+the front of a row buffer of the matrix's own size, and the histogram
+passes and routes stop at the last of them.  Integer sums do not depend on
+the order of their terms, so the tree is the mask form's bit for bit;
+every row's leaf then comes from the finished tree.  Elsewhere (a mesh,
+the streamed executor, the staged arm, f32 gradients, renewal from true
+sums) the tree is grown on all rows under the mask.
 
 Where this departs from goss.hpp (the count and the multiplier are equal):
 goss.hpp selects per thread block, this program over all rows at once, as
@@ -89,6 +100,73 @@ def smallest_keys(keys, pool, k):
                             lambda at: at, at)
 
 
+def kept_rows_first(weights):
+    """Where a round's kept rows go in the row buffer: ``(rows, live)``,
+    ``rows`` [n] i32 whose first ``live`` entries are the rows of weight
+    above 0 in row order (the rest: some row, never read), and ``live``
+    (i32) how many were kept.
+
+    A kept row moves toward the front by the count of weight-0 rows before
+    it, one bit of that distance a step, low bits first: two kept rows
+    never land on one place, so ceil(log2 n) selects over shifted copies
+    place them all, with no scatter and no sort (at 25.2M rows on one
+    v5e, 41.2 ms with a ``jnp.cumsum`` count, where a scatter takes
+    186.7: ``PERF.md`` section 5).  The prefix count that gives the
+    distance is ceil(log2 n) shifted adds: the TPU compiler takes 7-8 s
+    over ``jnp.cumsum`` of 262,144 rows, and under half a second over
+    these."""
+    n = weights.shape[0]
+    keep = weights > 0
+    steps = max(n - 1, 1).bit_length()
+    kept_upto = keep.astype(jnp.int32)
+    for b in range(steps):
+        s = 1 << b
+        kept_upto = kept_upto + jnp.concatenate(
+            [jnp.zeros(s, jnp.int32), kept_upto[:-s]])
+    row = lax.iota(jnp.int32, n)
+    dist = jnp.where(keep, row + 1 - kept_upto, -1)   # -1: no kept row
+    for b in range(steps):
+        s = 1 << b
+        d_in = jnp.concatenate([dist[s:], jnp.full(s, -1, jnp.int32)])
+        r_in = jnp.concatenate([row[s:], jnp.zeros(s, jnp.int32)])
+        come = (d_in >= 0) & ((d_in >> b) & 1 == 1)
+        stay = (dist >= 0) & ((dist >> b) & 1 == 0)
+        row = jnp.where(come, r_in, row)
+        dist = jnp.where(come, d_in, jnp.where(stay, dist, -1))
+    return row, kept_upto[-1]
+
+
+# The gather of the kept rows runs this many buffer columns a step: its
+# cost is per column gathered (one v5e, 25.2M x 67 uint8, 7.55M kept:
+# 94.1 ms in blocks of 2**17, 120.4 in blocks of 2**20, 418.3 over every
+# column at once; PERF.md section 5)
+KEPT_BLOCK = 1 << 17
+
+
+def take_kept(planes, rows, live):
+    """``planes`` [P, n] with their columns ``rows[:live]`` at the front of
+    a [P, n] buffer; past ``live`` it holds columns no one reads.  Where
+    the kept rows are the first ``live`` (a round that kept every row)
+    that is ``planes`` itself.  Else the gather runs a block of columns at
+    a time and stops at the block that holds the last kept row; a last
+    block that does not fit starts at ``n - block`` and rewrites columns
+    the one before took the same way."""
+    P, n = planes.shape
+    C = min(KEPT_BLOCK, n)
+
+    def step(i, buf):
+        s = jnp.minimum(i * C, n - C)
+        cols = lax.dynamic_slice_in_dim(rows, s, C)
+        return lax.dynamic_update_slice_in_dim(
+            buf, planes.at[:, cols].get(mode="promise_in_bounds"), s, 1)
+
+    def gather(planes):
+        return lax.fori_loop(0, (live + C - 1) // C, step,
+                             jnp.zeros_like(planes))
+    in_place = rows[jnp.maximum(live - 1, 0)] == live - 1
+    return lax.cond(in_place, lambda p: p, gather, planes)
+
+
 def make_goss_weights(n, top_rate, other_rate):
     """The round's selection for ``n`` real rows: ``fn(grad, hess, key,
     row_valid) -> (weights [n_pad] f32, [kept, top] int32)``.  ``grad`` and
@@ -118,6 +196,7 @@ def make_goss_weights(n, top_rate, other_rate):
 
 class GOSS(GBDT):
     boosting_type = "goss"
+    _rows_sampled = True
 
     def __init__(self, config, train_set, objective):
         super().__init__(config, train_set, objective)
@@ -185,4 +264,4 @@ class GOSS(GBDT):
             self._feature_masks(), jnp.float32(self.shrinkage_rate),
             self._node_key(), *self._cegb_state)
         self._cegb_state = (cu, cr)
-        return self._finish_iter(stacked, gstats.at[:, 6:].set(counts))
+        return self._finish_iter(stacked, gstats.at[:, 6:8].set(counts))

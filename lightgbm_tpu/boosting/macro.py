@@ -148,8 +148,9 @@ def make_chunk_fn(b):
         qss0 = jnp.zeros((c, K, 2), jnp.float32)
         # the grower loop's (rounds, offered, applied, slots, clipped,
         # lanes) per tree ride out the same way, beside the trees, with
-        # GOSS's (kept, top) rows of the round: 0 for an unsampled one
-        gss0 = jnp.zeros((c, K, 8), jnp.int32)
+        # GOSS's (kept, top) rows of the round (0 for an unsampled one)
+        # and the rows the tree's histogram passes read
+        gss0 = jnp.zeros((c, K, 9), jnp.int32)
         # GOSS: the weights the round's trees were grown on, one [n_pad]
         # buffer carried through the loop; the last round's ride out
         wl0 = _ix(masks, 0) if kind == "goss" else None
@@ -174,7 +175,7 @@ def make_chunk_fn(b):
                 binned, score_in, mask, g, h, _ix(fmasks, j), _ix(lrs, j),
                 _ix(keys, j), cu, cr, label_r, weight_r)
             if kind == "goss":
-                gst = gst.at[:, 6:].set(jnp.where(on, gcount, 0))
+                gst = gst.at[:, 6:8].set(jnp.where(on, gcount, 0))
             if kind == "rf":
                 new_score = (new_score + init_col) / (
                     it.astype(jnp.float32) + 1.0)

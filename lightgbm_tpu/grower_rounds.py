@@ -168,7 +168,7 @@ def set_bytes_hold(set_bytes, col):
 
 
 def route_lanes(binned_t, leaf_id, idl, k, W: int, best, kcap: int,
-                bin_layout, num_bins: int, set_words: int):
+                bin_layout, num_bins: int, set_words: int, live_rows=None):
     """The router form of a round's routing against its ``W`` lanes.
 
     Lane ``r`` is the round's ``r``-th candidate ``idl[r]``, live where
@@ -186,7 +186,12 @@ def route_lanes(binned_t, leaf_id, idl, k, W: int, best, kcap: int,
     else ``kcap``): what the candidate scan gives, with no per-row
     parameter array outside a block.  ``num_bins`` bounds every bin-valued
     parameter; ``set_words``: how many words of a categorical lane's set
-    ride along (0 = no categorical column)."""
+    ride along (0 = no categorical column).
+
+    ``live_rows`` (a traced int32, or None): the rows at or past it are
+    dead (a buffer whose live rows were gathered to the front).  The loop
+    then stops at the block that holds the last live row, and a dead row
+    is in no lane (``crank = slot = kcap``)."""
     num_bin, missing_type, default_bin, feat_group, feat_start = bin_layout
     G, n = binned_t.shape
     F = num_bin.shape[0]
@@ -250,28 +255,41 @@ def route_lanes(binned_t, leaf_id, idl, k, W: int, best, kcap: int,
 
     def step(i, outs):
         s = jnp.minimum(i * C, n - C)
-        got = decide(lax.dynamic_slice_in_dim(leaf_id, s, C),
-                     lax.dynamic_slice_in_dim(binned_t, s, C, axis=1))
+        crank, gl, slot = decide(
+            lax.dynamic_slice_in_dim(leaf_id, s, C),
+            lax.dynamic_slice_in_dim(binned_t, s, C, axis=1))
+        if live_rows is not None:
+            dead = s + lax.iota(jnp.int32, C) >= live_rows
+            crank = jnp.where(dead, kcap, crank)
+            slot = jnp.where(dead, kcap, slot)
         return tuple(lax.dynamic_update_slice_in_dim(o, v, s, 0)
-                     for o, v in zip(outs, got))
+                     for o, v in zip(outs, (crank, gl, slot)))
 
-    return lax.fori_loop(0, -(-n // C), step,
-                         (jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.bool_),
-                          jnp.zeros(n, jnp.int32)))
+    if live_rows is None:
+        return lax.fori_loop(
+            0, -(-n // C), step, (jnp.zeros(n, jnp.int32),
+                                  jnp.zeros(n, jnp.bool_),
+                                  jnp.zeros(n, jnp.int32)))
+    none = jnp.full(n, kcap, jnp.int32)
+    return lax.fori_loop(0, (live_rows + C - 1) // C, step,
+                         (none, jnp.zeros(n, jnp.bool_), none))
 
 
 def route_scan(binned_t, leaf_id, idl, k, best, kcap: int, bin_layout,
-               has_cat: bool):
+               has_cat: bool, live_rows=None):
     """The candidate scan form of a round's routing: one step per
     candidate reads its split feature as a CONTIGUOUS column of the
     transposed matrix and broadcasts scalar split params (kept for CPU,
     where one-hot matmuls lose, and as the router's oracle: the two forms
     grow the same trees bit for bit, tests/test_cat_router.py).  Returns
-    ``route_lanes``'s (crank, gl, slot)."""
+    ``route_lanes``'s (crank, gl, slot), ``live_rows`` included: a row at
+    or past it is in no candidate."""
     num_bin, missing_type, default_bin, feat_group, feat_start = bin_layout
     n = binned_t.shape[1]
     F = num_bin.shape[0]
     b = best
+    if live_rows is not None:
+        leaf_id = jnp.where(lax.iota(jnp.int32, n) < live_rows, leaf_id, -1)
 
     def cstep(carry, kk):
         def live(carry):
@@ -335,17 +353,27 @@ def _grow_tree_rounds_traced(
     quant_vals: Optional[tuple] = None,     # cfg.quant: (gq, hq, g_scale,
                                             # h_scale) — see grower.grow_tree
     with_stats: bool = False,
+    live_rows: Optional[jax.Array] = None,
 ):
     """Grow one tree; returns (TreeArrays, leaf_id [n] i32), and with
-    ``with_stats`` a third [6] i32: the loop's trips, the candidates it
+    ``with_stats`` a third [7] i32: the loop's trips, the candidates it
     offered (a round builds that many smaller-child histograms), the
     splits it committed, the slot widths its histogram passes ran at,
     summed (the fused arm's root pass included; a staged pass runs at the
     round cap), the trips the offer clipped (it bound ``k`` and all ``k``
-    committed: the trips it may have cost a pass), and the lanes its
+    committed: the trips it may have cost a pass), the lanes its
     route compared rows with, summed (the router's ``W`` a round, the
     width of its pass on the fused arm and the cap staged; the scan's
-    ``k``)."""
+    ``k``), and the rows its histogram passes read, summed over the
+    passes and the data axis (exact while that stays under 2**31).
+
+    ``live_rows`` (a traced int32, or None): the rows at or past it weigh
+    nothing, as in a buffer whose weighted rows were gathered to the front
+    (``boosting/goss.kept_rows_first``).  The fused arm's passes then read
+    only the row tiles before it and every route stops at it; the tree is
+    the one the same rows would grow in any order, since the integer
+    family sums exactly.  ``leaf_id`` is then the buffer's, and past
+    ``live_rows`` means nothing."""
     meta = meta.resolved()
     G, n = binned_t.shape
     L = cfg.num_leaves
@@ -375,6 +403,9 @@ def _grow_tree_rounds_traced(
     # planner-selected row tiling (ops/planner.py): all histogram passes
     # stream tiles of this many rows; 0/None = untiled
     tile = cfg.tile_rows if cfg.tile_rows > 0 else None
+    if live_rows is not None and (not quant or cfg.quant_renew):
+        raise ValueError("live_rows needs the integer family without "
+                         "renewal: the grower then reads no f32 row value")
     if quant:
         if quant_vals is None:
             raise ValueError("cfg.quant requires quant_vals="
@@ -494,7 +525,8 @@ def _grow_tree_rounds_traced(
                 (_vals_t_int(q_grad, q_hess, row_mask > 0) if quant
                  else _vals_t(grad, hess, row_mask)),
                 KCAP, Bg, feat_tile=fused_ftile,
-                block_rows=cfg.fused_block_rows or None, tile_rows=tile)
+                block_rows=cfg.fused_block_rows or None, tile_rows=tile,
+                live_rows=live_rows)
         # static categorical column index set for pick_fused_best's merge
         cat_idx = (tuple(int(i) for i, v in
                          enumerate(meta.is_categorical) if v)
@@ -555,7 +587,7 @@ def _grow_tree_rounds_traced(
         if quant:
             member = row_mask > 0
             if use_fused:
-                root_arena, root_width, _ = fused_accumulate(
+                root_arena, root_width, root_rows, _ = fused_accumulate(
                     lambda W: (jnp.where(member, 0, KCAP), None), 1)
                 root_local = root_arena[0]
             else:
@@ -571,7 +603,7 @@ def _grow_tree_rounds_traced(
             root_cnt = psum_(jnp.sum(member.astype(jnp.float32)))
         else:
             if use_fused:
-                root_arena, root_width, _ = fused_accumulate(
+                root_arena, root_width, root_rows, _ = fused_accumulate(
                     lambda W: (jnp.where(row_mask > 0, 0, KCAP), None), 1)
                 root_local = root_arena[0]
             else:
@@ -618,6 +650,7 @@ def _grow_tree_rounds_traced(
         last_m: jax.Array       # splits the last round committed
         clipped: jax.Array      # trips the offer bound and wholly committed
         lanes: jax.Array        # sum of the lanes the route compared rows with
+        rows: jax.Array         # sum of the rows the passes read
 
     iota_L = jnp.arange(L, dtype=jnp.int32)
 
@@ -714,11 +747,11 @@ def _grow_tree_rounds_traced(
             leaf_max = _pad_scatter(jnp.where(sel, l_max, leaf_max),
                                     newleaf_of, r_max, sel)
 
-        return Carry(tree, c.best, hist, leaf_sg, leaf_sh, leaf_cnt,
-                     leaf_parent_side, new_leaf_id, c.split_idx + k,
-                     leaf_min, leaf_max, c.rounds, c.offered,
-                     c.applied + k, c.slots, c.offer, c.last_m,
-                     c.clipped, c.lanes)
+        return c._replace(
+            tree=tree, hist=hist, leaf_sg=leaf_sg, leaf_sh=leaf_sh,
+            leaf_cnt=leaf_cnt, leaf_parent_side=leaf_parent_side,
+            leaf_id=new_leaf_id, split_idx=c.split_idx + k,
+            leaf_min=leaf_min, leaf_max=leaf_max, applied=c.applied + k)
 
     def child_bounds(c: Carry):
         """Per-leaf monotone bounds the two children of each leaf's cached
@@ -778,25 +811,27 @@ def _grow_tree_rounds_traced(
                     with jax.named_scope("lgbm.route"):
                         crank_, gl_, slot_ = route_lanes(
                             binned_t, c.leaf_id, idl, k, W, b, KCAP,
-                            bin_layout, max(B, Bg), set_words)
+                            bin_layout, max(B, Bg), set_words, live_rows)
                     return slot_, (crank_, gl_)
             else:
                 crank_s, gl_s, slot_s = route_scan(
                     binned_t, c.leaf_id, idl, k, b, KCAP, bin_layout,
-                    has_cat)
+                    has_cat, live_rows)
 
                 def route_at(W):
                     return slot_s, (crank_s, gl_s)
             if not use_fused:
                 slot, (crank, gl) = route_at(KCAP)
         width = jnp.int32(KCAP)
+        rows = jnp.int32(n)
         with jax.named_scope("lgbm.hist"):
             if use_fused:
                 # the accumulate half of the fused megakernel, at the
                 # narrowest compiled width that holds the k candidates, in
                 # one switch with the route; sharded, exactly these
                 # (padded) hists cross the wire
-                seg, width, (crank, gl) = fused_accumulate(route_at, k)
+                seg, width, rows, (crank, gl) = fused_accumulate(route_at,
+                                                                 k)
                 seg = (psum_quant_hist(seg, axis_name, rows_global,
                                        cfg.quant_bins, hierarchical=hier_rd)
                        if quant else psum_(seg))
@@ -946,13 +981,14 @@ def _grow_tree_rounds_traced(
                 slots=c.slots + width,
                 offer=next_offer(rungs, m, c.last_m), last_m=m,
                 clipped=c.clipped + ((c.offer < room) & (m == k)),
-                lanes=c.lanes + lanes)
+                lanes=c.lanes + lanes, rows=c.rows + rows)
 
     zero = jnp.array(0, jnp.int32)
     init = Carry(tree, best, hist_cache, leaf_sg, leaf_sh, leaf_cnt,
                  leaf_parent_side, leaf_id, zero, leaf_min, leaf_max,
                  zero, zero, zero, root_width if use_fused else zero,
-                 rungs[0], zero, zero, zero)
+                 rungs[0], zero, zero, zero,
+                 root_rows if use_fused else jnp.int32(n))
     out = lax.while_loop(cond, body, init)
 
     # finalize leaf values (reference: CalculateSplittedLeafOutput; clamped
@@ -984,5 +1020,5 @@ def _grow_tree_rounds_traced(
     if with_stats:
         return tree, out.leaf_id, jnp.stack(
             [out.rounds, out.offered, out.applied, out.slots, out.clipped,
-             out.lanes])
+             out.lanes, out.rows * max(cfg.num_machines, 1)])
     return tree, out.leaf_id

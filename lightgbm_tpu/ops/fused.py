@@ -283,13 +283,22 @@ def _fused_call(
     interpret: Optional[bool] = None,
     with_scan: bool = True,
     num_features: Optional[int] = None,   # F of a blocked ``binned_t``
+    live_rows: Optional[jax.Array] = None,    # i32: tiles past it unread
 ):
     """One megakernel invocation; returns ``(slot_hist [K, ch, F, B],
     NumericFeatureBest [NC, F])`` with NC = 2K (parent mode: children are
     [left 0..K-1, right K..2K-1]) or K (leaf mode: the slot histograms
     themselves are scanned).  ``with_scan=False`` drops the epilogue and
     its inputs entirely — the accumulate half of the collective seam —
-    and returns only the histogram."""
+    and returns only the histogram.
+
+    ``live_rows`` (a traced int32, or None): only the row tiles that hold
+    a row before it are read, so the caller's ``slot`` drops every row at
+    or past it in the last of them.  Their count rides in as a
+    scalar-prefetch operand; a grid step past the last of them points its
+    row blocks at that tile (no new DMA) and skips its accumulate, and the
+    epilogue still runs on the grid's last step.  Without it the kernel is
+    traced with no prefetch operand at all."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -299,7 +308,7 @@ def _fused_call(
                 binned_t, vals_t, slot, num_slots, num_bins, None, None,
                 None, feat_tile=feat_tile, block_rows=block_rows,
                 tile_rows=tile_rows, with_scan=False,
-                num_features=num_features)
+                num_features=num_features, live_rows=live_rows)
         with jax.named_scope("lgbm.scan"):
             return hist, fused_sibling_scan(
                 hist, child_sums, *meta_vecs, hp, small_left=small_left,
@@ -344,19 +353,32 @@ def _fused_call(
     st = jnp.pad(slot.astype(jnp.int32), (0, n_pad - n),
                  constant_values=K)[None, :]               # [1, n_pad]
     nt = n_pad // C
+    bounded = live_rows is not None
+    if bounded:
+        # the row tiles that hold a live row (at least one: the grid's
+        # first and last steps always run)
+        live_tiles = jnp.clip((live_rows + C - 1) // C, 1, nt).astype(
+            jnp.int32).reshape(1)
+
+        def tile_of(i, lt):
+            return jnp.minimum(i, lt[0] - 1)
+    else:
+        def tile_of(i):
+            return i
 
     in_arrays = [bt, vt, st]
     in_specs = [
-        pl.BlockSpec((None, Ft, C), lambda j, i: (j, 0, i)),
-        pl.BlockSpec((ch, C), lambda j, i: (0, i)),
-        pl.BlockSpec((1, C), lambda j, i: (0, i)),
+        pl.BlockSpec((None, Ft, C),
+                     lambda j, i, *lt: (j, 0, tile_of(i, *lt))),
+        pl.BlockSpec((ch, C), lambda j, i, *lt: (0, tile_of(i, *lt))),
+        pl.BlockSpec((1, C), lambda j, i, *lt: (0, tile_of(i, *lt))),
     ]
     if with_parent:
         in_arrays.append(parent_hist.astype(acc_dtype))
         in_specs.append(pl.BlockSpec((K, ch, Ft, B),
-                                     lambda j, i: (0, 0, j, 0)))
+                                     lambda j, i, *_: (0, 0, j, 0)))
         in_arrays.append(small_left.astype(jnp.int32)[None, :])  # [1, K]
-        in_specs.append(pl.BlockSpec((1, K), lambda j, i: (0, 0)))
+        in_specs.append(pl.BlockSpec((1, K), lambda j, i, *_: (0, 0)))
     if with_scan:
         num_bin_v, missing_v, default_v = meta_vecs
         meta_rows = [jnp.asarray(num_bin_v, jnp.int32),
@@ -371,23 +393,25 @@ def _fused_call(
         R = int(meta.shape[0])
         sums = jnp.asarray(child_sums, jnp.float32)        # [3, NC]
         in_arrays.append(sums)
-        in_specs.append(pl.BlockSpec((3, NC), lambda j, i: (0, 0)))
+        in_specs.append(pl.BlockSpec((3, NC), lambda j, i, *_: (0, 0)))
         in_arrays.append(_feature_blocked(meta, Ft))
-        in_specs.append(pl.BlockSpec((None, R, Ft), lambda j, i: (j, 0, 0)))
+        in_specs.append(pl.BlockSpec((None, R, Ft),
+                                     lambda j, i, *_: (j, 0, 0)))
         if quant:
             in_arrays.append(
                 jnp.stack([jnp.asarray(quant_scales[0], jnp.float32),
                            jnp.asarray(quant_scales[1],
                                        jnp.float32)])[None, :])
-            in_specs.append(pl.BlockSpec((1, 2), lambda j, i: (0, 0)))
+            in_specs.append(pl.BlockSpec((1, 2), lambda j, i, *_: (0, 0)))
         if has_bounds:
             in_arrays.append(jnp.stack(
                 [jnp.asarray(child_bounds[0], jnp.float32),
                  jnp.asarray(child_bounds[1], jnp.float32)]))   # [2, NC]
-            in_specs.append(pl.BlockSpec((2, NC), lambda j, i: (0, 0)))
+            in_specs.append(pl.BlockSpec((2, NC), lambda j, i, *_: (0, 0)))
 
     def kernel(*refs):
         it = iter(refs)
+        lt_ref = next(it) if bounded else None
         b_ref = next(it)
         v_ref = next(it)
         s_ref = next(it)
@@ -413,7 +437,11 @@ def _fused_call(
         def _init():
             acc[...] = jnp.zeros_like(acc)
 
-        _accumulate_tile(acc, b_ref, v_ref, s_ref, Kp, Ft, Bp, ch, quant)
+        if bounded:
+            pl.when(i < lt_ref[0])(lambda: _accumulate_tile(
+                acc, b_ref, v_ref, s_ref, Kp, Ft, Bp, ch, quant))
+        else:
+            _accumulate_tile(acc, b_ref, v_ref, s_ref, Kp, Ft, Bp, ch, quant)
 
         # ---- epilogue after the last tile: emit the 2-D arena as it
         # sits in VMEM (the [K, ch, F, B] view is an XLA reshape outside
@@ -441,9 +469,9 @@ def _fused_call(
                 lh_ref[...] = res.left_sum_hess
                 lc_ref[...] = res.left_count
 
-    hist_spec = pl.BlockSpec((ch * Kp, Ft * Bp), lambda j, i: (0, j))
+    hist_spec = pl.BlockSpec((ch * Kp, Ft * Bp), lambda j, i, *_: (0, j))
     hist_shape = jax.ShapeDtypeStruct((ch * Kp, F_pad * Bp), acc_dtype)
-    tuple_spec = pl.BlockSpec((None, NC, Ft), lambda j, i: (j, 0, 0))
+    tuple_spec = pl.BlockSpec((None, NC, Ft), lambda j, i, *_: (j, 0, 0))
     if with_scan:
         out_specs = [hist_spec] + [tuple_spec] * 6
         out_shape = [hist_shape] + [
@@ -452,15 +480,19 @@ def _fused_call(
     else:
         out_specs = [hist_spec]
         out_shape = [hist_shape]
+    grid = dict(grid=(nf_blocks, nt), in_specs=in_specs,
+                out_specs=out_specs,
+                scratch_shapes=[pltpu.VMEM((ch * Kp, Ft * Bp), acc_dtype)])
+    if bounded:
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **grid))
+        in_arrays = [live_tiles] + in_arrays
     out = pl.pallas_call(
         kernel,
-        grid=(nf_blocks, nt),
-        in_specs=in_specs,
-        out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((ch * Kp, Ft * Bp), acc_dtype)],
         interpret=_interp(interpret),
         name="lgbm_hist_accum",
+        **grid,
     )(*in_arrays)
     hist = out[0].reshape(ch, Kp, F_pad, Bp)[
         :, :K, :F, :B].transpose(1, 0, 2, 3)               # [K, ch, F, B]
@@ -604,13 +636,17 @@ def fused_frontier_accumulate(
     tile_rows: Optional[int] = None,
     interpret: Optional[bool] = None,
     num_features: Optional[int] = None,
+    live_rows: Optional[jax.Array] = None,
 ) -> jax.Array:
     """The accumulate HALF of the collective seam: build the K
     smaller-child (or slot) histograms in the VMEM arena and emit them —
     no scan, no parent.  Returns ``hist [K, ch, F, B]`` (int32 when
     ``vals_t`` is int8, f32 otherwise).  ``binned_t`` is the ``[F, n]``
     matrix, or its ``fused_blocked_bins`` operand with ``num_features``
-    = F (``feat_tile`` is then the operand's own).
+    = F (``feat_tile`` is then the operand's own).  ``live_rows``: the
+    pass reads only the row tiles that hold a row before it (a buffer
+    whose live rows were gathered to the front; ``slot`` drops the rows
+    past it).
 
     Sharded training runs THIS program per shard, reduces exactly its
     output over the data axes (``psum_int_tiered`` / tiered ``psum``),
@@ -622,7 +658,8 @@ def fused_frontier_accumulate(
     return _fused_call(
         binned_t, vals_t, slot, num_slots, num_bins, None, None, None,
         feat_tile=feat_tile, block_rows=block_rows, tile_rows=tile_rows,
-        interpret=interpret, with_scan=False, num_features=num_features)
+        interpret=interpret, with_scan=False, num_features=num_features,
+        live_rows=live_rows)
 
 
 # Slot widths the rounds grower compiles the accumulate pass at BELOW its
@@ -668,11 +705,15 @@ def frontier_accumulator(
     feat_tile: Optional[int] = None,
     block_rows: Optional[int] = None,   # row tile AT ``kcap`` slots
     tile_rows: Optional[int] = None,
+    live_rows: Optional[jax.Array] = None,
 ):
-    """``accumulate(route, k) -> (hist [kcap, ch, F, B], width, aux)``:
-    one ``fused_frontier_accumulate`` pass at the narrowest compiled slot
-    width ``W`` that holds the ``k`` live slots, zero-padded to ``kcap``
-    slots so whatever follows (the collective, the scan) keeps one shape.
+    """``accumulate(route, k) -> (hist [kcap, ch, F, B], width, rows,
+    aux)``: one ``fused_frontier_accumulate`` pass at the narrowest
+    compiled slot width ``W`` that holds the ``k`` live slots, zero-padded
+    to ``kcap`` slots so whatever follows (the collective, the scan) keeps
+    one shape; ``rows`` is how many of the ``n`` rows it read: ``n``, or
+    with ``live_rows`` (a traced int32: the pass reads only the row tiles
+    before it, ``fused_frontier_accumulate``) those of its live tiles.
     ``route(W) -> (slot, aux)`` makes the pass's ``slot`` ([n] i32 in
     [0, k) or >= k = dropped) inside the branch that runs at ``W``, so a
     router that works at the pass's width shares its switch; ``aux`` is
@@ -705,12 +746,17 @@ def frontier_accumulator(
     tiles = tuple(_row_tile(planned_rows(W), tile_rows, n) for W in widths)
     bins = fused_blocked_bins(binned_t, feat_tile, math.lcm(*tiles))
 
+    def rows_read(C):
+        if live_rows is None:
+            return jnp.int32(n)
+        return jnp.minimum((live_rows + C - 1) // C * C, n).astype(jnp.int32)
+
     def at(W, C, route):
         def run():
             slot, aux = route(W)
             hist = fused_frontier_accumulate(
                 bins, vals_t, jnp.minimum(slot, W), W, num_bins,
-                block_rows=C, num_features=F)
+                block_rows=C, num_features=F, live_rows=live_rows)
             return jnp.pad(hist, ((0, kcap - W),) + ((0, 0),) * 3), aux
         return run
 
@@ -719,9 +765,10 @@ def frontier_accumulator(
         i = sum(k > W for W in widths[:-1])     # the narrowest width >= k
         if isinstance(i, int):                  # static k, or one width
             hist, aux = branches[i]()
-            return hist, jnp.int32(widths[i]), aux
+            return hist, jnp.int32(widths[i]), rows_read(tiles[i]), aux
         hist, aux = lax.switch(i, branches)
-        return hist, jnp.asarray(widths, jnp.int32)[i], aux
+        return (hist, jnp.asarray(widths, jnp.int32)[i],
+                rows_read(jnp.asarray(tiles, jnp.int32)[i]), aux)
 
     return accumulate
 

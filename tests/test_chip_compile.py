@@ -189,6 +189,27 @@ def test_packed_accumulate_compiles_at_the_benchmarks_shapes(one_chip, cols,
     assert _kernels(c) == 1
 
 
+@pytest.mark.parametrize("width", [16, 64, 128])
+def test_bounded_accumulate_compiles_at_the_goss_shape(one_chip, width):
+    """A sampled tree's pass over its kept rows gathered to the front
+    (``live_rows``): the live tile count rides in as a scalar-prefetch
+    operand that the row blocks' index maps clamp to, and the accumulate
+    sits under a ``pl.when``: criteo-goss's 67 columns, 25.2M rows."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.fused import fused_frontier_accumulate
+    cols, rows, ft, tile = 67, 25_165_824, 8, 8192
+    c = _compile(
+        lambda b, v, s, live: fused_frontier_accumulate(
+            b, v, s, width, 64, block_rows=tile, num_features=cols,
+            interpret=False, live_rows=live),
+        _shape(one_chip, (-(-cols // ft), ft, rows), jnp.uint8),
+        _shape(one_chip, (2, rows), jnp.int8),
+        _shape(one_chip, (rows,), jnp.int32),
+        _shape(one_chip, (), jnp.int32))
+    assert _kernels(c) == 1
+
+
 @pytest.mark.parametrize("k,bins,bin_dtype", [
     (30, 63, "uint8"),       # 31 leaves: slots off the sublane tiling
     (128, 255, "uint8"),     # default max_bin: the planner drops to Ft=2

@@ -306,9 +306,13 @@ def test_width_election_matches_the_fixed_width(family, monkeypatch):
     np.testing.assert_allclose(np.asarray(t_el.leaf_value),
                                np.asarray(t_fx.leaf_value),
                                rtol=3e-5, atol=1e-7)
-    rounds, offered, applied, slots, clipped, lanes = (int(v) for v in st_el)
-    rounds_fx, offered_fx, applied_fx, slots_fx, clipped_fx, lanes_fx = (
-        int(v) for v in st_fx)
+    rounds, offered, applied, slots, clipped, lanes, rows = (
+        int(v) for v in st_el)
+    (rounds_fx, offered_fx, applied_fx, slots_fx, clipped_fx, lanes_fx,
+     rows_fx) = (int(v) for v in st_fx)
+    # every pass read every row, at any width
+    assert (rows, rows_fx) == ((rounds + 1) * len(mask),
+                               (rounds_fx + 1) * len(mask))
     # the candidate scan (the CPU's form) passes over the live lanes alone
     assert (lanes, lanes_fx) == (offered, offered_fx)
     assert applied == applied_fx == 254
@@ -338,9 +342,10 @@ def test_root_through_the_kernel_equals_the_staged_root():
     bt = jnp.asarray(binned.T)
     accumulate = FU.frontier_accumulator(
         bt, H._vals_t_int(gq, hq, jnp.asarray(member)), KCAP, B)
-    arena, width, _ = accumulate(
+    arena, width, rows, _ = accumulate(
         lambda W: (jnp.where(jnp.asarray(member), 0, KCAP), None), 1)
     assert int(width) == 16 and arena.shape == (KCAP, 2, F, B)
+    assert int(rows) == len(grad)               # an unbounded pass
     ref = H.build_histogram_int(bt, gq, hq, jnp.asarray(member), B,
                                 levels=H.quant_levels(8))
     assert np.array_equal(np.asarray(arena[0]), np.asarray(ref))
@@ -750,6 +755,35 @@ def test_packed_operands_give_the_numpy_arena(B, feat_tile, padded, K,
     assert got.dtype == jnp.int32
     assert np.array_equal(np.asarray(got),
                           _numpy_arena(binned, vals, slot, K, B))
+
+
+@pytest.mark.parametrize("live", ["inside_a_tile", "on_a_tile_edge",
+                                  "every_row"])
+@pytest.mark.parametrize("K", [16, 64, 128])
+def test_bounded_pass_equals_the_unbounded(K, live):
+    """A pass told its live rows (``live_rows``: a buffer whose kept rows
+    were gathered to the front) reads only the row tiles that hold them:
+    its arena is the unbounded pass's over the same buffer bit for bit,
+    rows past the bound dropped by their slots in the last live tile and
+    left with live slots in the tiles after it, which it must not read."""
+    B, F, C = 63, 17, 256
+    binned, vals, slot = _operand_case(B, F, K, n=5 * C)
+    n = binned.shape[1]
+    bound = {"inside_a_tile": 2 * C + 57, "on_a_tile_edge": 2 * C,
+             "every_row": n}[live]
+    rows = np.arange(n)
+    last_tile_end = -(-bound // C) * C
+    slot = np.where((rows >= bound) & (rows < last_tile_end), K, slot)
+    assert (slot[last_tile_end:] < K).any() or bound == n
+
+    def run(slot, **kw):
+        return np.asarray(FU.fused_frontier_accumulate(
+            jnp.asarray(binned), jnp.asarray(vals), jnp.asarray(slot), K, B,
+            feat_tile=8, block_rows=C, interpret=True, **kw))
+    got = run(slot, live_rows=jnp.int32(bound))
+    assert np.array_equal(got, run(np.where(rows < bound, slot, K)))
+    assert np.array_equal(got, _numpy_arena(
+        binned, vals, np.where(rows < bound, slot, K), K, B))
 
 
 @pytest.mark.parametrize("case,B,feat_tile,padded,bin_dtype,family", [

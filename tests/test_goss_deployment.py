@@ -234,3 +234,140 @@ def test_goss_tree_follows_the_weighted_reference_4_levels():
             + (np.abs(G - step["G"])[live] > 6 * g_scale * sd).sum()
     assert outside(step) == 0
     assert outside(flat) > 0
+
+
+def _tied_data(seed=8, n=2000):
+    """Few distinct rows: their |g*h| tie in runs, so the top set's
+    threshold holds many rows and the round keeps more than top_k +
+    other_k."""
+    rng = np.random.RandomState(seed)
+    X = rng.randint(0, 3, (n, 4)).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] + 2 * rng.rand(n) > 3.0).astype(np.float32)
+    return X, y
+
+
+def _train(params, X, y, rounds, capture=None):
+    """``rounds`` trees, the ``grower.tree`` records and, with ``capture``,
+    what each call of the per-iteration program returned."""
+    global_flight._ring.clear()
+    bst = lgb.Booster(params, lgb.Dataset(X, label=y, params=params))
+    if capture is not None:
+        step = bst.boosting._iter_fn
+
+        def spy(*a):
+            out = step(*a)
+            capture.append(jax.device_get(out))
+            return out
+        bst.boosting._iter_fn = spy
+    for _ in range(rounds):
+        bst.update()
+    return bst, _records()
+
+
+@pytest.mark.parametrize("family, path, rows", [
+    ("4_levels", "chunk", "tied"), ("4_levels", "per_iteration", "spread"),
+    ("f32", "chunk", "spread")])
+def test_kept_rows_grow_the_mask_tree(family, path, rows, monkeypatch):
+    """On the rounds grower's fused arm a sampled tree grows on its kept
+    rows gathered to the front of a row buffer (``kept_rows_first``), its
+    passes and routes stopping at the last of them; against the mask form
+    (every row under the weight mask), the trees are equal field for
+    field, every row's leaf is the same, the train score is the same to
+    the bit, and so are the weights the trees were grown on: in the chunk
+    program and on the per-iteration path, and where ties at the top
+    threshold keep more rows than top_k + other_k.  Under 4 levels the
+    passes read only the kept rows' tiles; at f32 the sums would take
+    another order, so the tree keeps the mask form and reads every row."""
+    from lightgbm_tpu.boosting.goss import GOSS
+    from lightgbm_tpu.ops import fused
+    # row tiles of 512, so that the 2,048-row buffer holds four
+    monkeypatch.setattr(fused, "_row_tile", lambda *_: 512)
+    monkeypatch.setenv("LGBM_TPU_CHUNK", "" if path == "chunk" else "0")
+    X, y = _tied_data() if rows == "tied" else _data(4, n=2000)
+    params = dict(BASE, learning_rate=0.5, tpu_tree_growth="rounds",
+                  tpu_hist_method="fused",
+                  use_quantized_grad=family == "4_levels",
+                  num_grad_quant_bins=4)
+    runs = []
+    for sampled in (True, False):
+        monkeypatch.setattr(GOSS, "_rows_sampled", sampled)
+        calls = [] if path == "per_iteration" else None
+        bst, recs = _train(params, X, y, 4, calls)
+        runs.append((bst, recs, calls))
+    (gathered, recs, calls), (masked, recs_m, calls_m) = runs
+    assert gathered.model_to_string() == masked.model_to_string()
+    for name in ("train_score", "last_row_weights"):
+        assert np.array_equal(np.asarray(getattr(gathered.boosting, name)),
+                              np.asarray(getattr(masked.boosting, name)))
+    if calls is not None:
+        assert len(calls) == len(calls_m) == 4
+        for got, want in zip(calls, calls_m):
+            for a, b in zip(jax.tree_util.tree_leaves(got[:3]),
+                            jax.tree_util.tree_leaves(want[:3])):
+                assert np.array_equal(a, b)          # score, trees, leaves
+    n_pad = gathered.boosting._n_pad
+    top_k, other_k, _ = ref_goss.sizes(len(y), 0.2, 0.1)
+    assert [r["goss_kept"] for r in recs] == [r["goss_kept"] for r in recs_m]
+    for r, r_m in zip(recs, recs_m):
+        passes = r["rounds"] + 1
+        assert r_m["pass_rows"] == passes * n_pad      # the mask: every row
+        if family == "f32":
+            assert r["pass_rows"] == passes * n_pad
+            continue
+        # whole row tiles of the kept rows (a warm-up round: every row)
+        live = r["goss_kept"] or len(y)
+        assert passes * live <= r["pass_rows"] <= passes * n_pad
+        if r["goss_kept"]:
+            assert r["pass_rows"] < passes * n_pad
+    assert all(r["goss_kept"] for r in recs[2:])
+    if rows == "tied":
+        assert all(r["goss_kept"] > top_k + other_k for r in recs[2:])
+
+
+def _chunk_jaxpr(params):
+    """The chunk program of a booster on ``params``, as a jaxpr."""
+    from lightgbm_tpu.boosting.macro import chunk_host_inputs, make_chunk_fn
+    X, y = _data(5, n=1000)
+    b = lgb.Booster(params, lgb.Dataset(X, label=y, params=params)).boosting
+    b.boost_from_average()
+    xs, _ = chunk_host_inputs(b, 1)
+    cu, cr = b._cegb_state
+    gc, hc = b._macro_const_grads()
+    return b._n_pad, jax.make_jaxpr(make_chunk_fn(b))(
+        b.binned, b.train_score, cu, cr, np.int32(1), xs,
+        b._macro_ctx["label"], b._macro_ctx["weight"], gc, hc,
+        b._macro_ctx["obj_tables"])
+
+
+def _eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("boosting", ["gbdt", "goss"])
+def test_plain_chunk_program_gathers_no_row(boosting):
+    """The gather and the bound are the sampled booster's alone: a plain
+    booster's chunk program holds no gather over the rows of the matrix
+    and no accumulate kernel with a scalar-prefetch operand, which the
+    GOSS program on the same arm holds both of."""
+    params = dict(BASE, boosting=boosting, tpu_tree_growth="rounds",
+                  tpu_hist_method="fused", use_quantized_grad=True,
+                  num_grad_quant_bins=4)
+    n_pad, closed = _chunk_jaxpr(params)
+    eqns = list(_eqns(closed.jaxpr))
+    row_gathers = [e for e in eqns if e.primitive.name == "gather"
+                   and n_pad in e.invars[0].aval.shape
+                   and e.invars[1].aval.shape[0] == n_pad]
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert kernels
+    prefetch = [e for e in kernels
+                if e.params["grid_mapping"].num_index_operands]
+    if boosting == "gbdt":
+        assert not row_gathers and not prefetch
+    else:
+        assert row_gathers and prefetch

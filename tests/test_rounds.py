@@ -474,17 +474,78 @@ def test_router_matmul_matches_scan(problem, monkeypatch, case):
                                       np.asarray(getattr(t_rt, name)),
                                       err_msg=name)
     np.testing.assert_array_equal(np.asarray(lid_scan), np.asarray(lid_rt))
-    rounds, offered, applied, slots, clipped, lanes = (
+    rounds, offered, applied, slots, clipped, lanes, rows = (
         int(v) for v in st_rt)
     np.testing.assert_array_equal(np.asarray(st_scan)[:5],
                                   np.asarray(st_rt)[:5])
     assert int(st_scan[5]) == offered            # the scan: live lanes
+    # every pass read every row: the root's and one a round
+    assert rows == int(st_scan[6]) == (rounds + 1) * len(lid_rt)
     if case == "rungs":
         # the router's lanes are the pass's widths, the root's aside
         assert lanes == slots - 16 and lanes < 128 * rounds
         assert rounds > 8
     else:                                        # staged: the cap
         assert lanes == (16 if case == "narrow" else 30) * rounds
+
+
+def _route_round(seed, n, F, bins, k, W):
+    """A random numeric carry as a round sees it: rows in 63 leaves, every
+    leaf with a cached split, the round's candidates in a random order,
+    ``k`` of the ``W`` lanes live."""
+    from lightgbm_tpu.grower import _LeafBest
+    rng = np.random.default_rng(seed)
+    L = 63
+    layout = tuple(jnp.asarray(a, jnp.int32) for a in (
+        np.full(F, bins - 1), rng.integers(0, 3, F),
+        rng.integers(0, bins - 1, F), np.arange(F), np.ones(F)))
+    best = _LeafBest.empty(L)._replace(
+        feature=jnp.asarray(rng.integers(0, F, L), jnp.int32),
+        threshold=jnp.asarray(rng.integers(0, bins - 1, L), jnp.int32),
+        default_left=jnp.asarray(rng.random(L) < 0.5),
+        left_count=jnp.asarray(rng.integers(1, 9, L), jnp.float32),
+        right_count=jnp.asarray(rng.integers(1, 9, L), jnp.float32))
+    idl = jnp.asarray(rng.permutation(L)[:W], jnp.int32)
+    return (jnp.asarray(rng.integers(0, bins, (F, n)).astype(np.uint8)),
+            jnp.asarray(rng.integers(0, L, n), jnp.int32), idl,
+            jnp.int32(k), best, layout)
+
+
+@pytest.mark.parametrize("live", ["inside_a_block", "on_a_block_edge",
+                                  "every_row"])
+@pytest.mark.parametrize("form", ["lanes", "scan"])
+def test_route_stops_at_the_live_rows(monkeypatch, form, live):
+    """``route_lanes`` and ``route_scan`` told the buffer's live rows
+    (``live_rows``) give every row before the bound what the unbounded call
+    gives, and put every row at or past it in no lane and no slot: the
+    bound inside a block of the router, on a block's edge, and at the last
+    row."""
+    import lightgbm_tpu.grower_rounds as GR
+    monkeypatch.setattr(GR, "ROUTE_BLOCK", 700)
+    n, bins, W, kcap = 5000, 32, 16, 16
+    args = _route_round(11, n, 7, bins, 13, W)
+    bound = {"inside_a_block": 2 * 700 + 311, "on_a_block_edge": 3 * 700,
+             "every_row": n}[live]
+
+    def route(**kw):
+        if form == "lanes":
+            out = GR.route_lanes(*args[:4], W, args[4], kcap, args[5], bins,
+                                 0, **kw)
+        else:
+            out = GR.route_scan(*args[:4], args[4], kcap, args[5], False,
+                                **kw)
+        return [np.asarray(a) for a in out]
+    crank, gl, slot = route()
+    crank_b, gl_b, slot_b = route(live_rows=jnp.int32(bound))
+    on = np.arange(n) < bound
+    assert np.array_equal(crank_b[on], crank[on])
+    assert np.array_equal(slot_b[on], slot[on])
+    held = on & (crank < kcap)
+    assert np.array_equal(gl_b[held], gl[held])
+    assert (crank_b[~on] == kcap).all() and (slot_b[~on] == kcap).all()
+    # the carry exercises what it should: rows in lanes and out of them
+    assert 0 < held.sum() < on.sum()
+    assert (crank[~on] < kcap).any() or bound == n
 
 
 @pytest.mark.parametrize("sharded", [False, True],
@@ -543,8 +604,9 @@ def test_rounds_width_election_equals_serial_quantized(sharded):
                                       np.asarray(getattr(t_r, name)),
                                       err_msg=name)
     np.testing.assert_array_equal(np.asarray(lid_s), np.asarray(lid_r))
-    rounds, offered, _, slots, clipped, lanes = (int(v) for v in stats)
+    rounds, offered, _, slots, clipped, lanes, rows = (int(v) for v in stats)
     assert lanes == offered             # the scan: the live lanes alone
+    assert rows == (rounds + 1) * len(lid_r)    # every pass, every row
     # the root at 16, then every round at the rung its offer named: with
     # the offer following the commits, under 64 slots a round on average
     assert offered <= slots - 16 < 64 * rounds
@@ -607,7 +669,9 @@ def test_offer_equals_serial_quantized(gains):
                                       np.asarray(getattr(t_r, name)),
                                       err_msg=name)
     np.testing.assert_array_equal(np.asarray(lid_s), np.asarray(lid_r))
-    rounds, offered, applied, slots, clipped, lanes = (int(v) for v in stats)
+    rounds, offered, applied, slots, clipped, lanes, rows = (
+        int(v) for v in stats)
+    assert rows == (rounds + 1) * len(lid_r)
     assert applied == int(t_r.num_leaves) - 1
     assert lanes == offered
     narrow, cap = FU.NARROW_SLOT_WIDTHS[0], 128
@@ -650,8 +714,10 @@ def test_offer_climbs_when_the_rounds_fill_the_rung(monkeypatch):
     assert int(t_of.num_leaves) == int(t_fx.num_leaves) == 255
     np.testing.assert_array_equal(np.sort(np.asarray(t_of.leaf_count)),
                                   np.sort(np.asarray(t_fx.leaf_count)))
-    rounds, offered, applied, slots, clipped, lanes = (int(v) for v in st_of)
+    rounds, offered, applied, slots, clipped, lanes, rows = (
+        int(v) for v in st_of)
     assert lanes == offered
+    assert rows == int(st_fx[6]) == (rounds + 1) * n
     assert (rounds, offered, applied) == tuple(int(v) for v in st_fx[:3])
     assert (rounds, offered, applied, clipped) == (8, 254, 254, 0)
     # root, 1, 2, 4, 8, 16 at 16 slots; 32 and 64 at 64; 127 at the cap

@@ -218,6 +218,8 @@ def test_serial_grower_counts_one_split_a_trip():
     assert t["slots"] == splits          # a pass one slot wide a split
     assert t["clipped"] == 0             # and no offer to clip
     assert t["lanes"] == splits          # rows routed against one split
+    # every row in each pass: the root's and one a split
+    assert t["pass_rows"] == (splits + 1) * bst.boosting._n_pad
 
 
 @pytest.mark.parametrize("method", ["scatter", "fused"])
@@ -248,6 +250,9 @@ def test_grower_tree_carries_slots(method):
         # the sixth: the lanes the route compared rows with (the CPU's
         # candidate scan passes over the live ones alone)
         assert t["lanes"] == t["offered"]
+        # the seventh: the rows the passes read, every row of a plain
+        # booster's matrix in each
+        assert t["pass_rows"] == (t["rounds"] + 1) * bst.boosting._n_pad
         if method == "fused":
             # root at 16, then each round at the rung its offer named:
             # 16 or the cap (62 < 64)
@@ -288,8 +293,10 @@ def test_counters_leave_the_tree_as_it_was():
                     jax.tree_util.tree_leaves(t1)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_array_equal(np.asarray(lid0), np.asarray(lid1))
-    rounds, offered, applied, slots, clipped, lanes = (int(v) for v in stats)
+    rounds, offered, applied, slots, clipped, lanes, rows = (
+        int(v) for v in stats)
     assert lanes == offered             # the scan: the live lanes alone
+    assert rows == (rounds + 1) * n     # the staged passes: every row
     assert applied == int(t1.num_leaves) - 1
     assert 1 <= rounds <= applied <= offered
     assert 0 <= clipped <= rounds
